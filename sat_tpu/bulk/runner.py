@@ -273,8 +273,6 @@ def run_bulk(config: Config, model_file: Optional[str] = None) -> int:
         return 0
 
     # ---- decode-plane boot (mirrors serve.server.serve) --------------
-    import jax
-
     tel = telemetry.get()
     if not tel.enabled:
         # bulk always records: the zero-recompile assertion and the
@@ -283,10 +281,6 @@ def run_bulk(config: Config, model_file: Optional[str] = None) -> int:
     from ..runtime import _install_compile_listener
 
     _install_compile_listener()
-    from ..utils.compile_cache import enable as _enable_compile_cache
-
-    _enable_compile_cache(jax, name=".jax_cache", min_compile_time_secs=0.5)
-
     from ..data.shards import resolve_shard_cache
     from ..data.vocabulary import Vocabulary
     from ..serve.engine import ServeEngine, load_serving_state

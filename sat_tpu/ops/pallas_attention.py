@@ -31,15 +31,14 @@ VMEM budget per program at flagship shapes (N=196→200, da=D=512, block_b=8,
 fp32): t1 3.3 MB + contexts 3.3 MB + outputs ≈ 6.8 MB — comfortably inside
 the ~16 MB/core budget (see /opt/skills/guides/pallas_guide.md).
 
-Measured on the real v5e chip (scripts/bench_pallas.py, on-device
-fori_loop timing, B=48 flagship shapes): ~400 µs vs 421-474 µs for XLA's
-fusion across runs (1.06-1.17x), with strictly better numerics — context
-max-error 9.5e-7 vs the XLA path's 1.7e-2 against an fp32 ground truth
-(the kernel's softmax and weighted-sum run in full fp32 on the VPU,
-whereas the XLA path's fp32 einsum lowers to default-precision bf16 MXU
-passes).  block_b=8 wins the {4, 8, 16} sweep (4 fails Mosaic's
-sublane-divisibility rule).  Enabled by default via
-config.use_pallas_attention.
+Speed against XLA's fusion: not measured on the current machine
+(scripts/bench_pallas.py is the vehicle; PERF.md keeps what an earlier
+machine showed).  The kernel's softmax and weighted sum run in full fp32
+on the VPU, whereas the XLA path's fp32 einsum lowers to
+default-precision bf16 MXU passes; on the chip the two agree to ~2e-3 in
+the context vector at flagship shapes (chip_smoke.py prints the figure).
+block_b=4 fails Mosaic's sublane-divisibility rule.  Enabled by default
+via config.use_pallas_attention.
 """
 
 from __future__ import annotations

@@ -1,11 +1,10 @@
 """Crash-only supervisor: restart a wedged/crashed run from LAST_GOOD.
 
-``scripts/tpu_retry.sh`` grew ad-hoc restart logic because nothing in
-the runtime could do it; this module is that logic as a first-class
-subsystem.  ``python -m sat_tpu.cli --supervise ...`` keeps the parent
-process **jax-free forever** (the r02/r05 failure was ``import jax`` +
-device init hanging uninterruptibly — the supervisor must outlive
-exactly that) and runs the real work in a child process:
+``python -m sat_tpu.cli --supervise ...`` keeps the parent process
+**jax-free forever** — a chip belongs to one process at a time, so a
+parent that touched the device stack would hold the chip its child
+needs, and a parent without it outlives any failure of the device
+runtime — and runs the real work in a child process:
 
 * the child is the identical CLI invocation minus ``--supervise``;
 * a nonzero child exit — the watchdog's ``WATCHDOG_EXIT_CODE`` (wedged,

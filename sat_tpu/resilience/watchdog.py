@@ -1,8 +1,7 @@
 """Zero-sync progress watchdog: detect a wedged run, dump, abort.
 
-The one failure mode PR 2's resilience layer cannot touch is the backend
-wedging *silently* — BENCH_r05 died with four consecutive probe timeouts
-and zero metrics because a hung dispatch makes no progress and raises
+The one failure mode PR 2's resilience layer cannot touch is the run
+wedging *silently*: a hung dispatch makes no progress and raises
 nothing.  This module watches the run from a side thread and escalates
 when a tracked phase stops completing:
 
@@ -47,10 +46,10 @@ from .. import telemetry
 
 # Distinct from every exit code already in the fleet's vocabulary:
 # 0 clean, 1 checkpoint-write/preemption failure, 2 pytest/argparse,
-# 3 bench-child watchdog + check_regression infra-skip, 4 bench
-# orchestrator gave up, 87 systemic data corruption (the quarantine
-# ceiling — resilience/quarantine.py; the supervisor must NOT restart it).  The supervisor treats this one as "wedged,
-# state on disk is good, restart me".
+# 3 check_regression infra-skip, 87 systemic data corruption (the
+# quarantine ceiling — resilience/quarantine.py; the supervisor must NOT
+# restart it).  The supervisor treats this one as "wedged, state on disk
+# is good, restart me".
 WATCHDOG_EXIT_CODE = 86
 
 # watchdog/state gauge values (heartbeat.json renders the raw number)

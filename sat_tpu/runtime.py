@@ -365,21 +365,16 @@ def _telemetry_begin(config: Config):
 
 def _device_static() -> dict:
     """Heartbeat ``static`` device facts: backend plus the first local
-    device's kind/platform (degrades to just the backend when device
-    objects don't expose them)."""
-    static = {
+    device's kind/platform."""
+    d0 = jax.local_devices()[0]
+    return {
         "backend": jax.default_backend(),
         "num_devices": jax.device_count(),
         "process_index": jax.process_index(),
         "process_count": jax.process_count(),
+        "device_kind": d0.device_kind,
+        "device_platform": d0.platform,
     }
-    try:
-        d0 = jax.local_devices()[0]
-        static["device_kind"] = d0.device_kind
-        static["device_platform"] = d0.platform
-    except Exception:
-        pass
-    return static
 
 
 def _fleet_gather(vec):
@@ -402,17 +397,13 @@ def _fleet_gather(vec):
 
 def _device_memory_sampler():
     """Heartbeat sampler: per-device HBM bytes-in-use via the backend's
-    ``memory_stats()``.  CPU devices return None (or raise) — the sampler
-    then contributes nothing and the heartbeat degrades gracefully, per
-    docs/OBSERVABILITY.md."""
+    ``memory_stats()``.  CPU devices return None — the sampler then
+    contributes nothing, per docs/OBSERVABILITY.md."""
 
     def sample() -> dict:
         per: dict = {}
         for d in jax.local_devices():
-            try:
-                stats = d.memory_stats()
-            except Exception:
-                stats = None
+            stats = d.memory_stats()
             if stats and "bytes_in_use" in stats:
                 per[str(d.id)] = int(stats["bytes_in_use"])
         return {"hbm_bytes_in_use": per} if per else {}

@@ -42,6 +42,17 @@ from typing import Any, Dict, Hashable, List, Optional, Sequence, Tuple
 import numpy as np
 
 
+def gather_rows(store, idx):
+    """The hit path: rows ``idx`` of the ring."""
+    return store[idx]
+
+
+def insert_rows(store, ctx, idx):
+    """The miss path: write ``ctx`` into rows ``idx`` of the ring.
+    Duplicate scratch indices are fine: scratch is write-only."""
+    return store.at[idx].set(ctx)
+
+
 class CachePlan(object):
     """One chunk's resolved lookup: a ring row per request, plus the
     unique misses that must be encoded (first occurrence wins; repeats
@@ -147,18 +158,11 @@ class EncodeCache(object):
             (self.rows + 1,) + self.row_shape, self.row_dtype
         )
 
-        def gather_fn(store, idx):
-            return store[idx]
-
-        def insert_fn(store, ctx, idx):
-            # duplicate scratch indices are fine: scratch is write-only
-            return store.at[idx].set(ctx)
-
-        gather_jit = jax.jit(gather_fn)
+        gather_jit = jax.jit(gather_rows)
         # the store is donated so an insert rewrites the ring in place
         # instead of copying capacity_mb per miss chunk (a no-op warning
         # on backends without donation, e.g. the CPU test container)
-        insert_jit = jax.jit(insert_fn, donate_argnums=0)
+        insert_jit = jax.jit(insert_rows, donate_argnums=0)
         for w in widths:
             w = int(w)
             if w in self._gather_execs:

@@ -27,6 +27,7 @@ which is also how tests assert zero recompiles during the request phase.
 
 from __future__ import annotations
 
+import os
 import sys
 import threading
 import time
@@ -126,6 +127,18 @@ class ServeEngine:
         self.eos_id = vocabulary.word2idx["."]
         self._tel = tel if tel is not None else telemetry.get()
         self.step = int(np.asarray(state.step))  # sync-ok: startup, before any request traffic
+        # which device this replica answers on (/stats "engine.device"):
+        # a one-chip fleet replica sees its chip as local device 0
+        # whichever chip it is, so the launcher's assignment rides along
+        import jax
+
+        d0 = jax.local_devices()[0]
+        self.device = {
+            "platform": d0.platform,
+            "kind": d0.device_kind,
+            "id": d0.id,
+            "chip": os.environ.get("TPU_VISIBLE_CHIPS", ""),
+        }
         self._variables: Dict[str, Any] = {"params": state.params}
         if state.batch_stats:
             self._variables["batch_stats"] = state.batch_stats

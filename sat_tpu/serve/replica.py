@@ -13,15 +13,19 @@ owns how those replicas come to exist and die.  Two modes:
   ``host:port,host:port`` spec into the same :class:`Endpoint` records
   the router polls; lifecycle stays with whoever started them.
 
-Deliberately jax-free (enforced by tests/test_device_diag.py): the
-router process must survive exactly the failures a wedged accelerator
-runtime causes, so — like the ``--supervise`` parent — it never imports
-the device stack.  Subprocesses inherit the environment, so a
-``JAX_PLATFORMS=cpu`` run spawns CPU replicas.
+Deliberately jax-free (enforced by tests/test_device_diag.py): a chip
+belongs to one process at a time, so the process that launches the
+replicas must hold none — like the ``--supervise`` parent, it never
+imports the device stack, and it outlives a replica whose device
+runtime dies.  On a TPU host :class:`LocalFleet` gives every replica
+exactly one chip through the child's environment and refuses to start
+more replicas than there are chips; subprocesses otherwise inherit the
+environment, so a ``JAX_PLATFORMS=cpu`` run spawns CPU replicas.
 """
 
 from __future__ import annotations
 
+import glob
 import http.client
 import json
 import os
@@ -96,6 +100,45 @@ def parse_endpoints(spec: str) -> List[Endpoint]:
     if not endpoints:
         raise ValueError(f"--replicas {spec!r} names no endpoints")
     return endpoints
+
+
+# PCI identity of a TPU chip (the ids jax._src.hardware_utils scans for;
+# read from sysfs here so counting chips never imports jax)
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICES = (
+    "0x0027", "0x0056", "0x005e", "0x0062", "0x0063", "0x006f", "0x0076",
+)
+# which of the host's chips a process may open, and the shape of the
+# one-chip "slice" it then forms on its own
+CHIP_ENV = "TPU_VISIBLE_CHIPS"
+_ONE_CHIP_BOUNDS = {
+    "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+    "TPU_PROCESS_BOUNDS": "1,1,1",
+}
+
+
+def local_tpu_chips(env: Optional[Dict[str, str]] = None) -> List[str]:
+    """Indices of the TPU chips a child of this process could be given:
+    the ones an outer ``TPU_VISIBLE_CHIPS`` names, else every chip on the
+    host's PCI bus.  Empty when ``JAX_PLATFORMS`` keeps children off the
+    TPU or the host has none."""
+    env = os.environ if env is None else env
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return []
+    if env.get(CHIP_ENV):
+        return [c.strip() for c in env[CHIP_ENV].split(",") if c.strip()]
+    count = 0
+    for vendor_path in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        try:
+            with open(vendor_path) as f:
+                if f.read().strip() != _GOOGLE_PCI_VENDOR:
+                    continue
+            with open(os.path.join(os.path.dirname(vendor_path), "device")) as f:
+                count += f.read().strip() in _TPU_PCI_DEVICES
+        except OSError:
+            continue
+    return [str(i) for i in range(count)]
 
 
 def free_port(host: str = "127.0.0.1") -> int:
@@ -179,7 +222,13 @@ class LocalFleet:
     under ``<root>/replica_<i>/``) and a config JSON recording exactly
     what it ran — the same auditability contract as ``--config`` runs.
     Params load through the shared ``save_dir`` lineage, so every
-    replica serves the same LAST_GOOD step."""
+    replica serves the same LAST_GOOD step.
+
+    One process for each chip: on a TPU host replica ``i`` is started
+    with ``TPU_VISIBLE_CHIPS`` naming the ``i``-th available chip (a
+    respawn keeps its index, hence its chip), and a fleet larger than
+    the host's chip count is refused before anything is spawned —
+    children left to the default would each try to take every chip."""
 
     def __init__(
         self,
@@ -206,6 +255,13 @@ class LocalFleet:
             if tier not in TIERS:
                 raise ValueError(f"tier {tier!r}: must be one of {TIERS}")
         self.replicas: List[ReplicaProcess] = []
+        self.chips = local_tpu_chips({**os.environ, **(env or {})})
+        if self.chips and count > len(self.chips):
+            raise ValueError(
+                f"{count} TPU replicas asked for, but this host has "
+                f"{len(self.chips)} chip(s) to give "
+                f"({CHIP_ENV}={','.join(self.chips)}): one process per chip"
+            )
         os.makedirs(root, exist_ok=True)
         ports = (
             [base_port + i for i in range(count)]
@@ -240,17 +296,17 @@ class LocalFleet:
         cfg_path = os.path.join(workdir, "serve_config.json")
         cfg.save(cfg_path)
         log_path = os.path.join(workdir, "serve.log")
+        env = {**os.environ, **(self.env or {})}
+        if self.chips:
+            env.update(_ONE_CHIP_BOUNDS)
+            env[CHIP_ENV] = self.chips[index]
         log = open(log_path, "ab")
         try:
             popen = subprocess.Popen(
                 [sys.executable, "-m", "sat_tpu.cli", "--config", cfg_path],
                 stdout=log,
                 stderr=subprocess.STDOUT,
-                env=(
-                    {**os.environ, **self.env}
-                    if self.env is not None
-                    else None
-                ),
+                env=env,
             )
         finally:
             log.close()  # the child holds its own descriptor

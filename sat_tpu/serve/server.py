@@ -1069,6 +1069,7 @@ class CaptionServer:
         # encode timing (batch mode records per-bucket lanes, continuous
         # mode per admission-lane width; both feed the aggregate span)
         engine_block: Dict[str, Any] = {
+            "device": self.engine.device,
             "encoder_quant": self.engine.encoder_quant,
             "quantize_seconds": round(self.engine.quantize_seconds, 3),
         }
@@ -1388,8 +1389,6 @@ def serve(config: Config, model_file: Optional[str] = None) -> int:
     """CLI entry point: ``python -m sat_tpu.cli --phase serve``.
 
     Lineage load → AOT bucket warmup → listen → drain on SIGTERM."""
-    import jax
-
     tel = telemetry.get()
     if not tel.enabled:
         # /stats and /healthz are part of the serving contract: spans and
@@ -1399,9 +1398,6 @@ def serve(config: Config, model_file: Optional[str] = None) -> int:
     from ..runtime import _install_compile_listener
 
     _install_compile_listener()
-    from ..utils.compile_cache import enable as _enable_compile_cache
-
-    _enable_compile_cache(jax, name=".jax_cache", min_compile_time_secs=0.5)
 
     vocabulary = Vocabulary(config.vocabulary_size, config.vocabulary_file)
     state, source = load_serving_state(config, model_file=model_file)

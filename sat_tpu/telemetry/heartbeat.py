@@ -1,8 +1,8 @@
 """Run-health heartbeat: an atomically replaced JSON file watchers can poll.
 
 A TPU run on preemptible capacity is usually observed from the *outside*
-— a supervisor shell (``scripts/tpu_retry.sh``-style), a bench
-orchestrator, a human with ``watch jq``.  Log files answer "what
+— the ``--supervise`` parent, a fleet router, a human with
+``watch jq``.  Log files answer "what
 happened"; the heartbeat answers "is it alive RIGHT NOW and how fast":
 one small JSON object (``heartbeat.json``), rewritten in place with
 tmp+rename every ``interval_s`` seconds by a daemon thread, holding

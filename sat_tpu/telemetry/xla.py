@@ -13,15 +13,15 @@ answer "did the working set grow" without a profiler window.
 ``analyze()`` uses the AOT path (``fn.lower(*args).compile()``) *before*
 the loop's first dispatch: lowering against live arguments does not
 consume donated buffers, and the lower/compile caches (plus the
-persistent compile cache ``__graft_entry__`` enables) are shared with
+persistent compile cache the CLI enables) are shared with
 the normal call path, so the real first step reuses the executable
 instead of compiling twice.
 
 Like ``device.py`` this module imports jax and is therefore NOT imported
 eagerly by the package ``__init__`` (the core telemetry package stays
 jax-free); runtime imports it directly and only when telemetry is on.
-Every probe degrades: a backend without ``memory_analysis`` (CPU) just
-leaves those fields null, and no failure here may take the run down.
+A program that fails to compile here is reported and skipped — the real
+dispatch right after raises the same error where it belongs.
 """
 
 from __future__ import annotations
@@ -97,37 +97,21 @@ def analyze(name: str, jitted, *args, tel=None, **kwargs) -> Optional[Dict]:
         )
         return None
 
+    ca = compiled.cost_analysis()
+    ma = compiled.memory_analysis()
     entry: Dict[str, Any] = {
         "lower_seconds": round(t1 - t0, 3),
         "compile_seconds": round(t2 - t1, 3),
         "argument_bytes_host_estimate": _arg_bytes(args, kwargs),
-        "cost": None,
-        "memory": None,
+        "cost": {
+            k.replace(" ", "_"): float(ca[k]) for k in _COST_KEYS if k in ca
+        },
+        "memory": {
+            attr.replace("_size_in_bytes", "_bytes"): int(getattr(ma, attr))
+            for attr in _MEMORY_ATTRS
+        },
         "donation": None,
     }
-
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):       # per-device list on older jax
-            ca = ca[0] if ca else None
-        if ca:
-            entry["cost"] = {
-                k.replace(" ", "_"): float(ca[k]) for k in _COST_KEYS if k in ca
-            }
-    except Exception:
-        pass
-
-    try:
-        ma = compiled.memory_analysis()
-        if ma is not None:
-            mem = {}
-            for attr in _MEMORY_ATTRS:
-                v = getattr(ma, attr, None)
-                if v is not None:
-                    mem[attr.replace("_size_in_bytes", "_bytes")] = int(v)
-            entry["memory"] = mem or None
-    except Exception:
-        pass  # CPU backends may not implement memory analysis
 
     try:
         import jax

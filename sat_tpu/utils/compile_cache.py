@@ -1,75 +1,42 @@
-"""Per-machine persistent XLA compilation cache location.
+"""Where the persistent XLA compilation cache lives.
 
-Every long-lived entry point (bench.py, __graft_entry__ dryrun, the test
-suite, quality/finetune scripts) persists compiled programs so re-runs
-skip the 20-40s (TPU) / minutes (CPU dp+tp step) XLA compile.  The cache
-key XLA uses does NOT include the host's CPU feature set, so a cache
-directory shared across heterogeneous build boxes makes XLA:CPU load
-AOT results compiled for a different machine — each load survives but
-logs a multi-KB "machine features don't match" warning, which buried the
-multichip-dryrun tail under ~4KB of spew per program (VERDICT r04 weak
-#7).  Keying the directory by a fingerprint of the execution machine
-gives each box its own cache: correct reuse, silent tails.
+One rule for every entry point (the CLI phases, bench.py, the test suite,
+the long-running scripts): when ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+reads it itself and the program sets no directory in code; otherwise the
+cache is ``<repo>/.jax_cache``.  The directory is part of XLA's cache key,
+so it must not move between runs — hence one fixed path inside the
+checkout and no per-machine component.
 """
 
 from __future__ import annotations
 
-import hashlib
 import os
-import platform
 
-__all__ = ["machine_tag", "cache_dir", "enable"]
+__all__ = ["ENV_VAR", "DEFAULT_DIR", "cache_dir", "enable"]
 
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
-
-def machine_tag() -> str:
-    """A short stable fingerprint of this machine's CPU feature set.
-
-    XLA:CPU AOT results embed the compile machine's features; loading
-    them on a host with a different set warns per program.  The 'flags'
-    line of /proc/cpuinfo captures exactly that set on Linux; elsewhere
-    fall back to the coarse architecture string.
-    """
-    basis = platform.machine()
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith(("flags", "Features")):
-                    basis += ":" + line.split(":", 1)[1].strip()
-                    break
-    except OSError:
-        pass
-    return hashlib.sha1(basis.encode()).hexdigest()[:12]
+_REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+DEFAULT_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 
 
-def cache_dir(name: str = ".jax_compile_cache", root: str | None = None) -> str:
-    """Machine-keyed cache directory ``<root>/<name>/<machine_tag>``."""
-    return os.path.join(root or _REPO_ROOT, name, machine_tag())
+def cache_dir() -> str:
+    """The directory in use: the outer setting when there is one, else
+    the fixed path inside the checkout."""
+    return os.environ.get(ENV_VAR) or DEFAULT_DIR
 
 
-def enable(
-    jax,
-    name: str = ".jax_compile_cache",
-    root: str | None = None,
-    min_compile_time_secs: float = 0.0,
-) -> str:
-    """Point jax's persistent compilation cache at the per-machine dir.
+def enable(jax) -> str:
+    """Switch the persistent compilation cache on and return its directory.
+    Programs that compile in under half a second are not worth an entry.
 
     Takes the jax module as an argument so importing this helper never
-    imports jax (bench.py's orchestrator process must stay jax-free).
-    Returns the directory used; raises nothing — cache enablement is
-    always best-effort.
-    """
-    path = cache_dir(name, root)
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", min_compile_time_secs
-        )
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception as e:
-        import sys
-
-        print(f"compilation cache not enabled: {e!r}", file=sys.stderr)
-    return path
+    imports jax (the ``--supervise`` and ``--phase route`` parents stay
+    jax-free)."""
+    if not os.environ.get(ENV_VAR):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return cache_dir()

@@ -50,15 +50,9 @@ def log(msg: str) -> None:
 
 
 def _child_env():
-    from sat_tpu.utils.compile_cache import cache_dir
-
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir(".jax_cache")
-    env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0.5"
-    env["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "0"
-    env["SAT_DEVICE_WATCHDOG_S"] = "0"
     return env
 
 

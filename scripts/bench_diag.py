@@ -51,7 +51,8 @@ def main() -> int:
                     help="interleaved measurement rounds per arm")
     ap.add_argument("--step-ms", type=float, default=30.0,
                     help="production device step the overhead is scored "
-                         "against (BASELINE.json: ~30 ms)")
+                         "against (~30 ms; not measured on the "
+                         "current machine)")
     args = ap.parse_args()
 
     log("importing jax")

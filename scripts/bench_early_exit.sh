@@ -5,15 +5,14 @@
 # random init never does — so train a quick flagship-shape model on the
 # self-contained corpus, then run scripts/bench_eval.py on its checkpoint
 # with and without the exit.  Artifact = two JSON lines on stdout
-# (early_exit true/false), consumed by tpu_retry.sh as stage
-# "bench_early_exit".
+# (early_exit true/false).
 #
 # Usage: bash scripts/bench_early_exit.sh [outdir]
 # Env knobs (CPU smoke: EE_CPU=1 EE_IMAGE_SIZE=64 EE_STEPS=30 EE_BATCH=4):
 #   EE_IMAGE_SIZE (default 224), EE_STEPS (400), EE_BATCH (bench batch,
 #   32), EE_CPU=1 (pin the CPU backend everywhere).
 set -u
-OUT=${1:-/root/repo/runs/tpu_session_r3}
+OUT=${1:-runs/early_exit}
 IMG=${EE_IMAGE_SIZE:-224}
 STEPS=${EE_STEPS:-400}
 # cache dir keyed on EVERY knob that shapes corpus + checkpoint —

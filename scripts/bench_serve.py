@@ -103,6 +103,7 @@ import json
 import os
 import random
 import shutil
+import subprocess
 import sys
 import tempfile
 import threading
@@ -156,19 +157,13 @@ def _make_jpegs(n: int, size: int) -> list:
     return out
 
 
-def _make_ckpt(args, workdir):
-    """Tiny fresh model saved through checkpoint+lineage; returns the
-    serve Config pointing at it — shared by the in-process servers below
-    and the subprocess replica fleet (--fleet), which both load the same
-    LAST_GOOD step through the lineage path."""
-    import jax
-
-    from sat_tpu import runtime, telemetry
+def _serve_config(args, workdir):
+    """The tiny model's vocabulary + serve Config + a telemetry recorder.
+    Touches no device (and imports no jax): the --fleet parent builds its
+    view of the run from this alone."""
+    from sat_tpu import telemetry
     from sat_tpu.config import Config
     from sat_tpu.data.vocabulary import Vocabulary
-    from sat_tpu.resilience import lineage
-    from sat_tpu.train.checkpoint import save_checkpoint
-    from sat_tpu.train.step import create_train_state
 
     vocab_file = os.path.join(workdir, "vocabulary.csv")
     vocabulary = Vocabulary(size=50)
@@ -199,6 +194,22 @@ def _make_ckpt(args, workdir):
     os.makedirs(config.save_dir, exist_ok=True)
 
     tel = telemetry.enable(capacity=1 << 18)
+    return config, vocabulary, tel
+
+
+def _make_ckpt(args, workdir):
+    """Tiny fresh model saved through checkpoint+lineage; returns the
+    serve Config pointing at it — shared by the in-process servers below
+    and the subprocess replica fleet (--fleet), which both load the same
+    LAST_GOOD step through the lineage path."""
+    import jax
+
+    from sat_tpu import runtime
+    from sat_tpu.resilience import lineage
+    from sat_tpu.train.checkpoint import save_checkpoint
+    from sat_tpu.train.step import create_train_state
+
+    config, vocabulary, tel = _serve_config(args, workdir)
     runtime._install_compile_listener()
     state = create_train_state(jax.random.PRNGKey(0), config)
     if args.eos_bias != 0.0:
@@ -432,7 +443,15 @@ def fleet_bench(args, workdir) -> int:
     from sat_tpu.serve.replica import LocalFleet
     from sat_tpu.serve.router import Router
 
-    config, vocabulary, tel = _make_ckpt(args, workdir)
+    # one process for each chip: this parent fronts the fleet and must
+    # hold no device, so the checkpoint is built by a child that has
+    # exited before the first replica starts
+    subprocess.run(
+        [sys.executable, os.path.abspath(__file__), *sys.argv[1:],
+         "--ckpt-only", "--workdir", workdir],
+        check=True,
+    )
+    config, vocabulary, tel = _serve_config(args, workdir)
     sizes = sorted({int(s) for s in args.fleet_sizes.split(",")})
     floor_ms = int(args.fleet_service_floor_ms)
     fleet_env = (
@@ -531,6 +550,9 @@ def fleet_bench(args, workdir) -> int:
         # — goodput should track the n=1 arm (one floored decode
         # replica) and the row prices the handoff overhead against it.
         disagg_res = None
+        # the untiered fleet is done: give its chips back before the
+        # tiered one takes them (one process for each chip)
+        fleet.stop_all()
         disagg = LocalFleet(
             config, 2, root=os.path.join(workdir, "fleet_disagg"),
             env=fleet_env, tiers=["encode", "decode"],
@@ -1556,8 +1578,14 @@ def main() -> int:
                     help="lifecycle mode: request fraction hash-routed "
                          "to the candidate during arm B")
     ap.add_argument("--workdir", default=None)
+    ap.add_argument("--ckpt-only", action="store_true",
+                    help="internal: save the fresh checkpoint into "
+                         "--workdir and exit (the --fleet parent's child)")
     args = ap.parse_args()
 
+    if args.ckpt_only:
+        _make_ckpt(args, args.workdir)
+        return 0
     workdir = args.workdir or tempfile.mkdtemp(prefix="bench_serve_")
     made_workdir = args.workdir is None
     if (args.fleet or args.lifecycle or args.tenants or args.metering

@@ -1,10 +1,9 @@
 """Automated perf/quality regression gate over BENCH + compile_report artifacts.
 
-The repo accumulates a measurement trajectory — ``BENCH_r0*.json`` driver
-wrappers, ``runs/*/bench_*.json`` BENCH-contract rows, ``BASELINE.json``
-published numbers, and (since the telemetry PRs) ``compile_report.json``
-FLOP/HBM accounting.  Until now a PR that regressed any of it relied on a
-human noticing.  This script is the contract: feed it the prior artifacts
+A measurement trajectory is a set of files: bench-driver wrappers,
+``runs/*/bench_*.json`` BENCH-contract rows, and ``compile_report.json``
+FLOP/HBM accounting.  Without a gate, a PR that regressed any of it
+relied on a human noticing.  This script is the contract: feed it the prior artifacts
 and a fresh one, and it exits nonzero when the fresh numbers are worse
 than the best prior beyond a per-metric noise margin.
 
@@ -12,7 +11,7 @@ Usage
 -----
 Trajectory mode (chronological; the LAST file is the candidate)::
 
-    python scripts/check_regression.py BENCH_r01.json BENCH_r02.json fresh.json
+    python scripts/check_regression.py prior_1.json prior_2.json fresh.json
 
 Explicit pair mode::
 
@@ -28,10 +27,9 @@ Inputs accepted per file: a BENCH-contract JSONL stream
 (``{"metric","value","unit","vs_baseline",...}`` per line), one JSON
 object/array of such rows, or a bench-driver wrapper
 (``{"n","cmd","rc","tail","parsed"}`` — only ``parsed`` is read).
-Wrappers whose run never produced numbers (``parsed: null``, the
-device-unreachable sessions) contribute nothing; when NO comparable pair
-exists the gate exits 0 with a warning — an unreachable device must not
-fail CI, only a measured regression may.
+Wrappers whose run never produced numbers (``parsed: null``) contribute
+nothing; when NO comparable pair exists the gate exits 0 with a warning
+— only a measured regression may fail CI.
 
 Schema compatibility: rows/reports stamped with a ``schema_version``
 different from the current ``sat_tpu.telemetry.SCHEMA_VERSION`` are
@@ -45,9 +43,9 @@ candidate is compared against the BEST prior value so a noisy low prior
 can't mask a real regression.
 
 Infra-skip: a CANDIDATE artifact carrying ``error: device_unreachable``
-rows (bench.py's fallback line when every probe/run attempt died inside
-the device-watchdog budget) means measurement never happened — that is
-an infrastructure outage, not a metric regression.  The gate exits 3
+rows (written by whatever launched the bench when it could not get a
+device) means measurement never happened — that is an infrastructure
+failure, not a metric regression.  The gate exits 3
 with a named reason so CI can mark the job skipped instead of failed;
 measured regressions in the same artifact still win (exit 2 takes
 precedence).
@@ -248,7 +246,7 @@ INFRA_SKIP_ERRORS = ("device_unreachable",)
 
 def _errors_from_obj(obj: Any) -> List[str]:
     """Error strings carried by BENCH rows (``value`` null, ``error``
-    set — the bench orchestrator's fallback line)."""
+    set)."""
     if obj is None:
         return []
     if isinstance(obj, list):

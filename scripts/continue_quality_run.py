@@ -49,7 +49,6 @@ def main() -> int:
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     from sat_tpu.utils.compile_cache import enable as _enable_cache
 
     _enable_cache(jax)

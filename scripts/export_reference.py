@@ -34,9 +34,8 @@ def main() -> int:
     )
     args = ap.parse_args()
 
+    os.environ["JAX_PLATFORMS"] = "cpu"  # host-side tensor shuffling
     import jax
-
-    jax.config.update("jax_platforms", "cpu")  # host-side tensor shuffling
 
     from sat_tpu.config import Config
     from sat_tpu.train.checkpoint import (

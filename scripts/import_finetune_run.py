@@ -54,9 +54,6 @@ def main() -> int:
 
     if args.cpu:
         os.environ["JAX_PLATFORMS"] = "cpu"
-        import jax as _jax
-
-        _jax.config.update("jax_platforms", "cpu")
 
     t0 = time.time()
     root = os.path.abspath(args.out)

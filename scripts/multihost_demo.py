@@ -30,10 +30,9 @@ repo, pid, nprocs, port, root = (
 sys.path.insert(0, repo)
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
-jax.config.update("jax_platforms", "cpu")
 # share the repo's persistent compile cache across workers/reruns
 from sat_tpu.utils.compile_cache import enable as _enable_cache
-_enable_cache(jax, name=".jax_cache", root=repo, min_compile_time_secs=0.5)
+_enable_cache(jax)
 
 from sat_tpu.parallel import initialize_distributed
 initialize_distributed(
@@ -112,9 +111,8 @@ repo, root = sys.argv[1], sys.argv[2]
 sys.path.insert(0, repo)
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
-jax.config.update("jax_platforms", "cpu")
 from sat_tpu.utils.compile_cache import enable as _enable_cache
-_enable_cache(jax, name=".jax_cache", root=repo, min_compile_time_secs=0.5)
+_enable_cache(jax)
 
 from sat_tpu.config import Config
 config = Config.load(os.path.join(root, "config.json")).replace(

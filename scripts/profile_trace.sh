@@ -2,8 +2,7 @@
 # Capture one real jax.profiler trace of the PrefetchLoader-fed train hot
 # loop on the current backend (round-1 ask #8: back the
 # "loader-hides-decode" claim with a trace, PERF.md §host-input-pipeline).
-# Writes <outdir>/profile_done.txt on success so tpu_retry.sh can treat
-# the trace as a stage artifact.
+# Writes <outdir>/profile_done.txt on success.
 #
 # Live-capture mode (ISSUE 9): point it at an already-running caption
 # server and it opens an on-demand profiler window over HTTP instead of
@@ -31,7 +30,7 @@ if [ "${1:-}" = "--live" ]; then
     *) echo "live capture refused"; exit 1 ;;
   esac
 fi
-OUT=${1:-/root/repo/runs/tpu_session_r3}
+OUT=${1:-runs/profile}
 cd "$(dirname "$0")/.."
 mkdir -p "$OUT"
 

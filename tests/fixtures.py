@@ -42,8 +42,11 @@ def _write_jpeg(path: str, seed: int, size: int = 64) -> None:
     cv2.imwrite(path, img)
 
 
-def make_coco_fixture(root: str, num_images: int = 12) -> Dict:
-    """Create train/val image dirs + caption JSONs under `root`.
+def make_coco_fixture(
+    root: str, num_images: int = 12, image_size: int = 64, seed: int = 0
+) -> Dict:
+    """Create train/val image dirs + caption JSONs under `root`:
+    ``num_images`` JPEGs of ``image_size`` px drawn from ``seed``.
     Returns a dict of paths plus a ready Config."""
     from sat_tpu.config import Config
 
@@ -57,8 +60,11 @@ def make_coco_fixture(root: str, num_images: int = 12) -> Dict:
     for i in range(num_images):
         fname = f"COCO_fixture_{i:012d}.jpg"
         images.append({"id": i + 1, "file_name": fname})
-        _write_jpeg(os.path.join(train_img_dir, fname), seed=i)
-        _write_jpeg(os.path.join(val_img_dir, fname), seed=i)
+        for img_dir in (train_img_dir, val_img_dir):
+            _write_jpeg(
+                os.path.join(img_dir, fname),
+                seed=seed * num_images + i, size=image_size,
+            )
         # two captions per image, cycling the pool
         for j in range(2):
             annotations.append(

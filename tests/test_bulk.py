@@ -523,7 +523,7 @@ def test_cli_phase_bulk_end_to_end(bulk_env, tmp_path):
     )
     cfg_path = str(tmp_path / "bulk.json")
     config.save(cfg_path)
-    env = dict(os.environ, JAX_PLATFORMS="cpu", SAT_DEVICE_WATCHDOG_S="0")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, "-m", "sat_tpu.cli", "--config", cfg_path],
         capture_output=True, text=True, cwd=REPO, env=env, timeout=300,

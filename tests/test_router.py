@@ -35,6 +35,78 @@ from sat_tpu.serve.router import (
 from sat_tpu.telemetry import tracectx
 
 # ---------------------------------------------------------------------------
+# One process for each chip (LocalFleet on a TPU host, stub children)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def tpu_host(monkeypatch, tmp_path):
+    """A host whose PCI bus shows four v5e chips and one other device,
+    and a LocalFleet whose children are recorded, not started."""
+    from sat_tpu.serve import replica
+
+    bus = tmp_path / "pci"
+    for i, (vendor, device) in enumerate(
+        [("0x1ae0", "0x0063")] * 4 + [("0x8086", "0x1237")]
+    ):
+        slot = bus / f"0000:00:0{i}.0"
+        slot.mkdir(parents=True)
+        (slot / "vendor").write_text(vendor + "\n")
+        (slot / "device").write_text(device + "\n")
+    real_glob = replica.glob.glob
+    monkeypatch.setattr(
+        replica.glob, "glob",
+        lambda pattern: real_glob(str(bus / "*" / "vendor"))
+        if pattern.startswith("/sys/bus/pci") else real_glob(pattern),
+    )
+    spawned = []
+
+    class StubChild:
+        def __init__(self, argv, env=None, **kwargs):
+            spawned.append(env)
+
+        def poll(self):
+            return 0
+
+    monkeypatch.setattr(replica.subprocess, "Popen", StubChild)
+    monkeypatch.delenv(replica.CHIP_ENV, raising=False)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    return replica, spawned, tmp_path
+
+
+def test_local_fleet_gives_each_child_its_own_chip(tpu_host, monkeypatch):
+    replica, spawned, tmp_path = tpu_host
+    assert replica.local_tpu_chips() == ["0", "1", "2", "3"]
+    fleet = replica.LocalFleet(Config(), 4, root=str(tmp_path / "fleet"))
+    assert [env[replica.CHIP_ENV] for env in spawned] == ["0", "1", "2", "3"]
+    for env in spawned:
+        assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    # a respawned replica keeps its index, hence its chip
+    fleet.respawn("r2")
+    assert spawned[-1][replica.CHIP_ENV] == "2"
+    # an outer restriction is what there is to give
+    monkeypatch.setenv(replica.CHIP_ENV, "2,3")
+    del spawned[:]
+    replica.LocalFleet(Config(), 2, root=str(tmp_path / "fleet2"))
+    assert [env[replica.CHIP_ENV] for env in spawned] == ["2", "3"]
+    # off the TPU nothing changes: children inherit the environment
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.delenv(replica.CHIP_ENV)
+    del spawned[:]
+    replica.LocalFleet(Config(), 5, root=str(tmp_path / "fleet3"))
+    assert len(spawned) == 5
+    assert all(replica.CHIP_ENV not in env for env in spawned)
+
+
+def test_local_fleet_refuses_more_tpu_replicas_than_chips(tpu_host):
+    replica, spawned, tmp_path = tpu_host
+    with pytest.raises(ValueError, match="5 TPU replicas.*4 chip"):
+        replica.LocalFleet(Config(), 5, root=str(tmp_path / "fleet"))
+    assert not spawned  # refused before anything started
+
+
+# ---------------------------------------------------------------------------
 # Pure routing math
 # ---------------------------------------------------------------------------
 
