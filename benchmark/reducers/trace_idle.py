@@ -1,0 +1,8 @@
+"""Device idle share (%): 1 - union of device-op intervals / traced window."""
+
+
+def read(run):
+    t = run.trace
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
