@@ -641,6 +641,14 @@ def main(argv: Optional[List[str]] = None) -> int:
     # but eval/test read shards and caption files through retry_io too)
     _retry.configure(config.io_retries, config.io_retry_base_s)
 
+    # the run's telemetry begins here, once, so that set-up (data, the
+    # restore, the first dispatch) is inside it; the loops take it over
+    tel = (
+        runtime._telemetry_begin(config)
+        if config.phase in ("train", "eval", "test")
+        else None
+    )
+
     if config.phase == "train":
         state = runtime.setup_state(
             config,
@@ -650,7 +658,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             cnn_model_file=cli["cnn_model_file"],
         )
         try:
-            runtime.train(config, state=state)
+            runtime.train(config, state=state, tel=tel)
         except CheckpointWriteError as e:
             # the run trained but a checkpoint it depends on did not land
             # — warn + non-zero exit instead of a swallowed queue failure
@@ -717,14 +725,14 @@ def main(argv: Optional[List[str]] = None) -> int:
         state = runtime.setup_state(
             config, load=True, model_file=cli["model_file"]
         )
-        scores = runtime.evaluate(config, state=state)
+        scores = runtime.evaluate(config, state=state, tel=tel)
         for k, v in scores.items():
             print(f"{k}: {v:.4f}")
     else:
         state = runtime.setup_state(
             config, load=True, model_file=cli["model_file"]
         )
-        runtime.test(config, state=state)
+        runtime.test(config, state=state, tel=tel)
     return 0
 
 

@@ -217,7 +217,7 @@ class PrefetchLoader:
         self, batch, pool: ThreadPoolExecutor, pass_idx: int = 0,
         batch_idx: int = 0,
     ):
-        with telemetry.span("data/decode_batch"):
+        with telemetry.span("data/decode_batch", batch_idx):
             return self._decode_batch_inner(batch, pool, pass_idx, batch_idx)
 
     def _decode_batch_inner(
@@ -251,7 +251,8 @@ class PrefetchLoader:
         if self.shard_cache is not None:
             gather_bad = None if q is None else []
             raw = self.shard_cache.gather(
-                files, fallback=self.loader.load_raw, bad_rows=gather_bad
+                files, fallback=self.loader.load_raw, bad_rows=gather_bad,
+                index=batch_idx,
             )
             if gather_bad:
                 for i, f, reason, exc in gather_bad:

@@ -198,8 +198,10 @@ class ShardCache:
         image_files: Sequence[str],
         fallback: Optional[Callable[[str], np.ndarray]] = None,
         bad_rows: Optional[List[Tuple[int, str, str, Optional[BaseException]]]] = None,
+        index: int = -1,
     ) -> np.ndarray:
-        """Assemble a uint8 [B, S, S, 3] batch for ``image_files``.
+        """Assemble a uint8 [B, S, S, 3] batch for ``image_files``
+        (``index``: the batch's number in its pass, the span's ``arg``).
 
         Rows are grouped by shard and copied with ONE fancy-index read per
         shard per batch — no JPEG codec, no per-image allocation.  Files
@@ -214,7 +216,7 @@ class ShardCache:
         raise (KeyError on a miss with no fallback, the decode error
         otherwise) so a mis-wired cache can't silently emit garbage.
         """
-        with telemetry.span("data/shard_gather"):
+        with telemetry.span("data/shard_gather", index):
             S = self.image_size
             out = np.empty((len(image_files), S, S, 3), np.uint8)
             by_shard: Dict[int, List[int]] = {}

@@ -53,6 +53,7 @@ def init_variables(rng: jax.Array, config: Config) -> Dict[str, Any]:
     return out
 
 
+@jax.named_scope("encoder")
 def encode(
     variables: Dict[str, Any],
     config: Config,

@@ -179,6 +179,7 @@ def lstm_step(
     return new_c, new_h
 
 
+@jax.named_scope("decoder/init")
 def init_state(
     params: Params,
     config: Config,
@@ -241,29 +242,31 @@ def attend(
         proj = precompute_attend(params, config, contexts)
         _, alpha = attend_with_precomputed(params, config, contexts, proj, output)
         return (alpha, jnp.float32(0)) if with_activity else alpha
-    kc, ko, kt = jax.random.split(rng, 3)
-    contexts = _dropout(kc, contexts, rate, train)
-    output = _dropout(ko, output, rate, train)
-    act = jnp.float32(0)
-    if config.num_attend_layers == 1:
-        # ctx→1 per position (no bias) + position-specific h→N projection
-        logits1 = _dense(p["fc_a"], contexts, dtype=dt)[..., 0]    # [B, N]
-        logits2 = _dense(p["fc_b"], output, dtype=dt)              # [B, N]
-        logits = logits1 + logits2
-    else:
-        t1 = _dense(p["fc_1a"], contexts, activation="tanh", dtype=dt)  # [B, N, da]
-        t2 = _dense(p["fc_1b"], output, activation="tanh", dtype=dt)    # [B, da]
-        # L1 activity sites: the tanh layer outputs, pre-dropout (the
-        # reference attaches l1_regularizer only to activation≠None
-        # layers, utils/nn.py:39-43 + model.py:417-429)
-        act = _l1(t1) + _l1(t2)
-        temp = t1 + t2[:, None, :]
-        temp = _dropout(kt, temp, rate, train)
-        logits = _dense(p["fc_2"], temp, dtype=dt)[..., 0]     # [B, N]
-    alpha = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    with jax.named_scope("decoder/attend"):
+        kc, ko, kt = jax.random.split(rng, 3)
+        contexts = _dropout(kc, contexts, rate, train)
+        output = _dropout(ko, output, rate, train)
+        act = jnp.float32(0)
+        if config.num_attend_layers == 1:
+            # ctx→1 per position (no bias) + position-specific h→N projection
+            logits1 = _dense(p["fc_a"], contexts, dtype=dt)[..., 0]    # [B, N]
+            logits2 = _dense(p["fc_b"], output, dtype=dt)              # [B, N]
+            logits = logits1 + logits2
+        else:
+            t1 = _dense(p["fc_1a"], contexts, activation="tanh", dtype=dt)  # [B, N, da]
+            t2 = _dense(p["fc_1b"], output, activation="tanh", dtype=dt)    # [B, da]
+            # L1 activity sites: the tanh layer outputs, pre-dropout (the
+            # reference attaches l1_regularizer only to activation≠None
+            # layers, utils/nn.py:39-43 + model.py:417-429)
+            act = _l1(t1) + _l1(t2)
+            temp = t1 + t2[:, None, :]
+            temp = _dropout(kt, temp, rate, train)
+            logits = _dense(p["fc_2"], temp, dtype=dt)[..., 0]     # [B, N]
+        alpha = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     return (alpha, act) if with_activity else alpha
 
 
+@jax.named_scope("decoder/attend")
 def precompute_attend(
     params: Params, config: Config, contexts: jnp.ndarray
 ) -> jnp.ndarray:
@@ -282,6 +285,7 @@ def precompute_attend(
     return _dense(p["fc_1a"], contexts, activation="tanh", dtype=dt)  # [B,N,da]
 
 
+@jax.named_scope("decoder/attend")
 def attend_with_precomputed(
     params: Params,
     config: Config,
@@ -343,6 +347,7 @@ def attend_with_precomputed(
     return context, alpha
 
 
+@jax.named_scope("decoder/logits")
 def decode_logits(
     params: Params,
     config: Config,
@@ -420,21 +425,25 @@ def decoder_step(
         )
         if with_activity:
             alpha, act = alpha
-        context = (contexts * alpha[..., None]).sum(axis=1)      # [B, D]
+        with jax.named_scope("decoder/attend"):
+            context = (contexts * alpha[..., None]).sum(axis=1)  # [B, D]
 
-    word_embed = params["word_embedding"]["weights"][word]        # [B, E]
+    with jax.named_scope("decoder/embed"):
+        word_embed = params["word_embedding"]["weights"][word]    # [B, E]
 
-    lstm_input = jnp.concatenate([context, word_embed], axis=-1)
-    lstm_input = _dropout(k_in, lstm_input, ldr, train)
-    new_c, new_h = lstm_step(
-        params["lstm"], state.memory, state.recurrent, lstm_input,
-        dtype=jnp.dtype(config.compute_dtype),
-    )
-    # DropoutWrapper: independent masks on emitted h and recurrent h; c exempt
-    emitted = _dropout(k_out, new_h, ldr, train)
-    recurrent_h = _dropout(k_state, new_h, ldr, train)
+    with jax.named_scope("decoder/lstm"):
+        lstm_input = jnp.concatenate([context, word_embed], axis=-1)
+        lstm_input = _dropout(k_in, lstm_input, ldr, train)
+        new_c, new_h = lstm_step(
+            params["lstm"], state.memory, state.recurrent, lstm_input,
+            dtype=jnp.dtype(config.compute_dtype),
+        )
+        # DropoutWrapper: independent masks on emitted h and recurrent h; c exempt
+        emitted = _dropout(k_out, new_h, ldr, train)
+        recurrent_h = _dropout(k_state, new_h, ldr, train)
 
-    expanded = jnp.concatenate([emitted, context, word_embed], axis=-1)
+    with jax.named_scope("decoder/logits"):
+        expanded = jnp.concatenate([emitted, context, word_embed], axis=-1)
     logits = decode_logits(
         params, config, expanded, train, k_dec, with_activity=with_activity
     )

@@ -117,6 +117,7 @@ def _init_search(B: int, K: int, T: int, An: int) -> SearchState:
     )
 
 
+@jax.named_scope("beam/expand")
 def _expand_step(
     eos_id: int,
     K: int,
@@ -157,7 +158,8 @@ def _expand_step(
     # eos is within its beam's top-(K+1) next words — the reference only
     # ever pushes words from that set (base_model.py:219-230), so junk
     # completions can't crowd out the partial-beam fallback.
-    kth = jax.lax.top_k(step_logp, min(K + 1, V))[0][..., -1]   # [B,K]
+    with jax.named_scope("beam/topk"):
+        kth = jax.lax.top_k(step_logp, min(K + 1, V))[0][..., -1]   # [B,K]
     eos_allowed = step_logp[:, :, eos_id] >= kth
     eos_scores = jnp.where(eos_allowed, logp[:, :, eos_id], NEG_INF)  # [B,K]
     eos_words = jnp.where(t_hot[:, None, :], jnp.int32(eos_id), s.live_words)
@@ -170,33 +172,38 @@ def _expand_step(
     cand_words = jnp.concatenate([s.fin_words, eos_words], axis=1)  # [B,2K,T]
     cand_len = jnp.concatenate([s.fin_len, eos_len], axis=1)
     cand_alphas = jnp.concatenate([s.fin_alphas, eos_alphas], axis=1)
-    top_fin, fin_sel = jax.lax.top_k(cand_logp, K)
+    with jax.named_scope("beam/topk"):
+        top_fin, fin_sel = jax.lax.top_k(cand_logp, K)
     fin_logp = top_fin
-    fin_words = cand_words[batch_idx, fin_sel]
-    fin_len = cand_len[batch_idx, fin_sel]
-    fin_alphas = cand_alphas[batch_idx, fin_sel]
+    with jax.named_scope("beam/tile"):
+        fin_words = cand_words[batch_idx, fin_sel]
+        fin_len = cand_len[batch_idx, fin_sel]
+        fin_alphas = cand_alphas[batch_idx, fin_sel]
 
     # --- continuations: global top-K over beam×vocab, eos excluded
     cont = logp.at[:, :, eos_id].set(NEG_INF).reshape(B, K * V)
-    top_live, flat_sel = jax.lax.top_k(cont, K)            # [B,K]
+    with jax.named_scope("beam/topk"):
+        top_live, flat_sel = jax.lax.top_k(cont, K)        # [B,K]
     parent = flat_sel // V                                 # source beam
     word = (flat_sel % V).astype(jnp.int32)                # chosen token
 
-    gather_bk = lambda x: x.reshape(B, K, -1)[batch_idx, parent]  # noqa: E731
-    state = DecoderState(
-        memory=gather_bk(new_state.memory).reshape(B * K, H),
-        output=gather_bk(new_state.output).reshape(B * K, H),
-        recurrent=gather_bk(new_state.recurrent).reshape(B * K, H),
-    )
-    live_words = jnp.where(
-        t_hot[:, None, :], word[:, :, None], s.live_words[batch_idx, parent]
-    )
-    live_len = s.live_len[batch_idx, parent] + 1
-    live_alphas = jnp.where(
-        t_hot[:, None, :, None],
-        step_alpha[batch_idx, parent][:, :, None, :],
-        s.live_alphas[batch_idx, parent],
-    )
+    # the per-parent gathers that reorder every beam's state
+    with jax.named_scope("beam/tile"):
+        gather_bk = lambda x: x.reshape(B, K, -1)[batch_idx, parent]  # noqa: E731
+        state = DecoderState(
+            memory=gather_bk(new_state.memory).reshape(B * K, H),
+            output=gather_bk(new_state.output).reshape(B * K, H),
+            recurrent=gather_bk(new_state.recurrent).reshape(B * K, H),
+        )
+        live_words = jnp.where(
+            t_hot[:, None, :], word[:, :, None], s.live_words[batch_idx, parent]
+        )
+        live_len = s.live_len[batch_idx, parent] + 1
+        live_alphas = jnp.where(
+            t_hot[:, None, :, None],
+            step_alpha[batch_idx, parent][:, :, None, :],
+            s.live_alphas[batch_idx, parent],
+        )
     return state, SearchState(
         live_logp=top_live,
         live_words=live_words,
@@ -222,6 +229,7 @@ def _sealed(fin_logp: jnp.ndarray, live_logp: jnp.ndarray) -> jnp.ndarray:
     )
 
 
+@jax.named_scope("beam/finalize")
 def _merge_results(
     s: SearchState,
     K: int,
@@ -245,7 +253,8 @@ def _merge_results(
     cand_logp = jnp.concatenate([s.fin_logp, s.live_logp], axis=1)
     cand_words = jnp.concatenate([s.fin_words, s.live_words], axis=1)
     cand_len = jnp.concatenate([s.fin_len, s.live_len], axis=1)
-    _, sel = jax.lax.top_k(rank_key, K)                     # [B,K]
+    with jax.named_scope("beam/topk"):
+        _, sel = jax.lax.top_k(rank_key, K)                 # [B,K]
     alphas = None
     if return_alphas:
         cand_alphas = jnp.concatenate([s.fin_alphas, s.live_alphas], axis=1)
@@ -315,14 +324,18 @@ def run_search(
         # tests).
         return (t < T) & ~jnp.all(_sealed(s.fin_logp, s.live_logp))
 
-    t_final, (_, search) = jax.lax.while_loop(
-        cond, body, (jnp.int32(0), (state0, search0))
-    )
+    # the loop's own ops (condition, counter, what only feeds it) carry a
+    # scope too, so a trace read by scope leaves nothing of it unnamed
+    with jax.named_scope("beam/loop"):
+        t_final, (_, search) = jax.lax.while_loop(
+            cond, body, (jnp.int32(0), (state0, search0))
+        )
     return _merge_results(
         search, K, return_alphas, steps=t_final if return_steps else None
     )
 
 
+@jax.named_scope("beam/tile")
 def tile_beams(x: jnp.ndarray, K: int) -> jnp.ndarray:
     """[B, ...] -> [B*K, ...] with each image's row repeated K times — the
     shared per-image tensors (context grid, hoisted projection, initial

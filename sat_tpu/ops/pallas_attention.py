@@ -60,6 +60,8 @@ FORCE_INTERPRET = False
 # shapes while giving Mosaic full-width vector work on every axis.
 DEFAULT_BLOCK_B = 8
 
+_PAD_SCOPE = "decoder/attend/pad"
+
 
 def _make_kernel(compute_dtype):
     dt = jnp.dtype(compute_dtype)
@@ -160,26 +162,31 @@ def fused_attend(
     b_pad = (-B) % bt
     Bp = B + b_pad
 
-    t1 = jnp.pad(t1.astype(jnp.float32), ((0, b_pad), (0, n_pad), (0, 0)))
-    contexts_p = jnp.pad(
-        contexts.astype(jnp.float32), ((0, b_pad), (0, n_pad), (0, 0))
-    )
-    t2 = jnp.pad(t2.astype(jnp.float32), ((0, b_pad), (0, 0))).reshape(
-        Bp, 1, da
-    )
-    w2_row = w2.astype(jnp.float32).reshape(1, da)
-    # padding grid rows get -inf logits so they vanish from the softmax
-    bias = jnp.where(
-        (jnp.arange(Np) < N)[None, :], 0.0, _NEG_INF
-    ).astype(jnp.float32)                                          # [1, Np]
+    # the data movement round the kernel carries a scope of its own, so
+    # that a device trace tells it from the kernel (docs/OBSERVABILITY.md)
+    with jax.named_scope(_PAD_SCOPE):
+        t1 = jnp.pad(t1.astype(jnp.float32), ((0, b_pad), (0, n_pad), (0, 0)))
+        contexts_p = jnp.pad(
+            contexts.astype(jnp.float32), ((0, b_pad), (0, n_pad), (0, 0))
+        )
+        t2 = jnp.pad(t2.astype(jnp.float32), ((0, b_pad), (0, 0))).reshape(
+            Bp, 1, da
+        )
+        w2_row = w2.astype(jnp.float32).reshape(1, da)
+        # padding grid rows get -inf logits so they vanish from the softmax
+        bias = jnp.where(
+            (jnp.arange(Np) < N)[None, :], 0.0, _NEG_INF
+        ).astype(jnp.float32)                                      # [1, Np]
 
     if row_mask is not None:
         # batch-pad rows are dead by construction (pad with 0 = masked)
-        mask_col = jnp.pad(
-            row_mask.astype(jnp.float32), ((0, b_pad),)
-        ).reshape(Bp, 1)
+        with jax.named_scope(_PAD_SCOPE):
+            mask_col = jnp.pad(
+                row_mask.astype(jnp.float32), ((0, b_pad),)
+            ).reshape(Bp, 1)
         out_ctx, out_alpha = pl.pallas_call(
             _make_masked_kernel(compute_dtype),
+            name="fused_attend_masked",
             grid=(Bp // bt,),
             in_specs=[
                 pl.BlockSpec((bt, Np, da), lambda b: (b, 0, 0)),
@@ -199,10 +206,12 @@ def fused_attend(
             ],
             interpret=interpret,
         )(t1, t2, w2_row, bias, contexts_p, mask_col)
-        return out_ctx[:B], out_alpha[:B, :N]
+        with jax.named_scope(_PAD_SCOPE):
+            return out_ctx[:B], out_alpha[:B, :N]
 
     out_ctx, out_alpha = pl.pallas_call(
         _make_kernel(compute_dtype),
+        name="fused_attend",
         grid=(Bp // bt,),
         in_specs=[
             pl.BlockSpec((bt, Np, da), lambda b: (b, 0, 0)),
@@ -221,7 +230,8 @@ def fused_attend(
         ],
         interpret=interpret,
     )(t1, t2, w2_row, bias, contexts_p)
-    return out_ctx[:B], out_alpha[:B, :N]
+    with jax.named_scope(_PAD_SCOPE):
+        return out_ctx[:B], out_alpha[:B, :N]
 
 
 def fused_attend_reference(

@@ -18,7 +18,11 @@ Equivalent of the reference ``BaseModel.train/eval/test``
 
 from __future__ import annotations
 
+import collections
+import gc
+import itertools
 import os
+import resource
 import sys
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -114,19 +118,19 @@ def device_prefetch(loader, ahead: int = 1):
     """
     from collections import deque
 
-    def put(batch):
+    def put(batch, index):
         # the span times only the (async) transfer DISPATCH — it runs
         # inside the feed's data wait, so the breakdown reports it as a
         # nested interval, not a phase of its own
-        with telemetry.span("feed/device_put"):
+        with telemetry.span("feed/device_put", index):
             return {
                 k: jax.device_put(v) if isinstance(v, np.ndarray) else v
                 for k, v in batch.items()
             }
 
     buf = deque()
-    for batch in loader:
-        buf.append(put(batch))
+    for index, batch in enumerate(loader):  # index within this pass
+        buf.append(put(batch, index))
         if len(buf) > ahead:
             yield buf.popleft()
     while buf:
@@ -165,7 +169,10 @@ def setup_state(
     (/root/reference/main.py:49-53)."""
     if seed is None:
         seed = config.seed
-    state = create_train_state(jax.random.PRNGKey(seed), config)
+    # set-up spans land in the run's telemetry where the CLI began it
+    # before this call (cli.main); elsewhere they hit the null object
+    with telemetry.span("setup/state"):
+        state = create_train_state(jax.random.PRNGKey(seed), config)
     if load or model_file:
         if model_file and model_file.endswith(".npy"):
             # a checkpoint written by the *reference* itself (flat TF1
@@ -175,16 +182,17 @@ def setup_state(
         else:
             from .data.vocabulary import vocab_fingerprint
 
-            state, count = restore_checkpoint(
-                state,
-                model_file=model_file,
-                save_dir=config.save_dir,
-                # fail fast on a vocabulary swap instead of silently
-                # skipping the mismatched embedding (partial restore)
-                expect_vocab=vocab_fingerprint(
-                    config.vocabulary_file, config.vocabulary_size
-                ),
-            )
+            with telemetry.span("setup/restore"):  # read + verify
+                state, count = restore_checkpoint(
+                    state,
+                    model_file=model_file,
+                    save_dir=config.save_dir,
+                    # fail fast on a vocabulary swap instead of silently
+                    # skipping the mismatched embedding (partial restore)
+                    expect_vocab=vocab_fingerprint(
+                        config.vocabulary_file, config.vocabulary_size
+                    ),
+                )
         if count == 0:
             raise ValueError(
                 f"checkpoint {model_file or config.save_dir} restored 0 tensors"
@@ -295,12 +303,16 @@ class ProfilerWindow:
 # (their totals + the "other" residual reconstruct measured wall time) and
 # the nested spans that occur INSIDE a phase (reported, not summed)
 _TRAIN_PHASES = (
-    "train/data_wait", "train/dispatch", "train/log_sync",
-    "train/summary", "train/checkpoint",
+    "train/data_wait", "train/place", "train/dispatch", "train/log_sync",
+    "train/log_io", "train/summary", "train/checkpoint",
 )
 _TRAIN_NESTED = ("feed/device_put", "ckpt/write", "ckpt/snapshot")
 _DECODE_PHASES = ("decode/data_wait", "decode/dispatch", "decode/drain")
-_DECODE_NESTED = ("feed/device_put",)
+_DECODE_NESTED = (
+    "feed/device_put",
+    "decode/dispatch/encode", "decode/dispatch/beam",
+    "decode/drain/wait", "decode/drain/detok",
+)
 
 _compile_listener_installed = False
 
@@ -331,18 +343,80 @@ def _install_compile_listener() -> None:
         pass  # observability never takes the run down
 
 
-def _timed_iter(it, tel, name: str):
+def _timed_iter(it, tel, name: str, first: int = 0):
     """Yield from ``it``, recording each ``next()`` wait as a ``name``
-    span — the feed-starvation phase of the consuming loop."""
+    span — the feed-starvation phase of the consuming loop — numbered
+    from ``first`` (the step or batch the item is for)."""
     it = iter(it)
-    while True:
-        t0 = time.perf_counter_ns()
+    for k in itertools.count(first):
+        span = tel.span(name, k)
+        span.__enter__()
         try:
             item = next(it)
         except StopIteration:
+            span.drop()  # the end of the feed is no wait for data
             return
-        tel.record(name, t0, time.perf_counter_ns() - t0)
+        span.__exit__(None, None, None)
         yield item
+
+
+class StallWatch:
+    """Evidence for the host stalls of PERF.md (0.2-4 s, cause unknown):
+    when an iteration the loop already timed takes over ``factor`` times
+    the running median, record one ``host/stall`` span (``arg`` = the
+    step) and gauge what the process did since the last boundary:
+    involuntary context switches (descheduled), major page faults
+    (paging), garbage collections, and its own CPU time (little of it in
+    a long stall = blocked: waiting on the device or on IO).  One
+    comparison an iteration; the median and the baseline are refreshed
+    every ``every`` iterations; no thread.
+
+    ``synced`` keeps the iterations that end in the loop's device sync
+    apart from those that only enqueue: the unsynced train loop runs
+    ``log_every`` - 1 steps ahead at the loader's pace and then waits out
+    the device at the boundary, so its step times have two medians, an
+    order of magnitude apart."""
+
+    def __init__(self, tel, factor: float = 3.0, every: int = 8) -> None:
+        self._tel, self._factor, self._every = tel, factor, every
+        # per kind of iteration (enqueue only, synced): durations seen,
+        # how many, the limit in force
+        self._lanes = [
+            [collections.deque(maxlen=4 * every), 0, np.inf] for _ in range(2)
+        ]
+        self._base = self._sample()
+
+    @staticmethod
+    def _sample():
+        ru = resource.getrusage(resource.RUSAGE_SELF)
+        return (
+            time.perf_counter_ns(), time.process_time_ns(), ru.ru_nivcsw,
+            ru.ru_majflt, sum(g["collections"] for g in gc.get_stats()),
+        )
+
+    def iteration(self, k: int, t0_ns: int, dur_ns: int, synced: bool = False) -> None:
+        lane = self._lanes[synced]
+        if dur_ns > lane[2]:
+            now = self._sample()
+            wall, cpu, nivcsw, majflt, collections_ = (
+                n - b for n, b in zip(now, self._base)
+            )
+            tel = self._tel
+            tel.record("host/stall", t0_ns, dur_ns, k)
+            tel.count("host/stalls")
+            tel.gauge("host/stall_step", k)
+            tel.gauge("host/stall_ms", dur_ns / 1e6)
+            tel.gauge("host/stall_since_boundary_ms", wall / 1e6)
+            tel.gauge("host/stall_cpu_ms", cpu / 1e6)
+            tel.gauge("host/stall_nivcsw", nivcsw)
+            tel.gauge("host/stall_majflt", majflt)
+            tel.gauge("host/stall_gc", collections_)
+            self._base = now
+        lane[0].append(dur_ns)
+        lane[1] += 1
+        if lane[1] % self._every == 0:
+            lane[2] = self._factor * np.median(lane[0])  # host durations
+            self._base = self._sample()
 
 
 def _telemetry_dir(config: Config) -> str:
@@ -354,9 +428,18 @@ def _telemetry_begin(config: Config):
     the null object when off) and the process-wide compile listener."""
     if config.telemetry:
         tel = telemetry.enable(config.telemetry_buffer)
+        # every ``with tel.span`` also enters the profiler's annotation, so
+        # a trace taken with the host tracer on (/profile, SIGUSR2,
+        # --profile_steps) shows the host phases on the device's clock
+        tel.annotate = jax.profiler.TraceAnnotation
         from .telemetry import xla as xla_acct
 
         xla_acct.reset()  # per-run compile accounting (compile_report.json)
+        # its op_scopes reads the named scopes out of the executables'
+        # metadata, which the persistent cache's key leaves out by default:
+        # a cache filled by a build with other scope names would hand back
+        # theirs.  With telemetry on, metadata is part of the key.
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     else:
         tel = telemetry.disable()
     _install_compile_listener()
@@ -464,21 +547,31 @@ def train(
     state: Optional[TrainState] = None,
     dataset: Optional[DataSet] = None,
     seed: Optional[int] = None,
+    tel=None,
 ) -> TrainState:
     """Epoch × batch training loop (reference base_model.py:39-68).
 
     With ``mesh_shape`` spanning more than one device the same loop runs
     SPMD: state sharded per the (data, model) placement rules, batches
     data-sharded, XLA inserting the gradient all-reduce — the synchronous
-    upgrade of the reference's async PS strategy (SURVEY.md §2.13)."""
+    upgrade of the reference's async PS strategy (SURVEY.md §2.13).
+
+    tel: the telemetry the caller began for this run (cli.main does, so
+    that the set-up before this call is inside it); None begins it here."""
     if seed is None:
         seed = config.seed
+    # host-side tracing (docs/OBSERVABILITY.md): fresh ring buffers when
+    # config.telemetry, the null object otherwise — the off path leaves
+    # run behavior bit-for-bit unchanged
+    if tel is None:
+        tel = _telemetry_begin(config)
     if dataset is None:
         # the explicit kwarg must drive the WHOLE run — shuffle order
         # included — not just init/dropout (batch order is f(seed, epoch))
-        dataset = prepare_train_data(
-            config if seed == config.seed else config.replace(seed=seed)
-        )
+        with tel.span("setup/data"):
+            dataset = prepare_train_data(
+                config if seed == config.seed else config.replace(seed=seed)
+            )
     if dataset.count == 0:
         raise ValueError(
             "training dataset is empty after preparation — every caption was "
@@ -537,7 +630,8 @@ def train(
         # async device slot: batch k+1's host→HBM transfer is dispatched
         # while step k still runs, so the step never pays the copy
         wrap_feed = device_prefetch
-    loader = make_loader(config, dataset)
+    with tel.span("setup/data"):  # opens (or builds) the shard cache
+        loader = make_loader(config, dataset)
     # Typed key with the configured bit-generator impl: dropout-mask
     # generation is ~40% of the flagship train step under threefry (the
     # decoder draws ~130M mask bits/step); config.rng_impl="rbg" routes it
@@ -567,10 +661,6 @@ def train(
         else None
     )
     ckpt_save = async_writer.save if async_writer else save_checkpoint
-    # host-side tracing (docs/OBSERVABILITY.md): fresh ring buffers when
-    # config.telemetry, the null object otherwise — the off path leaves
-    # run behavior bit-for-bit unchanged
-    tel = _telemetry_begin(config)
     # incarnation number under `--supervise`: the restart loop exports it
     # so heartbeat.json can show how many times this run has come back
     tel.gauge("supervisor/restarts", int(os.environ.get(RESTARTS_ENV, "0") or 0))
@@ -588,6 +678,10 @@ def train(
         tel=tel,
     )
     compile_probed = False  # train_step analyzed once, on the first batch
+    # the first call of train_step is part of set-up: trace + compile, or
+    # the load from the cache
+    first_dispatch = tel.span("setup/first_dispatch")
+    stall = StallWatch(tel) if tel.enabled else None
     import contextlib
 
     final_path: Optional[str] = None
@@ -758,6 +852,7 @@ def train(
                     _watched_iter(wrap_feed(loader), wd, "data_wait"),
                     tel,
                     "train/data_wait",
+                    first=step,
                 ):
                   # watchdog net around the whole body: a wedge landing
                   # between the finer-grained guards still trips the
@@ -776,14 +871,15 @@ def train(
                         stopped = True
                         break
                     prof.before_step(step)
-                    placed = place_batch(
-                        {
-                            "images": batch["images"],
-                            "word_idxs": batch["word_idxs"],
-                            "masks": batch["masks"],
-                        }
-                    )
-                    step_rng = jax.random.fold_in(root_rng, step)
+                    with tel.span("train/place", step):
+                        placed = place_batch(
+                            {
+                                "images": batch["images"],
+                                "word_idxs": batch["word_idxs"],
+                                "masks": batch["masks"],
+                            }
+                        )
+                        step_rng = jax.random.fold_in(root_rng, step)
                     if tel.enabled and not compile_probed:
                         # AOT cost/memory accounting BEFORE the first
                         # dispatch: lowering reads only avals (donated
@@ -797,9 +893,13 @@ def train(
                             "train_step", train_step, state, placed,
                             step_rng, tel=tel,
                         )
-                    with tel.span("train/dispatch"), wd.phase("dispatch"):
+                    with tel.span("train/dispatch", step), wd.phase(
+                        "dispatch"
+                    ), first_dispatch:
                         state, metrics = train_step(state, placed, step_rng)
+                    first_dispatch = telemetry.NULL_SPAN
                     prof.after_step(step, state)
+                    done = step  # the step just dispatched: its spans' arg
                     step += 1  # == int(state.step), without a device sync
                     tel.gauge("train/step", step)
                     # injected NaN gradient (inert unarmed): poisons params
@@ -808,90 +908,98 @@ def train(
                     if step % config.log_every == 0:
                         # the loop's ONE host sync — the sentinel reads
                         # these already-fetched floats, adding no syncs
-                        with tel.span("train/log_sync"):
+                        with tel.span("train/log_sync", done):
                             host = {
                                 k: float(v)  # sync-ok: the loop's ONE log-boundary fetch
                                 for k, v in jax.device_get(metrics).items()
                             }
-                        writer.scalars(step, host)
-                        if tel.enabled:
-                            from .telemetry import exporters
+                        # host IO of the boundary: nothing is queued on
+                        # the device behind the sync above while it runs
+                        with tel.span("train/log_io", done):
+                            writer.scalars(step, host)
+                            if tel.enabled:
+                                from .telemetry import exporters
 
-                            # diag taps (telemetry/device.py) ride the
-                            # host dict just fetched: gauging them here
-                            # lands the last-known snapshot in
-                            # telemetry.jsonl and heartbeat.json without
-                            # touching the device again
-                            for k, v in host.items():
-                                if k.startswith("diag/"):
-                                    tel.gauge(k, v)
-                            exporters.append_jsonl(
-                                tel,
-                                os.path.join(
-                                    _telemetry_dir(config), "telemetry.jsonl"
-                                ),
-                                step,
-                                cap_bytes=int(
-                                    config.telemetry_log_cap_mb * 1e6
-                                ),
-                            )
-                            # SIGUSR2 since the last boundary → start a
-                            # bounded live profiler window (refusals —
-                            # capture already running — just log)
-                            if (
-                                profile_trigger is not None
-                                and profile_trigger.pop()
-                            ):
-                                ok, info = profile_latch.start(
-                                    config.profile_window_ms
+                                # diag taps (telemetry/device.py) ride the
+                                # host dict just fetched: gauging them here
+                                # lands the last-known snapshot in
+                                # telemetry.jsonl and heartbeat.json without
+                                # touching the device again
+                                for k, v in host.items():
+                                    if k.startswith("diag/"):
+                                        tel.gauge(k, v)
+                                exporters.append_jsonl(
+                                    tel,
+                                    os.path.join(
+                                        _telemetry_dir(config), "telemetry.jsonl"
+                                    ),
+                                    step,
+                                    cap_bytes=int(
+                                        config.telemetry_log_cap_mb * 1e6
+                                    ),
                                 )
-                                print(
-                                    "sat_tpu: live profiler window "
-                                    + (f"-> {info}" if ok else f"refused ({info})"),
-                                    file=sys.stderr,
-                                    flush=True,
-                                )
-                        # fleet tick: every process writes its sidecar
-                        # (and joins the gather when available); only
-                        # process 0 aggregates.  Black-box journal rides
-                        # the same boundary — both are pure host IO.
-                        if fleet_plane is not None:
-                            with tel.span("fleet/tick"):
-                                fleet_plane.tick(step, gather_fn=_fleet_gather)
-                        if bb is not None:
-                            bb.journal(step)
-                        if sentinel.check(step, host) == "rollback":
+                                # SIGUSR2 since the last boundary → start a
+                                # bounded live profiler window (refusals —
+                                # capture already running — just log)
+                                if (
+                                    profile_trigger is not None
+                                    and profile_trigger.pop()
+                                ):
+                                    ok, info = profile_latch.start(
+                                        config.profile_window_ms
+                                    )
+                                    print(
+                                        "sat_tpu: live profiler window "
+                                        + (f"-> {info}" if ok else f"refused ({info})"),
+                                        file=sys.stderr,
+                                        flush=True,
+                                    )
+                            # fleet tick: every process writes its sidecar
+                            # (and joins the gather when available); only
+                            # process 0 aggregates.  Black-box journal rides
+                            # the same boundary — both are pure host IO.
+                            if fleet_plane is not None:
+                                with tel.span("fleet/tick"):
+                                    fleet_plane.tick(step, gather_fn=_fleet_gather)
                             if bb is not None:
-                                from .telemetry import blackbox as _bbx
+                                bb.journal(step)
+                            if sentinel.check(step, host) == "rollback":
+                                if bb is not None:
+                                    from .telemetry import blackbox as _bbx
 
-                                bb.event(
-                                    "anomaly_rollback",
-                                    step=step,
-                                    reason=sentinel.last_reason,
-                                )
-                                _bbx.dump(
-                                    "anomaly_rollback",
-                                    step=step,
-                                    reason_detail=sentinel.last_reason,
-                                )
-                            rollback = True
-                            break
+                                    bb.event(
+                                        "anomaly_rollback",
+                                        step=step,
+                                        reason=sentinel.last_reason,
+                                    )
+                                    _bbx.dump(
+                                        "anomaly_rollback",
+                                        step=step,
+                                        reason_detail=sentinel.last_reason,
+                                    )
+                                rollback = True
+                                break
                     if (
                         config.var_summary_period
                         and step % config.var_summary_period == 0
                     ):
-                        with tel.span("train/summary"):
+                        with tel.span("train/summary", done):
                             writer.variable_stats(step, state.params)
                     if (
                         config.save_period
                         and step % config.save_period == 0
                         and not sentinel.suppress_save
                     ):
-                        with tel.span("train/checkpoint"), wd.phase("checkpoint"):
+                        with tel.span("train/checkpoint", done), wd.phase("checkpoint"):
                             ckpt_save(state, config, healthy=sentinel.healthy)
                     bar.update()
                     now = time.perf_counter_ns()
-                    tel.record("train/step", step_t0, now - step_t0)
+                    tel.record("train/step", step_t0, now - step_t0, done)
+                    if stall is not None:
+                        stall.iteration(
+                            done, step_t0, now - step_t0,
+                            synced=step % config.log_every == 0,
+                        )
                     step_t0 = now
                 bar.close()
                 if stopped or rollback:
@@ -1020,10 +1128,19 @@ def decode_dataset(
     state: TrainState,
     dataset: DataSet,
     vocabulary: Vocabulary,
+    tel=None,
 ) -> List[Dict[str, Any]]:
     """Beam-search every image; returns [{image_id, image_file, caption,
     prob}] with last-batch padding dropped and per-image dedup — the
-    reference's fake_count/set handling (base_model.py:83-88)."""
+    reference's fake_count/set handling (base_model.py:83-88).
+
+    tel: the telemetry the caller began for this run (see train); None
+    begins it here, so every decode of a sweep starts fresh."""
+    # host tracing over the decode loop: data_wait / dispatch / drain per
+    # batch (the drain of batch n overlaps batch n+1's device beam search
+    # — the breakdown shows whether the host decode keeps up)
+    if tel is None:
+        tel = _telemetry_begin(config)
     variables: Dict[str, Any] = {"params": state.params}
     if state.batch_stats:
         variables["batch_stats"] = state.batch_stats
@@ -1087,7 +1204,7 @@ def decode_dataset(
             return_alphas=config.save_attention_maps,
         )
 
-        def run_batch(batch):
+        def run_batch(batch, b):
             images = make_global_batch(mesh, {"images": batch["images"]})
             return caption_fn(variables, images["images"])
 
@@ -1115,7 +1232,7 @@ def decode_dataset(
                     track(loader, local_ds.num_batches, desc="decode(mesh)")
                 ):
                     prof.before_step(b)
-                    out = run_batch(batch)
+                    out = run_batch(batch, b)
                     prof.after_step(b, out.words)
                     # assembly only consumes beam 0: slice on device, then
                     # one batched cross-host gather for the whole tuple
@@ -1140,35 +1257,39 @@ def decode_dataset(
             contexts, _ = encode(variables, config, images, train=False)
             return contexts
 
-        decode_probed = []  # compile accounting fires once, on batch 0
+        def first_dispatch(b):
+            # the first call of each program is part of set-up: trace +
+            # compile, or the load from the cache
+            return tel.span("setup/first_dispatch") if b == 0 else telemetry.NULL_SPAN
 
-        def run_batch(batch):
-            contexts = encode_fn(variables, batch["images"])
+        def run_batch(batch, b):
+            with tel.span("decode/dispatch/encode", b), first_dispatch(b):
+                contexts = encode_fn(variables, batch["images"])
             beam_kwargs = dict(
                 beam_size=config.beam_size,
                 valid_size=len(vocabulary.words),
                 return_alphas=config.save_attention_maps,
             )
-            if not decode_probed:
-                decode_probed.append(True)
-                tel_now = telemetry.get()
-                if tel_now.enabled:
-                    from .telemetry import xla as xla_acct
+            if b == 0 and tel.enabled:
+                # compile accounting fires once, on batch 0
+                from .telemetry import xla as xla_acct
 
-                    xla_acct.analyze(
-                        "decode/encode", encode_fn, variables,
-                        batch["images"], tel=tel_now,
-                    )
-                    xla_acct.analyze(
-                        "decode/beam_search", beam_search_jit,
-                        state.params["decoder"], config, contexts, eos,
-                        tel=tel_now, **beam_kwargs,
-                    )
-            return beam_search_jit(
-                state.params["decoder"], config, contexts, eos, **beam_kwargs
-            )
+                xla_acct.analyze(
+                    "decode/encode", encode_fn, variables,
+                    batch["images"], tel=tel,
+                )
+                xla_acct.analyze(
+                    "decode/beam_search", beam_search_jit,
+                    state.params["decoder"], config, contexts, eos,
+                    tel=tel, **beam_kwargs,
+                )
+            with tel.span("decode/dispatch/beam", b), first_dispatch(b):
+                return beam_search_jit(
+                    state.params["decoder"], config, contexts, eos, **beam_kwargs
+                )
 
-    loader = make_loader(config, dataset)
+    with tel.span("setup/data"):
+        loader = make_loader(config, dataset)
 
     results: List[Dict[str, Any]] = []
     seen = set()
@@ -1176,41 +1297,45 @@ def decode_dataset(
     # depth-1 pipeline: dispatch batch n+1 to the device before fetching
     # batch n's results, so host-side decode of words/captions overlaps
     # device-side beam search (np.asarray is the sync point)
-    prev: Optional[Tuple[Any, List[str]]] = None
+    prev: Optional[Tuple[Any, List[str], int]] = None
 
-    def drain(out, files):
+    def drain(out, files, b):
         nonlocal emitted
-        words = np.asarray(out.words[:, 0])        # best caption per image  # sync-ok: decode drain boundary
-        lengths = np.asarray(out.lengths[:, 0])  # sync-ok: decode drain boundary
-        scores = np.asarray(out.log_scores[:, 0])  # sync-ok: decode drain boundary
-        alphas = (
-            np.asarray(out.alphas[:, 0]) if out.alphas is not None else None  # sync-ok: decode drain boundary
-        )
-        for i, image_file in enumerate(files):
-            if emitted >= dataset.count:           # fake_count padding
-                break
-            # eval/test DataSets are unshuffled, so batch order is
-            # image_ids order (reference drops fake_count the same way,
-            # base_model.py:86-88)
-            image_id = int(dataset.image_ids[emitted])
-            emitted += 1
-            if image_id in seen:                   # reference's set() dedup
-                continue
-            seen.add(image_id)
-            length = max(1, int(lengths[i]))
-            caption = vocabulary.get_sentence(words[i, :length])
-            row = {
-                "image_id": image_id,
-                "image_file": str(image_file),
-                "caption": caption,
-                "prob": float(np.exp(scores[i])),  # sync-ok: host numpy, already drained
-            }
-            if alphas is not None:
-                row["words"] = [
-                    vocabulary.words[w] for w in words[i, :length]
-                ]
-                row["alphas"] = alphas[i, :length]    # [len, N]
-            results.append(row)
+        # until the outputs are on the host: the wait for the device and
+        # the device-to-host copy
+        with tel.span("decode/drain/wait", b):
+            words = np.asarray(out.words[:, 0])        # best caption per image  # sync-ok: decode drain boundary
+            lengths = np.asarray(out.lengths[:, 0])  # sync-ok: decode drain boundary
+            scores = np.asarray(out.log_scores[:, 0])  # sync-ok: decode drain boundary
+            alphas = (
+                np.asarray(out.alphas[:, 0]) if out.alphas is not None else None  # sync-ok: decode drain boundary
+            )
+        with tel.span("decode/drain/detok", b):  # host work after it
+            for i, image_file in enumerate(files):
+                if emitted >= dataset.count:           # fake_count padding
+                    break
+                # eval/test DataSets are unshuffled, so batch order is
+                # image_ids order (reference drops fake_count the same way,
+                # base_model.py:86-88)
+                image_id = int(dataset.image_ids[emitted])
+                emitted += 1
+                if image_id in seen:                   # reference's set() dedup
+                    continue
+                seen.add(image_id)
+                length = max(1, int(lengths[i]))
+                caption = vocabulary.get_sentence(words[i, :length])
+                row = {
+                    "image_id": image_id,
+                    "image_file": str(image_file),
+                    "caption": caption,
+                    "prob": float(np.exp(scores[i])),  # sync-ok: host numpy, already drained
+                }
+                if alphas is not None:
+                    row["words"] = [
+                        vocabulary.words[w] for w in words[i, :length]
+                    ]
+                    row["alphas"] = alphas[i, :length]    # [len, N]
+                results.append(row)
 
     # profiler window over the decode loop — same knobs and semantics as
     # train's (shared ProfilerWindow), start clamped to the batch count so
@@ -1223,10 +1348,7 @@ def decode_dataset(
         if int(np.prod(config.mesh_shape)) == 1
         else loader
     )
-    # host tracing over the decode loop: data_wait / dispatch / drain per
-    # batch (the drain of batch n overlaps batch n+1's device beam search
-    # — the breakdown shows whether the host decode keeps up)
-    tel = _telemetry_begin(config)
+    stall = StallWatch(tel) if tel.enabled else None
     # black-box flight recorder for decode (same contract as train's):
     # journal per batch so an uncaught exception mid-eval still leaves a
     # postmortem bundle behind via the CLI's exception handler
@@ -1258,20 +1380,22 @@ def decode_dataset(
                 )
             ):
                 prof.before_step(b)
-                with tel.span("decode/dispatch"):
-                    out = run_batch(batch)         # async dispatch
+                with tel.span("decode/dispatch", b):
+                    out = run_batch(batch, b)      # async dispatch
                 prof.after_step(b, out.words)
                 if prev is not None:
-                    with tel.span("decode/drain"):
+                    with tel.span("decode/drain", prev[2]):  # batch b-1
                         drain(*prev)
-                prev = (out, batch["files"])
+                prev = (out, batch["files"], b)
                 now = time.perf_counter_ns()
-                tel.record("decode/batch", batch_t0, now - batch_t0)
+                tel.record("decode/batch", batch_t0, now - batch_t0, b)
+                if stall is not None:
+                    stall.iteration(b, batch_t0, now - batch_t0)
                 batch_t0 = now
                 if dec_bb is not None:
                     dec_bb.journal(b)
         if prev is not None:
-            with tel.span("decode/drain"):
+            with tel.span("decode/drain", prev[2]):
                 drain(*prev)
     finally:
         if dec_bb is not None:
@@ -1528,6 +1652,7 @@ def evaluate(
     state: Optional[TrainState] = None,
     model_file: Optional[str] = None,
     prepared: Optional[Tuple[Any, DataSet, Any]] = None,
+    tel=None,
 ) -> Dict[str, float]:
     """Scored beam-search decoding over the eval split
     (reference base_model.py:70-117): results.json + BLEU/METEOR/ROUGE/CIDEr.
@@ -1535,12 +1660,19 @@ def evaluate(
     prepared: an existing ``(coco, dataset, vocabulary)`` triple from
     :func:`prepare_eval_data` — callers scoring many checkpoints against
     the same split (evaluate_sweep) pass it so the caption JSON is read
-    and indexed once, not once per checkpoint."""
-    coco, dataset, vocabulary = prepared or prepare_eval_data(config)
+    and indexed once, not once per checkpoint.
+
+    tel: the run's telemetry where the caller began it (cli.main), so
+    that set-up is inside it; a sweep passes none and every decode of it
+    starts fresh."""
+    if prepared is None:
+        with telemetry.span("setup/data"):
+            prepared = prepare_eval_data(config)
+    coco, dataset, vocabulary = prepared
     if state is None:
         state = setup_state(config, load=True, model_file=model_file)
 
-    results = decode_dataset(config, state, dataset, vocabulary)
+    results = decode_dataset(config, state, dataset, vocabulary, tel=tel)
     payload = [
         {"image_id": r["image_id"], "caption": r["caption"]} for r in results
     ]
@@ -1603,17 +1735,19 @@ def test(
     config: Config,
     state: Optional[TrainState] = None,
     model_file: Optional[str] = None,
+    tel=None,
 ) -> List[Dict[str, Any]]:
     """Caption arbitrary JPEGs (reference base_model.py:119-161):
-    captioned images + results.csv."""
-    dataset, vocabulary = prepare_test_data(config)
+    captioned images + results.csv.  ``tel``: as in :func:`evaluate`."""
+    with telemetry.span("setup/data"):
+        dataset, vocabulary = prepare_test_data(config)
     if dataset.count == 0:
         print(f"no images found in {config.test_image_dir}")
         return []
     if state is None:
         state = setup_state(config, load=True, model_file=model_file)
 
-    results = decode_dataset(config, state, dataset, vocabulary)
+    results = decode_dataset(config, state, dataset, vocabulary, tel=tel)
 
     os.makedirs(config.test_result_dir, exist_ok=True)
     _render_caption_images(results, config.test_result_dir)

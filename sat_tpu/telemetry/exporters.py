@@ -58,7 +58,7 @@ def chrome_trace(
     into one Perfetto timeline with a lane per host.  The OS pid still
     rides in ``otherData``.
     """
-    names, ids, t0s, durs, tids = tel.spans_snapshot()
+    names, ids, t0s, durs, tids, args = tel.spans_snapshot(with_args=True)
     process_index, process_count = process_identity()
     if pid is None:
         pid = process_index
@@ -78,17 +78,18 @@ def chrome_trace(
     ]
     anchor = tel.anchor_ns
     for k in range(len(ids)):
-        events.append(
-            {
-                "name": names[int(ids[k])],
-                "cat": "host",
-                "ph": "X",
-                "pid": pid,
-                "tid": int(tids[k]),
-                "ts": (int(t0s[k]) - anchor) / 1e3,
-                "dur": int(durs[k]) / 1e3,
-            }
-        )
+        event = {
+            "name": names[int(ids[k])],
+            "cat": "host",
+            "ph": "X",
+            "pid": pid,
+            "tid": int(tids[k]),
+            "ts": (int(t0s[k]) - anchor) / 1e3,
+            "dur": int(durs[k]) / 1e3,
+        }
+        if args[k] >= 0:  # the step or batch the span worked on
+            event["args"] = {"i": int(args[k])}
+        events.append(event)
     if extra_events:
         events.extend(extra_events)
     return {

@@ -5,7 +5,10 @@ module answers "where did the HOST milliseconds of the whole run go" —
 cheaply enough to leave on for every step of every run.  Three primitives:
 
 * **span** — a named wall-clock interval (``time.perf_counter_ns``)
-  recorded into preallocated numpy ring buffers.  The hot path takes no
+  recorded into preallocated numpy ring buffers, with one integer
+  ``arg`` (the step or batch the work was for; -1 = none), so that
+  ``train/dispatch`` #k can be tied to the ``train/log_sync`` that waited
+  for it.  The hot path takes no
   lock: a slot index comes from ``itertools.count`` (``next()`` on it is
   a single C-level operation, atomic under the GIL, so producer threads
   — prefetch, checkpoint writer — never tear each other's slots) and the
@@ -28,7 +31,12 @@ off-path behavior is bit-for-bit what it was before instrumentation.
 
 Deliberately jax-free (like ``resilience/``): host-only tools —
 ``scripts/bench_telemetry.py`` — must import this without dragging in an
-accelerator backend, and recording must never add a device sync.
+accelerator backend, and recording must never add a device sync.  The one
+door to the profiler is ``Telemetry.annotate``: a factory the runtime sets
+to ``jax.profiler.TraceAnnotation`` and every ``with tel.span(...)``
+enters, so that a profiler trace taken with the host tracer on shows the
+host phases, with their step numbers, on the device's clock.  The ring's
+own ``perf_counter_ns`` stays the source of every span metric.
 """
 
 from __future__ import annotations
@@ -56,6 +64,9 @@ class _NullSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def drop(self) -> None:
+        pass
+
 
 NULL_SPAN = _NullSpan()
 
@@ -66,10 +77,12 @@ class NullTelemetry:
 
     enabled = False
 
-    def span(self, name: str) -> _NullSpan:
+    annotate = None
+
+    def span(self, name: str, arg: int = -1) -> _NullSpan:
         return NULL_SPAN
 
-    def record(self, name: str, t0_ns: int, dur_ns: int) -> None:
+    def record(self, name: str, t0_ns: int, dur_ns: int, arg: int = -1) -> None:
         pass
 
     def count(self, name: str, n: int = 1) -> None:
@@ -90,8 +103,12 @@ class NullTelemetry:
     def durations_ns(self, name: str) -> np.ndarray:
         return np.empty(0, np.int64)
 
-    def spans_snapshot(self):
-        return [], *(np.empty(0, d) for d in (np.int32, np.int64, np.int64, np.int64))
+    def spans_snapshot(self, with_args: bool = False):
+        dtypes = (np.int32, np.int64, np.int64, np.int64) + (np.int64,) * with_args
+        return [], *(np.empty(0, d) for d in dtypes)
+
+    def span_args(self) -> np.ndarray:
+        return np.empty(0, np.int64)
 
 
 NULL_TELEMETRY = NullTelemetry()
@@ -101,20 +118,33 @@ class _Span(object):
     """One timed interval; created per use (re-entrant and thread-safe by
     construction — no shared mutable timing state)."""
 
-    __slots__ = ("_tel", "_sid", "_t0")
+    __slots__ = ("_tel", "_sid", "_arg", "_ann", "_t0")
 
-    def __init__(self, tel: "Telemetry", sid: int) -> None:
+    def __init__(self, tel: "Telemetry", sid: int, arg: int, ann) -> None:
         self._tel = tel
         self._sid = sid
+        self._arg = arg
+        self._ann = ann  # the profiler's annotation of this span, or None
 
     def __enter__(self) -> "_Span":
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         t0 = self._t0
-        self._tel._record(self._sid, t0, time.perf_counter_ns() - t0)
+        dur = time.perf_counter_ns() - t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._tel._record(self._sid, t0, dur, self._arg)
         return False
+
+    def drop(self) -> None:
+        """Leave an entered span without recording it (a fetch that ended
+        in StopIteration is no wait for data)."""
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
 
 
 class Telemetry:
@@ -137,6 +167,10 @@ class Telemetry:
         self._t0s = np.zeros(cap, np.int64)
         self._durs = np.zeros(cap, np.int64)
         self._tids = np.zeros(cap, np.int64)
+        self._args = np.full(cap, -1, np.int64)
+        # optional factory ``annotate(name, i=arg)`` -> context manager,
+        # entered by every ``with span(...)`` (module docstring)
+        self.annotate = None
         self._slot = itertools.count()
         self._written = 0  # approximate under racing writers; exact enough
         self._names: Dict[str, int] = {}
@@ -155,27 +189,32 @@ class Telemetry:
 
     # -- hot path ----------------------------------------------------------
 
-    def span(self, name: str) -> _Span:
+    def span(self, name: str, arg: int = -1) -> _Span:
         sid = self._names.get(name)
         if sid is None:
             sid = self._intern(name)
-        return _Span(self, sid)
+        annotate = self.annotate
+        return _Span(
+            self, sid, arg, None if annotate is None else annotate(name, i=arg)
+        )
 
-    def record(self, name: str, t0_ns: int, dur_ns: int) -> None:
+    def record(self, name: str, t0_ns: int, dur_ns: int, arg: int = -1) -> None:
         """Record a manually timed interval (loop bodies that can't wrap a
-        ``with`` around their own ``for``-statement fetch)."""
+        ``with`` around their own ``for``-statement fetch).  Not annotated:
+        the profiler cannot be told of an interval that is over."""
         sid = self._names.get(name)
         if sid is None:
             sid = self._intern(name)
-        self._record(sid, t0_ns, dur_ns)
+        self._record(sid, t0_ns, dur_ns, arg)
 
-    def _record(self, sid: int, t0_ns: int, dur_ns: int) -> None:
+    def _record(self, sid: int, t0_ns: int, dur_ns: int, arg: int = -1) -> None:
         i = next(self._slot)          # lock-free slot reservation
         j = i & self._mask
         self._ids[j] = sid
         self._t0s[j] = t0_ns
         self._durs[j] = dur_ns
         self._tids[j] = threading.get_ident()
+        self._args[j] = arg
         self._written = i + 1
         # racing writers may drop one aggregate update; the ring row above
         # is slot-exclusive and never torn
@@ -246,19 +285,25 @@ class Telemetry:
         idx = self._window()
         return self._durs[idx][self._ids[idx] == sid]
 
-    def spans_snapshot(self):
+    def spans_snapshot(self, with_args: bool = False):
         """(names, ids, t0s, durs, tids) — the retained window in
-        chronological order; ``names[ids[k]]`` is span k's name."""
+        chronological order; ``names[ids[k]]`` is span k's name.  Five
+        values, as every reader unpacks them; ``with_args`` appends the
+        ``arg`` column, taken over the same window."""
         idx = self._window()
         with self._name_lock:
             names = list(self._name_list)
-        return (
-            names,
-            self._ids[idx].copy(),
-            self._t0s[idx].copy(),
-            self._durs[idx].copy(),
-            self._tids[idx].copy(),
-        )
+        columns = (self._ids, self._t0s, self._durs, self._tids)
+        if with_args:
+            columns += (self._args,)
+        return (names, *(c[idx].copy() for c in columns))
+
+    def span_args(self) -> np.ndarray:
+        """The ``arg`` of every retained span, in ``spans_snapshot()``'s
+        order (-1 where none was given).  Readers that need the columns
+        to line up while spans are still being recorded take
+        ``spans_snapshot(with_args=True)`` instead."""
+        return self._args[self._window()].copy()
 
 
 # ---------------------------------------------------------------------------
@@ -291,12 +336,12 @@ def disable() -> NullTelemetry:
     return _impl
 
 
-def span(name: str):
-    return _impl.span(name)
+def span(name: str, arg: int = -1):
+    return _impl.span(name, arg)
 
 
-def record(name: str, t0_ns: int, dur_ns: int) -> None:
-    _impl.record(name, t0_ns, dur_ns)
+def record(name: str, t0_ns: int, dur_ns: int, arg: int = -1) -> None:
+    _impl.record(name, t0_ns, dur_ns, arg)
 
 
 def count(name: str, n: int = 1) -> None:

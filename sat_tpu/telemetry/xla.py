@@ -22,15 +22,23 @@ eagerly by the package ``__init__`` (the core telemetry package stays
 jax-free); runtime imports it directly and only when telemetry is on.
 A program that fails to compile here is reported and skipped — the real
 dispatch right after raises the same error where it belongs.
+
+``op_scopes`` (per program): the map from each instruction of the
+optimized module that a device trace can show — ``%fusion.503`` says
+nothing, and its number changes with every compile — to the ``op_name``
+the compiler kept in its metadata, which holds the ``jax.named_scope``
+path the op was traced under (``.../while/body/decoder/lstm/dot_general``).
+Read a trace by layer with it: docs/OBSERVABILITY.md "XLA accounting".
 """
 
 from __future__ import annotations
 
 import json
 import os
+import re
 import sys
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 from ..utils.fileio import atomic_write
 from . import SCHEMA_VERSION, run_id
@@ -47,6 +55,163 @@ _MEMORY_ATTRS = (
     "alias_size_in_bytes",
     "generated_code_size_in_bytes",
 )
+
+
+# ---------------------------------------------------------------------------
+# op_scopes: optimized-HLO instruction -> named-scope path
+# ---------------------------------------------------------------------------
+
+OP_SCOPE_COLUMNS = ("name", "shape", "container", "op_name", "inherited")
+
+# their time in a trace is their children's: a reader that sums device time
+# by scope skips them, or a loop and its body are counted twice
+_CONTAINERS = {
+    "while": ("condition", "body"),
+    "conditional": ("true_computation", "false_computation", "branch_computations"),
+    "call": ("to_apply",),
+}
+# never a device event of their own (no work is scheduled for them)
+_NO_EVENT = frozenset(
+    ("parameter", "get-tuple-element", "tuple", "constant", "bitcast")
+)
+
+_COMPUTATION = re.compile(r"^(ENTRY )?(%[^\s(]+) \(")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?(%[^\s=]+) = (.*)$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_OPERAND = re.compile(r"%[^\s,(){}]+")
+_SHAPE_NOISE = re.compile(r"/\*.*?\*/|\{[^{}]*\}|\s+")
+
+
+def normal_shape(shape: str) -> str:
+    """A result shape without what printers disagree on: layouts
+    (``{1,0:T(8,128)}``), ``/*index=5*/`` comments and spaces."""
+    return _SHAPE_NOISE.sub("", shape)
+
+
+def _split_shape(rest: str):
+    """(result shape, what follows it) of an instruction's right-hand
+    side; a tuple shape is a balanced parenthesis."""
+    if not rest.startswith("("):
+        shape, _, tail = rest.partition(" ")
+        return shape, tail
+    depth = 0
+    for i, ch in enumerate(rest):
+        depth += (ch == "(") - (ch == ")")
+        if depth == 0:
+            return rest[: i + 1], rest[i + 2:]
+    return rest, ""
+
+
+def parse_op_scopes(hlo_text: str) -> List[list]:
+    """Rows of :data:`OP_SCOPE_COLUMNS` for every instruction of the
+    optimized module that can appear as a device event: the entry
+    computation and, recursively, the computations its containers run
+    (loop bodies and conditions, branches, calls) — not the insides of
+    fusions, which the device runs as one op.
+
+    The compiler makes instructions of its own and gives them no
+    ``op_name``: layout copies, the asynchronous ``copy-start`` /
+    ``slice-start`` pairs that move an operand to faster memory, dtype
+    conversions of weights.  They are data movement FOR another op, so
+    such a row takes the ``op_name`` of the first named instruction that
+    uses its result (through other unnamed ones), else of the first named
+    one it reads, else of the loop it only feeds, and says so in
+    ``inherited``."""
+    computations: Dict[str, List[str]] = {}
+    entry = current = None
+    for line in hlo_text.splitlines():
+        if current is None:
+            m = _COMPUTATION.match(line) if line[:1] in ("%", "E") else None
+            if m and line.endswith("{"):
+                current = computations.setdefault(m.group(2), [])
+                if m.group(1):
+                    entry = m.group(2)
+        elif line.startswith("}"):
+            current = None
+        else:
+            current.append(line)
+    rows: List[list] = []
+    todo, seen = [entry] if entry else [], set()
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in computations:
+            continue
+        seen.add(comp)
+        # name -> [opcode, shape, op_name, operands], in program order
+        parsed: Dict[str, list] = {}
+        for line in computations[comp]:
+            m = _INSTRUCTION.match(line)
+            if m is None:
+                continue
+            shape, tail = _split_shape(m.group(2))
+            opcode, _, rest = tail.partition("(")
+            name = _OP_NAME.search(rest)
+            body = rest.partition(", metadata=")[0]
+            parsed[m.group(1)] = [opcode, shape, name.group(1) if name else "", _OPERAND.findall(body), body]
+        users: Dict[str, List[str]] = {}
+        for inst, (_o, _s, _n, operands, _b) in parsed.items():
+            for operand in operands:
+                if operand in parsed:
+                    users.setdefault(operand, []).append(inst)
+
+        def named(inst: str, edges, skip, depth: int = 0, visited=None) -> str:
+            """op_name of the nearest named instruction along ``edges``,
+            not counting (nor passing through) the opcodes in ``skip``."""
+            visited = visited if visited is not None else set()
+            for nxt in edges(inst):
+                if nxt in visited or nxt not in parsed or parsed[nxt][0] in skip:
+                    continue
+                visited.add(nxt)
+                found = parsed[nxt][2] or (
+                    named(nxt, edges, skip, depth + 1, visited) if depth < 8 else ""
+                )
+                if found:
+                    return found
+            return ""
+
+        def inherit(inst: str) -> str:
+            uses = lambda i: users.get(i, ())  # noqa: E731
+            reads = lambda i: parsed[i][3]  # noqa: E731
+            return (
+                named(inst, uses, _CONTAINERS)
+                # a parameter's op_name is its argument's path, not a scope
+                or named(inst, reads, ("parameter",))
+                # what only feeds a loop is that loop's set-up
+                or named(inst, uses, ())
+            )
+
+        for inst, (opcode, shape, op_name, _operands, body) in parsed.items():
+            if opcode in _NO_EVENT:
+                continue
+            container = opcode in _CONTAINERS
+            if container:
+                for attr in _CONTAINERS[opcode]:
+                    called = re.search(attr + r"=(\{[^}]*\}|%[^\s,]+)", body)
+                    if called:
+                        todo += _OPERAND.findall(called.group(1))
+            inherited = "" if op_name else inherit(inst)
+            rows.append([inst, normal_shape(shape), container, op_name or inherited, bool(inherited)])
+    return rows
+
+
+def _op_scopes(compiled, tel) -> Optional[Dict[str, Any]]:
+    """The op map of one compiled program, timed as the
+    ``setup/compile_accounting`` span.  None where the executable gives no
+    text (never raises: the map is an aid, the run goes on without it)."""
+    t0 = time.perf_counter_ns()
+    try:
+        rows = parse_op_scopes(compiled.as_text())
+    except Exception as e:
+        print(
+            f"sat_tpu: op_scopes skipped: {e!r}", file=sys.stderr, flush=True
+        )
+        return None
+    finally:
+        if tel is not None:
+            tel.record("setup/compile_accounting", t0, time.perf_counter_ns() - t0)
+    if not rows:
+        return None
+    return {"columns": list(OP_SCOPE_COLUMNS), "rows": rows}
 
 
 def reset() -> None:
@@ -112,6 +277,9 @@ def analyze(name: str, jitted, *args, tel=None, **kwargs) -> Optional[Dict]:
         },
         "donation": None,
     }
+    scopes = _op_scopes(compiled, tel)
+    if scopes is not None:
+        entry["op_scopes"] = scopes
 
     try:
         import jax

@@ -66,12 +66,14 @@ def make_train_step(config: Config):
             variables: Dict[str, Any] = {"params": params}
             if state.batch_stats:
                 variables["batch_stats"] = state.batch_stats
-            total, aux = compute_loss(variables, config, batch, rng, train=True)
+            with jax.named_scope("loss"):
+                total, aux = compute_loss(variables, config, batch, rng, train=True)
             return total, aux
 
         grads, aux = jax.grad(loss_fn, has_aux=True)(trainable)
-        updates, new_opt_state = optimizer.update(grads, state.opt_state, trainable)
-        new_trainable = optax.apply_updates(trainable, updates)
+        with jax.named_scope("optimizer"):  # clip + Adam
+            updates, new_opt_state = optimizer.update(grads, state.opt_state, trainable)
+            new_trainable = optax.apply_updates(trainable, updates)
 
         new_params = {**state.params, **new_trainable}
         new_batch_stats = aux["model_state"].get("batch_stats", state.batch_stats)
@@ -82,13 +84,14 @@ def make_train_step(config: Config):
             step=state.step + 1,
         )
         metrics = dict(aux["metrics"])
-        metrics["grad_norm"] = optax.global_norm(grads)
-        # attention-map stats (the reference's attentions summary,
-        # model.py:538-540): Σ_t α per context position, ideally ≈1
-        att = aux["attentions"]
-        metrics["attention/mean"] = jnp.mean(att)
-        metrics["attention/std"] = jnp.std(att)
-        metrics["attention/max"] = jnp.max(att)
+        with jax.named_scope("metrics"):
+            metrics["grad_norm"] = optax.global_norm(grads)
+            # attention-map stats (the reference's attentions summary,
+            # model.py:538-540): Σ_t α per context position, ideally ≈1
+            att = aux["attentions"]
+            metrics["attention/mean"] = jnp.mean(att)
+            metrics["attention/std"] = jnp.std(att)
+            metrics["attention/max"] = jnp.max(att)
         if config.diag_level != "off":
             # update-side diag taps (telemetry/device.py): merged into the
             # metrics pytree so they ride the existing log-sync fetch —
