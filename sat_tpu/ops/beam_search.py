@@ -21,7 +21,14 @@ Semantics preserved (the reference is the correctness oracle):
 Deliberate upgrade: each step takes the global top-K over all beam×vocab
 continuations (the eos column excluded from continuation) instead of the
 reference's per-beam top-(K+1) heap pushes — a strictly-at-least-as-good
-candidate set, computed as one ``lax.top_k`` on device.
+candidate set, computed as one ``lax.top_k`` over ``[B, K*V]`` on device.
+The reference's top-(K+1) survives as a gate on completions only: eos
+closes a beam when it is among that beam's K+1 likeliest next words, and
+the gate needs one number per beam, the (K+1)-th largest log-probability
+(``_kth_largest``: an exact ``top_k`` over the ``[B*K, V]`` rows the
+logits arrive in, reduced to its minimum).  Both selections over the
+vocabulary are written so that XLA:TPU emits its ``TopK`` call for them;
+no step sorts the vocabulary (tests/test_aot_tpu.py pins it).
 
 Greedy decoding is the beam_size=1 special case of the same program.
 
@@ -39,8 +46,10 @@ Two drivers run the SAME expansion math (``_expand_step``):
   freezes the step it seals (all K finished slots filled and
   min(fin) ≥ max(live)) — from that step on the monolithic search can no
   longer alter that image's merged result either (a later completion
-  scores ≤ max(live) ≤ min(fin), and ``lax.top_k`` tie-breaks toward the
-  lower index, where the finished entries sit).
+  scores ≤ max(live) ≤ min(fin), and the finished-set merge — the one
+  ``lax.top_k`` of the step whose INDICES break ties — prefers the lower
+  index, where the finished entries sit; the eos gate's threshold is a
+  value, the same number however its ties are ordered).
 """
 
 from __future__ import annotations
@@ -117,6 +126,17 @@ def _init_search(B: int, K: int, T: int, An: int) -> SearchState:
     )
 
 
+@jax.named_scope("beam/topk")
+def _kth_largest(rows: jnp.ndarray, k: int) -> jnp.ndarray:
+    """[R, V] -> [R]: each row's k-th largest value, exactly (a value, so
+    ties cannot matter).  Written the one way XLA:TPU turns into its
+    ``TopK`` custom call: a rank-2 operand, and every returned value
+    consumed — the k-th of a descending top-k is its minimum.  A rank-3
+    operand, or slicing the last column out, each lowers instead to a
+    full stable sort of the vocabulary axis."""
+    return jax.lax.top_k(rows, k)[0].min(axis=-1)
+
+
 @jax.named_scope("beam/expand")
 def _expand_step(
     eos_id: int,
@@ -150,16 +170,15 @@ def _expand_step(
     step_alpha = alpha.reshape(B, K, -1)[:, :, :An]             # [B,K,An]
     if valid_size is not None and valid_size < V:
         logits = logits.at[:, valid_size:].set(NEG_INF)
-    step_logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    step_logp = step_logp.reshape(B, K, V)
+    row_logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    step_logp = row_logp.reshape(B, K, V)
     logp = step_logp + s.live_logp[..., None]          # [B,K,V] cumulative
 
     # --- completions: an eos hypothesis only becomes a candidate when
     # eos is within its beam's top-(K+1) next words — the reference only
     # ever pushes words from that set (base_model.py:219-230), so junk
     # completions can't crowd out the partial-beam fallback.
-    with jax.named_scope("beam/topk"):
-        kth = jax.lax.top_k(step_logp, min(K + 1, V))[0][..., -1]   # [B,K]
+    kth = _kth_largest(row_logp, min(K + 1, V)).reshape(B, K)
     eos_allowed = step_logp[:, :, eos_id] >= kth
     eos_scores = jnp.where(eos_allowed, logp[:, :, eos_id], NEG_INF)  # [B,K]
     eos_words = jnp.where(t_hot[:, None, :], jnp.int32(eos_id), s.live_words)

@@ -19,6 +19,7 @@ every later file's assumptions about what the chip accepts.
 
 import functools
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else compiler logs go to /tmp
 
@@ -110,35 +111,74 @@ def _decoder_params(config):
     return variables, _on_chip(variables["params"]["decoder"])
 
 
-def test_stepped_pool_program_compiles_with_kernel(monkeypatch):
-    """``decode_multi_step`` over the default slot pool, through the
-    decoder's backend gate with the masked kernel on."""
+def _lower_pool_program(config):
+    """``decode_multi_step`` over the default slot pool, lowered for the
+    described chip."""
     from sat_tpu.ops.beam_search import decode_multi_step, init_slot_pool
 
-    config = Config()
     slots = config.serve_slot_pages * config.serve_page_width
     _, decoder = _decoder_params(config)
     carry = _on_chip(jax.eval_shape(functools.partial(
         init_slot_pool, config, slots, beam_size=config.beam_size,
         max_len=config.max_caption_length,
     )))
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = (
-        jax.jit(
-            decode_multi_step,
-            static_argnames=("config", "eos_id", "beam_size", "valid_size"),
-        )
-        .lower(
-            decoder, config, carry, _sd((slots,), jnp.bool_), 1,
-            _sd((), jnp.int32), beam_size=config.beam_size,
-            valid_size=config.vocabulary_size,
-        )
-        .compile()
+    return jax.jit(
+        decode_multi_step,
+        static_argnames=("config", "eos_id", "beam_size", "valid_size"),
+    ).lower(
+        decoder, config, carry, _sd((slots,), jnp.bool_), 1,
+        _sd((), jnp.int32), beam_size=config.beam_size,
+        valid_size=config.vocabulary_size,
     )
+
+
+def test_stepped_pool_program_compiles_with_kernel(monkeypatch):
+    """``decode_multi_step`` over the default slot pool, through the
+    decoder's backend gate with the masked kernel on."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _lower_pool_program(Config()).compile()
     assert "tpu_custom_call" in compiled.as_text()
     # carry + params + temporaries of one pool fit a 16 GB chip many times
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 2 << 30
+
+
+@pytest.mark.parametrize(
+    "program,K", [("search", 3), ("search", 1), ("pool", Config().beam_size)],
+    ids=["beam3-b512", "greedy-b512", "pool"],
+)
+def test_beam_programs_select_without_a_vocabulary_sort(monkeypatch, program, K):
+    """The beam step's selections over the vocabulary reach the chip's
+    ``TopK`` call; no ``sort`` is left with a vocabulary-sized operand
+    (the per-beam threshold used to be one: a stable sort of
+    f32[512,3,5000] on every step, 28% of the eval cell's device time).
+    The eval cell's program, greedy at its batch, and the serve pool's."""
+    from sat_tpu.ops.beam_search import beam_search_jit
+
+    config = Config()
+    V = config.vocabulary_size
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if program == "pool":
+        lowered = _lower_pool_program(config)
+    else:
+        _, decoder = _decoder_params(config)
+        lowered = beam_search_jit.lower(
+            decoder, config, _sd((512, config.num_ctx, config.dim_ctx)), 3,
+            beam_size=K, valid_size=V,
+        )
+    text = lowered.compile().as_text()
+
+    sorts = [ln for ln in text.splitlines() if re.search(r"\bsort\(", ln)]
+    wide = [
+        ln.strip()[:160] for ln in sorts
+        if {V, K * V} & {
+            int(d) for dims in re.findall(r"\[([\d,]+)\]", ln)
+            for d in dims.split(",")
+        }
+    ]
+    assert not wide, wide
+    # the threshold over [rows, V] and the continuations over [B, K*V]
+    assert text.count('custom_call_target="TopK"') >= 2
 
 
 @pytest.mark.parametrize("mode", ["int8", "bf16"])

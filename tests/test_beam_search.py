@@ -3,10 +3,12 @@ host-heap oracle (the algorithm of reference base_model.py:163-240
 re-implemented as a correctness baseline), and the no-completion fallback."""
 
 import heapq
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from sat_tpu.config import Config
 from sat_tpu.models.decoder import decoder_step, init_decoder_params, init_state
@@ -422,3 +424,165 @@ def test_early_exit_actually_exits():
             f"early-exit wall-clock advisory: fast={t_fast:.3f}s "
             f"full={t_full:.3f}s (deterministic steps_run check passed)"
         )
+
+
+# ---------------------------------------------------------------------------
+# The per-beam top-(K+1) threshold: one exact value per row, by whatever
+# selection — pinned bit for bit against the sliced rank-3 ``top_k`` it
+# replaced (which XLA:TPU lowered to a full sort of the vocabulary axis)
+# ---------------------------------------------------------------------------
+
+_bs = sys.modules["sat_tpu.ops.beam_search"]  # the module, not the function
+
+
+def _threshold_rows(case, K, rng):
+    """[B, K, V] float32 next-word log-probabilities of one step."""
+    B, V = 4, 37
+    if case == "random":
+        logits = rng.normal(size=(B, K, V))
+    elif case == "ties":
+        # a handful of distinct values, so every row repeats its maximum
+        # and holds exact ties at and around rank K+1
+        logits = rng.integers(0, 3, size=(B, K, V)).astype(np.float32)
+        logits[0] = 1.0  # a row of one value throughout
+    elif case == "masked_tail":
+        logits = rng.normal(size=(B, K, V))
+        logits[..., V - 9:] = _bs.NEG_INF  # what valid_size does
+        logits[1, :, K:] = _bs.NEG_INF     # fewer real words than K+1
+    elif case == "vocab_not_over_k":
+        logits = rng.normal(size=(B, K, K))  # V == K: min(K+1, V) guards
+    else:
+        raise AssertionError(case)
+    return jax.nn.log_softmax(jnp.asarray(logits, jnp.float32), axis=-1)
+
+
+@pytest.mark.parametrize("K", [1, 3, 5])
+@pytest.mark.parametrize(
+    "case", ["random", "ties", "masked_tail", "vocab_not_over_k"]
+)
+def test_kth_threshold_is_bitwise_the_sliced_top_k(case, K):
+    step_logp = _threshold_rows(case, K, np.random.default_rng(K))
+    B, _, V = step_logp.shape
+    k = min(K + 1, V)
+    want = jax.lax.top_k(step_logp, k)[0][..., -1]
+    got = jax.jit(_bs._kth_largest, static_argnums=1)(
+        step_logp.reshape(B * K, V), k
+    ).reshape(B, K)
+    assert got.dtype == want.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # and what the step makes of it, for an eos in any column
+    np.testing.assert_array_equal(
+        np.asarray(step_logp >= got[..., None]),
+        np.asarray(step_logp >= want[..., None]),
+    )
+
+
+def _parent_expand_step(eos_id, K, V, An, valid_size, new_state, logits,
+                        alpha, t_vec, s):
+    """``_expand_step`` as it stood before the threshold changed (commit
+    5072338), scopes dropped: the oracle of the whole-search case."""
+    from sat_tpu.models.decoder import DecoderState
+
+    NEG_INF = _bs.NEG_INF
+    B = s.live_logp.shape[0]
+    T = s.live_words.shape[2]
+    H = new_state.output.shape[-1]
+    batch_idx = jnp.arange(B)[:, None]
+    t_hot = jnp.arange(T)[None, :] == t_vec[:, None]
+
+    step_alpha = alpha.reshape(B, K, -1)[:, :, :An]
+    if valid_size is not None and valid_size < V:
+        logits = logits.at[:, valid_size:].set(NEG_INF)
+    step_logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    step_logp = step_logp.reshape(B, K, V)
+    logp = step_logp + s.live_logp[..., None]
+
+    kth = jax.lax.top_k(step_logp, min(K + 1, V))[0][..., -1]
+    eos_allowed = step_logp[:, :, eos_id] >= kth
+    eos_scores = jnp.where(eos_allowed, logp[:, :, eos_id], NEG_INF)
+    eos_words = jnp.where(t_hot[:, None, :], jnp.int32(eos_id), s.live_words)
+    eos_len = s.live_len + 1
+    eos_alphas = jnp.where(
+        t_hot[:, None, :, None], step_alpha[:, :, None, :], s.live_alphas
+    )
+    cand_logp = jnp.concatenate([s.fin_logp, eos_scores], axis=1)
+    cand_words = jnp.concatenate([s.fin_words, eos_words], axis=1)
+    cand_len = jnp.concatenate([s.fin_len, eos_len], axis=1)
+    cand_alphas = jnp.concatenate([s.fin_alphas, eos_alphas], axis=1)
+    fin_logp, fin_sel = jax.lax.top_k(cand_logp, K)
+    fin_words = cand_words[batch_idx, fin_sel]
+    fin_len = cand_len[batch_idx, fin_sel]
+    fin_alphas = cand_alphas[batch_idx, fin_sel]
+
+    cont = logp.at[:, :, eos_id].set(NEG_INF).reshape(B, K * V)
+    top_live, flat_sel = jax.lax.top_k(cont, K)
+    parent = flat_sel // V
+    word = (flat_sel % V).astype(jnp.int32)
+
+    gather_bk = lambda x: x.reshape(B, K, -1)[batch_idx, parent]  # noqa: E731
+    state = DecoderState(
+        memory=gather_bk(new_state.memory).reshape(B * K, H),
+        output=gather_bk(new_state.output).reshape(B * K, H),
+        recurrent=gather_bk(new_state.recurrent).reshape(B * K, H),
+    )
+    live_words = jnp.where(
+        t_hot[:, None, :], word[:, :, None], s.live_words[batch_idx, parent]
+    )
+    live_len = s.live_len[batch_idx, parent] + 1
+    live_alphas = jnp.where(
+        t_hot[:, None, :, None],
+        step_alpha[batch_idx, parent][:, :, None, :],
+        s.live_alphas[batch_idx, parent],
+    )
+    return state, _bs.SearchState(
+        live_logp=top_live, live_words=live_words, live_len=live_len,
+        last_word=word, fin_logp=fin_logp, fin_words=fin_words,
+        fin_len=fin_len, live_alphas=live_alphas, fin_alphas=fin_alphas,
+    )
+
+
+@pytest.mark.parametrize(
+    "seed,K,V,valid",
+    [(3, 1, 10, None), (0, 3, 10, None), (2, 5, 10, None), (1, 5, 12, 8)],
+    ids=["greedy", "beam3", "beam5", "beam5-valid8"],
+)
+def test_whole_search_equals_the_parents_expand_step(
+    monkeypatch, seed, K, V, valid
+):
+    """words, log_scores, lengths and alphas of a whole search, bit for
+    bit against the same engine run over the parent's step body — on
+    inputs where the threshold decides captions: a threshold one rank
+    off, either way, changes the result."""
+    cfg, params, contexts = setup(seed=seed, B=5, beam_size=K,
+                                  max_caption_length=8, vocabulary_size=V)
+
+    def search():
+        # a fresh jit each time, so the trace reads what is patched in
+        return jax.jit(lambda c: beam_search(
+            params, cfg, c, EOS, valid_size=valid,
+            return_alphas=True, return_steps=True,
+        ))(contexts)
+
+    def same(a, b):
+        return all(
+            np.array_equal(np.asarray(x), np.asarray(y)) for x, y in zip(a, b)
+        )
+
+    got = search()
+    with monkeypatch.context() as m:
+        m.setattr(_bs, "_expand_step", _parent_expand_step)
+        want = search()
+    for name in got._fields:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(got, name)), np.asarray(getattr(want, name)),
+            err_msg=name,
+        )
+    for off in (-1, 1):
+        with monkeypatch.context() as m:
+            m.setattr(
+                _bs, "_kth_largest",
+                lambda rows, k, off=off: jax.lax.top_k(
+                    rows, max(1, min(k + off, rows.shape[-1]))
+                )[0].min(axis=-1),
+            )
+            assert not same(search(), want), f"rank {off:+d} went unnoticed"
