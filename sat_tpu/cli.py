@@ -46,7 +46,8 @@ def _parse_override(config: Config, key: str, raw: str):
     if isinstance(current, float):
         return float(raw)
     if isinstance(current, tuple):
-        return tuple(int(x) for x in raw.split(","))
+        cast = str if current and isinstance(current[0], str) else int
+        return tuple(cast(x) for x in raw.split(","))
     if current is None:  # field currently None: best-effort int, else str
         try:
             return int(raw)

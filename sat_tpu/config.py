@@ -36,6 +36,40 @@ class Config:
     num_decode_layers: int = 2         # 1 or 2
     dim_decode_layer: int = 1024
 
+    # ---- caption decoder family ----
+    # "lstm": the paper's attention-LSTM (every field above).  "lfm2_moe":
+    # the encoder grid as an N-position prefix into a language-model
+    # stack of gated short convolutions, grouped-query attention and a
+    # sparse mixture of experts (models/lfm2.py); its widths below are
+    # named as in the source's config.json (LiquidAI/LFM2-8B-A1B) and
+    # default to it.  vocabulary_size is the source's vocab_size.
+    decoder: str = "lstm"
+    hidden_size: int = 2048
+    intermediate_size: int = 7168          # dense SwiGLU of the leading layers
+    moe_intermediate_size: int = 1792      # one expert's SwiGLU
+    num_hidden_layers: int = 24
+    num_dense_layers: int = 2              # leading layers with the dense ffn
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    conv_L_cache: int = 3                  # taps of the causal short convolution
+    num_experts: int = 32
+    num_experts_per_tok: int = 4
+    norm_topk_prob: bool = True
+    use_expert_bias: bool = True           # selects experts, never weighs them
+    routed_scaling_factor: float = 1.0
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-5
+    # one of "conv" / "full_attention" per layer, num_hidden_layers long
+    layer_types: Tuple[str, ...] = (
+        "conv", "conv", "full_attention", "conv", "conv", "conv",
+        "full_attention", "conv", "conv", "conv", "full_attention", "conv",
+        "conv", "conv", "full_attention", "conv", "conv", "conv",
+        "full_attention", "conv", "conv", "full_attention", "conv", "conv",
+    )
+    # train_cnn's twin for the language-model stack: frozen by default,
+    # so the connector alone trains and Adam holds slots for it alone
+    train_lm: bool = False
+
     # ---- init / regularization (reference config.py:20-27) ----
     fc_kernel_initializer_scale: float = 0.08
     fc_kernel_regularizer_scale: float = 1e-4
@@ -501,6 +535,7 @@ class Config:
         same, /root/reference/model.py:16-21)."""
         checks = (
             ("cnn", ("vgg16", "resnet50")),
+            ("decoder", ("lstm", "lfm2_moe")),
             ("phase", ("train", "eval", "test", "serve", "route", "bulk")),
             ("optimizer", ("Adam", "RMSProp", "Momentum", "SGD")),
             ("num_initialize_layers", (1, 2)),
@@ -521,6 +556,8 @@ class Config:
                 raise ValueError(
                     f"Config.{name}={getattr(self, name)!r}: must be one of {allowed}"
                 )
+        if self.decoder == "lfm2_moe":
+            self._check_lfm2()
         if self.io_retries < 0:
             raise ValueError(f"Config.io_retries={self.io_retries}: must be >= 0")
         if self.keep_checkpoints < 0:
@@ -755,6 +792,56 @@ class Config:
                 ">= 1 (a host at the fleet median is not a straggler)"
             )
 
+    def _check_lfm2(self) -> None:
+        """decoder="lfm2_moe": the stack's fields agree, and what this
+        decoder cannot run yet is refused by name (ROADMAP B7-B9)."""
+        kinds = ("conv", "full_attention")
+        if len(self.layer_types) != self.num_hidden_layers or any(
+            k not in kinds for k in self.layer_types
+        ):
+            raise ValueError(
+                f"Config.layer_types: {self.num_hidden_layers} entries "
+                f"(num_hidden_layers), each one of {kinds}; got "
+                f"{self.layer_types!r}"
+            )
+        if (
+            self.hidden_size % self.num_attention_heads
+            or self.num_attention_heads % self.num_key_value_heads
+            or (self.hidden_size // self.num_attention_heads) % 2
+        ):
+            raise ValueError(
+                "Config: hidden_size must divide into num_attention_heads "
+                "even-sized heads, and num_attention_heads into "
+                "num_key_value_heads groups"
+            )
+        if not 0 <= self.num_dense_layers <= self.num_hidden_layers:
+            raise ValueError(
+                f"Config.num_dense_layers={self.num_dense_layers}: must be "
+                f"within 0..num_hidden_layers"
+            )
+        if not 1 <= self.num_experts_per_tok <= self.num_experts:
+            raise ValueError(
+                f"Config.num_experts_per_tok={self.num_experts_per_tok}: "
+                f"must be within 1..num_experts"
+            )
+        refused = None
+        if self.phase in ("serve", "bulk", "route"):
+            refused = (
+                f"phase={self.phase!r}: the slot pool carries the LSTM's "
+                "three [S*K, H] leaves only"
+            )
+        elif any(int(d) != 1 for d in self.mesh_shape):
+            refused = f"mesh_shape={self.mesh_shape}: one device only"
+        elif self.context_parallel != 1:
+            refused = f"context_parallel={self.context_parallel}"
+        elif self.save_attention_maps:
+            refused = (
+                "save_attention_maps (return_alphas): this decoder has no "
+                "per-word attention map over the grid"
+            )
+        if refused:
+            raise ValueError(f'Config.decoder="lfm2_moe" does not run with {refused}')
+
     def replace(self, **kw: Any) -> "Config":
         return dataclasses.replace(self, **kw)
 
@@ -783,7 +870,8 @@ class Config:
         # JSON has no tuples; these fields must come back hashable (the
         # Config rides jit static_argnames — a list field breaks lower())
         for key in (
-            "mesh_shape", "mesh_axes", "serve_buckets", "serve_decode_depth"
+            "mesh_shape", "mesh_axes", "serve_buckets", "serve_decode_depth",
+            "layer_types",
         ):
             if key in kw and isinstance(kw[key], list):
                 kw[key] = tuple(kw[key])

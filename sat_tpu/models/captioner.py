@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from ..config import Config
 from ..nn.layers import regularization_loss
-from .decoder import init_decoder_params, teacher_forced_decode
+from . import decoders
 from .resnet50 import ResNet50
 from .vgg16 import VGG16
 
@@ -45,7 +45,7 @@ def init_variables(rng: jax.Array, config: Config) -> Dict[str, Any]:
     out = {
         "params": {
             "cnn": cnn_vars["params"],
-            "decoder": init_decoder_params(k_dec, config),
+            "decoder": decoders.init_params(k_dec, config),
         }
     }
     if "batch_stats" in cnn_vars:
@@ -193,20 +193,34 @@ def compute_loss(
     B, T = sentences.shape
     N = contexts.shape[1]
 
-    decoded = teacher_forced_decode(
+    logits, alphas, fc_activity = decoders.train_logits(
         variables["params"]["decoder"], config, contexts, sentences, train, rng,
         with_activity=fc_act_scale > 0,
     )  # [B,T,V], [B,T,N] (+ activity L1)
-    fc_activity = jnp.float32(0)
-    if fc_act_scale > 0:
-        logits, alphas, fc_activity = decoded
-    else:
-        logits, alphas = decoded
+    if fc_activity is None:
+        fc_activity = jnp.float32(0)
 
     # masked sparse softmax cross-entropy, summed / mask-sum (model.py:316-318)
     ce = token_ce(logits, sentences, config, train)            # [B,T]
     mask_sum = masks.sum()
     cross_entropy_loss = (ce * masks).sum() / mask_sum
+    if alphas is None:
+        # a decoder with no attention over the grid (lfm2_moe): the loss
+        # is the masked token cross-entropy alone — no doubly stochastic
+        # penalty exists for it, and its frozen stack is not regularised
+        predictions = jnp.argmax(logits, axis=-1)
+        zero = jnp.float32(0)
+        return cross_entropy_loss, {
+            "metrics": {
+                "cross_entropy_loss": cross_entropy_loss,
+                "attention_loss": zero,
+                "reg_loss": zero,
+                "total_loss": cross_entropy_loss,
+                "accuracy": ((predictions == sentences) * masks).sum() / mask_sum,
+            },
+            "attentions": None,
+            "model_state": new_state,
+        }
 
     # doubly stochastic attention penalty (model.py:320-326):
     # alphas masked per-step, summed over time; penalize departure from 1

@@ -1,0 +1,189 @@
+"""The decoder interface: the one place that knows which caption decoders
+exist (``Config.decoder``) and what each gives the rest of the program.
+
+==================  =====================================================
+``init_params``     the ``params['decoder']`` sub-tree (captioner, and
+                    through it ``create_train_state`` and the checkpoint
+                    path, which see only a tree of named leaves)
+``split_frozen``    which of that sub-tree trains (train/step.py)
+``train_logits``    teacher-forced logits ``[B, T, V]`` (+ attention maps
+                    where the decoder has them) for the loss
+``search``          what ``ops/beam_search.run_search`` needs of a decoder:
+                    a state from the image (the LSTM's initial carry; the
+                    language model's prefill), one step over ``[B*K]``
+                    rows, which leaves are per beam (a plain tree, or
+                    ``StepState.beam``), which are carried unreordered
+                    (``StepState.shared``) and which are per image
+                    (closed over, never tiled), and what the decoder
+                    itself reports of the batch (``Search.finish``)
+==================  =====================================================
+
+No other module tests ``Config.decoder``.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..config import Config
+from . import lfm2
+from .decoder import (
+    DecoderState,
+    decoder_step,
+    init_decoder_params,
+    init_state,
+    precompute_attend,
+    teacher_forced_decode,
+)
+
+Params = Dict[str, Any]
+
+
+class StepState(NamedTuple):
+    """A decoder's loop-carried state where not all of it is per beam.
+    A decoder whose whole state is per beam (the LSTM's ``DecoderState``)
+    hands the search that tree itself.  What is per IMAGE and never
+    changes (an image prefix's keys and values) is no state at all: the
+    step function closes over it, ``[B, ...]``, untiled."""
+
+    beam: Any     # leaves [B*K, ...]: reordered by parent every step
+    shared: Any   # counters and the like: carried, never reordered
+
+
+class Search(NamedTuple):
+    """One batch's search, as the decoder hands it to ``run_search``."""
+
+    # step_fn(state, last_word [B*K] int32) -> (state, logits [B*K, V],
+    # alpha [B*K, alpha_width])
+    step_fn: Callable
+    state0: Any            # a tree of per-beam leaves, or a StepState
+    alpha_width: int       # 0: the decoder has no map over the grid
+    # finish(result, final state) -> result: where the decoder attaches
+    # what it reports of the batch (``BeamResult.decoder_stats``)
+    finish: Callable
+
+
+@jax.named_scope("beam/tile")
+def tile_beams(x: jnp.ndarray, K: int) -> jnp.ndarray:
+    """[B, ...] -> [B*K, ...] with each image's row repeated K times — the
+    shared per-image tensors (context grid, hoisted projection, initial
+    state) flattened to the search's [B*K] step batch."""
+    B = x.shape[0]
+    return jnp.broadcast_to(x[:, None], (B, K) + x.shape[1:]).reshape(
+        (B * K,) + x.shape[1:]
+    )
+
+
+def init_params(rng: jax.Array, config: Config) -> Params:
+    if config.decoder == "lfm2_moe":
+        return lfm2.init_params(rng, config)
+    return init_decoder_params(rng, config)
+
+
+def split_frozen(decoder: Params, config: Config) -> Tuple[Params, Params]:
+    """(trainable, frozen) of ``params['decoder']``.  The language-model
+    stack is frozen as the CNN is (``train_lm``, ``train_cnn``'s twin):
+    the connector alone trains and the optimizer holds slots for it
+    alone."""
+    if config.decoder == "lfm2_moe" and not config.train_lm:
+        return {"connector": decoder["connector"]}, {"lm": decoder["lm"]}
+    return decoder, {}
+
+
+def train_logits(
+    decoder: Params,
+    config: Config,
+    contexts: jnp.ndarray,
+    sentences: jnp.ndarray,
+    train: bool,
+    rng: Optional[jax.Array],
+    with_activity: bool = False,
+):
+    """(logits [B,T,V], alphas [B,T,N] or None, fc activity L1 or None)."""
+    if config.decoder == "lfm2_moe":
+        # no dropout and no activity term: the stack defines neither
+        return lfm2.teacher_forced(decoder, config, contexts, sentences), None, None
+    out = teacher_forced_decode(
+        decoder, config, contexts, sentences, train, rng, with_activity=with_activity
+    )
+    return out if with_activity else (*out, None)
+
+
+def search(
+    params: Params,
+    config: Config,
+    contexts: jnp.ndarray,
+    K: int,
+    T: int,
+    hoist_attention: bool = True,
+    return_alphas: bool = False,
+) -> Search:
+    """The search of one batch of grids ``[B, N, D]`` with K beams an
+    image over at most T steps."""
+    if config.decoder == "lfm2_moe":
+        if return_alphas:
+            raise ValueError(
+                'decoder="lfm2_moe" has no per-word attention map over the '
+                "grid: return_alphas is refused"
+            )
+        return _lm_search(params, config, contexts, K, T)
+
+    # one shared context grid per image, flattened to a [B*K] step batch
+    ctx_tiled = tile_beams(contexts, K)
+    # hoist the context half of the attention MLP out of the T×K loop
+    # (loop-invariant at inference; the reference recomputes it every step)
+    proj_tiled = None
+    if hoist_attention:
+        proj_tiled = tile_beams(precompute_attend(params, config, contexts), K)
+    state0 = init_state(params, config, contexts, train=False)  # [B, H]
+    state0 = DecoderState(*(tile_beams(s, K) for s in state0))
+
+    def step_fn(state, last_word):
+        return decoder_step(
+            params, config, ctx_tiled, state, last_word,
+            train=False, ctx_proj=proj_tiled,
+        )
+
+    return Search(step_fn, state0, contexts.shape[1], lambda result, state: result)
+
+
+def _lm_search(params: Params, config: Config, contexts: jnp.ndarray, K: int, T: int) -> Search:
+    """The language-model decoder's: the N prefix positions go through
+    the stack once per IMAGE; their keys and values stay ``[B, N, ...]``
+    for every beam of the image to read in place.  Per beam: the conv
+    states (tiled from the prefix's), an empty suffix cache of T
+    positions, and the record of the experts the beam's own tokens chose."""
+    B = contexts.shape[0]
+    with jax.named_scope("beam/prefill"):
+        prefix, counts, prefix_routes = lfm2.prefill(params, config, contexts)
+    cache = lfm2.init_cache(
+        config, tuple(tile_beams(x, K) for x in prefix.conv), B * K, T
+    )
+    state0 = StepState(beam=cache, shared=lfm2.init_counters(counts, T))
+
+    def step_fn(state, last_word):
+        cache, counters, logits = lfm2.step(
+            params, config, prefix, state.beam, state.shared, last_word
+        )
+        alpha = jnp.zeros((last_word.shape[0], 0), jnp.float32)
+        return StepState(beam=cache, shared=counters), logits, alpha
+
+    def finish(result, state):
+        return result._replace(
+            decoder_stats={
+                # [moe layers, E]: tokens each expert took, prefill + steps
+                "moe_counts": state.shared.moe_counts,
+                # [moe layers, T]: experts that took a token at each step
+                "moe_step_visits": state.shared.step_visits,
+                # [B, N, moe layers * k]: the experts each prefix position chose
+                "prefix_routes": prefix_routes,
+                # [B, K, T, moe layers * k]: the experts the tokens of each
+                # LIVE beam chose, step by step along its own ancestry
+                "step_routes": state.beam.routes.reshape(B, K, T, -1),
+            }
+        )
+
+    return Search(step_fn, state0, 0, finish)

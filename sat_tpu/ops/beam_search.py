@@ -55,18 +55,20 @@ Two drivers run the SAME expansion math (``_expand_step``):
 from __future__ import annotations
 
 from functools import partial
-from typing import NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..config import Config
+from ..models import decoders
 from ..models.decoder import (
     DecoderState,
     decoder_step,
     init_state,
     precompute_attend,
 )
+from ..models.decoders import StepState, tile_beams  # noqa: F401  (re-exported)
 
 NEG_INF = -1e30
 # Added to completed-caption scores when ranking them against live partial
@@ -94,6 +96,23 @@ class BeamResult(NamedTuple):
     # built from it — is unchanged).  Scalar int32 from run_search;
     # per-slot [S] int32 from harvest_slots.
     steps_run: Optional[jnp.ndarray] = None
+    # what the decoder itself reports of the batch, {name: array}, opaque
+    # to the search (``models/decoders.py`` ``Search.finish``: a decoder
+    # with experts returns its token counts and chosen experts); None
+    # where the decoder reports nothing
+    decoder_stats: Optional[dict] = None
+
+
+def _reorder_beams(state, B: int, K: int, batch_idx, parent):
+    """ONE tree-wide per-parent gather: every per-beam leaf ``[B*K, ...]``
+    follows its beam to the slot the search gave it."""
+
+    def gather(x):
+        return x.reshape((B, K) + x.shape[1:])[batch_idx, parent].reshape(x.shape)
+
+    if isinstance(state, StepState):
+        return state._replace(beam=jax.tree_util.tree_map(gather, state.beam))
+    return jax.tree_util.tree_map(gather, state)
 
 
 class SearchState(NamedTuple):
@@ -144,7 +163,7 @@ def _expand_step(
     V: int,
     An: int,
     valid_size: Optional[int],
-    new_state: DecoderState,
+    new_state,
     logits: jnp.ndarray,
     alpha: jnp.ndarray,
     t_vec: jnp.ndarray,
@@ -155,7 +174,8 @@ def _expand_step(
     pool run (bitwise parity between the two paths is BY CONSTRUCTION).
 
     new_state/logits/alpha: the decoder step's outputs over the flattened
-    [B*K] beam batch.  t_vec [B] int32: each row's own time index —
+    [B*K] beam batch; new_state is a tree of per-beam leaves (the LSTM's
+    ``DecoderState``) or a ``StepState``.  t_vec [B] int32: each row's own time index —
     per-row because pool slots run staggered; the monolithic driver
     passes the loop counter broadcast to all rows.  Time-indexed writes
     use a one-hot select over the T axis (value-identical to an
@@ -163,11 +183,10 @@ def _expand_step(
     """
     B = s.live_logp.shape[0]
     T = s.live_words.shape[2]
-    H = new_state.output.shape[-1]
     batch_idx = jnp.arange(B)[:, None]  # [B,1] for beam gathers
     t_hot = jnp.arange(T)[None, :] == t_vec[:, None]            # [B,T]
 
-    step_alpha = alpha.reshape(B, K, -1)[:, :, :An]             # [B,K,An]
+    step_alpha = alpha.reshape(B, K, alpha.shape[-1])[:, :, :An]  # [B,K,An]
     if valid_size is not None and valid_size < V:
         logits = logits.at[:, valid_size:].set(NEG_INF)
     row_logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
@@ -208,12 +227,7 @@ def _expand_step(
 
     # the per-parent gathers that reorder every beam's state
     with jax.named_scope("beam/tile"):
-        gather_bk = lambda x: x.reshape(B, K, -1)[batch_idx, parent]  # noqa: E731
-        state = DecoderState(
-            memory=gather_bk(new_state.memory).reshape(B * K, H),
-            output=gather_bk(new_state.output).reshape(B * K, H),
-            recurrent=gather_bk(new_state.recurrent).reshape(B * K, H),
-        )
+        state = _reorder_beams(new_state, B, K, batch_idx, parent)
         live_words = jnp.where(
             t_hot[:, None, :], word[:, :, None], s.live_words[batch_idx, parent]
         )
@@ -290,7 +304,7 @@ def _merge_results(
 def run_search(
     config: Config,
     step_fn,
-    state0: DecoderState,
+    state0,
     B: int,
     eos_id: int,
     beam_size: Optional[int] = None,
@@ -300,13 +314,17 @@ def run_search(
     alpha_width: Optional[int] = None,
     early_exit: bool = True,
     return_steps: bool = False,
-) -> BeamResult:
+    return_state: bool = False,
+):
     """The search engine shared by the single-device and context-parallel
-    decode paths.
+    decode paths.  Returns the BeamResult, or with ``return_state``
+    (result, the decoder's state as the last step left it).
 
     step_fn(state, last_word [B*K] int32) -> (new_state, logits [B*K, V],
     alpha [B*K, Na]) — one decoder step over the flattened beam batch.
-    state0: the per-image initial DecoderState already tiled to [B*K, H].
+    state0: the initial state already tiled to [B*K, ...] rows: a tree of
+    per-beam leaves (the LSTM's DecoderState), or a StepState whose
+    ``shared`` part rides along unreordered.
     alpha_width: Na of step_fn's alpha (the LOCAL context-block width
     under context parallelism); required when return_alphas is set.
     early_exit: stop the while_loop as soon as no image's result can
@@ -346,23 +364,13 @@ def run_search(
     # the loop's own ops (condition, counter, what only feeds it) carry a
     # scope too, so a trace read by scope leaves nothing of it unnamed
     with jax.named_scope("beam/loop"):
-        t_final, (_, search) = jax.lax.while_loop(
+        t_final, (state, search) = jax.lax.while_loop(
             cond, body, (jnp.int32(0), (state0, search0))
         )
-    return _merge_results(
+    result = _merge_results(
         search, K, return_alphas, steps=t_final if return_steps else None
     )
-
-
-@jax.named_scope("beam/tile")
-def tile_beams(x: jnp.ndarray, K: int) -> jnp.ndarray:
-    """[B, ...] -> [B*K, ...] with each image's row repeated K times — the
-    shared per-image tensors (context grid, hoisted projection, initial
-    state) flattened to the search's [B*K] step batch."""
-    B = x.shape[0]
-    return jnp.broadcast_to(x[:, None], (B, K) + x.shape[1:]).reshape(
-        (B * K,) + x.shape[1:]
-    )
+    return (result, state) if return_state else result
 
 
 def beam_search(
@@ -400,32 +408,17 @@ def beam_search(
     function into the same :func:`run_search` engine.
     """
     K = beam_size or config.beam_size
-    B, N, D = contexts.shape
-
-    # one shared context grid per image, flattened to a [B*K] step batch
-    ctx_tiled = tile_beams(contexts, K)
-
-    # hoist the context half of the attention MLP out of the T×K loop
-    # (loop-invariant at inference; the reference recomputes it every step)
-    proj_tiled = None
-    if hoist_attention:
-        proj_tiled = tile_beams(precompute_attend(params, config, contexts), K)
-
-    state0 = init_state(params, config, contexts, train=False)  # [B, H]
-    state0 = DecoderState(*(tile_beams(s, K) for s in state0))
-
-    def step_fn(state, last_word):
-        return decoder_step(
-            params, config, ctx_tiled, state, last_word,
-            train=False, ctx_proj=proj_tiled,
-        )
-
-    return run_search(
-        config, step_fn, state0, B, eos_id,
-        beam_size=K, max_len=max_len, valid_size=valid_size,
-        return_alphas=return_alphas, alpha_width=N, early_exit=early_exit,
-        return_steps=return_steps,
+    search = decoders.search(
+        params, config, contexts, K, max_len or config.max_caption_length,
+        hoist_attention=hoist_attention, return_alphas=return_alphas,
     )
+    result, state = run_search(
+        config, search.step_fn, search.state0, contexts.shape[0], eos_id,
+        beam_size=K, max_len=max_len, valid_size=valid_size,
+        return_alphas=return_alphas, alpha_width=search.alpha_width,
+        early_exit=early_exit, return_steps=return_steps, return_state=True,
+    )
+    return search.finish(result, state)
 
 
 @partial(

@@ -102,9 +102,15 @@ def write_sidecar(
     ckpt_path: str,
     topology: Optional[dict] = None,
     vocab: Optional[dict] = None,
+    dtypes: Optional[dict] = None,
 ) -> str:
     """Hash the landed checkpoint and record it; the sidecar is what makes
     later verification a byte-for-byte statement instead of a guess.
+
+    ``dtypes`` (optional) names the entries stored by a view of another
+    dtype — ``{entry: "bfloat16"}`` for leaves written as uint16, since
+    numpy's format has no bfloat16 (train.checkpoint.load_flat views them
+    back).
 
     ``topology`` (optional) is the device topology the checkpoint was
     written under — ``{"device_count", "mesh_shape", "mesh_axes",
@@ -129,6 +135,8 @@ def write_sidecar(
         meta["topology"] = topology
     if vocab:
         meta["vocab"] = vocab
+    if dtypes:
+        meta["dtypes"] = dtypes
     if meta:
         lines += json.dumps(meta, sort_keys=True) + "\n"
     atomic_write(sidecar_path(ckpt_path), "w", lambda f: f.write(lines))

@@ -171,9 +171,22 @@ def setup_state(
         seed = config.seed
     # set-up spans land in the run's telemetry where the CLI began it
     # before this call (cli.main); elsewhere they hit the null object
+    restoring = bool(load or model_file)
+    # where a checkpoint of this program is about to fill the tree, build
+    # its shapes only: nothing is initialised (small programs compiled and
+    # run, gigabytes for a language-model decoder) to be overwritten at
+    # once, and the device never holds an initialised copy beside the
+    # restored one.  (The reference's own .npy is imported leaf by leaf
+    # INTO an initialised state.)
+    shapes_only = restoring and not (model_file or "").endswith(".npy")
     with telemetry.span("setup/state"):
-        state = create_train_state(jax.random.PRNGKey(seed), config)
-    if load or model_file:
+        if shapes_only:
+            state = jax.eval_shape(
+                lambda: create_train_state(jax.random.PRNGKey(seed), config)
+            )
+        else:
+            state = create_train_state(jax.random.PRNGKey(seed), config)
+    if restoring:
         if model_file and model_file.endswith(".npy"):
             # a checkpoint written by the *reference* itself (flat TF1
             # var.name dict, base_model.py:242-249) — imported via the
@@ -196,6 +209,14 @@ def setup_state(
         if count == 0:
             raise ValueError(
                 f"checkpoint {model_file or config.save_dir} restored 0 tensors"
+            )
+        is_shape = lambda x: isinstance(x, jax.ShapeDtypeStruct)  # noqa: E731
+        if shapes_only and any(map(is_shape, jax.tree_util.tree_leaves(state))):
+            # a partial checkpoint (trimmed of its optimizer slots, say):
+            # what it did not fill is initialised after all
+            fresh = create_train_state(jax.random.PRNGKey(seed), config)
+            state = jax.tree_util.tree_map(
+                lambda got, new: new if is_shape(got) else got, state, fresh
             )
         print(f"{count} tensors loaded from checkpoint (step {int(state.step)}).")
     if load_cnn and cnn_model_file:
@@ -1310,6 +1331,14 @@ def decode_dataset(
             alphas = (
                 np.asarray(out.alphas[:, 0]) if out.alphas is not None else None  # sync-ok: decode drain boundary
             )
+            if out.decoder_stats and "moe_counts" in out.decoder_stats:
+                # tokens of the fullest expert over the mean, worst layer:
+                # the counts came back with the results, no sync of their own
+                counts = np.asarray(out.decoder_stats["moe_counts"], np.float64)  # sync-ok: decode drain boundary
+                tel.gauge(
+                    "decode/moe_load_max_over_mean",
+                    float((counts.max(axis=1) / counts.mean(axis=1)).max()),  # sync-ok: host numpy, already drained
+                )
         with tel.span("decode/drain/detok", b):  # host work after it
             for i, image_file in enumerate(files):
                 if emitted >= dataset.count:           # fake_count padding

@@ -76,6 +76,20 @@ def flatten_with_names(tree: Any, prefix: str = "") -> Dict[str, Any]:
     return {_path_name(prefix, path): leaf for path, leaf in leaves}
 
 
+_BFLOAT16 = np.dtype(jnp.bfloat16)
+
+
+def _bfloat16_as_uint16(flat: Dict[str, np.ndarray]):
+    """numpy's file format has no bfloat16 (it would write the leaf as an
+    opaque 2-byte void): such leaves are stored by an explicit uint16 view
+    of the same bytes.  Returns (what to write, {name: "bfloat16"} for the
+    sidecar's dtype note)."""
+    noted = {k: "bfloat16" for k, v in flat.items() if v.dtype == _BFLOAT16}
+    if not noted:
+        return flat, noted
+    return {k: v.view(np.uint16) if k in noted else v for k, v in flat.items()}, noted
+
+
 def _assign_leaves(tree: Any, prefix: str, data: Dict[str, np.ndarray]):
     """Rebuild ``tree`` with any leaf whose name appears in ``data`` (same
     shape) replaced.  Returns (new_tree, loaded_count) — the per-variable
@@ -88,6 +102,14 @@ def _assign_leaves(tree: Any, prefix: str, data: Dict[str, np.ndarray]):
         if name in data:
             value = np.asarray(data[name])
             if hasattr(leaf, "shape") and tuple(value.shape) == tuple(leaf.shape):
+                if leaf.dtype == _BFLOAT16 and value.dtype == np.uint16:
+                    # a view whose dtype note (the sidecar) was lost:
+                    # casting would turn bit patterns into numbers
+                    raise ValueError(
+                        f"checkpoint entry {name} holds raw uint16 for a "
+                        "bfloat16 leaf and its sidecar's dtype note is "
+                        "missing; restore the .sha256 file beside the npz"
+                    )
                 # jnp.array, not the raw numpy value: the CPU backend turns
                 # an aligned numpy argument into a ZERO-COPY device buffer
                 # that borrows the host memory, and train_step's
@@ -285,12 +307,14 @@ def _write_flat(
     verify passed AND the run was ``healthy`` at its last metrics check),
     and keep-N retention (docs/RESILIENCE.md)."""
     step = int(flat["global_step"])
+    stored, dtypes = _bfloat16_as_uint16(flat)
     # write through the file object: np.savez(path) appends '.npz' itself
     with telemetry.span("ckpt/write"):
         retry_io(
-            lambda: atomic_write(path, "wb", lambda f: np.savez(f, **flat)),
+            lambda: atomic_write(path, "wb", lambda f: np.savez(f, **stored)),
             desc=f"write checkpoint {path}",
         )
+    del stored
     # hash NOW, while the file is still exactly what we serialized: a
     # sidecar computed later would faithfully fingerprint whatever rot
     # happened in between and the verify would bless corrupt bytes
@@ -304,7 +328,7 @@ def _write_flat(
         except Exception:
             vocab = None  # attestation is best-effort; the save is not
         lineage.write_sidecar(
-            path, topology=_topology_snapshot(config), vocab=vocab
+            path, topology=_topology_snapshot(config), vocab=vocab, dtypes=dtypes
         )
     retry_io(
         lambda: config.replace(global_step=step).save(
@@ -385,9 +409,16 @@ def latest_checkpoint(save_dir: str) -> Optional[str]:
 
 
 def load_flat(path: str) -> Dict[str, np.ndarray]:
+    """The archive's entries, with the leaves the sidecar's dtype note
+    names viewed back as what they are (bit-exact: no cast)."""
+    noted = lineage.read_sidecar_meta(path).get("dtypes") or {}
+
     def _read() -> Dict[str, np.ndarray]:
         with np.load(path, allow_pickle=False) as z:
-            return {k: z[k] for k in z.files}
+            return {
+                k: z[k].view(_BFLOAT16) if noted.get(k) == "bfloat16" else z[k]
+                for k in z.files
+            }
 
     return retry_io(_read, desc=f"read checkpoint {path}")
 
@@ -528,7 +559,10 @@ def trim_checkpoint(in_path: str, out_path: str) -> int:
     number of entries kept."""
     flat = load_flat(in_path)
     kept = {k: v for k, v in flat.items() if not k.startswith("optimizer/")}
-    atomic_write(out_path, "wb", lambda f: np.savez(f, **kept))
+    stored, dtypes = _bfloat16_as_uint16(kept)
+    atomic_write(out_path, "wb", lambda f: np.savez(f, **stored))
+    if dtypes:  # the views need their note to be read back
+        lineage.write_sidecar(out_path, dtypes=dtypes)
     return len(kept)
 
 

@@ -23,6 +23,7 @@ import optax
 
 from ..config import Config
 from ..models.captioner import compute_loss, init_variables
+from ..models.decoders import split_frozen
 from .optimizer import make_optimizer
 
 
@@ -35,10 +36,30 @@ class TrainState(NamedTuple):
 
 def split_trainable(params: Dict[str, Any], config: Config):
     """(trainable, frozen) partition — CNN params are frozen unless
-    train_cnn (reference utils/nn.py:66)."""
+    train_cnn (reference utils/nn.py:66), and a decoder freezes what its
+    interface says (models/decoders.py: the language-model stack unless
+    train_lm)."""
+    dec_train, dec_frozen = split_frozen(params["decoder"], config)
+    trainable: Dict[str, Any] = {"decoder": dec_train}
+    frozen: Dict[str, Any] = {"decoder": dec_frozen} if dec_frozen else {}
     if config.train_cnn:
-        return dict(params), {}
-    return {"decoder": params["decoder"]}, {"cnn": params["cnn"]}
+        trainable = {**params, **trainable}
+    else:
+        frozen["cnn"] = params["cnn"]
+    return trainable, frozen
+
+
+def merge_params(base: Dict[str, Any], over: Dict[str, Any]) -> Dict[str, Any]:
+    """``base`` with ``over``'s leaves laid over it, dict by dict: the
+    inverse of split_trainable, whose two halves may share a key (a
+    decoder part frozen, part trained)."""
+    out = dict(base)
+    for key, value in over.items():
+        if isinstance(value, dict) and isinstance(out.get(key), dict):
+            out[key] = merge_params(out[key], value)
+        else:
+            out[key] = value
+    return out
 
 
 def create_train_state(rng: jax.Array, config: Config) -> TrainState:
@@ -62,7 +83,7 @@ def make_train_step(config: Config):
         trainable, frozen = split_trainable(state.params, config)
 
         def loss_fn(trainable_params):
-            params = {**frozen, **trainable_params}
+            params = merge_params(frozen, trainable_params)
             variables: Dict[str, Any] = {"params": params}
             if state.batch_stats:
                 variables["batch_stats"] = state.batch_stats
@@ -75,7 +96,7 @@ def make_train_step(config: Config):
             updates, new_opt_state = optimizer.update(grads, state.opt_state, trainable)
             new_trainable = optax.apply_updates(trainable, updates)
 
-        new_params = {**state.params, **new_trainable}
+        new_params = merge_params(state.params, new_trainable)
         new_batch_stats = aux["model_state"].get("batch_stats", state.batch_stats)
         new_state = TrainState(
             params=new_params,
@@ -89,9 +110,10 @@ def make_train_step(config: Config):
             # attention-map stats (the reference's attentions summary,
             # model.py:538-540): Σ_t α per context position, ideally ≈1
             att = aux["attentions"]
-            metrics["attention/mean"] = jnp.mean(att)
-            metrics["attention/std"] = jnp.std(att)
-            metrics["attention/max"] = jnp.max(att)
+            if att is not None:
+                metrics["attention/mean"] = jnp.mean(att)
+                metrics["attention/std"] = jnp.std(att)
+                metrics["attention/max"] = jnp.max(att)
         if config.diag_level != "off":
             # update-side diag taps (telemetry/device.py): merged into the
             # metrics pytree so they ride the existing log-sync fetch —

@@ -249,3 +249,84 @@ def test_encode_cache_ring_compiles(program):
     else:
         compiled = jax.jit(encode_cache.gather_rows).lower(store, idx).compile()
     assert compiled.memory_analysis().argument_size_in_bytes >= rows * row_bytes
+
+
+def _strip_metadata(text):
+    """Optimized HLO without what names source lines: metadata, the tables
+    of files and stack frames, and the Mosaic kernels' embedded locations."""
+    text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"stack_frame_id=\d+", "", text)
+    text = re.sub(r'"body":"[^"]*"', "", text)
+    return "\n".join(
+        ln for ln in text.splitlines() if not re.match(r'^\d+ (\{|")', ln)
+    )
+
+
+def test_the_tree_wide_reorder_leaves_the_lstm_beam_program_as_it_was(monkeypatch):
+    """``_reorder_beams`` (one gather over whatever tree the decoder
+    carries) against the three hand-written gathers of the LSTM's state
+    it replaced, kept here as the reference: the eval cell's program
+    comes out of the compiler the same but for metadata."""
+    from sat_tpu.models.decoder import DecoderState
+
+    bs = __import__("sat_tpu.ops.beam_search", fromlist=["x"])
+    config = Config()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, decoder = _decoder_params(config)
+    args = (decoder, config, _sd((64, config.num_ctx, config.dim_ctx)), 3)
+
+    def compiled():
+        fn = jax.jit(bs.beam_search, static_argnames=("config", "eos_id", "beam_size", "valid_size"))
+        return _strip_metadata(
+            fn.lower(*args, beam_size=3, valid_size=config.vocabulary_size).compile().as_text()
+        )
+
+    new = compiled()
+
+    def by_hand(state, B, K, batch_idx, parent):
+        H = state.output.shape[-1]
+        gather_bk = lambda x: x.reshape(B, K, -1)[batch_idx, parent]  # noqa: E731
+        return DecoderState(
+            memory=gather_bk(state.memory).reshape(B * K, H),
+            output=gather_bk(state.output).reshape(B * K, H),
+            recurrent=gather_bk(state.recurrent).reshape(B * K, H),
+        )
+
+    monkeypatch.setattr(bs, "_reorder_beams", by_hand)
+    assert compiled() == new
+
+
+def test_lm_beam_program_compiles_with_the_grouped_kernel_and_no_vocabulary_sort(monkeypatch):
+    """The language-model decoder's beam program at the new cell's widths
+    and batch (one period of the stack: the selections and the kernels do
+    not depend on depth): accepted by the chip's compiler, the experts
+    through the Pallas grouped product, the vocabulary through ``TopK``
+    and never a sort, and within the chip's memory beside 6.4 GB of
+    weights."""
+    from sat_tpu.ops.beam_search import beam_search_jit
+
+    config = Config(
+        decoder="lfm2_moe", vocabulary_size=65536, num_hidden_layers=5, num_dense_layers=1,
+        layer_types=("conv", "full_attention", "conv", "conv", "conv"),
+    )
+    V, K = config.vocabulary_size, 3
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, decoder = _decoder_params(config)
+    compiled = beam_search_jit.lower(
+        decoder, config, _sd((256, config.num_ctx, config.dim_ctx)), 1,
+        beam_size=K, valid_size=V,
+    ).compile()
+    text = compiled.as_text()
+    sorts = [ln for ln in text.splitlines() if re.search(r"\bsort\(", ln)]
+    wide = [
+        ln.strip()[:160] for ln in sorts
+        if {V, K * V} & {
+            int(d) for dims in re.findall(r"\[([\d,]+)\]", ln) for d in dims.split(",")
+        }
+    ]
+    assert not wide, wide
+    assert text.count('custom_call_target="TopK"') >= 2
+    # three grouped products an expert layer, prefill and step
+    assert len(re.findall(r"decoder/lm/moe/experts[^\n]*tpu_custom_call|"
+                          r"tpu_custom_call[^\n]*decoder/lm/moe/experts", text)) >= 6
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 << 30

@@ -102,6 +102,40 @@ def test_restore_0_tensors_is_an_error(coco_fixture, tmp_path):
         )
 
 
+@pytest.mark.parametrize("trimmed", [False, True], ids=["full", "trimmed"])
+def test_a_restore_initialises_only_what_the_checkpoint_lacks(trained, tmp_path, monkeypatch, trimmed):
+    """``setup_state`` builds shapes only where a checkpoint is about to
+    fill the tree: a full checkpoint initialises NOTHING (no state is made
+    to be overwritten at once), a trimmed one (no optimizer slots) only
+    what it lacks, and either way the restored leaves are the file's."""
+    import jax
+
+    from sat_tpu.train.checkpoint import load_flat, state_to_flat, trim_checkpoint
+
+    config, _ = trained
+    path = latest_checkpoint(config.save_dir)
+    if trimmed:
+        path, full = str(tmp_path / "trimmed.npz"), path
+        trim_checkpoint(full, path)
+    made = []
+    real = runtime.create_train_state
+
+    def counted(rng, cfg):
+        out = real(rng, cfg)
+        made.append(any(not isinstance(x, jax.core.Tracer) for x in jax.tree_util.tree_leaves(out)))
+        return out
+
+    monkeypatch.setattr(runtime, "create_train_state", counted)
+    state = runtime.setup_state(config, load=True, model_file=path)
+    # the shape pass traces (abstract leaves); a concrete call is an initialisation
+    assert made.count(True) == (1 if trimmed else 0), made
+    assert not any(isinstance(x, jax.ShapeDtypeStruct) for x in jax.tree_util.tree_leaves(state))
+    got, want = state_to_flat(state), load_flat(path)
+    for name, value in want.items():
+        np.testing.assert_array_equal(got[name], value, err_msg=name)
+    assert set(got) >= set(want) and any(k.startswith("optimizer/") for k in got)
+
+
 # ---------------------------------------------------------------------------
 # CLI
 # ---------------------------------------------------------------------------
