@@ -344,13 +344,13 @@ def phase_eval(cfg_path: str, meter: CompileMeter, rehearsal: bool) -> None:
           "kernel was not taken")
 
     # kernel vs its plain-XLA twin on the same inputs, on this device, at
-    # the beam program's shapes (B images x 3 beams rows)
+    # the beam program's shapes (B per-image grids, 3 beam rows an image)
     rng = np.random.default_rng(config.seed)
     rows, da = 3 * B, config.dim_attend_layer
-    t1 = jnp.tanh(jnp.asarray(rng.normal(size=(rows, N, da)), jnp.float32))
+    t1 = jnp.tanh(jnp.asarray(rng.normal(size=(B, N, da)), jnp.float32))
     t2 = jnp.tanh(jnp.asarray(rng.normal(size=(rows, da)), jnp.float32))
     w2 = jnp.asarray(0.08 * rng.normal(size=(da, 1)), jnp.float32)
-    ctx = jnp.abs(jnp.asarray(rng.normal(size=(rows, N, D)), jnp.float32))
+    ctx = jnp.abs(jnp.asarray(rng.normal(size=(B, N, D)), jnp.float32))
     got = fused_attend(
         t1, t2, w2, ctx, compute_dtype=config.compute_dtype,
         interpret=rehearsal,

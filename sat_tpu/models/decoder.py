@@ -296,11 +296,16 @@ def attend_with_precomputed(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Inference-path attention using the hoisted ``ctx_proj``.
 
-    Returns (context [B, D], alpha [B, N]).  With use_pallas_attention the
-    2-layer combine runs as one fused Pallas kernel (add → matvec →
-    softmax → weighted sum in a single VMEM residency).
+    ``contexts`` [B, N, D] and ``ctx_proj`` are per IMAGE; ``output``
+    [B*K, H] is per step row, the K beams of an image adjacent (K read from
+    the shapes; 1 where every row has a grid of its own: greedy, the slot
+    pool's carry).  Returns (context [B*K, D], alpha [B*K, N]).  With
+    use_pallas_attention the 2-layer combine runs as one fused Pallas
+    kernel (add → matvec → softmax → weighted sum in a single VMEM
+    residency of an image's grid for all its beams); the XLA path
+    broadcasts the grid over the beams inside its fusions.
 
-    row_mask: optional [B] bool — slot-pool geometry (the stepped decode
+    row_mask: optional [B*K] bool — slot-pool geometry (the stepped decode
     batches dead slots alongside live ones).  False rows get zero
     scores/alpha/context so stale slot state can never emit a NaN; True
     rows are bitwise identical to the unmasked call.  Masking is applied
@@ -308,43 +313,44 @@ def attend_with_precomputed(
     """
     p = params["attend"]
     dt = jnp.dtype(config.compute_dtype)
-    valid = None if row_mask is None else row_mask.reshape(-1, 1)   # [B, 1]
+    B, N = contexts.shape[:2]
+    rows = output.shape[0]
+    K = rows // B
+    if K * B != rows:
+        raise ValueError(
+            f"{rows} decoder rows over {B} context grids: the rows must be "
+            "a whole number of beams per grid"
+        )
     if config.num_attend_layers == 1:
-        logits = ctx_proj + _dense(p["fc_b"], output, dtype=dt)     # [B, N]
-        if valid is not None:
-            logits = jnp.where(valid, logits, 0.0)
-        alpha = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        if valid is not None:
-            alpha = jnp.where(valid, alpha, 0.0)
-        context = (contexts * alpha[..., None]).sum(axis=1)
-        if valid is not None:
-            context = jnp.where(valid, context, 0.0)
-        return context, alpha
+        logits = (
+            ctx_proj[:, None] + _dense(p["fc_b"], output, dtype=dt).reshape(B, K, N)
+        )                                                           # [B, K, N]
+    else:
+        t2 = _dense(p["fc_1b"], output, activation="tanh", dtype=dt)  # [B*K, da]
+        if config.use_pallas_attention:
+            from ..ops import pallas_attention
 
-    t2 = _dense(p["fc_1b"], output, activation="tanh", dtype=dt)    # [B, da]
-    if config.use_pallas_attention:
-        from ..ops import pallas_attention
-
-        # Interpret mode is a test vehicle only — off TPU the XLA branch
-        # below is the fast mathematically-identical fallback.
-        if jax.default_backend() == "tpu" or pallas_attention.FORCE_INTERPRET:
-            return pallas_attention.fused_attend(
-                ctx_proj, t2, p["fc_2"]["kernel"], contexts,
-                row_mask=row_mask,
-                compute_dtype=config.compute_dtype,
-                interpret=jax.default_backend() != "tpu",
-            )
-    temp = ctx_proj + t2[:, None, :]
-    logits = _dense(p["fc_2"], temp, dtype=dt)[..., 0]              # [B, N]
+            # Interpret mode is a test vehicle only — off TPU the XLA branch
+            # below is the fast mathematically-identical fallback.
+            if jax.default_backend() == "tpu" or pallas_attention.FORCE_INTERPRET:
+                return pallas_attention.fused_attend(
+                    ctx_proj, t2, p["fc_2"]["kernel"], contexts,
+                    row_mask=row_mask,
+                    compute_dtype=config.compute_dtype,
+                    interpret=jax.default_backend() != "tpu",
+                )
+        temp = ctx_proj[:, None] + t2.reshape(B, K, 1, -1)         # [B, K, N, da]
+        logits = _dense(p["fc_2"], temp, dtype=dt)[..., 0]          # [B, K, N]
+    valid = None if row_mask is None else row_mask.reshape(B, K, 1)
     if valid is not None:
         logits = jnp.where(valid, logits, 0.0)
     alpha = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     if valid is not None:
         alpha = jnp.where(valid, alpha, 0.0)
-    context = (contexts * alpha[..., None]).sum(axis=1)
+    context = (contexts[:, None] * alpha[..., None]).sum(axis=2)    # [B, K, D]
     if valid is not None:
         context = jnp.where(valid, context, 0.0)
-    return context, alpha
+    return context.reshape(rows, -1), alpha.reshape(rows, N)
 
 
 @jax.named_scope("decoder/logits")

@@ -68,9 +68,10 @@ class Search(NamedTuple):
 
 @jax.named_scope("beam/tile")
 def tile_beams(x: jnp.ndarray, K: int) -> jnp.ndarray:
-    """[B, ...] -> [B*K, ...] with each image's row repeated K times — the
-    shared per-image tensors (context grid, hoisted projection, initial
-    state) flattened to the search's [B*K] step batch."""
+    """[B, ...] -> [B*K, ...] with each image's row repeated K times: what
+    starts out per image and then differs per beam (the initial state),
+    flattened to the search's [B*K] step batch.  What never differs per
+    beam (the context grid, its hoisted projection) is not tiled."""
     B = x.shape[0]
     return jnp.broadcast_to(x[:, None], (B, K) + x.shape[1:]).reshape(
         (B * K,) + x.shape[1:]
@@ -131,20 +132,20 @@ def search(
             )
         return _lm_search(params, config, contexts, K, T)
 
-    # one shared context grid per image, flattened to a [B*K] step batch
-    ctx_tiled = tile_beams(contexts, K)
-    # hoist the context half of the attention MLP out of the T×K loop
-    # (loop-invariant at inference; the reference recomputes it every step)
-    proj_tiled = None
+    # the grid and the hoisted context half of the attention MLP stay per
+    # IMAGE: every beam of an image attends over the same [N, D] block
+    # (loop-invariant at inference; the reference recomputes the
+    # projection every step).  The per-step oracle keeps a grid per row.
     if hoist_attention:
-        proj_tiled = tile_beams(precompute_attend(params, config, contexts), K)
+        grid, proj = contexts, precompute_attend(params, config, contexts)
+    else:
+        grid, proj = tile_beams(contexts, K), None
     state0 = init_state(params, config, contexts, train=False)  # [B, H]
     state0 = DecoderState(*(tile_beams(s, K) for s in state0))
 
     def step_fn(state, last_word):
         return decoder_step(
-            params, config, ctx_tiled, state, last_word,
-            train=False, ctx_proj=proj_tiled,
+            params, config, grid, state, last_word, train=False, ctx_proj=proj
         )
 
     return Search(step_fn, state0, contexts.shape[1], lambda result, state: result)

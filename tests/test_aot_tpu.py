@@ -89,14 +89,17 @@ _DA = Config().dim_attend_layer
 @pytest.mark.parametrize("batch", [3, 32])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("masked", [False, True], ids=["unmasked", "masked"])
+@pytest.mark.parametrize("beams", [1, 3], ids=["k1", "k3"])
 @pytest.mark.parametrize("cnn", sorted(_WIDTHS))
-def test_fused_attend_compiles(cnn, masked, dtype, batch):
+def test_fused_attend_compiles(cnn, beams, masked, dtype, batch):
+    """``batch`` per-image grids, ``beams`` step rows an image."""
     from sat_tpu.ops.pallas_attention import fused_attend
 
     N, D = _WIDTHS[cnn]
-    kwargs = {"row_mask": _sd((batch,), jnp.bool_)} if masked else {}
+    rows = batch * beams
+    kwargs = {"row_mask": _sd((rows,), jnp.bool_)} if masked else {}
     compiled = fused_attend.lower(
-        _sd((batch, N, _DA)), _sd((batch, _DA)), _sd((_DA, 1)),
+        _sd((batch, N, _DA)), _sd((rows, _DA)), _sd((_DA, 1)),
         _sd((batch, N, D)), compute_dtype=dtype, **kwargs,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -179,6 +182,63 @@ def test_beam_programs_select_without_a_vocabulary_sort(monkeypatch, program, K)
     assert not wide, wide
     # the threshold over [rows, V] and the continuations over [B, K*V]
     assert text.count('custom_call_target="TopK"') >= 2
+
+
+def _computations(text):
+    """{name: body text} of an optimized HLO module's computations."""
+    chunks = re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)
+    named = (re.match(r"(?:ENTRY )?%([\w.\-]+) \(", c) for c in chunks)
+    return {m.group(1): c for m, c in zip(named, chunks) if m}
+
+
+def _reachable(computations, root):
+    """``root`` and every computation it calls (fusions, nested loops)."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in computations:
+            continue
+        seen.add(name)
+        todo += re.findall(
+            r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", computations[name]
+        )
+    return seen
+
+
+def test_beam_program_holds_one_grid_per_image(monkeypatch):
+    """The eval cell's beam program (B = 512, K = 3, VGG16 widths): the K
+    beams of an image read the image's grid and projection in place.  No
+    array holds a copy per beam (1536 = 512 x 3 rows of the grid, padded
+    or not), in the loop or outside it; what the loop still pads on every
+    step is the per-image grid and projection, 196 -> 200 rows (PERF.md
+    section 7 says why that pad has not left the step yet)."""
+    from sat_tpu.ops.beam_search import beam_search_jit
+
+    config = Config()
+    N, D = config.num_ctx, config.dim_ctx
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, decoder = _decoder_params(config)
+    compiled = beam_search_jit.lower(
+        decoder, config, _sd((512, N, D)), 3, beam_size=3,
+        valid_size=config.vocabulary_size,
+    ).compile()
+    text = compiled.as_text()
+    for shape in (f"[1536,{N},", f"[1536,{N + 4},", f"[512,3,{N},"):
+        assert shape not in text, shape
+
+    computations = _computations(text)
+    bodies = re.findall(r" while\([^\n]*body=%([\w.\-]+)", text)
+    assert bodies
+    in_loop = set().union(*(_reachable(computations, b) for b in bodies))
+    lines = [ln for name in in_loop for ln in computations[name].splitlines()]
+    assert sum("tpu_custom_call" in ln for ln in lines) == 1
+    pads = [
+        re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (f32\[[\d,]+\])", ln).group(1)
+        for ln in lines if re.search(r" pad\(", ln) and re.search(r"= f32\[\d+,\d+,\d+\]", ln)
+    ]
+    assert sorted(pads) == [f"f32[512,{N + 4},{D}]"] * 2, pads
+    # the two copies per beam and their padded twins (2.36 GB) are gone
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 @pytest.mark.parametrize("mode", ["int8", "bf16"])

@@ -32,18 +32,79 @@ def _cfg(**kw):
     return Config(**{**base, **kw})
 
 
-def test_fused_attend_matches_reference(rng):
-    B, N, da, D = 3, 17, 16, 24
+def _kernel_inputs(rng, B, K, N=17, da=16, D=24):
+    """Per-image grid and projection, K beam rows an image."""
     t1 = jnp.asarray(rng.normal(size=(B, N, da)).astype(np.float32))
-    t2 = jnp.asarray(rng.normal(size=(B, da)).astype(np.float32))
+    t2 = jnp.asarray(rng.normal(size=(B * K, da)).astype(np.float32))
     w2 = jnp.asarray(rng.normal(size=(da, 1)).astype(np.float32))
     ctx = jnp.asarray(rng.normal(size=(B, N, D)).astype(np.float32))
+    return t1, t2, w2, ctx
+
+
+@pytest.mark.parametrize("K", [1, 3])
+def test_fused_attend_matches_reference(rng, K):
+    t1, t2, w2, ctx = _kernel_inputs(rng, 3, K)
 
     want_ctx, want_alpha = fused_attend_reference(t1, t2, w2, ctx)
     got_ctx, got_alpha = fused_attend(t1, t2, w2, ctx, interpret=True)
+    assert got_ctx.shape == (3 * K, 24) and got_alpha.shape == (3 * K, 17)
     np.testing.assert_allclose(got_alpha, want_alpha, rtol=1e-6, atol=1e-6)
     np.testing.assert_allclose(got_ctx, want_ctx, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(got_alpha).sum(-1), 1.0, rtol=1e-6)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["unmasked", "masked"])
+@pytest.mark.parametrize(
+    "B,K,block_b", [(5, 1, 4), (5, 3, 4), (3, 3, 8), (13, 3, 8), (8, 3, 8)]
+)
+def test_fused_attend_per_image_equals_tiled(rng, B, K, block_b, masked):
+    """One grid per image under K beams gives, bitwise, what the call on
+    K copies of the grid gives (every row a grid of its own: the layout
+    before the kernel's grid ran over images): a row's arithmetic does not
+    depend on K.  Odd B pads the image axis.  Masked: a dead row among an
+    image's beams, its per-row input poisoned, comes out exactly zero and
+    leaves its siblings as they were."""
+    t1, t2, w2, ctx = _kernel_inputs(rng, B, K)
+    kwargs = {}
+    if masked:
+        mask = jnp.asarray(rng.integers(0, 2, size=(B * K,)).astype(bool))
+        mask = mask.at[0].set(False).at[K - 1].set(K > 1)
+        t2 = t2.at[~mask].set(jnp.nan)
+        kwargs = {"row_mask": mask}
+
+    def tile(x):
+        return jnp.repeat(x, K, axis=0)
+
+    got_ctx, got_alpha = fused_attend(
+        t1, t2, w2, ctx, interpret=True, block_b=block_b, **kwargs
+    )
+    base_ctx, base_alpha = fused_attend(
+        tile(t1), t2, w2, tile(ctx), interpret=True, block_b=block_b, **kwargs
+    )
+    np.testing.assert_array_equal(np.asarray(got_ctx), np.asarray(base_ctx))
+    np.testing.assert_array_equal(np.asarray(got_alpha), np.asarray(base_alpha))
+
+    # the oracle takes the same shapes, and agrees with itself on the tiled
+    ref_ctx, ref_alpha = fused_attend_reference(t1, t2, w2, ctx, **kwargs)
+    tiled_ctx, tiled_alpha = fused_attend_reference(
+        tile(t1), t2, w2, tile(ctx), **kwargs
+    )
+    np.testing.assert_allclose(ref_alpha, tiled_alpha, rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(ref_ctx, tiled_ctx, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(got_alpha, ref_alpha, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(got_ctx, ref_ctx, rtol=1e-5, atol=1e-5)
+    if masked:
+        dead = np.asarray(~mask)
+        assert dead.any() and not dead.all()
+        assert bool(jnp.isfinite(got_ctx).all() and jnp.isfinite(got_alpha).all())
+        assert (np.asarray(got_ctx)[dead] == 0).all()
+        assert (np.asarray(got_alpha)[dead] == 0).all()
+
+
+def test_fused_attend_refuses_rows_that_are_no_whole_number_of_beams(rng):
+    t1, t2, w2, ctx = _kernel_inputs(rng, 3, 2)
+    with pytest.raises(ValueError, match="whole number of beams"):
+        fused_attend(t1, t2[:5], w2, ctx, interpret=True)
 
 
 @pytest.mark.parametrize("B,block_b", [(5, 4), (8, 8), (2, 8), (13, 4)])
@@ -229,6 +290,43 @@ def test_attend_with_precomputed_row_mask_xla_path(rng, layers):
     np.testing.assert_array_equal(
         np.asarray(alpha_m)[live], np.asarray(alpha_base)[live]
     )
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["unmasked", "masked"])
+@pytest.mark.parametrize("layers", [1, 2])
+def test_attend_with_precomputed_per_image_equals_tiled_xla_path(rng, layers, masked):
+    """The XLA twin under K beams an image: the grid broadcast over the
+    beams inside the fusion gives, bitwise, what K copies of the grid
+    gave; a dead beam row with NaN state comes out zero."""
+    config = _cfg(num_attend_layers=layers, use_pallas_attention=False)
+    params = init_decoder_params(jax.random.PRNGKey(0), config)
+    B, K, N, D = 3, 3, config.num_ctx, config.dim_ctx
+    contexts = jnp.asarray(rng.normal(size=(B, N, D)).astype(np.float32))
+    output = jnp.asarray(
+        rng.normal(size=(B * K, config.num_lstm_units)).astype(np.float32)
+    )
+    proj = precompute_attend(params, config, contexts)
+    mask = None
+    if masked:
+        mask = jnp.asarray(np.arange(B * K) % 4 != 1)
+        output = output.at[~mask].set(jnp.nan)
+
+    got_ctx, got_alpha = attend_with_precomputed(
+        params, config, contexts, proj, output, row_mask=mask
+    )
+    base_ctx, base_alpha = attend_with_precomputed(
+        params, config, jnp.repeat(contexts, K, axis=0),
+        jnp.repeat(proj, K, axis=0), output, row_mask=mask,
+    )
+    assert got_ctx.shape == (B * K, D) and got_alpha.shape == (B * K, N)
+    np.testing.assert_array_equal(np.asarray(got_ctx), np.asarray(base_ctx))
+    np.testing.assert_array_equal(np.asarray(got_alpha), np.asarray(base_alpha))
+    if masked:
+        dead = np.asarray(~mask)
+        assert (np.asarray(got_ctx)[dead] == 0).all()
+        assert (np.asarray(got_alpha)[dead] == 0).all()
+    with pytest.raises(ValueError, match="whole number of beams"):
+        attend_with_precomputed(params, config, contexts, proj, output[:-1])
 
 
 def test_fused_attend_bf16_scoring_matches_oracle(rng):

@@ -265,10 +265,14 @@ def test_backward_pass_and_stacked_residuals_are_told_apart(analyzed):
     assert any(_bucket(TRAIN_RULES, o) == "scan_plumbing" for o in ops)
 
 
-def test_the_pad_round_the_kernel_carries_its_own_scope():
+@pytest.mark.parametrize("beams", [1, 3])
+def test_the_pad_round_the_kernel_carries_its_own_scope(beams):
+    """One grid a row, and one grid an image under three beams: what is
+    left round the kernel (the image axis padded to a block, the rows
+    regrouped by image) is named apart from it."""
     from sat_tpu.ops.pallas_attention import fused_attend
 
-    text = fused_attend.lower(jnp.zeros((3, 5, 16)), jnp.zeros((3, 16)), jnp.zeros((16, 1)),
+    text = fused_attend.lower(jnp.zeros((3, 5, 16)), jnp.zeros((3 * beams, 16)), jnp.zeros((16, 1)),
                               jnp.zeros((3, 5, 8)), interpret=True).as_text(debug_info=True)
     assert "decoder/attend/pad" in text and "fused_attend" in text
 
