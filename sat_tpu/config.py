@@ -148,8 +148,8 @@ class Config:
     supervise_backoff_s: float = 1.0
     # Data-plane integrity (data/integrity.py): verify gathered shard
     # rows against their per-row crc32c sidecars.  'off' trusts storage;
-    # 'sample' scrubs one rotating row every few gathers (≪1% of a
-    # step — scripts/bench_integrity.py gates it); 'open' fully verifies
+    # 'sample' scrubs one rotating row every few gathers (<1% of a
+    # step: tests/test_integrity.py holds it); 'open' fully verifies
     # each shard on first touch; 'full' verifies every row every batch.
     verify_shards: str = "off"
     # Quarantine ledger path ("" = <summary_dir>/quarantine.jsonl) and
@@ -495,9 +495,8 @@ class Config:
     context_parallel: int = 1          # shard the context grid over 'model'
     prefetch_depth: int = 2            # host→HBM async pipeline depth
     # Fused Pallas soft-attention kernel on the decode path (train and
-    # non-TPU backends always use the XLA path).  Its speed against XLA's
-    # fusion is not measured on the current machine
-    # (scripts/bench_pallas.py is the vehicle).
+    # non-TPU backends always use the XLA path); `attend_kernel_us` of
+    # vgg16-eval-beam3-b512 is its time on the chip.
     use_pallas_attention: bool = True
     # Post-training quantization of the FROZEN encoder on the serve path
     # (sat_tpu/nn/quant.py; docs/SERVING.md "Precision & parity").  "off"

@@ -15,7 +15,7 @@ This module is the detection half of the data-plane immune system
 
   - ``off``    — nothing (default; trust the storage);
   - ``sample`` — one rotating row every :data:`SAMPLE_EVERY` gathers,
-    amortized ≪1% of a step (scripts/bench_integrity.py gates it);
+    amortized <1% of a step (tests/test_integrity.py holds it);
   - ``open``   — full verify of each shard the first time a gather
     touches it, cached bad-row set consulted thereafter;
   - ``full``   — every gathered row, every batch (audit mode);
@@ -54,9 +54,9 @@ from ..utils.summary import (
 
 CRC_SUFFIX = ".crc.npy"
 VERIFY_MODES = ("off", "sample", "open", "full")
-# sample mode verifies one row every this many gather calls: with the
-# ~3 ms cost of one 224px-row crc, cadence 16 amortizes to ~0.2 ms per
-# step — under the 1%-of-30ms budget bench_integrity.py enforces
+# sample mode verifies one row every this many gather calls, so a step
+# pays a sixteenth of one row's crc: under 1% of the train cell's step
+# (test_sampled_shard_verification_under_one_percent_of_a_step)
 SAMPLE_EVERY = 16
 
 

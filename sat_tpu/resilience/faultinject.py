@@ -11,8 +11,8 @@ deterministically at a configured step (or call count) and exactly once.
 All knobs are **inert by default**: with no ``SAT_FI_*`` variables set,
 every hook is a handful of host-side compares and the production hot loop
 is untouched (``tests/conftest.py`` asserts this).  No jax is imported at
-module level so the harness (and ``scripts/bench_ckpt.py``) stays usable
-on hosts with no accelerator backend at all.
+module level so the harness stays usable on hosts with no accelerator
+backend at all.
 
 Knobs::
 

@@ -29,7 +29,7 @@ Directory layout::
       config.json        # step-stamped Config sidecar (train.checkpoint)
 
 No jax at module level: lineage is pure host IO, shared with the jax-free
-``scripts/bench_ckpt.py``.
+``--supervise`` parent and ``scripts/chaos_campaign.py``.
 """
 
 from __future__ import annotations

@@ -19,7 +19,7 @@ propagates untouched.
 No jax, and no sat_tpu imports beyond ``faultinject`` (the injection
 point ``SAT_FI_IO_FAILURES`` lands here) and the equally jax-free
 ``telemetry`` (each retry ticks the ``io/retries`` counter), so the
-wrapper is usable from host-only tools like ``scripts/bench_ckpt.py``.
+wrapper is usable from processes that must not hold an accelerator.
 """
 
 from __future__ import annotations

@@ -3,7 +3,7 @@
 The train loop's one deliberate host sync is the ``log_every`` metrics
 fetch (``runtime.train``); the sentinel inspects THOSE host-side floats
 and nothing else, so arming it adds **zero device syncs** to the hot
-path (``scripts/bench_ckpt.py`` tracks the cost).  The trade-off is
+path.  The trade-off is
 detection latency: a poison step is noticed at the next log boundary,
 which is why recovery is lineage-based (roll back to ``LAST_GOOD``)
 rather than "undo one step".

@@ -46,7 +46,8 @@ from .. import telemetry
 
 # Distinct from every exit code already in the fleet's vocabulary:
 # 0 clean, 1 checkpoint-write/preemption failure, 2 pytest/argparse,
-# 3 check_regression infra-skip, 87 systemic data corruption (the
+# 3 unmeasured (a measurement that could not be taken: neither a pass
+# nor a regression), 87 systemic data corruption (the
 # quarantine ceiling — resilience/quarantine.py; the supervisor must NOT
 # restart it).  The supervisor treats this one as "wedged, state on disk
 # is good, restart me".

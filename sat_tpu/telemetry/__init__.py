@@ -6,10 +6,11 @@ gauges (``spans``), Chrome-trace / JSONL / breakdown exporters
 (``exporters``), and the pollable ``heartbeat.json`` writer
 (``heartbeat``).  See docs/OBSERVABILITY.md.
 
-This package is deliberately jax-free so host-only tools
-(``scripts/bench_telemetry.py``) can use it without an accelerator
-backend.  Only ``spans`` is imported eagerly; runtime imports the
-exporters and heartbeat directly.
+This package is deliberately jax-free so the processes that must never
+hold an accelerator (the ``--supervise`` parent, the router) can use it
+(``tests/test_device_diag.py::test_telemetry_core_is_jax_free``).  Only
+``spans`` is imported eagerly; runtime imports the exporters and
+heartbeat directly.
 """
 
 from __future__ import annotations
@@ -37,10 +38,11 @@ from .spans import (  # noqa: F401
 # joins never depend on file mtimes or directory layout.
 RUN_ID = f"{int(time.time()):x}-{os.getpid()}"
 
-# Version of the benchmark/report artifact contract (BENCH JSON rows,
-# compile_report.json).  scripts/check_regression.py refuses to compare
-# artifacts stamped with a different major version; bump it when a field
-# changes meaning (not when fields are added).
+# Version of the report artifact contract (compile_report.json,
+# heartbeat.json, slo.jsonl, the fleet files, a chaos campaign's rows).
+# A reader refuses artifacts stamped with another version, as
+# scripts/check_slo.py does; bump it when a field changes meaning (not
+# when fields are added).
 SCHEMA_VERSION = 1
 
 
@@ -54,7 +56,7 @@ def process_identity() -> tuple:
     Same contract as :func:`bench_stamp`: never imports jax — the facts
     are read via ``sys.modules`` only when the caller already initialized
     a backend, and a single-process / host-only caller gets (0, 1).  The
-    fleet plane (telemetry/fleet.py), heartbeat.json, and bench rows all
+    fleet plane (telemetry/fleet.py), heartbeat.json, and report rows all
     stamp through here so cross-host artifacts agree on who wrote them."""
     import sys
 
@@ -68,17 +70,17 @@ def process_identity() -> tuple:
 
 
 def bench_stamp() -> dict:
-    """Provenance stamp shared by every ``scripts/bench_*.py`` JSON output
-    and ``compile_report.json``: artifact schema version, git SHA, and a
-    device/host descriptor — the fields ``check_regression.py`` needs to
-    decide whether two artifacts are comparable at all.
+    """Provenance stamp of a report row (``scripts/chaos_campaign.py``
+    writes it on every row): artifact schema version, git SHA, and a
+    device/host descriptor — what a reader needs to decide whether two
+    artifacts are comparable at all.
 
     Deliberately import-light: no jax import ever (this package is
     jax-free); device facts are read only when the caller already
-    initialized jax, and only via ``sys.modules`` so a host-only bench
-    (bench_telemetry, bench_input) never drags a backend in.  Callers
-    stamp at emit time — after their device work — so touching
-    ``local_devices()`` here never triggers a fresh backend init."""
+    initialized jax, and only via ``sys.modules`` so a host-only caller
+    never drags a backend in.  Callers stamp at emit time — after their
+    device work — so touching ``local_devices()`` here never triggers a
+    fresh backend init."""
     import platform
     import subprocess
     import sys

@@ -98,10 +98,10 @@ def sidecar_path(fleet_dir: str, process_index: int) -> str:
 def _atomic_json(path: str, doc) -> None:
     """Hot-path atomic JSON rewrite: fixed per-pid tmp name + replace.
 
-    ``utils.fileio.atomic_write`` (mkstemp + fchmod) costs ~3x this on
-    the boundary budget (bench_fleet.py gates it); fleet files have
-    exactly one writer per process, so a fixed tmp name is race-free and
-    the ``os.replace`` keeps readers torn-proof all the same."""
+    ``utils.fileio.atomic_write`` pays an mkstemp and an fchmod on top
+    of this at every log boundary; fleet files have exactly one writer
+    per process, so a fixed tmp name is race-free and the ``os.replace``
+    keeps readers torn-proof all the same."""
     tmp = f"{path}.tmp.{os.getpid()}"
     with open(tmp, "w") as f:
         f.write(json.dumps(doc))

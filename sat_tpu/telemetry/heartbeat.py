@@ -100,8 +100,7 @@ class Heartbeat:
         # must say which host wrote each
         process_index, process_count = process_identity()
         payload = {
-            # consumers get the same contract check_regression gives bench
-            # rows: refuse payloads whose schema they don't understand
+            # consumers refuse payloads whose schema they don't understand
             "schema_version": SCHEMA_VERSION,
             "run_id": run_id(),
             "seq": self._seq,

@@ -29,9 +29,9 @@ lookup and one call (~0.1 µs), so instrumented library code (shards,
 retry, checkpoint) pays nothing measurable when telemetry is off and the
 off-path behavior is bit-for-bit what it was before instrumentation.
 
-Deliberately jax-free (like ``resilience/``): host-only tools —
-``scripts/bench_telemetry.py`` — must import this without dragging in an
-accelerator backend, and recording must never add a device sync.  The one
+Deliberately jax-free (like ``resilience/``): the ``--supervise`` parent
+and the router must import this without dragging in an accelerator
+backend, and recording must never add a device sync.  The one
 door to the profiler is ``Telemetry.annotate``: a factory the runtime sets
 to ``jax.profiler.TraceAnnotation`` and every ``with tel.span(...)``
 enters, so that a profiler trace taken with the host tracer on shows the

@@ -5,10 +5,11 @@ already knows: every compiled executable carries a cost analysis (FLOPs,
 bytes accessed, transcendentals) and a memory analysis (temp / argument
 / output / alias HBM bytes).  This module snapshots those per jitted
 function — ``train_step``, the eval encoder, the beam program — into one
-JSON artifact per run, so the regression gate
-(``scripts/check_regression.py``) can catch a silent FLOP or HBM
-regression even when wall-clock noise hides it, and a post-mortem can
-answer "did the working set grow" without a profiler window.
+JSON artifact per run, so the benchmark's compile accounting
+(``entries()`` -> ``memory_peak_bytes``, ``train_mfu``) can catch a
+silent FLOP or HBM regression even when wall-clock noise hides it, and a
+post-mortem can answer "did the working set grow" without a profiler
+window.
 
 ``analyze()`` uses the AOT path (``fn.lower(*args).compile()``) *before*
 the loop's first dispatch: lowering against live arguments does not

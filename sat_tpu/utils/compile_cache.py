@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
-One rule for every entry point (the CLI phases, bench.py, the test suite,
-the long-running scripts): when ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+One rule for every entry point (the CLI phases, the test suite, the
+long-running scripts): when ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
 reads it itself and the program sets no directory in code; otherwise the
 cache is ``<repo>/.jax_cache``.  The directory is part of XLA's cache key,
 so it must not move between runs — hence one fixed path inside the

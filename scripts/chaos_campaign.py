@@ -16,10 +16,10 @@ invariant for that failure mode:
 * process-plane faults (preempt/wedge/SIGTERM/ckpt rot/IO flake) resume
   or degrade exactly as their tests promise, end-to-end through the CLI.
 
-Emits a campaign report: a JSON array of BENCH-contract rows
+Emits a campaign report: a JSON array of rows
 ({"metric": "chaos_<scenario>", "value": 1.0|0.0, ...}) plus a
-``chaos_pass_rate`` summary, stamped with ``schema_version`` so
-``scripts/check_regression.py`` accepts the artifact as-is.
+``chaos_pass_rate`` summary, each stamped with
+``telemetry.bench_stamp()`` (``schema_version``, git SHA, host).
 
 Runs on CPU (JAX_PLATFORMS=cpu), sharing the test suite's persistent XLA
 compile cache, so the whole matrix is minutes, not hours.

@@ -2,10 +2,9 @@
 """CI gate over SLO alert logs (``slo.jsonl`` from telemetry/slo.py).
 
 The SLO engine appends one record per ok↔burning transition.  This
-script turns that log into exit codes the same way ``check_regression.py``
-gates BENCH rows: point it at one or more ``slo.jsonl`` files (a chaos
-campaign's, a serve soak's, a training run's) and it fails CI when an
-objective is burning.
+script turns that log into exit codes: point it at one or more
+``slo.jsonl`` files (a chaos campaign's, a serve soak's, a training
+run's) and it fails CI when an objective is burning.
 
 Usage::
 

@@ -350,7 +350,7 @@ def main() -> int:
 
     from sat_tpu import runtime
 
-    # Persistent compilation cache (same dir as bench.py): the resnet50
+    # Persistent compilation cache (the CLI's directory): the resnet50
     # CPU-XLA compile in particular runs tens of minutes cold on this
     # 1-core host; a rerun must not pay it twice.
     from sat_tpu.utils.compile_cache import enable as _enable_cache

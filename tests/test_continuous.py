@@ -4,10 +4,11 @@ Pins the stepped-decode contracts the continuous-batching ISSUE promises:
 
 * BITWISE parity — the stepped slot-pool decode (staggered admission,
   early retirement, slot reuse) produces `BeamResult`s identical to the
-  monolithic `beam_search` per request: words, log_scores, lengths and
-  alphas, including the early-exit and valid_size paths.  Both drivers
-  run the same `_expand_step` body, and these tests prove the carry
-  freeze preserves equality end to end;
+  monolithic `beam_search` per request: words, log_scores and lengths,
+  including the early-exit and valid_size paths; the attention maps to
+  one float32 ulp (`_assert_alphas_match` says why not bitwise).  Both
+  drivers run the same `_expand_step` body, and these tests prove the
+  carry freeze preserves equality end to end;
 * `return_steps` plumbing through `beam_search_jit` / `greedy_decode`;
 * `PagedSlotPool` bookkeeping: capacity, page-local seeding, harvest
   frees slots, reset empties the pool;
@@ -162,11 +163,35 @@ def _stepped_decode_all(
     return [results[r] for r in range(B)]
 
 
+# Attention maps of two PROGRAMS OF DIFFERENT SHAPES are not bitwise equal
+# on the CPU backend, and the pool is not the cause: the monolithic search
+# at B=1 differs from itself at B=5 in the same way (1 float32 ulp,
+# 4.66e-10 at alpha ~ 1/196, in <= 21 of 17,640 elements).  The optimized
+# HLO of the two is the same but for its shapes (one exp, one divide over
+# [B, K, 196] in decoder/attend), and `attend_with_precomputed` jitted
+# alone is bitwise at every shape and layout ([B, K] beams per grid or the
+# pool's [S*K, 1]): what differs is the code XLA:CPU emits for those loops
+# inside the whole step program.  An ulp in one of 196 weights is below
+# the rounding of the context it sums to, so logits, words, scores and
+# lengths are bitwise, and are held so below; programs of EQUAL shapes
+# (stepped against the fused window) stay bitwise in the maps too.  The
+# bound is set from the dtype, four times what was measured.
+ALPHA_RTOL = 4 * float(np.finfo(np.float32).eps)
+
+
+def _assert_alphas_match(mono_alphas, pool_alphas, where):
+    np.testing.assert_allclose(
+        pool_alphas, mono_alphas, rtol=ALPHA_RTOL, atol=0.0,
+        err_msg=str(where),
+    )
+
+
 @pytest.mark.parametrize("valid_size", [None, 25])
 def test_stepped_parity_staggered_admission(valid_size):
     """5 requests through a 2x2 pool, admitted one per step: words,
-    scores, lengths AND alphas bitwise-equal to the monolithic search,
-    with early finishers retiring (and their slots reseeding) mid-run."""
+    scores and lengths bitwise-equal to the monolithic search, alphas to
+    an ulp, with early finishers retiring (and their slots reseeding)
+    mid-run."""
     cfg, params, contexts = _ops_setup(B=5)
     mono = bs.beam_search(
         params, cfg, contexts, EOS,
@@ -182,7 +207,7 @@ def test_stepped_parity_staggered_admission(valid_size):
             np.asarray(mono.log_scores)[i], got.log_scores
         ), i
         assert np.array_equal(np.asarray(mono.lengths)[i], got.lengths), i
-        assert np.array_equal(np.asarray(mono.alphas)[i], got.alphas), i
+        _assert_alphas_match(np.asarray(mono.alphas)[i], got.alphas, i)
 
 
 def test_stepped_parity_bursty_admission_and_single_slot():
@@ -250,8 +275,12 @@ def test_fused_window_bitwise_parity_staggered(k):
     for i, got in enumerate(fused):
         assert np.array_equal(np.asarray(mono.words)[i], got.words), (k, i)
         assert np.array_equal(
-            np.asarray(mono.alphas)[i], got.alphas
+            np.asarray(mono.log_scores)[i], got.log_scores
         ), (k, i)
+        assert np.array_equal(
+            np.asarray(mono.lengths)[i], got.lengths
+        ), (k, i)
+        _assert_alphas_match(np.asarray(mono.alphas)[i], got.alphas, (k, i))
 
 
 @pytest.mark.parametrize("valid_size", [None, 25])

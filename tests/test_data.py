@@ -268,6 +268,7 @@ def test_prefetch_loader_surfaces_worker_errors(coco_fixture, tmp_path):
     import shutil
 
     from sat_tpu.data import PrefetchLoader
+    from sat_tpu.data.images import PrefetchDecodeError
 
     cfg = coco_fixture["config"]
     # private image dir so deleting a file can't break sibling tests
@@ -281,9 +282,17 @@ def test_prefetch_loader_surfaces_worker_errors(coco_fixture, tmp_path):
     ds = prepare_train_data(cfg)
     victim = sorted(img_dir.iterdir())[2]
     victim.unlink()
-    with pytest.raises(FileNotFoundError):
+    with pytest.raises(PrefetchDecodeError) as raised:
         for _ in PrefetchLoader(ds, num_workers=2, prefetch_depth=2):
             pass
+    err = raised.value
+    # the read failure is the cause; the wrapper says which record broke
+    assert isinstance(err.__cause__, FileNotFoundError)
+    assert err.image_file == str(victim)
+    assert err.batch_index >= 0 and 0 <= err.row < cfg.batch_size
+    message = str(err)
+    assert str(victim) in message
+    assert f"(batch {err.batch_index}, row {err.row})" in message
 
 
 def test_prefetch_loader_abandoned_iterator_releases_producer(coco_fixture):

@@ -6,8 +6,8 @@ verify-on-gather modes, --repair_shards), the containment half
 systemic-corruption ceiling and its exit code), the hardened prefetch
 path (data/images.py), the satellites (prefetch error context, vocab
 compatibility guard, serve bad-input handling), and — as one
-subprocess test — the chaos-campaign acceptance e2e plus the
-regression-gate contract of its report.
+subprocess test — the chaos-campaign acceptance e2e and the rows of
+its report.
 
 Everything but the campaign test is in-process and jax-free.
 """
@@ -18,6 +18,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 import zlib
 
 import numpy as np
@@ -44,6 +45,8 @@ from sat_tpu.resilience.quarantine import (
 )
 from sat_tpu.resilience.watchdog import WATCHDOG_EXIT_CODE
 from sat_tpu.utils import summary
+
+from tests.fixtures import LEDGER_TRAIN_STEP_MS
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -632,15 +635,16 @@ def test_serve_rejects_undecodable_post_cleanly(coco_fixture, tel):
 
 
 # ---------------------------------------------------------------------------
-# chaos campaign + regression gate (acceptance e2e)
+# chaos campaign (acceptance e2e)
 # ---------------------------------------------------------------------------
 
 
-def test_chaos_campaign_acceptance_and_regression_gate(tmp_path):
+def test_chaos_campaign_poison_and_systemic_abort(tmp_path):
     """One command runs the poison e2e (shard rot + decode faults ->
     clean completion, populated ledger, heartbeat gauges, bitwise
     replay) and the systemic-abort scenario (exit 87, supervisor does
-    not restart), emitting a report check_regression.py accepts."""
+    not restart), and its report carries one stamped row per scenario
+    plus the pass rate."""
     env = {k: v for k, v in os.environ.items()
            if not k.startswith("SAT_FI_")}
     report = tmp_path / "chaos_report.json"
@@ -657,27 +661,26 @@ def test_chaos_campaign_acceptance_and_regression_gate(tmp_path):
     assert metrics["chaos_systemic_no_restart"]["value"] == 1.0
     assert metrics["chaos_pass_rate"]["value"] == 1.0
     assert metrics["chaos_pass_rate"]["scenarios"] == 2
-    assert all("schema_version" in r for r in rows)
-
-    gate = subprocess.run(
-        [sys.executable, os.path.join("scripts", "check_regression.py"),
-         str(report)],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=60,
+    assert all(
+        r["schema_version"] == telemetry.SCHEMA_VERSION for r in rows
     )
-    assert gate.returncode == 0, gate.stdout + gate.stderr
 
 
-def test_bench_integrity_contract(tmp_path):
-    proc = subprocess.run(
-        [sys.executable, os.path.join("scripts", "bench_integrity.py"),
-         "--iters", "256", "--files", "16", "--batch", "4", "--size", "32",
-         "--workdir", str(tmp_path / "bench")],
-        cwd=REPO, env=dict(os.environ), capture_output=True, text=True,
-        timeout=120,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    row = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert row["metric"] == "integrity_verify_overhead"
-    assert row["unit"] == "%_of_step"
-    assert row["value"] < 1.0  # the gate bench_integrity itself enforces
-    assert "schema_version" in row and "vs_baseline" in row
+def test_sampled_shard_verification_under_one_percent_of_a_step(tmp_path):
+    """What `verify_shards=sample` adds to a `ShardCache.gather` (one
+    crc32c of one row every SAMPLE_EVERY gathers, amortized): < 1% of
+    the train cell's device step."""
+    files, _, _, cache = _build_cache(tmp_path, n=16, size=32,
+                                      rows_per_shard=16)
+    batches = [files[i:i + 4] for i in range(0, len(files), 4)]
+
+    def per_gather_s(mode, iters):
+        cache.enable_integrity(mode)
+        t0 = time.perf_counter()
+        for i in range(iters):
+            cache.gather(batches[i % len(batches)])
+        return (time.perf_counter() - t0) / iters
+
+    per_gather_s("sample", 64)  # warm: page cache, sidecars
+    added = per_gather_s("sample", 256) - per_gather_s("off", 256)
+    assert 1e3 * added < 0.01 * LEDGER_TRAIN_STEP_MS
