@@ -462,8 +462,10 @@ class Config:
     seed: int = 0
     # Rematerialize the decoder scan step in the backward pass (keep
     # matmul outputs, regenerate dropout masks/elementwise from the
-    # per-step keys instead of stacking T steps of residuals).
-    # Numerically identical; off by default pending a measured win.
+    # per-step keys instead of stacking T steps of residuals).  The
+    # attention chain is rebuilt from its saved masks whatever this says
+    # (models/decoder.py attend_context); this covers the rest of the
+    # step.  Numerically identical; off by default pending a measured win.
     remat_decoder: bool = False
     # Full-encoder rematerialization under --train_cnn: backward
     # recomputes the CNN forward from the images instead of storing every

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import Config
+from ..nn.layers import DROPOUT_MASK
 from ..nn.layers import dropout as _nn_dropout
 from ..nn.layers import fc_kernel_init
 
@@ -266,6 +267,54 @@ def attend(
     return (alpha, act) if with_activity else alpha
 
 
+def attend_context(
+    params: Params,
+    config: Config,
+    contexts: jnp.ndarray,
+    output: jnp.ndarray,
+    train: bool = False,
+    rng: Optional[jax.Array] = None,
+    with_activity: bool = False,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """:func:`attend` and the weighted sum of the grid it feeds:
+    (context [B, D], alpha [B, N], L1 activity sum).
+
+    In training the pair is rebuilt in the backward pass from what the
+    scan carries or closes over (the attend parameters, the grid,
+    ``output``, the step's key) and from its dropout masks, the only
+    values of it that are kept.  Left to itself the scan stacks over its
+    T steps everything the chain's gradient reads: the dropped-out grid,
+    the tanh layer and its sum after dropout, each [T, B, N, .] (4 GB of
+    float32 at B=256, T=20, N=196, 512 wide), written by the forward loop
+    and read back by the backward one.  The masks stay because they are
+    an eighth of that and drawing their bits again costs more than
+    reading them (PERF.md section 6, PR 29: this against nothing kept and
+    against ``dots_saveable``, on the chip).  Same masks and same
+    arithmetic: a loss or a gradient differs from the stacked version's
+    only by how XLA fuses and sums the chain (float32: the order of a
+    sum; bfloat16 on the chip: which roundings a fusion skips, 1e-5 of
+    the loss).  prevent_cse off: a scan body is not subject to the CSE
+    hazard checkpoint guards against."""
+
+    def pair(p_attend, contexts, output, rng):
+        out = attend(
+            {"attend": p_attend}, config, contexts, output, train, rng,
+            with_activity=with_activity,
+        )
+        alpha, act = out if with_activity else (out, jnp.float32(0))
+        with jax.named_scope("decoder/attend"):
+            context = (contexts * alpha[..., None]).sum(axis=1)  # [B, D]
+        return context, alpha, act
+
+    if train:
+        pair = jax.checkpoint(
+            pair,
+            policy=jax.checkpoint_policies.save_only_these_names(DROPOUT_MASK),
+            prevent_cse=False,
+        )
+    return pair(params["attend"], contexts, output, rng)
+
+
 @jax.named_scope("decoder/attend")
 def precompute_attend(
     params: Params, config: Config, contexts: jnp.ndarray
@@ -425,14 +474,10 @@ def decoder_step(
             row_mask=row_mask,
         )
     else:
-        alpha = attend(
+        context, alpha, act = attend_context(
             params, config, contexts, state.output, train, k_att,
             with_activity=with_activity,
         )
-        if with_activity:
-            alpha, act = alpha
-        with jax.named_scope("decoder/attend"):
-            context = (contexts * alpha[..., None]).sum(axis=1)  # [B, D]
 
     with jax.named_scope("decoder/embed"):
         word_embed = params["word_embedding"]["weights"][word]    # [B, E]
@@ -512,12 +557,16 @@ def teacher_forced_decode(
         return state, (logits, alpha)
 
     if train and config.remat_decoder:
-        # Rematerialize the step in backward: keep matmul outputs,
+        # Rematerialize the whole step in backward: keep matmul outputs,
         # regenerate dropout masks / elementwise chains from rng_t instead
         # of stacking them as residuals across T steps.  Numerically
         # identical (same keys -> same masks); trades recompute for HBM
-        # residual traffic.  prevent_cse off: scan bodies are not subject
-        # to the CSE hazard checkpoint guards against.
+        # residual traffic.  This policy decides what the scan stacks, for
+        # the attention chain too: under it attend_context's own
+        # checkpoint keeps nothing across steps (its masks are drawn again
+        # with the rest of the step) and only shapes the step's backward.
+        # prevent_cse off: scan bodies are not subject to the CSE hazard
+        # checkpoint guards against.
         body = jax.checkpoint(
             body,
             policy=jax.checkpoint_policies.dots_saveable,

@@ -23,6 +23,7 @@ from typing import Any, Callable, Optional, Sequence, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 Dtype = Any
 
@@ -79,12 +80,20 @@ def max_pool2d(x, pool_size=(2, 2), strides=(2, 2)):
     return nn.max_pool(x, window_shape=pool_size, strides=strides, padding="SAME")
 
 
+# the name a dropout mask carries for ``jax.checkpoint`` policies
+DROPOUT_MASK = "dropout_mask"
+
+
 def dropout(x, rate: float, deterministic: bool, rng=None):
-    """Inverted dropout matching tf.layers.dropout semantics."""
+    """Inverted dropout matching tf.layers.dropout semantics.
+
+    The mask is named (an identity outside a ``jax.checkpoint``), so that
+    a rematerialised region can keep it with ``save_only_these_names``
+    and rebuild the rest without drawing the bits again."""
     if deterministic or rate == 0.0:
         return x
     keep = 1.0 - rate
-    mask = jax.random.bernoulli(rng, keep, x.shape)
+    mask = checkpoint_name(jax.random.bernoulli(rng, keep, x.shape), DROPOUT_MASK)
     return jnp.where(mask, x / keep, jnp.zeros_like(x))
 
 

@@ -390,3 +390,95 @@ def test_lm_beam_program_compiles_with_the_grouped_kernel_and_no_vocabulary_sort
     assert len(re.findall(r"decoder/lm/moe/experts[^\n]*tpu_custom_call|"
                           r"tpu_custom_call[^\n]*decoder/lm/moe/experts", text)) >= 6
     assert compiled.memory_analysis().temp_size_in_bytes < 6 << 30
+
+
+# ---------------------------------------------------------------------------
+# The train step: what the backward pass keeps of the attention chain
+# ---------------------------------------------------------------------------
+
+
+# Ceilings of the compiled temporaries, bytes, with room over the readings
+# (compiled here, PR 29): the decoder's gradient 1.64 GB at N=196 (7.58
+# before) and 1.27 GB at N=49 (3.16 before); train_step 3.30 GB (7.54
+# before), which the VGG16 forward alone now sets.
+_DECODER_GRAD_TEMPS = {"vgg16": int(2.4e9), "resnet50": int(1.9e9)}
+_TRAIN_STEP_TEMPS = int(4.3e9)
+
+
+def _stacked_over_steps(text, T, B, N):
+    """Element types of the arrays ``[T,B,N,w]`` (w over 1) in an optimized
+    HLO module: the [B,N,.] values of the attention chain that the forward
+    scan stacks over its T steps for the backward one."""
+    return set(re.findall(rf"\b(\w+)\[{T},{B},{N},(?!1\])\d+\]", text))
+
+
+def _key_data(config):
+    """The shape of a dropout key's data (a jitted program takes the data;
+    ``wrap_key_data`` inside it restores the key)."""
+    return _on_chip(jax.eval_shape(
+        lambda: jax.random.key_data(jax.random.key(0, impl=config.rng_impl))
+    ))
+
+
+@pytest.mark.parametrize("cnn", sorted(_WIDTHS))
+def test_decoder_gradient_stacks_only_its_masks_over_the_steps(cnn):
+    """The decoder's gradient alone at the train cell's batch and length,
+    both encoders' grid widths (bf16 grid, rbg keys, a stand-in loss over
+    logits and maps): the attention chain is rebuilt in the backward scan
+    (``decoder.attend_context``) from its dropout masks, so the only
+    [T,B,N,.] arrays are the masks: no float32 or bfloat16 one, where f32,
+    bf16 and pred stacks stood before; and the temporaries stay under a
+    ceiling with room."""
+    from sat_tpu.models.decoder import teacher_forced_decode
+
+    config = Config(cnn=cnn, rng_impl="rbg")
+    B, T = 256, config.max_caption_length
+    N, D = _WIDTHS[cnn]
+    assert (N, D) == (config.num_ctx, config.dim_ctx)
+    _, decoder = _decoder_params(config)
+
+    def loss(params, contexts, sentences, key_data):
+        key = jax.random.wrap_key_data(key_data, impl=config.rng_impl)
+        logits, maps = teacher_forced_decode(
+            params, config, contexts, sentences, train=True, rng=key
+        )
+        return jnp.square(logits).mean() + jnp.square(1.0 - maps.sum(1)).mean()
+
+    compiled = jax.jit(jax.grad(loss)).lower(
+        decoder, _sd((B, N, D), jnp.bfloat16), _sd((B, T), jnp.int32),
+        _key_data(config),
+    ).compile()
+    assert _stacked_over_steps(compiled.as_text(), T, B, N) == {"pred"}
+    assert compiled.memory_analysis().temp_size_in_bytes < _DECODER_GRAD_TEMPS[cnn]
+
+
+def test_train_step_stacks_only_its_masks_over_the_steps():
+    """``train_step`` as the train cell runs it (B=256, T=20, VGG16 at 224
+    px from uint8 images, bf16 compute, rbg keys, frozen CNN): of the
+    attention chain only the masks are stacked over the steps, no float32
+    [20,256,196,.] array is in the optimized program, and its temporaries,
+    the term ``memory_peak_bytes`` adds for the cell, stay under a ceiling
+    with room."""
+    from sat_tpu.train.step import create_train_state, make_train_step
+
+    config = Config(batch_size=256, rng_impl="rbg")
+    B, T, N = config.batch_size, config.max_caption_length, config.num_ctx
+    assert (config.cnn, config.compute_dtype, N) == ("vgg16", "bfloat16", 196)
+    state = jax.eval_shape(
+        lambda: create_train_state(jax.random.PRNGKey(0), config)
+    )
+    batch = {
+        "images": _sd((B, config.image_size, config.image_size, 3), jnp.uint8),
+        "word_idxs": _sd((B, T), jnp.int32),
+        "masks": _sd((B, T), jnp.float32),
+    }
+    step = make_train_step(config)
+
+    def train_step(state, batch, key_data):
+        return step(state, batch, jax.random.wrap_key_data(key_data, impl=config.rng_impl))
+
+    compiled = jax.jit(train_step, donate_argnums=(0,)).lower(
+        _on_chip(state), batch, _key_data(config)
+    ).compile()
+    assert _stacked_over_steps(compiled.as_text(), T, B, N) == {"pred"}
+    assert compiled.memory_analysis().temp_size_in_bytes < _TRAIN_STEP_TEMPS

@@ -349,6 +349,83 @@ class TestRematDecoder:
             )
 
 
+_EPS = float(np.finfo(np.float32).eps)
+
+
+def _assert_same_forward(loss0, maps0, loss, maps):
+    """A rebuilt value is the forward's value (same key, same masks, same
+    arithmetic), so the loss is held bitwise.  The maps are held to a few
+    ulp: a program that no longer hands the chain's values to a second
+    consumer is fused differently by XLA:CPU, which moves a softmax by one
+    ulp (the same float32 sums in another order; tests/test_continuous.py
+    holds maps between two programs the same way: ALPHA_RTOL).  This is
+    float32 on the CPU; on the chip in bfloat16 the two programs' losses
+    differ by 1e-5 (XLA skips other roundings inside other fusions), which
+    the benchmark's ``loss_gap`` holds against the float32 reference."""
+    assert float(loss) == float(loss0)
+    np.testing.assert_allclose(np.asarray(maps), np.asarray(maps0), rtol=4 * _EPS, atol=0)
+
+
+def _assert_same_gradient(g0, g):
+    """Gradients are sums over batch, grid and time of float32 products;
+    the backward pass that rebuilds its operands is fused differently, so
+    the sums run in another order: a few ulp of the leaf's largest element
+    (read: up to 4.4)."""
+    g0, g = np.asarray(g0), np.asarray(g)
+    np.testing.assert_allclose(g, g0, rtol=0, atol=16 * _EPS * np.abs(g0).max())
+
+
+class TestAttendRebuiltInBackward:
+    """Training rebuilds the attention chain and its weighted sum in the
+    backward scan (``decoder.attend_context`` under ``jax.checkpoint``)
+    instead of stacking its [B,N,.] values over the T steps.  The oracle is
+    the same decode with ``jax.checkpoint`` made the identity: the step as
+    it was, every value of the chain kept for the backward pass."""
+
+    @staticmethod
+    def _value_and_grads(cfg, with_grid, unwrapped, monkeypatch):
+        params = init_decoder_params(jax.random.PRNGKey(1), cfg)
+        batch = tiny_contexts_batch(cfg, rng_seed=2)
+        key = jax.random.key(5, impl=cfg.rng_impl)
+
+        def loss(params, contexts):
+            logits, maps, act = teacher_forced_decode(
+                params, cfg, contexts, batch["word_idxs"], train=True, rng=key,
+                with_activity=True,
+            )
+            total = (
+                jnp.square(logits).mean()
+                + jnp.square(1.0 - maps.sum(axis=1)).mean()
+                + 1e-3 * act
+            )
+            return total, maps
+
+        with monkeypatch.context() as m:
+            if unwrapped:
+                m.setattr(jax, "checkpoint", lambda f, **kw: f)
+            # train_cnn: the grid is the encoder's output and takes a
+            # gradient too, which flows through the checkpoint
+            argnums = (0, 1) if with_grid else 0
+            fn = jax.jit(jax.value_and_grad(loss, argnums=argnums, has_aux=True))
+            return fn(params, batch["contexts"])
+
+    @pytest.mark.parametrize("train_cnn", [False, True], ids=["frozen", "train_cnn"])
+    @pytest.mark.parametrize("cnn", ["vgg16", "resnet50"])
+    def test_loss_maps_and_gradients_match_the_unwrapped_step(
+        self, cnn, train_cnn, monkeypatch
+    ):
+        # 64 px: a 4x4 grid of 512 (vgg16) or a 2x2 grid of 2048 (resnet50)
+        cfg = tiny_config(cnn=cnn, image_size=64, fc_drop_rate=0.3, lstm_drop_rate=0.2)
+        (l0, maps0), g0 = self._value_and_grads(cfg, train_cnn, True, monkeypatch)
+        (l1, maps1), g1 = self._value_and_grads(cfg, train_cnn, False, monkeypatch)
+        (l2, maps2), g2 = self._value_and_grads(
+            cfg.replace(remat_decoder=True), train_cnn, False, monkeypatch
+        )
+        for loss, maps, grads in ((l1, maps1, g1), (l2, maps2, g2)):
+            _assert_same_forward(l0, maps0, loss, maps)
+            jax.tree_util.tree_map(_assert_same_gradient, g0, grads)
+
+
 class TestActivityRegularization:
     """L1 activity regularization (reference utils/nn.py:23-26,40-43):
     scale·Σ|output| over *activated* layer outputs — tanh fc layers when
