@@ -43,6 +43,11 @@ class Config:
     # sparse mixture of experts (models/lfm2.py); its widths below are
     # named as in the source's config.json (LiquidAI/LFM2-8B-A1B) and
     # default to it.  vocabulary_size is the source's vocab_size.
+    # "deepseek_v3": the same prefix into a stack of latent attention (MLA)
+    # and a mixture of small experts beside shared ones
+    # (models/deepseek_v3.py; kakaocorp/kanana-2-30b-a3b-instruct-2601);
+    # the fields the two stacks share keep one name, num_dense_layers is
+    # that source's first_k_dense_replace, num_experts its n_routed_experts.
     decoder: str = "lstm"
     hidden_size: int = 2048
     intermediate_size: int = 7168          # dense SwiGLU of the leading layers
@@ -59,13 +64,27 @@ class Config:
     routed_scaling_factor: float = 1.0
     rope_theta: float = 1e6
     norm_eps: float = 1e-5
-    # one of "conv" / "full_attention" per layer, num_hidden_layers long
+    # one of "conv" / "full_attention" per layer (lfm2_moe), or
+    # "latent_attention" throughout (deepseek_v3); num_hidden_layers long
     layer_types: Tuple[str, ...] = (
         "conv", "conv", "full_attention", "conv", "conv", "conv",
         "full_attention", "conv", "conv", "conv", "full_attention", "conv",
         "conv", "conv", "full_attention", "conv", "conv", "conv",
         "full_attention", "conv", "conv", "full_attention", "conv", "conv",
     )
+    # latent attention (deepseek_v3 only), named as in the source: one
+    # kv_lora_rank-wide latent and one qk_rope_head_dim-wide rotary key a
+    # token for all heads; per head qk_nope_head_dim + qk_rope_head_dim of
+    # query and v_head_dim of value
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    # shared experts beside the routed ones: ONE SwiGLU of
+    # n_shared_experts x moe_intermediate_size every token goes through
+    n_shared_experts: int = 2
+    # lfm2_moe's head IS its embedding; deepseek_v3 reads this
+    tie_word_embeddings: bool = True
     # train_cnn's twin for the language-model stack: frozen by default,
     # so the connector alone trains and Adam holds slots for it alone
     train_lm: bool = False
@@ -536,7 +555,7 @@ class Config:
         same, /root/reference/model.py:16-21)."""
         checks = (
             ("cnn", ("vgg16", "resnet50")),
-            ("decoder", ("lstm", "lfm2_moe")),
+            ("decoder", ("lstm", "lfm2_moe", "deepseek_v3")),
             ("phase", ("train", "eval", "test", "serve", "route", "bulk")),
             ("optimizer", ("Adam", "RMSProp", "Momentum", "SGD")),
             ("num_initialize_layers", (1, 2)),
@@ -557,8 +576,8 @@ class Config:
                 raise ValueError(
                     f"Config.{name}={getattr(self, name)!r}: must be one of {allowed}"
                 )
-        if self.decoder == "lfm2_moe":
-            self._check_lfm2()
+        if self.decoder != "lstm":
+            self._check_lm()
         if self.io_retries < 0:
             raise ValueError(f"Config.io_retries={self.io_retries}: must be >= 0")
         if self.keep_checkpoints < 0:
@@ -793,10 +812,13 @@ class Config:
                 ">= 1 (a host at the fleet median is not a straggler)"
             )
 
-    def _check_lfm2(self) -> None:
-        """decoder="lfm2_moe": the stack's fields agree, and what this
-        decoder cannot run yet is refused by name (ROADMAP B7-B9)."""
-        kinds = ("conv", "full_attention")
+    def _check_lm(self) -> None:
+        """A language-model decoder: the stack's fields agree, and what
+        these decoders cannot run yet is refused by name (ROADMAP B7-B9)."""
+        kinds = (
+            ("conv", "full_attention") if self.decoder == "lfm2_moe"
+            else ("latent_attention",)
+        )
         if len(self.layer_types) != self.num_hidden_layers or any(
             k not in kinds for k in self.layer_types
         ):
@@ -805,15 +827,26 @@ class Config:
                 f"(num_hidden_layers), each one of {kinds}; got "
                 f"{self.layer_types!r}"
             )
-        if (
-            self.hidden_size % self.num_attention_heads
-            or self.num_attention_heads % self.num_key_value_heads
-            or (self.hidden_size // self.num_attention_heads) % 2
-        ):
+        if self.decoder == "lfm2_moe":
+            if (
+                self.hidden_size % self.num_attention_heads
+                or self.num_attention_heads % self.num_key_value_heads
+                or (self.hidden_size // self.num_attention_heads) % 2
+            ):
+                raise ValueError(
+                    "Config: hidden_size must divide into num_attention_heads "
+                    "even-sized heads, and num_attention_heads into "
+                    "num_key_value_heads groups"
+                )
+            if not self.tie_word_embeddings:
+                raise ValueError(
+                    'Config.tie_word_embeddings=False: decoder="lfm2_moe" '
+                    "has no head but its embedding"
+                )
+        elif self.qk_rope_head_dim % 2 or self.n_shared_experts < 0:
             raise ValueError(
-                "Config: hidden_size must divide into num_attention_heads "
-                "even-sized heads, and num_attention_heads into "
-                "num_key_value_heads groups"
+                "Config: qk_rope_head_dim must be even (rotary pairs) and "
+                "n_shared_experts not negative"
             )
         if not 0 <= self.num_dense_layers <= self.num_hidden_layers:
             raise ValueError(
@@ -841,7 +874,9 @@ class Config:
                 "per-word attention map over the grid"
             )
         if refused:
-            raise ValueError(f'Config.decoder="lfm2_moe" does not run with {refused}')
+            raise ValueError(
+                f"Config.decoder={self.decoder!r} does not run with {refused}"
+            )
 
     def replace(self, **kw: Any) -> "Config":
         return dataclasses.replace(self, **kw)

@@ -9,7 +9,7 @@ exist (``Config.decoder``) and what each gives the rest of the program.
 ``train_logits``    teacher-forced logits ``[B, T, V]`` (+ attention maps
                     where the decoder has them) for the loss
 ``search``          what ``ops/beam_search.run_search`` needs of a decoder:
-                    a state from the image (the LSTM's initial carry; the
+                    a state from the image (the LSTM's initial carry; a
                     language model's prefill), one step over ``[B*K]``
                     rows, which leaves are per beam (a plain tree, or
                     ``StepState.beam``), which are carried unreordered
@@ -17,6 +17,12 @@ exist (``Config.decoder``) and what each gives the rest of the program.
                     (closed over, never tiled), and what the decoder
                     itself reports of the batch (``Search.finish``)
 ==================  =====================================================
+
+The language-model decoders (``lfm2_moe``: convs and grouped-query
+attention; ``deepseek_v3``: latent attention) are a module each with one
+set of entry points, and share one search (``_lm_search``): what differs
+between them is the KIND of leaf their caches hold, which the search never
+looks at.
 
 No other module tests ``Config.decoder``.
 """
@@ -29,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import Config
-from . import lfm2
+from . import deepseek_v3, lfm2
 from .decoder import (
     DecoderState,
     decoder_step,
@@ -40,6 +46,10 @@ from .decoder import (
 )
 
 Params = Dict[str, Any]
+
+# the language-model decoders: a module each, with one set of entry points
+# (init_params, teacher_forced, prefill, start_beams, step)
+_LM = {"lfm2_moe": lfm2, "deepseek_v3": deepseek_v3}
 
 
 class StepState(NamedTuple):
@@ -79,8 +89,8 @@ def tile_beams(x: jnp.ndarray, K: int) -> jnp.ndarray:
 
 
 def init_params(rng: jax.Array, config: Config) -> Params:
-    if config.decoder == "lfm2_moe":
-        return lfm2.init_params(rng, config)
+    if config.decoder in _LM:
+        return _LM[config.decoder].init_params(rng, config)
     return init_decoder_params(rng, config)
 
 
@@ -89,7 +99,7 @@ def split_frozen(decoder: Params, config: Config) -> Tuple[Params, Params]:
     stack is frozen as the CNN is (``train_lm``, ``train_cnn``'s twin):
     the connector alone trains and the optimizer holds slots for it
     alone."""
-    if config.decoder == "lfm2_moe" and not config.train_lm:
+    if config.decoder in _LM and not config.train_lm:
         return {"connector": decoder["connector"]}, {"lm": decoder["lm"]}
     return decoder, {}
 
@@ -104,9 +114,10 @@ def train_logits(
     with_activity: bool = False,
 ):
     """(logits [B,T,V], alphas [B,T,N] or None, fc activity L1 or None)."""
-    if config.decoder == "lfm2_moe":
-        # no dropout and no activity term: the stack defines neither
-        return lfm2.teacher_forced(decoder, config, contexts, sentences), None, None
+    if config.decoder in _LM:
+        # no dropout and no activity term: the stacks define neither
+        lm = _LM[config.decoder]
+        return lm.teacher_forced(decoder, config, contexts, sentences), None, None
     out = teacher_forced_decode(
         decoder, config, contexts, sentences, train, rng, with_activity=with_activity
     )
@@ -124,13 +135,17 @@ def search(
 ) -> Search:
     """The search of one batch of grids ``[B, N, D]`` with K beams an
     image over at most T steps."""
-    if config.decoder == "lfm2_moe":
+    if config.decoder in _LM:
         if return_alphas:
             raise ValueError(
-                'decoder="lfm2_moe" has no per-word attention map over the '
-                "grid: return_alphas is refused"
+                f"decoder={config.decoder!r} has no per-word attention map "
+                "over the grid: return_alphas is refused"
             )
-        return _lm_search(params, config, contexts, K, T)
+        return _lm_search(
+            _LM[config.decoder], params, config, contexts, K, T,
+            # the latent cache's reason to be is its size: it reports it
+            report_state=config.decoder == "deepseek_v3",
+        )
 
     # the grid and the hoisted context half of the attention MLP stay per
     # IMAGE: every beam of an image attends over the same [N, D] block
@@ -151,40 +166,49 @@ def search(
     return Search(step_fn, state0, contexts.shape[1], lambda result, state: result)
 
 
-def _lm_search(params: Params, config: Config, contexts: jnp.ndarray, K: int, T: int) -> Search:
-    """The language-model decoder's: the N prefix positions go through
-    the stack once per IMAGE; their keys and values stay ``[B, N, ...]``
-    for every beam of the image to read in place.  Per beam: the conv
-    states (tiled from the prefix's), an empty suffix cache of T
-    positions, and the record of the experts the beam's own tokens chose."""
+def _tree_bytes(tree) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(tree))
+
+
+def _lm_search(
+    lm, params: Params, config: Config, contexts: jnp.ndarray, K: int, T: int,
+    report_state: bool = False,
+) -> Search:
+    """A language-model decoder's (``lm``: its module): the N prefix
+    positions go through the stack once per IMAGE; what they leave for
+    the steps (keys and values; latents) stays ``[B, N, ...]`` for every
+    beam of the image to read in place.  Per beam: what ``lm.start_beams``
+    gives (an empty suffix cache of T positions, the record of the experts
+    the beam's own tokens chose, the stack's per-beam state)."""
     B = contexts.shape[0]
     with jax.named_scope("beam/prefill"):
-        prefix, counts, prefix_routes = lfm2.prefill(params, config, contexts)
-    cache = lfm2.init_cache(
-        config, tuple(tile_beams(x, K) for x in prefix.conv), B * K, T
-    )
-    state0 = StepState(beam=cache, shared=lfm2.init_counters(counts, T))
+        prefix, counts, prefix_routes = lm.prefill(params, config, contexts)
+    cache = lm.start_beams(config, prefix, K, T, tile_beams)
+    state0 = StepState(beam=cache, shared=lm.init_counters(counts, T))
 
     def step_fn(state, last_word):
-        cache, counters, logits = lfm2.step(
+        cache, counters, logits = lm.step(
             params, config, prefix, state.beam, state.shared, last_word
         )
         alpha = jnp.zeros((last_word.shape[0], 0), jnp.float32)
         return StepState(beam=cache, shared=counters), logits, alpha
 
     def finish(result, state):
-        return result._replace(
-            decoder_stats={
-                # [moe layers, E]: tokens each expert took, prefill + steps
-                "moe_counts": state.shared.moe_counts,
-                # [moe layers, T]: experts that took a token at each step
-                "moe_step_visits": state.shared.step_visits,
-                # [B, N, moe layers * k]: the experts each prefix position chose
-                "prefix_routes": prefix_routes,
-                # [B, K, T, moe layers * k]: the experts the tokens of each
-                # LIVE beam chose, step by step along its own ancestry
-                "step_routes": state.beam.routes.reshape(B, K, T, -1),
-            }
-        )
+        stats = {
+            # [moe layers, E]: tokens each expert took, prefill + steps
+            "moe_counts": state.shared.moe_counts,
+            # [moe layers, T]: experts that took a token at each step
+            "moe_step_visits": state.shared.step_visits,
+            # [B, N, moe layers * k]: the experts each prefix position chose
+            "prefix_routes": prefix_routes,
+            # [B, K, T, moe layers * k]: the experts the tokens of each
+            # LIVE beam chose, step by step along its own ancestry
+            "step_routes": state.beam.routes.reshape(B, K, T, -1),
+        }
+        if report_state:
+            # bytes of the search's state a batch, from the shapes: what
+            # the steps close over per image + the per-beam tree
+            stats["state_bytes"] = jnp.float32(_tree_bytes(prefix) + _tree_bytes(state.beam))
+        return result._replace(decoder_stats=stats)
 
     return Search(step_fn, state0, 0, finish)

@@ -15,22 +15,17 @@ the caption's tokens.  The stack is LiquidAI's ``lfm2_moe``
   rotary embedding (rotate-half, ``rope_theta``, the whole head), grouped
   queries, scale ``head ** -0.5``, causal, ``out_proj``.  State: keys and
   values.
-* ffn: a dense SwiGLU in the first ``num_dense_layers`` layers, else a
-  mixture of ``num_experts`` SwiGLU experts: sigmoid scores, the
-  ``num_experts_per_tok`` largest of ``score + expert_bias`` chosen, the
-  scores at the chosen (the bias selects and never weighs) divided by
-  their sum + 1e-6, times ``routed_scaling_factor``.  No capacity and no
-  dropped token: the routed (token, expert) pairs are sorted by expert
-  and go through a grouped product (``grouped_matmul``), which computes
-  those pairs and no others.
+* ffn: a dense SwiGLU in the first ``num_dense_layers`` layers, else
+  ``models/lm_common.py``'s mixture of ``num_experts`` SwiGLU experts (the
+  router, the sort by expert, the grouped product, the un-sort: shared with
+  ``models/deepseek_v3.py``), with this source's 1e-6 in the sum of the
+  chosen scores and no shared expert.
 * after the last layer ``embedding_norm``; the head is tied to the
   embedding.
 
-Precision: parameters of the stack in bfloat16 (the source's), matmuls
-bfloat16 x bfloat16 with float32 accumulation, the residual stream
-bfloat16; norms, softmax and the whole router (product, sigmoid, bias,
-choice: a ``hidden_size x num_experts`` product at ``HIGHEST``, so that
-near-ties do not flip against the float32 reference) in float32.
+Precision: ``lm_common``'s (bfloat16 parameters and products with float32
+accumulation, a bfloat16 residual stream; norms, softmax and the whole
+router in float32).
 
 Three entry points share one set of layer functions: ``teacher_forced``
 (train, and the tests' full forward), ``prefill`` (the N prefix positions
@@ -46,15 +41,26 @@ it is the LSTM's.
 
 from __future__ import annotations
 
-from typing import Any, Dict, NamedTuple, Tuple
+from functools import partial
+from typing import Any, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..config import Config
+from . import lm_common
+from .lm_common import Params, StepCounters, init_counters, layer_name  # noqa: F401
+from .lm_common import embed as _embed
+from .lm_common import join_routes as _join_routes
+from .lm_common import mm as _mm
+from .lm_common import prefix as _prefix
+from .lm_common import rms_norm as _rms_norm
+from .lm_common import stack_counts as _stack_counts
 
-Params = Dict[str, Any]
-HIGHEST = jax.lax.Precision.HIGHEST
+# the source's router adds 1e-6 to the sum of the chosen scores
+_route = partial(lm_common.route, sum_eps=1e-6)
+moe_ffn = partial(lm_common.moe_ffn, sum_eps=1e-6)
+_ffn = partial(lm_common.ffn, sum_eps=1e-6)
 
 
 class BeamCache(NamedTuple):
@@ -73,33 +79,13 @@ class BeamCache(NamedTuple):
     routes: Any = None
 
 
-class StepCounters(NamedTuple):
-    """What a step carries besides the beams' state: never reordered."""
-
-    t: jnp.ndarray                  # () int32 caption steps taken
-    moe_counts: jnp.ndarray         # [moe layers, E] int32 tokens routed
-    # [moe layers, T] int32: experts that took a token at each step (an
-    # expert no row chose is not read: what a step's grouped products had
-    # to fetch is this many experts' maps; at step 0 every row holds
-    # ``<start>``, so few are)
-    step_visits: jnp.ndarray
-
-
 # ---------------------------------------------------------------------------
 # parameters
 # ---------------------------------------------------------------------------
 
 
-def _is_moe(config: Config, layer: int) -> bool:
-    return layer >= config.num_dense_layers
-
-
 def _head_dim(config: Config) -> int:
     return config.hidden_size // config.num_attention_heads
-
-
-def layer_name(layer: int) -> str:
-    return f"{layer:02d}"
 
 
 def init_params(rng: jax.Array, config: Config) -> Params:
@@ -108,7 +94,7 @@ def init_params(rng: jax.Array, config: Config) -> Params:
     unit norm weights: a starting point for the connector's training, not
     the source's weights (a checkpoint carries those)."""
     c = config
-    H, E = c.hidden_size, c.num_experts
+    H = c.hidden_size
     hd, kv = _head_dim(c), c.num_key_value_heads
     bf16 = jnp.bfloat16
     keys = iter(jax.random.split(rng, 8 * c.num_hidden_layers + 4))
@@ -132,22 +118,10 @@ def init_params(rng: jax.Array, config: Config) -> Params:
                 "v_proj": linear(H, kv * hd), "out_proj": linear(H, H),
                 "q_layernorm": ones(hd), "k_layernorm": ones(hd),
             }
-        if _is_moe(c, i):
-            I = c.moe_intermediate_size
-            p["feed_forward"] = {
-                "gate": linear(H, E),
-                "expert_bias": jnp.zeros((E,), jnp.float32),
-                "w1": linear(E, H, I), "w3": linear(E, H, I), "w2": linear(E, I, H),
-            }
-        else:
-            I = c.intermediate_size
-            p["feed_forward"] = {"w1": linear(H, I), "w3": linear(H, I), "w2": linear(I, H)}
+        p["feed_forward"] = lm_common.ffn_params(c, i, linear)
         layers[layer_name(i)] = p
     return {
-        "connector": {
-            "kernel": 0.02 * jax.random.normal(next(keys), (c.dim_ctx, H), jnp.float32),
-            "bias": jnp.zeros((H,), jnp.float32),
-        },
+        "connector": lm_common.connector_params(next(keys), c),
         "lm": {
             "embed_tokens": linear(c.vocabulary_size, H),
             "embedding_norm": ones(H),
@@ -159,25 +133,6 @@ def init_params(rng: jax.Array, config: Config) -> Params:
 # ---------------------------------------------------------------------------
 # building blocks
 # ---------------------------------------------------------------------------
-
-
-def _rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
-    """float32 in and out of the statistics; the caller casts."""
-    x = x.astype(jnp.float32)
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)
-
-
-def _mm(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
-    """bfloat16 operands, float32 accumulation, bfloat16 result."""
-    return jnp.dot(
-        x.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
-        preferred_element_type=jnp.float32,
-    ).astype(jnp.bfloat16)
-
-
-def _swiglu(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    return (jax.nn.silu(a.astype(jnp.float32)) * b.astype(jnp.float32)).astype(jnp.bfloat16)
 
 
 def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
@@ -206,116 +161,9 @@ def _qkv(p: Params, config: Config, h: jnp.ndarray, positions: jnp.ndarray):
     return q.astype(jnp.bfloat16), k.astype(jnp.bfloat16), v
 
 
-def _route(p: Params, config: Config, h: jnp.ndarray):
-    """h [T, H] normed -> (experts [T, k] int32, weights [T, k] float32),
-    all of it in float32."""
-    c = config
-    logits = jnp.dot(
-        h.astype(jnp.float32), p["gate"].astype(jnp.float32), precision=HIGHEST
-    )
-    scores = jax.nn.sigmoid(logits)
-    choose = scores + p["expert_bias"] if c.use_expert_bias else scores
-    _, experts = jax.lax.top_k(choose, c.num_experts_per_tok)
-    weights = jnp.take_along_axis(scores, experts, axis=-1)
-    if c.norm_topk_prob:
-        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
-    return experts.astype(jnp.int32), weights * c.routed_scaling_factor
-
-
-def _gmm_tiling(pairs: int):
-    """(m, k, n) tiles of the grouped-product kernel, chosen from the one
-    thing that tells its two regimes apart, the number of routed pairs: a
-    prefill's are compute-bound and want big tiles; a step's few rows an
-    expert are bound by reading the experts' maps, and want the whole
-    contraction in one tile.  Timed on a v5e at the published widths
-    (PERF.md section 6, PR 26)."""
-    return (512, 2048, 512) if pairs >= 8192 else (128, 2048, 1024)
-
-
-def grouped_matmul(rows: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray) -> jnp.ndarray:
-    """rows [P, k] sorted by group, w [E, k, n], sizes [E] summing to P ->
-    [P, n] bfloat16: row i times the map of ITS group, float32
-    accumulation.  On the TPU the Pallas grouped-matmul kernel that ships
-    with JAX (megablox ``gmm``: 2x XLA's own ``ragged_dot`` at both the
-    step's and the prefill's shape on a v5e); elsewhere ``ragged_dot``."""
-    if jax.default_backend() != "tpu":
-        return jax.lax.ragged_dot(
-            rows, w, sizes, preferred_element_type=jnp.float32
-        ).astype(jnp.bfloat16)
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
-
-    P = rows.shape[0]
-    tiling = _gmm_tiling(P)
-    pad = -P % tiling[0]            # the kernel wants whole row tiles
-    if pad:
-        rows = jnp.pad(rows, ((0, pad), (0, 0)))
-    out = gmm(rows, w, sizes, preferred_element_type=jnp.bfloat16, tiling=tiling)
-    return out[:P] if pad else out
-
-
-def moe_ffn(p: Params, config: Config, x: jnp.ndarray):
-    """x [T, H] -> (x + experts' weighted sum [T, H], tokens per expert
-    [E] int32, experts chosen [T, k] int32).  Only the T*k routed pairs
-    are computed, grouped by expert; no capacity, nothing dropped."""
-    c = config
-    T, H = x.shape
-    k, E = c.num_experts_per_tok, c.num_experts
-    with jax.named_scope("decoder/lm/moe/route"):
-        h = _rms_norm(x, p["ffn_norm"], c.norm_eps).astype(jnp.bfloat16)
-        experts, weights = _route(p["feed_forward"], c, h)
-    f = p["feed_forward"]
-    with jax.named_scope("decoder/lm/moe/dispatch"):
-        flat = experts.reshape(T * k)
-        order = jnp.argsort(flat, stable=True)           # pairs, by expert
-        rows = h[order // k]                                # [T*k, H]
-        sizes = jnp.sum(
-            flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :],
-            axis=0, dtype=jnp.int32,
-        )
-    with jax.named_scope("decoder/lm/moe/experts"):
-        hidden = _swiglu(
-            grouped_matmul(rows, f["w1"], sizes), grouped_matmul(rows, f["w3"], sizes)
-        )
-        out = grouped_matmul(hidden, f["w2"], sizes)
-    with jax.named_scope("decoder/lm/moe/combine"):
-        back = jnp.zeros((T * k,), jnp.int32).at[order].set(
-            jnp.arange(T * k, dtype=jnp.int32)
-        )
-        picked = out[back].reshape(T, k, H).astype(jnp.float32)
-        y = jnp.sum(picked * weights[..., None], axis=1)
-        return x + y.astype(x.dtype), sizes, experts
-
-
-def dense_ffn(p: Params, config: Config, x: jnp.ndarray) -> jnp.ndarray:
-    with jax.named_scope("decoder/lm/dense_ffn"):
-        f = p["feed_forward"]
-        h = _rms_norm(x, p["ffn_norm"], config.norm_eps).astype(jnp.bfloat16)
-        return x + _mm(_swiglu(_mm(h, f["w1"]), _mm(h, f["w3"])), f["w2"])
-
-
-def _ffn(p: Params, config: Config, layer: int, x: jnp.ndarray):
-    """x [..., H] -> (y, tokens per expert [E], experts chosen [..., k]),
-    the last two None in a dense layer."""
-    if not _is_moe(config, layer):
-        return dense_ffn(p, config, x), None, None
-    y, sizes, experts = moe_ffn(p, config, x.reshape(-1, x.shape[-1]))
-    return y.reshape(x.shape), sizes, experts.reshape(x.shape[:-1] + (-1,))
-
-
 def _conv_taps(window: jnp.ndarray, taps: jnp.ndarray) -> jnp.ndarray:
     """window [..., L, H] (oldest first), taps [L, H] -> [..., H]."""
     return jnp.sum(window.astype(jnp.float32) * taps.astype(jnp.float32), axis=-2)
-
-
-def _stack_counts(counts) -> jnp.ndarray:
-    return jnp.stack(counts) if counts else jnp.zeros((0, 0), jnp.int32)
-
-
-def _join_routes(routes, lead) -> jnp.ndarray:
-    """Per expert layer [..., k] -> [..., moe layers * k] (layer-major)."""
-    if not routes:
-        return jnp.zeros(tuple(lead) + (0,), jnp.int32)
-    return jnp.concatenate(routes, axis=-1)
 
 
 # ---------------------------------------------------------------------------
@@ -378,22 +226,6 @@ def sequence_forward(lm: Params, config: Config, x: jnp.ndarray):
     return x, state, _stack_counts(counts), _join_routes(routes, (B, S))
 
 
-def _prefix(params: Params, contexts: jnp.ndarray) -> jnp.ndarray:
-    """The connector: grid [B, N, D] -> the prefix's embeddings [B, N, H]."""
-    with jax.named_scope("decoder/lm/prefix"):
-        p = params["connector"]
-        y = jnp.dot(
-            contexts.astype(jnp.bfloat16), p["kernel"].astype(jnp.bfloat16),
-            preferred_element_type=jnp.float32,
-        )
-        return (y + p["bias"]).astype(jnp.bfloat16)
-
-
-def _embed(lm: Params, words: jnp.ndarray) -> jnp.ndarray:
-    with jax.named_scope("decoder/lm/embed"):
-        return lm["embed_tokens"][words]
-
-
 def _head(lm: Params, config: Config, x: jnp.ndarray) -> jnp.ndarray:
     """[..., H] -> float32 logits [..., V] through the tied embedding."""
     with jax.named_scope("decoder/lm/head"):
@@ -412,12 +244,8 @@ def teacher_forced(
     """logits [B, T, V]: the input at caption step t is sentences[:, t-1]
     (``<start>`` = 0 at t = 0), after the N prefix positions."""
     lm = params["lm"]
-    B, T = sentences.shape
     N = contexts.shape[1]
-    words_in = jnp.concatenate(
-        [jnp.zeros((B, 1), sentences.dtype), sentences[:, :-1]], axis=1
-    )
-    x = jnp.concatenate([_prefix(params, contexts), _embed(lm, words_in)], axis=1)
+    x = lm_common.sequence_inputs(params, contexts, sentences)
     hidden, _, _, _ = sequence_forward(lm, config, x)
     return _head(lm, config, hidden[:, N:])
 
@@ -438,14 +266,6 @@ def prefill(params: Params, config: Config, contexts: jnp.ndarray):
 # ---------------------------------------------------------------------------
 
 
-def init_counters(prefill_counts: jnp.ndarray, max_len: int) -> StepCounters:
-    """Step 0's counters, the prefill's tokens per expert already in."""
-    return StepCounters(
-        t=jnp.int32(0), moe_counts=prefill_counts,
-        step_visits=jnp.zeros(prefill_counts.shape[:1] + (max_len,), jnp.int32),
-    )
-
-
 def init_cache(config: Config, conv, rows: int, max_len: int) -> BeamCache:
     """The per-beam cache of ``rows`` beams before the first step: their
     conv states ``conv`` (the prefix's, tiled by the caller), an empty
@@ -454,10 +274,17 @@ def init_cache(config: Config, conv, rows: int, max_len: int) -> BeamCache:
     c = config
     width = c.num_key_value_heads * _head_dim(c)
     n_attn = sum(kind == "full_attention" for kind in c.layer_types)
-    n_moe = c.num_hidden_layers - c.num_dense_layers
     empty = tuple(jnp.zeros((rows, max_len, width), jnp.bfloat16) for _ in range(n_attn))
-    routes = jnp.zeros((rows, max_len * n_moe * c.num_experts_per_tok), jnp.int32)
+    routes = lm_common.empty_routes(c, rows, max_len)
     return BeamCache(conv=tuple(conv), keys=empty, values=empty, routes=routes)
+
+
+def start_beams(config: Config, prefix: BeamCache, K: int, max_len: int, tile) -> BeamCache:
+    """The per-beam cache of the K beams of each image before the first
+    step: the prefix's conv states, ``tile``d to a row a beam (they start
+    per image and then differ per beam), over ``init_cache``'s empties."""
+    conv = tuple(tile(x, K) for x in prefix.conv)
+    return init_cache(config, conv, prefix.keys[0].shape[0] * K, max_len)
 
 
 def step(
@@ -539,24 +366,9 @@ def step(
         if sizes is not None:
             counts.append(sizes)
             routes.append(experts)
-    moe_counts, step_visits = counters.moe_counts, counters.step_visits
-    if counts:
-        sizes = jnp.stack(counts)
-        moe_counts = moe_counts + sizes
-        step_visits = jnp.where(
-            jnp.arange(step_visits.shape[1])[None, :] == t,
-            jnp.sum(sizes > 0, axis=1, dtype=jnp.int32)[:, None], step_visits,
-        )
-    taken = cache.routes
-    if routes:
-        with jax.named_scope("decoder/lm/moe/route"):
-            chosen = _join_routes(routes, (R,))             # [R, moe layers * k]
-            width = chosen.shape[1]
-            steps = taken.shape[1] // width
-            at_t = jnp.arange(steps * width) // width == t
-            taken = jnp.where(at_t[None, :], jnp.tile(chosen, (1, steps)), taken)
+    counters, taken = lm_common.record_step(counters, cache.routes, counts, routes)
     return (
         BeamCache(tuple(conv_state), tuple(keys), tuple(values), taken),
-        StepCounters(t=t + 1, moe_counts=moe_counts, step_visits=step_visits),
+        counters,
         _head(lm, c, x),
     )

@@ -1,0 +1,310 @@
+"""What the language-model caption decoders share (``models/lfm2.py``,
+``models/deepseek_v3.py``): the connector and the embedding, RMSNorm, the
+bfloat16 product, SwiGLU, the dense ffn, the mixture of experts (router,
+the sort by expert, the grouped product, the weighted un-sort, a shared
+expert where the layer has one), the counters a step carries and the
+record of chosen experts.  Each stack keeps what is its own: its sequence
+mixers, its cache and its head.
+
+Precision: parameters of a stack in bfloat16 (the sources'), matmuls
+bfloat16 x bfloat16 with float32 accumulation, the residual stream
+bfloat16; norms, softmax and the whole router (product, sigmoid, bias,
+choice: a ``hidden_size x num_experts`` product at ``HIGHEST``, so that
+near-ties do not flip against the float32 reference) in float32.
+
+The expert layer: sigmoid scores, the ``num_experts_per_tok`` largest of
+``score + expert_bias`` chosen, the scores at the chosen (the bias selects
+and never weighs) divided by their sum + ``sum_eps`` (the one constant in
+which the sources' routers differ: an argument), times
+``routed_scaling_factor``.  No capacity and no dropped token: the routed
+(token, expert) pairs are sorted by expert and go through a grouped
+product (``grouped_matmul``), which computes those pairs and no others.
+A layer whose ``feed_forward`` holds a ``shared`` SwiGLU sends every token
+through it too, beside the routed sum.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from ..config import Config
+
+Params = Dict[str, Any]
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+class StepCounters(NamedTuple):
+    """What a step carries besides the beams' state: never reordered."""
+
+    t: jnp.ndarray                  # () int32 caption steps taken
+    moe_counts: jnp.ndarray         # [moe layers, E] int32 tokens routed
+    # [moe layers, T] int32: experts that took a token at each step (an
+    # expert no row chose is not read: what a step's grouped products had
+    # to fetch is this many experts' maps; at step 0 every row holds
+    # ``<start>``, so few are)
+    step_visits: jnp.ndarray
+
+
+def is_moe(config: Config, layer: int) -> bool:
+    return layer >= config.num_dense_layers
+
+
+def layer_name(layer: int) -> str:
+    return f"{layer:02d}"
+
+
+# ---------------------------------------------------------------------------
+# building blocks
+# ---------------------------------------------------------------------------
+
+
+def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
+    """float32 in and out of the statistics; the caller casts."""
+    x = x.astype(jnp.float32)
+    var = jnp.mean(x * x, axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)
+
+
+def mm(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """bfloat16 operands, float32 accumulation, bfloat16 result."""
+    return jnp.dot(
+        x.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.bfloat16)
+
+
+def swiglu(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    return (jax.nn.silu(a.astype(jnp.float32)) * b.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def route(p: Params, config: Config, h: jnp.ndarray, sum_eps: float):
+    """h [T, H] normed -> (experts [T, k] int32, weights [T, k] float32),
+    all of it in float32."""
+    c = config
+    logits = jnp.dot(
+        h.astype(jnp.float32), p["gate"].astype(jnp.float32), precision=HIGHEST
+    )
+    scores = jax.nn.sigmoid(logits)
+    choose = scores + p["expert_bias"] if c.use_expert_bias else scores
+    _, experts = jax.lax.top_k(choose, c.num_experts_per_tok)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if c.norm_topk_prob:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + sum_eps)
+    return experts.astype(jnp.int32), weights * c.routed_scaling_factor
+
+
+# (k, n) of a grouped product -> its (m, k, n) tiles for a step's few rows
+# an expert and for a prefill's many, each timed on a v5e at the published
+# widths (PERF.md section 6): 2048 x 1792 experts PR 26, 2048 x 768 PR 30
+_GMM_TILES = {
+    (2048, 1792): ((128, 2048, 1024), (512, 2048, 512)),
+    (1792, 2048): ((128, 2048, 1024), (512, 2048, 512)),
+    (2048, 768): ((128, 2048, 768), (256, 2048, 768)),
+    (768, 2048): ((128, 768, 2048), (512, 768, 2048)),
+}
+
+
+def _gmm_tiling(pairs: int, k: int, n: int):
+    """(m, k, n) tiles of the grouped-product kernel from the product's
+    own shape.  The number of routed pairs tells its two regimes apart: a
+    prefill's are compute-bound and want big tiles; a step's few rows an
+    expert are bound by reading the experts' maps, and want the whole
+    contraction in one tile.  A width that was timed takes the tiles that
+    won; any other takes the contraction whole (up to 2048) and the widest
+    output tile up to 1024 lanes that divides n, else n whole."""
+    prefill = pairs >= 8192
+    if (k, n) in _GMM_TILES:
+        return _GMM_TILES[(k, n)][prefill]
+    tn = next((t for t in (1024, 512, 256, 128) if n % t == 0), n)
+    return (512 if prefill else 128, min(k, 2048), tn)
+
+
+def grouped_matmul(rows: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray) -> jnp.ndarray:
+    """rows [P, k] sorted by group, w [E, k, n], sizes [E] summing to P ->
+    [P, n] bfloat16: row i times the map of ITS group, float32
+    accumulation.  On the TPU the Pallas grouped-matmul kernel that ships
+    with JAX (megablox ``gmm``: 2x XLA's own ``ragged_dot`` at both the
+    step's and the prefill's shape on a v5e); elsewhere ``ragged_dot``."""
+    if jax.default_backend() != "tpu":
+        return jax.lax.ragged_dot(
+            rows, w, sizes, preferred_element_type=jnp.float32
+        ).astype(jnp.bfloat16)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    P = rows.shape[0]
+    tiling = _gmm_tiling(P, w.shape[1], w.shape[2])
+    pad = -P % tiling[0]            # the kernel wants whole row tiles
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    out = gmm(rows, w, sizes, preferred_element_type=jnp.bfloat16, tiling=tiling)
+    return out[:P] if pad else out
+
+
+def moe_ffn(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
+    """x [T, H] -> (x + experts' weighted sum [T, H], tokens per expert
+    [E] int32, experts chosen [T, k] int32).  Only the T*k routed pairs
+    are computed, grouped by expert; no capacity, nothing dropped.  With a
+    ``shared`` expert in the layer, every token's pass through it is added
+    to the routed sum."""
+    c = config
+    T, H = x.shape
+    k, E = c.num_experts_per_tok, c.num_experts
+    with jax.named_scope("decoder/lm/moe/route"):
+        h = rms_norm(x, p["ffn_norm"], c.norm_eps).astype(jnp.bfloat16)
+        experts, weights = route(p["feed_forward"], c, h, sum_eps)
+    f = p["feed_forward"]
+    with jax.named_scope("decoder/lm/moe/dispatch"):
+        flat = experts.reshape(T * k)
+        order = jnp.argsort(flat, stable=True)           # pairs, by expert
+        rows = h[order // k]                                # [T*k, H]
+        sizes = jnp.sum(
+            flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :],
+            axis=0, dtype=jnp.int32,
+        )
+    with jax.named_scope("decoder/lm/moe/experts"):
+        hidden = swiglu(
+            grouped_matmul(rows, f["w1"], sizes), grouped_matmul(rows, f["w3"], sizes)
+        )
+        out = grouped_matmul(hidden, f["w2"], sizes)
+    with jax.named_scope("decoder/lm/moe/combine"):
+        back = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32)
+        )
+        picked = out[back].reshape(T, k, H).astype(jnp.float32)
+        y = jnp.sum(picked * weights[..., None], axis=1)
+    if "shared" in f:
+        with jax.named_scope("decoder/lm/moe/shared"):
+            s = f["shared"]
+            y = y + mm(swiglu(mm(h, s["w1"]), mm(h, s["w3"])), s["w2"]).astype(jnp.float32)
+    with jax.named_scope("decoder/lm/moe/combine"):
+        return x + y.astype(x.dtype), sizes, experts
+
+
+def dense_ffn(p: Params, config: Config, x: jnp.ndarray) -> jnp.ndarray:
+    with jax.named_scope("decoder/lm/dense_ffn"):
+        f = p["feed_forward"]
+        h = rms_norm(x, p["ffn_norm"], config.norm_eps).astype(jnp.bfloat16)
+        return x + mm(swiglu(mm(h, f["w1"]), mm(h, f["w3"])), f["w2"])
+
+
+def ffn(p: Params, config: Config, layer: int, x: jnp.ndarray, sum_eps: float):
+    """x [..., H] -> (y, tokens per expert [E], experts chosen [..., k]),
+    the last two None in a dense layer."""
+    if not is_moe(config, layer):
+        return dense_ffn(p, config, x), None, None
+    y, sizes, experts = moe_ffn(p, config, x.reshape(-1, x.shape[-1]), sum_eps)
+    return y.reshape(x.shape), sizes, experts.reshape(x.shape[:-1] + (-1,))
+
+
+def ffn_params(config: Config, layer: int, linear) -> Params:
+    """One layer's ``feed_forward`` leaves; ``linear(*shape)`` draws a map."""
+    c = config
+    H, E = c.hidden_size, c.num_experts
+    if not is_moe(c, layer):
+        I = c.intermediate_size
+        return {"w1": linear(H, I), "w3": linear(H, I), "w2": linear(I, H)}
+    I = c.moe_intermediate_size
+    return {
+        "gate": linear(H, E),
+        "expert_bias": jnp.zeros((E,), jnp.float32),
+        "w1": linear(E, H, I), "w3": linear(E, H, I), "w2": linear(E, I, H),
+    }
+
+
+def connector_params(key: jax.Array, config: Config) -> Params:
+    """float32: it trains."""
+    H = config.hidden_size
+    return {
+        "kernel": 0.02 * jax.random.normal(key, (config.dim_ctx, H), jnp.float32),
+        "bias": jnp.zeros((H,), jnp.float32),
+    }
+
+
+def prefix(params: Params, contexts: jnp.ndarray) -> jnp.ndarray:
+    """The connector: grid [B, N, D] -> the prefix's embeddings [B, N, H]."""
+    with jax.named_scope("decoder/lm/prefix"):
+        p = params["connector"]
+        y = jnp.dot(
+            contexts.astype(jnp.bfloat16), p["kernel"].astype(jnp.bfloat16),
+            preferred_element_type=jnp.float32,
+        )
+        return (y + p["bias"]).astype(jnp.bfloat16)
+
+
+def embed(lm: Params, words: jnp.ndarray) -> jnp.ndarray:
+    with jax.named_scope("decoder/lm/embed"):
+        return lm["embed_tokens"][words]
+
+
+def sequence_inputs(params: Params, contexts: jnp.ndarray, sentences: jnp.ndarray):
+    """[prefix; <start>; the sentence but its last word], embedded: the
+    input at caption step t is sentences[:, t-1] (``<start>`` = 0 at
+    t = 0), after the N prefix positions."""
+    B = sentences.shape[0]
+    words_in = jnp.concatenate(
+        [jnp.zeros((B, 1), sentences.dtype), sentences[:, :-1]], axis=1
+    )
+    return jnp.concatenate(
+        [prefix(params, contexts), embed(params["lm"], words_in)], axis=1
+    )
+
+
+# ---------------------------------------------------------------------------
+# counters and the record of routes
+# ---------------------------------------------------------------------------
+
+
+def stack_counts(counts) -> jnp.ndarray:
+    return jnp.stack(counts) if counts else jnp.zeros((0, 0), jnp.int32)
+
+
+def join_routes(routes, lead) -> jnp.ndarray:
+    """Per expert layer [..., k] -> [..., moe layers * k] (layer-major)."""
+    if not routes:
+        return jnp.zeros(tuple(lead) + (0,), jnp.int32)
+    return jnp.concatenate(routes, axis=-1)
+
+
+def init_counters(prefill_counts: jnp.ndarray, max_len: int) -> StepCounters:
+    """Step 0's counters, the prefill's tokens per expert already in."""
+    return StepCounters(
+        t=jnp.int32(0), moe_counts=prefill_counts,
+        step_visits=jnp.zeros(prefill_counts.shape[:1] + (max_len,), jnp.int32),
+    )
+
+
+def empty_routes(config: Config, rows: int, max_len: int) -> jnp.ndarray:
+    """[R, T * moe layers * k] int32 (step-major; one row of lanes a
+    beam): the experts the beam's own tokens chose, step by step.  A
+    per-beam leaf: it follows the beam through every reorder, so at the
+    end a live beam holds the choices of ITS ancestry (what a
+    teacher-forced pass over its caption would choose)."""
+    n_moe = config.num_hidden_layers - config.num_dense_layers
+    return jnp.zeros((rows, max_len * n_moe * config.num_experts_per_tok), jnp.int32)
+
+
+def record_step(counters: StepCounters, taken: jnp.ndarray, counts, routes):
+    """After a step over R rows: (the counters with the step's tokens per
+    expert ``counts`` (one [E] a moe layer) added and t advanced, the
+    record ``taken`` with the step's choices ``routes`` (one [R, k] a moe
+    layer) written at step t)."""
+    t = counters.t
+    moe_counts, step_visits = counters.moe_counts, counters.step_visits
+    if counts:
+        sizes = jnp.stack(counts)
+        moe_counts = moe_counts + sizes
+        step_visits = jnp.where(
+            jnp.arange(step_visits.shape[1])[None, :] == t,
+            jnp.sum(sizes > 0, axis=1, dtype=jnp.int32)[:, None], step_visits,
+        )
+    if routes:
+        with jax.named_scope("decoder/lm/moe/route"):
+            chosen = join_routes(routes, (taken.shape[0],))    # [R, moe layers * k]
+            width = chosen.shape[1]
+            steps = taken.shape[1] // width
+            at_t = jnp.arange(steps * width) // width == t
+            taken = jnp.where(at_t[None, :], jnp.tile(chosen, (1, steps)), taken)
+    return StepCounters(t=t + 1, moe_counts=moe_counts, step_visits=step_visits), taken

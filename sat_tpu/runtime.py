@@ -1339,6 +1339,13 @@ def decode_dataset(
                     "decode/moe_load_max_over_mean",
                     float((counts.max(axis=1) / counts.mean(axis=1)).max()),  # sync-ok: host numpy, already drained
                 )
+            if out.decoder_stats and "state_bytes" in out.decoder_stats:
+                # the search's state a batch (prefix per image + per-beam
+                # tree), as the decoder counted it from the shapes
+                tel.gauge(
+                    "decode/lm_state_mb",
+                    float(out.decoder_stats["state_bytes"]) / 1e6,  # sync-ok: decode drain boundary
+                )
         with tel.span("decode/drain/detok", b):  # host work after it
             for i, image_file in enumerate(files):
                 if emitted >= dataset.count:           # fake_count padding

@@ -392,6 +392,95 @@ def test_lm_beam_program_compiles_with_the_grouped_kernel_and_no_vocabulary_sort
     assert compiled.memory_analysis().temp_size_in_bytes < 6 << 30
 
 
+# sha256 of the beam programs' optimized HLO without metadata, compiled for
+# the described v5e from the PARENT of PR 30 (commit 24822bf, before the
+# language-model decoders' shared pieces moved to models/lm_common.py and
+# the search took a decoder's module) with the jax these were read under
+_PARENT_BEAM_PROGRAMS = {
+    "jax": "0.9.0",
+    "lstm": "b7c48655267979285830386bac78e3ad59cfa9649271f5547a1402656bba0bf7",
+    "lfm2": "df0b273fb34614e8293bee0c11ea631f0d5dd9ddedb560cc912a4123a2e83772",
+}
+
+
+@pytest.mark.parametrize("program", ["lstm", "lfm2"])
+def test_a_third_decoder_leaves_the_other_two_beam_programs_as_they_were(monkeypatch, program):
+    """The eval cells' beam programs (the LSTM's at B = 512, lfm2's at
+    B = 256 and one period of its stack) come out of the compiler as they
+    did before ``decoder="deepseek_v3"`` existed, but for metadata: the
+    shared module, the tiles chosen from a product's own shape and the
+    search's ``state_bytes`` changed nothing that runs for them."""
+    import hashlib
+
+    from sat_tpu.ops.beam_search import beam_search_jit
+
+    if jax.__version__ != _PARENT_BEAM_PROGRAMS["jax"]:
+        pytest.skip(f"the parent's programs were read under jax {_PARENT_BEAM_PROGRAMS['jax']}")
+    config, B = (Config(), 512) if program == "lstm" else (Config(
+        decoder="lfm2_moe", vocabulary_size=65536, num_hidden_layers=5, num_dense_layers=1,
+        layer_types=("conv", "full_attention", "conv", "conv", "conv"),
+    ), 256)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, decoder = _decoder_params(config)
+    text = beam_search_jit.lower(
+        decoder, config, _sd((B, config.num_ctx, config.dim_ctx)), 3 if program == "lstm" else 1,
+        beam_size=3, valid_size=config.vocabulary_size,
+    ).compile().as_text()
+    assert hashlib.sha256(_strip_metadata(text).encode()).hexdigest() == _PARENT_BEAM_PROGRAMS[program]
+
+
+def test_mla_beam_program_keeps_the_prefix_latent_and_per_image(monkeypatch):
+    """``decoder="deepseek_v3"`` at the new cell's batch and the published
+    widths (B = 256, K = 3, depth 5, V = 128,256): accepted by the chip's
+    compiler beside 6.4 GB of weights; the experts of 768 through the
+    Pallas grouped product, prefill and step; the vocabulary through
+    ``TopK`` and never a sort; and in the loop the prefix is the per-image
+    latent ``bf16[256,196,576]``, never a copy per beam (``[768,196,..]``)
+    nor keys or values per head (``[256,196,32,..]``)."""
+    from sat_tpu.ops.beam_search import beam_search_jit
+
+    config = Config(
+        decoder="deepseek_v3", vocabulary_size=128256, hidden_size=2048, intermediate_size=6144,
+        moe_intermediate_size=768, num_hidden_layers=5, num_dense_layers=1, num_attention_heads=32,
+        num_experts=128, num_experts_per_tok=6, routed_scaling_factor=2.448, norm_eps=1e-6,
+        tie_word_embeddings=False, layer_types=("latent_attention",) * 5,
+    )
+    V, K, N = config.vocabulary_size, 3, config.num_ctx
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, decoder = _decoder_params(config)
+    compiled = beam_search_jit.lower(
+        decoder, config, _sd((256, N, config.dim_ctx)), 1, beam_size=K, valid_size=V,
+    ).compile()
+    text = compiled.as_text()
+    sorts = [ln for ln in text.splitlines() if re.search(r"\bsort\(", ln)]
+    wide = [
+        ln.strip()[:160] for ln in sorts
+        if {V, K * V} & {
+            int(d) for dims in re.findall(r"\[([\d,]+)\]", ln) for d in dims.split(",")
+        }
+    ]
+    assert not wide, wide
+    assert text.count('custom_call_target="TopK"') >= 2
+    # three grouped products an expert layer: four layers' in the steps, and
+    # in the prefill three (the last layer's experts feed nothing there)
+    assert len(re.findall(r"decoder/lm/moe/experts[^\n]*tpu_custom_call|"
+                          r"tpu_custom_call[^\n]*decoder/lm/moe/experts", text)) >= 21
+
+    computations = _computations(text)
+    bodies = re.findall(r" while\([^\n]*body=%([\w.\-]+)", text)
+    assert bodies
+    in_loop = set().union(*(_reachable(computations, b) for b in bodies))
+    shapes = {
+        shape for name in in_loop for shape in re.findall(r"(?:bf16|f32)\[[\d,]+\]", computations[name])
+    }
+    assert f"bf16[256,{N},576]" in shapes
+    held = [s for s in shapes if re.search(rf"\[(768,{N},|256,{K},{N},\d{{3,}}|256,{N},32,)", s)]
+    assert not held, held
+    # read here: 5.27 GB (the prefill's un-grouping f32[50176,6,2048] and
+    # scores f32[256,32,196,196] set it), beside 6.41 GB of arguments
+    assert compiled.memory_analysis().temp_size_in_bytes < int(6.5e9)
+
+
 # ---------------------------------------------------------------------------
 # The train step: what the backward pass keeps of the attention chain
 # ---------------------------------------------------------------------------

@@ -299,9 +299,21 @@ def test_the_stack_is_frozen_as_the_cnn_is(params):
     (dict(context_parallel=2), "context_parallel"), (dict(save_attention_maps=True), "return_alphas"),
     (dict(layer_types=("conv",)), "layer_types"),
 ])
-def test_what_this_decoder_cannot_run_is_refused_by_name(kw, said):
-    with pytest.raises(ValueError, match=said):
-        Config(**{**TOY, **kw})
+@pytest.mark.parametrize("decoder", ["lfm2_moe", "deepseek_v3"])
+def test_what_this_decoder_cannot_run_is_refused_by_name(decoder, kw, said):
+    """Both language-model decoders, one rule: each case names what it
+    refuses and the decoder it refuses it for."""
+    toy = TOY if decoder == "lfm2_moe" else {
+        **TOY, "decoder": decoder, "layer_types": ("latent_attention",) * 5, "tie_word_embeddings": False}
+    Config(**toy)                                  # the toy itself is accepted
+    with pytest.raises(ValueError, match=said) as refusal:
+        Config(**{**toy, **kw})
+    assert said == "layer_types" or f"decoder={decoder!r}" in str(refusal.value)
+
+
+def test_lfm2_s_head_is_its_embedding_and_a_config_that_unties_it_is_refused():
+    with pytest.raises(ValueError, match="tie_word_embeddings"):
+        Config(**{**TOY, "tie_word_embeddings": False})
 
 
 def test_return_alphas_is_refused_by_the_search_too(params):

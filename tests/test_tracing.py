@@ -277,6 +277,47 @@ def test_the_pad_round_the_kernel_carries_its_own_scope(beams):
     assert "decoder/attend/pad" in text and "fused_attend" in text
 
 
+def test_the_latent_attention_s_scopes_reach_op_scopes():
+    """``decoder="deepseek_v3"``: every scope the benchmark's ``lm_mla_*`` and
+    ``lm_moe_shared_device_ms`` metrics read names at least one leaf
+    instruction of the beam program, by phase (the expanded form under
+    ``beam/prefill``, the absorbed one under ``beam/loop``'s body), and the
+    rules of benchmark/scopes/lm_mla.json claim them."""
+    from sat_tpu.models import decoders
+    from sat_tpu.ops.beam_search import beam_search_jit
+
+    config = Config(
+        decoder="deepseek_v3", image_size=32, hidden_size=64, intermediate_size=96, moe_intermediate_size=24,
+        # three layers: the prefill keeps latents alone, so the LAST layer's
+        # experts are dead code there and an expert layer has to come before it
+        num_hidden_layers=3, num_dense_layers=1, num_attention_heads=4, num_experts=8, num_experts_per_tok=3,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, n_shared_experts=2,
+        tie_word_embeddings=False, layer_types=("latent_attention",) * 3, vocabulary_size=100,
+        max_caption_length=6, beam_size=3, batch_size=4,
+    )
+    params = decoders.init_params(jax.random.PRNGKey(0), config)
+    contexts = jnp.zeros((4, config.num_ctx, config.dim_ctx))
+    tel = Telemetry(capacity=64)
+    xla_acct.reset()
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    xla_acct.analyze("decode/beam_search", beam_search_jit, params, config, contexts, 1,
+                     beam_size=3, valid_size=100, return_alphas=False, tel=tel)
+    rows = xla_acct.entries()["decode/beam_search"]["op_scopes"]["rows"]
+    xla_acct.reset()
+    ops = [r[3] for r in rows if not r[2]]
+    both = ("q", "latent", "scores", "out")
+    for scope in [f"beam/prefill.*decoder/lm/attn/{s}" for s in both + ("expand",)] + \
+                 [f"while/body.*decoder/lm/attn/{s}" for s in both + ("absorb",)] + \
+                 ["beam/prefill.*decoder/lm/moe/shared", "while/body.*decoder/lm/moe/shared"]:
+        assert any(re.search(scope, o) for o in ops), scope
+    assert not any(re.search(r"beam/prefill.*attn/absorb|while/body.*attn/expand", o) for o in ops)
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                           "benchmark", "scopes", "lm_mla.json")) as f:
+        rules = [tuple(r) for r in json.load(f)["rules"]]
+    claimed = {_bucket(rules, o) for o in ops}
+    assert {"prefill_attn", "step_attn", "shared", "other"} <= claimed
+
+
 def test_parse_op_scopes_on_a_written_module():
     text = """HloModule jit_f, is_scheduled=true
 
