@@ -21,14 +21,39 @@ Semantics preserved (the reference is the correctness oracle):
 Deliberate upgrade: each step takes the global top-K over all beam×vocab
 continuations (the eos column excluded from continuation) instead of the
 reference's per-beam top-(K+1) heap pushes — a strictly-at-least-as-good
-candidate set, computed as one ``lax.top_k`` over ``[B, K*V]`` on device.
-The reference's top-(K+1) survives as a gate on completions only: eos
-closes a beam when it is among that beam's K+1 likeliest next words, and
-the gate needs one number per beam, the (K+1)-th largest log-probability
-(``_kth_largest``: an exact ``top_k`` over the ``[B*K, V]`` rows the
-logits arrive in, reduced to its minimum).  Both selections over the
-vocabulary are written so that XLA:TPU emits its ``TopK`` call for them;
-no step sorts the vocabulary (tests/test_aot_tpu.py pins it).
+candidate set.  The reference's top-(K+1) survives as a gate on
+completions only: eos closes a beam when it is among that beam's K+1
+likeliest next words, and the gate needs one number per beam, the
+(K+1)-th largest log-probability.
+
+Both come out of ONE selection (``_top_rows``): ``lax.top_k`` with k = K+1
+over the ``[B*K, V]`` rows the logits arrive in, values and indices.  Its
+values' minimum is the gate's threshold.  Its K+1 candidates a row hold
+the row's K best words other than eos, whether or not eos is among them,
+and the global top-K lies within the union of the rows' own top-K; so the
+continuations are a second ``top_k`` over ``[B, K*(K+1)]`` numbers (the
+candidates plus their beam's score, eos masked), the same K — values,
+parents and words — that a ``top_k`` over ``[B, K*V]`` gives: adding a
+beam's score is monotone within its row, and both stages put the lower
+index first among equals, beam-major.  (Only where V < K, or where the
+add rounds two DIFFERENT log-probabilities of a row onto one score, can
+the two orders part: the latter keeps the likelier word first, where the
+flat form kept the lower index.)  The selection is on the log-softmax and
+not on the raw logits, because two logits that round to one
+log-probability are a tie to the search, won by the lower index.
+
+Why per row: on the chip ``[B*K, V]`` is tiled (8, 128) and ``[B, K, V]``
+(4, 128) over ``(K, V)``, so the reshape between them, the ``+ live_logp``
+broadcast, the eos overwrite and the ``[B, K*V]`` view each wrote the
+whole array again — at V = 128,256 four copies of f32[256,3,V] and a
+second ``TopK``, 18% of the device's time.  Now nothing vocabulary-wide is
+written after the logits: the log-softmax's last subtraction fuses into
+the ``TopK`` call, and the eos column is a masked sum inside the pass
+that sums the exponentials.  XLA:TPU emits its ``TopK`` call only for a
+rank-2 operand whose returned values are all consumed (a rank-3 operand
+or a sliced column lower to a full stable sort of the vocabulary); no
+step sorts the vocabulary or holds an array per beam × vocabulary
+(tests/test_aot_tpu.py pins both).
 
 Greedy decoding is the beam_size=1 special case of the same program.
 
@@ -46,10 +71,10 @@ Two drivers run the SAME expansion math (``_expand_step``):
   freezes the step it seals (all K finished slots filled and
   min(fin) ≥ max(live)) — from that step on the monolithic search can no
   longer alter that image's merged result either (a later completion
-  scores ≤ max(live) ≤ min(fin), and the finished-set merge — the one
-  ``lax.top_k`` of the step whose INDICES break ties — prefers the lower
-  index, where the finished entries sit; the eos gate's threshold is a
-  value, the same number however its ties are ordered).
+  scores ≤ max(live) ≤ min(fin), and the finished-set merge's
+  ``lax.top_k`` prefers the lower index among equals, where the finished
+  entries sit; the eos gate's threshold is a value, the same number
+  however its ties are ordered).
 """
 
 from __future__ import annotations
@@ -146,14 +171,17 @@ def _init_search(B: int, K: int, T: int, An: int) -> SearchState:
 
 
 @jax.named_scope("beam/topk")
-def _kth_largest(rows: jnp.ndarray, k: int) -> jnp.ndarray:
-    """[R, V] -> [R]: each row's k-th largest value, exactly (a value, so
-    ties cannot matter).  Written the one way XLA:TPU turns into its
-    ``TopK`` custom call: a rank-2 operand, and every returned value
-    consumed — the k-th of a descending top-k is its minimum.  A rank-3
-    operand, or slicing the last column out, each lowers instead to a
-    full stable sort of the vocabulary axis."""
-    return jax.lax.top_k(rows, k)[0].min(axis=-1)
+def _top_rows(rows: jnp.ndarray, k: int):
+    """[R, V] -> (kth [R], values [R, k], indices [R, k]): each row's k
+    largest values in descending order, the lower index first among
+    equals, and the k-th of them (the minimum: a value, so ties cannot
+    matter).  The ONE selection of a step over the vocabulary, written the
+    one way XLA:TPU turns into its ``TopK`` custom call: a rank-2 operand
+    (the ``[B*K, V]`` rows as the head writes them) and every returned
+    value consumed.  A rank-3 operand, or slicing the last column out,
+    each lowers instead to a full stable sort of the vocabulary axis."""
+    vals, idx = jax.lax.top_k(rows, k)
+    return vals.min(axis=-1), vals, idx
 
 
 @jax.named_scope("beam/expand")
@@ -189,17 +217,33 @@ def _expand_step(
     step_alpha = alpha.reshape(B, K, alpha.shape[-1])[:, :, :An]  # [B,K,An]
     if valid_size is not None and valid_size < V:
         logits = logits.at[:, valid_size:].set(NEG_INF)
-    row_logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    step_logp = row_logp.reshape(B, K, V)
-    logp = step_logp + s.live_logp[..., None]          # [B,K,V] cumulative
+    # log-softmax over the [B*K, V] rows, in its parts (the arithmetic of
+    # ``jax.nn.log_softmax`` to the bit), so that the rows stay as the head
+    # wrote them and nothing is written out again for the sake of the eos
+    # column's B*K numbers.  That column is read as a masked sum along the
+    # row (x + zeros: exact): it rides the pass that sums the exponentials.
+    # A slice would not do: ``row_logp[:, eos_id]`` makes XLA:TPU write the
+    # whole log-softmax out beside the copy it fuses into ``TopK``, and
+    # ``logits[:, eos_id]`` made it hand a bfloat16 decoder's logits to the
+    # slice rounded and to ``TopK`` unrounded, so that an eos at rank K+1
+    # fell under its own threshold (seen on the chip, PR 31).
+    x = logits.astype(jnp.float32)
+    x_max = x.max(axis=-1, keepdims=True)
+    lse = jnp.log(jnp.exp(x - x_max).sum(axis=-1, keepdims=True))
+    row_logp = (x - x_max) - lse
+    x_eos = jnp.where(jnp.arange(V) == eos_id, x, 0.0).sum(axis=-1)
+    eos_row = (x_eos - x_max[:, 0]) - lse[:, 0]
+    live_row = s.live_logp.reshape(B * K)
+    C = min(K + 1, V)                       # candidates a row
+    kth, row_top, row_word = _top_rows(row_logp, C)     # [B*K], 2 x [B*K,C]
 
     # --- completions: an eos hypothesis only becomes a candidate when
     # eos is within its beam's top-(K+1) next words — the reference only
     # ever pushes words from that set (base_model.py:219-230), so junk
     # completions can't crowd out the partial-beam fallback.
-    kth = _kth_largest(row_logp, min(K + 1, V)).reshape(B, K)
-    eos_allowed = step_logp[:, :, eos_id] >= kth
-    eos_scores = jnp.where(eos_allowed, logp[:, :, eos_id], NEG_INF)  # [B,K]
+    eos_scores = jnp.where(
+        eos_row >= kth, eos_row + live_row, NEG_INF
+    ).reshape(B, K)
     eos_words = jnp.where(t_hot[:, None, :], jnp.int32(eos_id), s.live_words)
     eos_len = s.live_len + 1
     # the eos word was emitted from THIS step's attention
@@ -218,12 +262,16 @@ def _expand_step(
         fin_len = cand_len[batch_idx, fin_sel]
         fin_alphas = cand_alphas[batch_idx, fin_sel]
 
-    # --- continuations: global top-K over beam×vocab, eos excluded
-    cont = logp.at[:, :, eos_id].set(NEG_INF).reshape(B, K * V)
+    # --- continuations: global top-K over beam×vocab, eos excluded: the
+    # K best of the rows' K+1 candidates each plus its beam's score (the
+    # module docstring says why they are the K of one top_k over [B, K*V])
+    cont = jnp.where(
+        row_word == eos_id, NEG_INF, row_top + live_row[:, None]
+    ).reshape(B, K * C)
     with jax.named_scope("beam/topk"):
-        top_live, flat_sel = jax.lax.top_k(cont, K)        # [B,K]
-    parent = flat_sel // V                                 # source beam
-    word = (flat_sel % V).astype(jnp.int32)                # chosen token
+        top_live, flat_sel = jax.lax.top_k(cont, K)        # [B,K] of K*C
+    parent = flat_sel // C                                 # source beam
+    word = row_word.reshape(B, K * C)[batch_idx, flat_sel]  # chosen token
 
     # the per-parent gathers that reorder every beam's state
     with jax.named_scope("beam/tile"):

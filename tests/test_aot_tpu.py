@@ -146,44 +146,6 @@ def test_stepped_pool_program_compiles_with_kernel(monkeypatch):
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 2 << 30
 
 
-@pytest.mark.parametrize(
-    "program,K", [("search", 3), ("search", 1), ("pool", Config().beam_size)],
-    ids=["beam3-b512", "greedy-b512", "pool"],
-)
-def test_beam_programs_select_without_a_vocabulary_sort(monkeypatch, program, K):
-    """The beam step's selections over the vocabulary reach the chip's
-    ``TopK`` call; no ``sort`` is left with a vocabulary-sized operand
-    (the per-beam threshold used to be one: a stable sort of
-    f32[512,3,5000] on every step, 28% of the eval cell's device time).
-    The eval cell's program, greedy at its batch, and the serve pool's."""
-    from sat_tpu.ops.beam_search import beam_search_jit
-
-    config = Config()
-    V = config.vocabulary_size
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    if program == "pool":
-        lowered = _lower_pool_program(config)
-    else:
-        _, decoder = _decoder_params(config)
-        lowered = beam_search_jit.lower(
-            decoder, config, _sd((512, config.num_ctx, config.dim_ctx)), 3,
-            beam_size=K, valid_size=V,
-        )
-    text = lowered.compile().as_text()
-
-    sorts = [ln for ln in text.splitlines() if re.search(r"\bsort\(", ln)]
-    wide = [
-        ln.strip()[:160] for ln in sorts
-        if {V, K * V} & {
-            int(d) for dims in re.findall(r"\[([\d,]+)\]", ln)
-            for d in dims.split(",")
-        }
-    ]
-    assert not wide, wide
-    # the threshold over [rows, V] and the continuations over [B, K*V]
-    assert text.count('custom_call_target="TopK"') >= 2
-
-
 def _computations(text):
     """{name: body text} of an optimized HLO module's computations."""
     chunks = re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)
@@ -203,6 +165,81 @@ def _reachable(computations, root):
             r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", computations[name]
         )
     return seen
+
+
+def _loop_lines(text):
+    """The lines of every computation a ``while`` body of an optimized HLO
+    module reaches (fusions and nested loops included)."""
+    computations = _computations(text)
+    bodies = re.findall(r" while\([^\n]*body=%([\w.\-]+)", text)
+    assert bodies
+    in_loop = set().union(*(_reachable(computations, b) for b in bodies))
+    return [ln for name in in_loop for ln in computations[name].splitlines()]
+
+
+def _assert_the_step_selects_per_row(text, B, K, V):
+    """What is true of every decoder's beam program since the step selects
+    per row (``ops/beam_search.py`` ``_expand_step``), read off the
+    optimized HLO: no ``sort`` has a vocabulary-sized operand, anywhere;
+    in the loop ONE ``TopK`` call reads a vocabulary-wide operand, the
+    ``[B*K, V]`` rows, and it returns K+1 a row; and the loop holds no
+    array ``[B, K, V]`` or ``[B, K*V]`` (on the chip either is a copy of
+    the rows in another tiling), nor a second selection over one."""
+    wide = {V, K * V}
+
+    def dims(ln):
+        return {
+            int(d) for group in re.findall(r"\[([\d,]+)\]", ln) for d in group.split(",")
+        }
+
+    sorts = [ln.strip()[:160] for ln in text.splitlines() if re.search(r"\bsort\(", ln) and wide & dims(ln)]
+    assert not sorts, sorts
+
+    lines = _loop_lines(text)
+    shapes = {}  # instruction -> its result, as the line that defines it gives it
+    for ln in lines:
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\(?\w+\[[\d,]*\])", ln)
+        if m:
+            shapes[m.group(1)] = m.group(2)
+    selections = []
+    for ln in lines:
+        m = re.search(r'= \((\w+\[[\d,]*\])[^=]*? custom-call\(%([\w.\-]+)\), custom_call_target="TopK"', ln)
+        if m and wide & dims(shapes[m.group(2)]):
+            selections.append((shapes[m.group(2)], m.group(1)))
+    assert selections == [(f"f32[{B * K},{V}]", f"f32[{B * K},{min(K + 1, V)}]")], selections
+
+    per_beam = [f"[{B},{K},{V}]"] + ([f"[{B},{K * V}]"] if K > 1 else [])  # K = 1: [B, K*V] IS the rows
+    held = sorted({shape for ln in lines for shape in per_beam if shape in ln.split(", metadata=")[0]})
+    assert not held, held
+
+
+@pytest.mark.parametrize(
+    "program,K", [("search", 3), ("search", 1), ("pool", Config().beam_size)],
+    ids=["beam3-b512", "greedy-b512", "pool"],
+)
+def test_beam_programs_select_per_row_without_a_vocabulary_sort(monkeypatch, program, K):
+    """The beam step's one selection over the vocabulary reaches the chip's
+    ``TopK`` call in the rows the logits arrive in; no ``sort`` is left
+    with a vocabulary-sized operand (the per-beam threshold used to be
+    one: a stable sort of f32[512,3,5000] on every step, 28% of the eval
+    cell's device time) and nothing is laid out per beam x vocabulary.
+    The eval cell's program, greedy at its batch, and the serve pool's."""
+    from sat_tpu.ops.beam_search import beam_search_jit
+
+    config = Config()
+    V = config.vocabulary_size
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if program == "pool":
+        B = config.serve_slot_pages * config.serve_page_width
+        lowered = _lower_pool_program(config)
+    else:
+        B = 512
+        _, decoder = _decoder_params(config)
+        lowered = beam_search_jit.lower(
+            decoder, config, _sd((B, config.num_ctx, config.dim_ctx)), 3,
+            beam_size=K, valid_size=V,
+        )
+    _assert_the_step_selects_per_row(lowered.compile().as_text(), B, K, V)
 
 
 def test_beam_program_holds_one_grid_per_image(monkeypatch):
@@ -226,11 +263,7 @@ def test_beam_program_holds_one_grid_per_image(monkeypatch):
     for shape in (f"[1536,{N},", f"[1536,{N + 4},", f"[512,3,{N},"):
         assert shape not in text, shape
 
-    computations = _computations(text)
-    bodies = re.findall(r" while\([^\n]*body=%([\w.\-]+)", text)
-    assert bodies
-    in_loop = set().union(*(_reachable(computations, b) for b in bodies))
-    lines = [ln for name in in_loop for ln in computations[name].splitlines()]
+    lines = _loop_lines(text)
     assert sum("tpu_custom_call" in ln for ln in lines) == 1
     pads = [
         re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (f32\[[\d,]+\])", ln).group(1)
@@ -360,9 +393,10 @@ def test_lm_beam_program_compiles_with_the_grouped_kernel_and_no_vocabulary_sort
     """The language-model decoder's beam program at the new cell's widths
     and batch (one period of the stack: the selections and the kernels do
     not depend on depth): accepted by the chip's compiler, the experts
-    through the Pallas grouped product, the vocabulary through ``TopK``
-    and never a sort, and within the chip's memory beside 6.4 GB of
-    weights."""
+    through the Pallas grouped product, the vocabulary through ONE
+    ``TopK`` over the ``f32[768,65536]`` rows and never a sort, no
+    ``f32[256,3,65536]`` or ``f32[256,196608]`` in the loop, and within
+    the chip's memory beside 6.4 GB of weights."""
     from sat_tpu.ops.beam_search import beam_search_jit
 
     config = Config(
@@ -377,66 +411,23 @@ def test_lm_beam_program_compiles_with_the_grouped_kernel_and_no_vocabulary_sort
         beam_size=K, valid_size=V,
     ).compile()
     text = compiled.as_text()
-    sorts = [ln for ln in text.splitlines() if re.search(r"\bsort\(", ln)]
-    wide = [
-        ln.strip()[:160] for ln in sorts
-        if {V, K * V} & {
-            int(d) for dims in re.findall(r"\[([\d,]+)\]", ln) for d in dims.split(",")
-        }
-    ]
-    assert not wide, wide
-    assert text.count('custom_call_target="TopK"') >= 2
+    _assert_the_step_selects_per_row(text, 256, K, V)
     # three grouped products an expert layer, prefill and step
     assert len(re.findall(r"decoder/lm/moe/experts[^\n]*tpu_custom_call|"
                           r"tpu_custom_call[^\n]*decoder/lm/moe/experts", text)) >= 6
     assert compiled.memory_analysis().temp_size_in_bytes < 6 << 30
 
 
-# sha256 of the beam programs' optimized HLO without metadata, compiled for
-# the described v5e from the PARENT of PR 30 (commit 24822bf, before the
-# language-model decoders' shared pieces moved to models/lm_common.py and
-# the search took a decoder's module) with the jax these were read under
-_PARENT_BEAM_PROGRAMS = {
-    "jax": "0.9.0",
-    "lstm": "b7c48655267979285830386bac78e3ad59cfa9649271f5547a1402656bba0bf7",
-    "lfm2": "df0b273fb34614e8293bee0c11ea631f0d5dd9ddedb560cc912a4123a2e83772",
-}
-
-
-@pytest.mark.parametrize("program", ["lstm", "lfm2"])
-def test_a_third_decoder_leaves_the_other_two_beam_programs_as_they_were(monkeypatch, program):
-    """The eval cells' beam programs (the LSTM's at B = 512, lfm2's at
-    B = 256 and one period of its stack) come out of the compiler as they
-    did before ``decoder="deepseek_v3"`` existed, but for metadata: the
-    shared module, the tiles chosen from a product's own shape and the
-    search's ``state_bytes`` changed nothing that runs for them."""
-    import hashlib
-
-    from sat_tpu.ops.beam_search import beam_search_jit
-
-    if jax.__version__ != _PARENT_BEAM_PROGRAMS["jax"]:
-        pytest.skip(f"the parent's programs were read under jax {_PARENT_BEAM_PROGRAMS['jax']}")
-    config, B = (Config(), 512) if program == "lstm" else (Config(
-        decoder="lfm2_moe", vocabulary_size=65536, num_hidden_layers=5, num_dense_layers=1,
-        layer_types=("conv", "full_attention", "conv", "conv", "conv"),
-    ), 256)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    _, decoder = _decoder_params(config)
-    text = beam_search_jit.lower(
-        decoder, config, _sd((B, config.num_ctx, config.dim_ctx)), 3 if program == "lstm" else 1,
-        beam_size=3, valid_size=config.vocabulary_size,
-    ).compile().as_text()
-    assert hashlib.sha256(_strip_metadata(text).encode()).hexdigest() == _PARENT_BEAM_PROGRAMS[program]
-
-
 def test_mla_beam_program_keeps_the_prefix_latent_and_per_image(monkeypatch):
     """``decoder="deepseek_v3"`` at the new cell's batch and the published
     widths (B = 256, K = 3, depth 5, V = 128,256): accepted by the chip's
     compiler beside 6.4 GB of weights; the experts of 768 through the
-    Pallas grouped product, prefill and step; the vocabulary through
-    ``TopK`` and never a sort; and in the loop the prefix is the per-image
-    latent ``bf16[256,196,576]``, never a copy per beam (``[768,196,..]``)
-    nor keys or values per head (``[256,196,32,..]``)."""
+    Pallas grouped product, prefill and step; the vocabulary through ONE
+    ``TopK`` over the ``f32[768,128256]`` rows and never a sort, with no
+    ``f32[256,3,128256]`` or ``f32[256,384768]`` in the loop; and there
+    the prefix is the per-image latent ``bf16[256,196,576]``, never a copy
+    per beam (``[768,196,..]``) nor keys or values per head
+    (``[256,196,32,..]``)."""
     from sat_tpu.ops.beam_search import beam_search_jit
 
     config = Config(
@@ -452,33 +443,47 @@ def test_mla_beam_program_keeps_the_prefix_latent_and_per_image(monkeypatch):
         decoder, config, _sd((256, N, config.dim_ctx)), 1, beam_size=K, valid_size=V,
     ).compile()
     text = compiled.as_text()
-    sorts = [ln for ln in text.splitlines() if re.search(r"\bsort\(", ln)]
-    wide = [
-        ln.strip()[:160] for ln in sorts
-        if {V, K * V} & {
-            int(d) for dims in re.findall(r"\[([\d,]+)\]", ln) for d in dims.split(",")
-        }
-    ]
-    assert not wide, wide
-    assert text.count('custom_call_target="TopK"') >= 2
+    _assert_the_step_selects_per_row(text, 256, K, V)
     # three grouped products an expert layer: four layers' in the steps, and
     # in the prefill three (the last layer's experts feed nothing there)
     assert len(re.findall(r"decoder/lm/moe/experts[^\n]*tpu_custom_call|"
                           r"tpu_custom_call[^\n]*decoder/lm/moe/experts", text)) >= 21
 
-    computations = _computations(text)
-    bodies = re.findall(r" while\([^\n]*body=%([\w.\-]+)", text)
-    assert bodies
-    in_loop = set().union(*(_reachable(computations, b) for b in bodies))
     shapes = {
-        shape for name in in_loop for shape in re.findall(r"(?:bf16|f32)\[[\d,]+\]", computations[name])
+        shape for ln in _loop_lines(text) for shape in re.findall(r"(?:bf16|f32)\[[\d,]+\]", ln)
     }
     assert f"bf16[256,{N},576]" in shapes
     held = [s for s in shapes if re.search(rf"\[(768,{N},|256,{K},{N},\d{{3,}}|256,{N},32,)", s)]
     assert not held, held
-    # read here: 5.27 GB (the prefill's un-grouping f32[50176,6,2048] and
-    # scores f32[256,32,196,196] set it), beside 6.41 GB of arguments
+    # read here: 5.27 GB, beside 6.41 GB of arguments.  The prefill's
+    # un-grouping f32[50176,6,2048] and scores f32[256,32,196,196] set it,
+    # so the step's two f32[256,4,128256] buffers less (PR 31) do not show
+    # here: the step alone is held just below
     assert compiled.memory_analysis().temp_size_in_bytes < int(6.5e9)
+
+
+@pytest.mark.parametrize("V", [65536, 128256], ids=["lfm2", "kanana2"])
+def test_beam_step_alone_needs_no_vocabulary_sized_temporary(V):
+    """``_expand_step`` by itself over the lm cells' 768 rows of logits:
+    what it selects from is its argument, in place.  Through ``[B, K, V]``
+    the step alone took 538 MB of temporaries at V = 65,536 and 1,052 MB
+    at 128,256 (compiled here at the parent of PR 31: the rows written
+    out, then per beam in two tilings); one ``f32[768,V]`` is 201 / 394
+    MB, and none is left."""
+    from sat_tpu.models.decoder import DecoderState
+
+    bs = __import__("sat_tpu.ops.beam_search", fromlist=["x"])
+    B, K, T = 256, 3, 20
+    search = _on_chip(jax.eval_shape(lambda: bs._init_search(B, K, T, 0)))
+    state = DecoderState(*(_sd((B * K, 8)) for _ in range(3)))
+
+    def step(state, logits, alpha, t_vec, s):
+        return bs._expand_step(1, K, V, 0, V, state, logits, alpha, t_vec, s)
+
+    compiled = jax.jit(step).lower(
+        state, _sd((B * K, V)), _sd((B * K, 1)), _sd((B,), jnp.int32), search
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 # ---------------------------------------------------------------------------

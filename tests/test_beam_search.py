@@ -4,6 +4,7 @@ re-implemented as a correctness baseline), and the no-completion fallback."""
 
 import heapq
 import sys
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -465,9 +466,9 @@ def test_kth_threshold_is_bitwise_the_sliced_top_k(case, K):
     B, _, V = step_logp.shape
     k = min(K + 1, V)
     want = jax.lax.top_k(step_logp, k)[0][..., -1]
-    got = jax.jit(_bs._kth_largest, static_argnums=1)(
+    got = jax.jit(_bs._top_rows, static_argnums=1)(
         step_logp.reshape(B * K, V), k
-    ).reshape(B, K)
+    )[0].reshape(B, K)
     assert got.dtype == want.dtype == jnp.float32
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     # and what the step makes of it, for an eos in any column
@@ -480,7 +481,9 @@ def test_kth_threshold_is_bitwise_the_sliced_top_k(case, K):
 def _parent_expand_step(eos_id, K, V, An, valid_size, new_state, logits,
                         alpha, t_vec, s):
     """``_expand_step`` as it stood before the threshold changed (commit
-    5072338), scopes dropped: the oracle of the whole-search case."""
+    5072338), scopes dropped: one ``top_k`` over ``[B, K*V]`` and a sliced
+    rank-3 ``top_k`` for the threshold.  The oracle of the whole-search
+    cases and of the step-level ones below."""
     from sat_tpu.models.decoder import DecoderState
 
     NEG_INF = _bs.NEG_INF
@@ -577,12 +580,195 @@ def test_whole_search_equals_the_parents_expand_step(
             np.asarray(getattr(got, name)), np.asarray(getattr(want, name)),
             err_msg=name,
         )
+    top_rows = _bs._top_rows
     for off in (-1, 1):
         with monkeypatch.context() as m:
+            # the threshold alone: the candidates stay the K+1 they were
             m.setattr(
-                _bs, "_kth_largest",
-                lambda rows, k, off=off: jax.lax.top_k(
-                    rows, max(1, min(k + off, rows.shape[-1]))
-                )[0].min(axis=-1),
+                _bs, "_top_rows",
+                lambda rows, k, off=off: (
+                    top_rows(rows, max(1, min(k + off, rows.shape[-1])))[0],
+                    *top_rows(rows, k)[1:],
+                ),
             )
             assert not same(search(), want), f"rank {off:+d} went unnoticed"
+
+
+# ---------------------------------------------------------------------------
+# The step selects per row: one top-(K+1) over the [B*K, V] rows, then a
+# merge of K x (K+1) candidates.  Pinned bit for bit, every output of the
+# step, against the parent's one ``top_k`` over [B, K*V]
+# ---------------------------------------------------------------------------
+
+
+class _StepInputs(NamedTuple):
+    eos_id: int
+    V: int
+    valid: Optional[int]
+    new_state: tuple
+    logits: jnp.ndarray   # [B*K, V]
+    alpha: jnp.ndarray
+    t_vec: jnp.ndarray
+    s: tuple              # SearchState
+
+
+def _step_inputs(case, K, eos_at, rng):
+    """One step over B = 4 images mid-search: every beam's words, lengths
+    and state rows differ, so a wrong parent shows in each gathered
+    output."""
+    from sat_tpu.models.decoder import DecoderState
+
+    B, T, H, An = 4, 5, 3, 2
+    V = {"vocab_k_plus_1": K + 1, "vocab_k": K}.get(case, 37)
+    eos_id = {"first": 0, "mid": V // 2, "last": V - 1}[eos_at]
+    valid = None
+    if case == "ties":
+        # a handful of distinct values: exact ties within a row at and
+        # around rank K+1, eos among them; beams 0 and 1 of an image see
+        # the same row from the same score, so they tie across beams too
+        logits = rng.integers(0, 3, size=(B, K, V)).astype(np.float32)
+        if K > 1:
+            logits[:, 1] = logits[:, 0]
+        logits[0] = 1.0  # an image of one value throughout
+    else:
+        logits = rng.normal(size=(B, K, V)).astype(np.float32)
+        # eos inside the top K+1 of some rows and the best word of others
+        logits[1, :, eos_id] += 2.0
+        logits[2, 0, eos_id] += 9.0
+    if case == "masked_tail":
+        valid = V - 9           # eos "last" then lies in the masked tail
+        logits[3, :, K:] = _bs.NEG_INF  # fewer real words than K+1
+
+    s = _bs._init_search(B, K, T, An)
+    t = 0
+    if case != "step0":
+        t = 2
+        live = -rng.uniform(1.0, 9.0, size=(B, K)).astype(np.float32)
+        if case == "ties" and K > 1:
+            live[:, 1] = live[:, 0]
+        fin = np.where(
+            rng.random((B, K)) < 0.5, -rng.uniform(1.0, 20.0, size=(B, K)),
+            _bs.NEG_INF,
+        ).astype(np.float32)
+        s = s._replace(
+            live_logp=jnp.asarray(live),
+            live_words=jnp.asarray(
+                rng.integers(0, V, size=(B, K, T)), jnp.int32
+            ),
+            live_len=jnp.asarray(rng.integers(1, 3, size=(B, K)), jnp.int32),
+            fin_logp=jnp.asarray(-np.sort(-fin, axis=1)),
+            fin_words=jnp.asarray(
+                rng.integers(0, V, size=(B, K, T)), jnp.int32
+            ),
+            live_alphas=jnp.asarray(
+                rng.random((B, K, T, An)), jnp.float32
+            ),
+        )
+    new_state = DecoderState(*(
+        jnp.asarray(rng.normal(size=(B * K, H)), jnp.float32) for _ in range(3)
+    ))
+    alpha = jnp.asarray(rng.random((B * K, An + 1)), jnp.float32)
+    return _StepInputs(
+        eos_id, V, valid, new_state,
+        jnp.asarray(logits.reshape(B * K, V)), alpha,
+        jnp.full((B,), t, jnp.int32), s,
+    )
+
+
+def _run_step(step, K, i):
+    An = i.s.live_alphas.shape[3]
+    return jax.jit(
+        lambda ns, lg, al, tv, ss: step(
+            i.eos_id, K, i.V, An, i.valid, ns, lg, al, tv, ss
+        )
+    )(i.new_state, i.logits, i.alpha, i.t_vec, i.s)
+
+
+def _assert_same_step(got, want):
+    g, w = (jax.tree_util.tree_leaves_with_path(x) for x in (got, want))
+    assert len(g) == len(w)
+    for (path, a), (_, b) in zip(g, w):
+        assert a.dtype == b.dtype, path
+        np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b), err_msg=jax.tree_util.keystr(path)
+        )
+
+
+@pytest.mark.parametrize("eos_at", ["first", "mid", "last"])
+@pytest.mark.parametrize("K", [1, 3, 5])
+@pytest.mark.parametrize(
+    "case",
+    ["random", "ties", "step0", "masked_tail", "vocab_k_plus_1", "vocab_k"],
+)
+def test_step_selects_per_row_what_one_top_k_over_beams_by_vocabulary_did(
+    case, K, eos_at
+):
+    """Every output of one step (the reordered state; live scores, words,
+    lengths, maps and the next input word, which carry ``top_live``,
+    ``parent`` and ``word``; the finished set, which carries
+    ``eos_scores``) bit for bit the oracle's, dtypes included."""
+    inputs = _step_inputs(case, K, eos_at, np.random.default_rng(7 * K + 1))
+    _assert_same_step(
+        _run_step(_bs._expand_step, K, inputs),
+        _run_step(_parent_expand_step, K, inputs),
+    )
+
+
+@pytest.mark.parametrize(
+    "mutant,first_seen_in",
+    # the first leaf to differ: a wrong parent, a wrong word of beam 0
+    [("k_candidates_a_row", "memory"), ("raw_logits", "live_words")],
+)
+def test_step_parity_notices_a_narrower_or_a_raw_selection(
+    monkeypatch, mutant, first_seen_in
+):
+    """The two shortcuts the step must not take, each made here and seen
+    by the comparison above.  K candidates a row lose a beam's K-th word
+    where eos is among its top K.  A selection on the raw logits orders
+    two words by logits that round to ONE log-probability, where the
+    search's order is the lower index first."""
+    B = 4
+    top_rows = _bs._top_rows
+    rng = np.random.default_rng(0)
+    if mutant == "k_candidates_a_row":
+        K = 3
+        inputs = _step_inputs("random", K, "mid", rng)
+        # eos is every row's best word, and one beam an image so far
+        # ahead that all K continuations are its own
+        logits = np.asarray(inputs.logits).copy()
+        logits[:, inputs.eos_id] += 9.0
+        live = np.asarray(inputs.s.live_logp).copy()
+        live[:, 1:] -= 50.0
+        inputs = inputs._replace(
+            logits=jnp.asarray(logits),
+            s=inputs.s._replace(live_logp=jnp.asarray(live)),
+        )
+
+        def patched(rows, k):
+            # the threshold as it is; the (K+1)-th candidate void
+            kth, vals, idx = top_rows(rows, k)
+            return kth, vals.at[:, K:].set(_bs.NEG_INF), idx
+    else:
+        K = 1
+        inputs = _step_inputs("random", K, "first", rng)
+        # words 3 and 5 lead every row by ten nats, their logits one ulp
+        # apart with the higher at the higher index; a log-sum-exp of
+        # log 2 rounds both to one log-probability
+        logits = np.asarray(inputs.logits).copy() - 10.0
+        logits[:, 3] = np.float32(1e-3)
+        logits[:, 5] = np.nextafter(np.float32(1e-3), np.float32(1.0))
+        raw = jnp.asarray(logits)
+        logp = np.asarray(jax.nn.log_softmax(raw, axis=-1))
+        assert (logp[:, 3] == logp[:, 5]).all(), "the ulp survived: resize it"
+        inputs = inputs._replace(logits=raw)
+
+        def patched(rows, k):
+            kth = top_rows(rows, k)[0]
+            _, idx = jax.lax.top_k(raw, k)
+            return kth, jnp.take_along_axis(rows, idx, axis=-1), idx
+
+    want = _run_step(_parent_expand_step, K, inputs)
+    _assert_same_step(_run_step(_bs._expand_step, K, inputs), want)
+    monkeypatch.setattr(_bs, "_top_rows", patched)
+    with pytest.raises(AssertionError, match=first_seen_in):
+        _assert_same_step(_run_step(_bs._expand_step, K, inputs), want)
