@@ -48,6 +48,10 @@ class Config:
     # (models/deepseek_v3.py; kakaocorp/kanana-2-30b-a3b-instruct-2601);
     # the fields the two stacks share keep one name, num_dense_layers is
     # that source's first_k_dense_replace, num_experts its n_routed_experts.
+    # "glm_moe_dsa": that block with the query compressed (q_lora_rank) and
+    # learned sparse attention on top: an indexer scores every earlier
+    # position and the index_topk best are attended
+    # (models/glm_moe_dsa.py; zai-org/GLM-5.2).
     decoder: str = "lstm"
     hidden_size: int = 2048
     intermediate_size: int = 7168          # dense SwiGLU of the leading layers
@@ -83,7 +87,22 @@ class Config:
     # shared experts beside the routed ones: ONE SwiGLU of
     # n_shared_experts x moe_intermediate_size every token goes through
     n_shared_experts: int = 2
-    # lfm2_moe's head IS its embedding; deepseek_v3 reads this
+    # learned sparse attention (glm_moe_dsa only), named as in the source:
+    # the query goes through a q_lora_rank-wide normed bottleneck; an
+    # indexer of index_n_heads x index_head_dim scores the positions and
+    # the index_topk best are attended; per layer "full" (it computes a
+    # selection) or "shared" (it reuses the last one computed)
+    q_lora_rank: int = 2048
+    index_n_heads: int = 32
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    indexer_types: Tuple[str, ...] = ()
+    # the share of an expert layer this chip holds (expert parallelism):
+    # experts [first_expert, first_expert + experts_held) of num_experts;
+    # the router still scores all num_experts.  0 = all of them
+    experts_held: int = 0
+    first_expert: int = 0
+    # lfm2_moe's head IS its embedding; the others read this
     tie_word_embeddings: bool = True
     # train_cnn's twin for the language-model stack: frozen by default,
     # so the connector alone trains and Adam holds slots for it alone
@@ -555,7 +574,7 @@ class Config:
         same, /root/reference/model.py:16-21)."""
         checks = (
             ("cnn", ("vgg16", "resnet50")),
-            ("decoder", ("lstm", "lfm2_moe", "deepseek_v3")),
+            ("decoder", ("lstm", "lfm2_moe", "deepseek_v3", "glm_moe_dsa")),
             ("phase", ("train", "eval", "test", "serve", "route", "bulk")),
             ("optimizer", ("Adam", "RMSProp", "Momentum", "SGD")),
             ("num_initialize_layers", (1, 2)),
@@ -848,6 +867,29 @@ class Config:
                 "Config: qk_rope_head_dim must be even (rotary pairs) and "
                 "n_shared_experts not negative"
             )
+        if self.decoder == "glm_moe_dsa" and (
+            len(self.indexer_types) != self.num_hidden_layers
+            or any(k not in ("full", "shared") for k in self.indexer_types)
+            or self.indexer_types[0] != "full"
+            or self.index_head_dim < self.qk_rope_head_dim
+            or min(self.q_lora_rank, self.index_n_heads, self.index_topk) < 1
+        ):
+            raise ValueError(
+                f"Config.indexer_types: {self.num_hidden_layers} entries "
+                '(num_hidden_layers), each "full" or "shared", the first '
+                f'"full"; got {self.indexer_types!r}; q_lora_rank, '
+                "index_n_heads and index_topk at least 1, index_head_dim "
+                "no less than qk_rope_head_dim (the rotary part)"
+            )
+        if self.experts_held < 0 or self.experts_held and not (
+            0 <= self.first_expert
+            and self.first_expert + self.experts_held <= self.num_experts
+        ):
+            raise ValueError(
+                f"Config.experts_held={self.experts_held} from first_expert="
+                f"{self.first_expert}: must lie within num_experts="
+                f"{self.num_experts}"
+            )
         if not 0 <= self.num_dense_layers <= self.num_hidden_layers:
             raise ValueError(
                 f"Config.num_dense_layers={self.num_dense_layers}: must be "
@@ -907,7 +949,7 @@ class Config:
         # Config rides jit static_argnames — a list field breaks lower())
         for key in (
             "mesh_shape", "mesh_axes", "serve_buckets", "serve_decode_depth",
-            "layer_types",
+            "layer_types", "indexer_types",
         ):
             if key in kw and isinstance(kw[key], list):
                 kw[key] = tuple(kw[key])

@@ -19,7 +19,8 @@ exist (``Config.decoder``) and what each gives the rest of the program.
 ==================  =====================================================
 
 The language-model decoders (``lfm2_moe``: convs and grouped-query
-attention; ``deepseek_v3``: latent attention) are a module each with one
+attention; ``deepseek_v3``: latent attention; ``glm_moe_dsa``: latent
+attention over positions an indexer chooses) are a module each with one
 set of entry points, and share one search (``_lm_search``): what differs
 between them is the KIND of leaf their caches hold, which the search never
 looks at.
@@ -35,7 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import Config
-from . import deepseek_v3, lfm2
+from . import deepseek_v3, glm_moe_dsa, lfm2
 from .decoder import (
     DecoderState,
     decoder_step,
@@ -49,7 +50,7 @@ Params = Dict[str, Any]
 
 # the language-model decoders: a module each, with one set of entry points
 # (init_params, teacher_forced, prefill, start_beams, step)
-_LM = {"lfm2_moe": lfm2, "deepseek_v3": deepseek_v3}
+_LM = {"lfm2_moe": lfm2, "deepseek_v3": deepseek_v3, "glm_moe_dsa": glm_moe_dsa}
 
 
 class StepState(NamedTuple):
@@ -144,7 +145,7 @@ def search(
         return _lm_search(
             _LM[config.decoder], params, config, contexts, K, T,
             # the latent cache's reason to be is its size: it reports it
-            report_state=config.decoder == "deepseek_v3",
+            report_state=config.decoder in ("deepseek_v3", "glm_moe_dsa"),
         )
 
     # the grid and the hoisted context half of the attention MLP stay per
@@ -209,6 +210,8 @@ def _lm_search(
             # bytes of the search's state a batch, from the shapes: what
             # the steps close over per image + the per-beam tree
             stats["state_bytes"] = jnp.float32(_tree_bytes(prefix) + _tree_bytes(state.beam))
+        if hasattr(lm, "report"):       # what the stack itself counts besides
+            stats.update(lm.report(config, state, B, K, T))
         return result._replace(decoder_stats=stats)
 
     return Search(step_fn, state0, 0, finish)

@@ -1,0 +1,577 @@
+"""GLM-5.2's block (``model_type: "glm_moe_dsa"``) as the caption decoder —
+pure-functional JAX.
+
+It is ``models/deepseek_v3.py``'s block (latent attention, small experts
+beside a shared one, an untied head; the image as the first N positions of
+one causal sequence) with three things of its own, and this module holds
+only those; the latent, the rope, ``W_kvb``'s two halves and the head are
+imported from there, the expert layer from ``models/lm_common.py``.
+
+* a compressed query: ``qr = q_a_layernorm(u W_qa)`` (``q_lora_rank``
+  wide), ``q = qr W_qb``, per head ``q_nope`` and ``q_rope`` (rotated);
+  ``v_head_dim`` need not equal ``qk_nope_head_dim``;
+* learned sparse attention (DeepSeek-V3.2's, "DSA").  A small INDEXER
+  scores every visible position for every query,
+
+      qI = qr W_qI          (index_n_heads x index_head_dim)
+      kI = LayerNorm(u W_kI)  (index_head_dim; weight and bias, eps 1e-6)
+      w  = u W_w * index_n_heads^-0.5 * index_head_dim^-0.5
+      I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s]),   s <= t
+
+  the first ``qk_rope_head_dim`` of each indexer head and of ``kI`` turned
+  by the same interleaved rope, and the attention's softmax runs over
+  ``S_t``, the ``min(index_topk, t + 1)`` positions of largest
+  ``I[t, .]``, alone.  ``Config.indexer_types`` says which layers compute a
+  selection (``"full"``) and which reuse the last one computed
+  (``"shared"``: no indexer parameters, IndexShare).  The indexer runs in
+  bfloat16 with float32 accumulation (the source's float8 and its Hadamard
+  rotation of ``qI`` and ``kI``, orthogonal and so without effect on
+  ``qI . kI`` in exact arithmetic, are not taken);
+* an expert layer that holds a share of its experts
+  (``lm_common.moe_ffn_held``) and reports what it held.
+
+Forms.  Whole sequences (``teacher_forced``, ``prefill``) go one image at
+a time (``lax.map``) and one block of ``_QUERY_BLOCK`` queries at a time
+against the keys up to the block's end, in the EXPANDED form: a block's
+scores are ``[heads, block, keys]``, and no ``[.., S, S]`` array per head
+exists.  The selection there is a MASK: ``causal & (I[t, s] >= the
+index_topk-th largest of row t)``, the threshold found exactly by 32
+counting passes over the floats' order-preserving bits (``_kth_largest``;
+no sort); a block whose keys number ``index_topk`` or fewer attends all it
+sees and computes no scores of the indexer.  One token through the cache
+(``step``) takes ONE ``lax.top_k`` a row over the indexer's scores of the
+image's prefix keys and the row's own suffix keys and runs the ABSORBED
+form over the chosen latents alone, the choice a mask over the image's
+prefix (read in place once per image, never tiled, never gathered: its K
+beams choose more positions between them than it has) and over the row's
+suffix: no key or value is expanded in a step.
+
+The cache: per IMAGE the prefix's latents ``[B, N, 576]`` a layer and the
+indexer's keys ``[B, N, 128]`` a ``full`` layer; per BEAM a suffix of each
+(``[B*K, T, ..]``), the record of routes and the record of chosen
+positions, all moved by the search's per-parent reorder.  The chosen
+indices go from a ``full`` layer to the ``shared`` layers after it inside
+one step and are not carried across steps.
+"""
+
+from __future__ import annotations
+
+from typing import Any, NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..config import Config
+from . import lm_common
+from .deepseek_v3 import _head, _kv_b, _latents, _rope
+from .lm_common import HeldPairs, Params, layer_name, mm, rms_norm
+
+_QUERY_BLOCK = 512          # queries a block of a whole sequence's attention
+_INDEX_NORM_EPS = 1e-6      # the indexer key's LayerNorm
+_SUM_EPS = 1e-20            # DeepSeek-V3's router, in the sum of chosen scores
+
+
+class DsaCache(NamedTuple):
+    """Per layer the latents ``[c ; k_rope]``, per ``full`` layer the
+    indexer's keys.  As the prefix's: ``[B, N, ..]``, per image, closed
+    over by the step.  As the beams' own: ``[B*K, T, ..]`` and the two
+    records, every leaf moved by the search's reorder."""
+
+    latents: Tuple[jnp.ndarray, ...]
+    index_keys: Tuple[jnp.ndarray, ...]
+    routes: Any = None      # lm_common.empty_routes; None for the prefix's
+    # [R, T * full layers * k] int32 (step-major): the positions the beam's
+    # own tokens attended, step by step; -1 where fewer than k were visible
+    selected: Any = None
+
+
+class DsaCounters(NamedTuple):
+    """``lm_common.StepCounters`` and what this stack counts besides."""
+
+    t: jnp.ndarray
+    moe_counts: jnp.ndarray
+    step_visits: jnp.ndarray
+    # [2, 3] int32, the prefill then the steps: pairs held here, pairs
+    # routed, pairs over the rows
+    pairs: jnp.ndarray
+    attended: jnp.ndarray   # [2] int32: positions attended, positions visible (steps, full layers)
+
+
+def _full_layers(config: Config):
+    return [i for i, kind in enumerate(config.indexer_types) if kind == "full"]
+
+
+def _chosen_width(config: Config, max_len: int) -> int:
+    """Positions a step's row attends: ``index_topk``, or all there can be."""
+    return min(config.index_topk, config.num_ctx + max_len)
+
+
+# ---------------------------------------------------------------------------
+# parameters
+# ---------------------------------------------------------------------------
+
+
+def init_params(rng: jax.Array, config: Config) -> Params:
+    """{'connector': float32 (it trains), 'lm': the stack, bfloat16 but
+    for ``expert_bias`` (a float32 buffer)}: ``deepseek_v3.init_params``'s
+    draws over this stack's leaves."""
+    c = config
+    H, nh, rank = c.hidden_size, c.num_attention_heads, c.kv_lora_rank
+    bf16 = jnp.bfloat16
+    keys = iter(jax.random.split(rng, 20 * c.num_hidden_layers + 4))
+
+    def linear(*shape):
+        return (0.02 * jax.random.normal(next(keys), shape, jnp.float32)).astype(bf16)
+
+    ones = lambda n: jnp.ones((n,), bf16)  # noqa: E731
+    layers: Params = {}
+    for i in range(c.num_hidden_layers):
+        p: Params = {"operator_norm": ones(H), "ffn_norm": ones(H)}
+        p["self_attn"] = {
+            "q_a_proj": linear(H, c.q_lora_rank),
+            "q_a_layernorm": ones(c.q_lora_rank),
+            "q_b_proj": linear(c.q_lora_rank, nh * (c.qk_nope_head_dim + c.qk_rope_head_dim)),
+            "kv_a_proj": linear(H, rank + c.qk_rope_head_dim),
+            "kv_a_layernorm": ones(rank),
+            "kv_b_proj": linear(rank, nh * (c.qk_nope_head_dim + c.v_head_dim)),
+            "o_proj": linear(nh * c.v_head_dim, H),
+        }
+        if c.indexer_types[i] == "full":
+            p["self_attn"]["indexer"] = {
+                "wq_b": linear(c.q_lora_rank, c.index_n_heads * c.index_head_dim),
+                "wk": linear(H, c.index_head_dim),
+                "k_norm_weight": ones(c.index_head_dim),
+                "k_norm_bias": jnp.zeros((c.index_head_dim,), bf16),
+                "weights_proj": linear(H, c.index_n_heads),
+            }
+        p["feed_forward"] = lm_common.ffn_params(c, i, linear)
+        if lm_common.is_moe(c, i) and c.n_shared_experts:
+            I = c.n_shared_experts * c.moe_intermediate_size
+            p["feed_forward"]["shared"] = {
+                "w1": linear(H, I), "w3": linear(H, I), "w2": linear(I, H),
+            }
+        layers[layer_name(i)] = p
+    lm: Params = {
+        "embed_tokens": linear(c.vocabulary_size, H),
+        "norm": ones(H),
+        "layers": layers,
+    }
+    if not c.tie_word_embeddings:
+        lm["lm_head"] = linear(H, c.vocabulary_size)
+    return {"connector": lm_common.connector_params(next(keys), c), "lm": lm}
+
+
+# ---------------------------------------------------------------------------
+# the compressed query and the indexer
+# ---------------------------------------------------------------------------
+
+
+def _queries(m: Params, config: Config, h: jnp.ndarray, positions: jnp.ndarray):
+    """h [..., S, H] normed -> (qr [..., S, q_lora_rank], the normed
+    bottleneck the indexer reads too; q [..., S, nh, nope + rope], its rope
+    part rotated), bfloat16."""
+    c = config
+    with jax.named_scope("decoder/lm/attn/q"):
+        qr = rms_norm(mm(h, m["q_a_proj"]), m["q_a_layernorm"], c.norm_eps).astype(jnp.bfloat16)
+        q = mm(qr, m["q_b_proj"]).reshape(
+            h.shape[:-1] + (c.num_attention_heads, c.qk_nope_head_dim + c.qk_rope_head_dim)
+        )
+        q_rope = _rope(q[..., c.qk_nope_head_dim:].astype(jnp.float32), positions, c.rope_theta)
+        return qr, jnp.concatenate(
+            [q[..., : c.qk_nope_head_dim], q_rope.astype(jnp.bfloat16)], axis=-1
+        )
+
+
+def _index_rope(x: jnp.ndarray, positions: jnp.ndarray, config: Config) -> jnp.ndarray:
+    """x [..., S, heads, index_head_dim] float32: its first
+    ``qk_rope_head_dim`` numbers turned as the attention's rotary key is."""
+    r = config.qk_rope_head_dim
+    return jnp.concatenate(
+        [_rope(x[..., :r], positions, config.rope_theta), x[..., r:]], axis=-1
+    )
+
+
+def _index_maps(ix: Params, config: Config, h: jnp.ndarray, qr: jnp.ndarray, positions):
+    """h [..., S, H] normed, qr its query bottleneck -> (qI [..., S, nI, dI]
+    bfloat16, kI [..., S, dI] bfloat16, w [..., S, nI] float32)."""
+    c = config
+    nI, dI = c.index_n_heads, c.index_head_dim
+    with jax.named_scope("decoder/lm/attn/index"):
+        qI = mm(qr, ix["wq_b"]).reshape(h.shape[:-1] + (nI, dI)).astype(jnp.float32)
+        qI = _index_rope(qI, positions, c).astype(jnp.bfloat16)
+        k = mm(h, ix["wk"]).astype(jnp.float32)
+        k = k - jnp.mean(k, axis=-1, keepdims=True)
+        k = k * jax.lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True) + _INDEX_NORM_EPS)
+        k = k * ix["k_norm_weight"].astype(jnp.float32) + ix["k_norm_bias"].astype(jnp.float32)
+        kI = _index_rope(k[..., None, :], positions, c)[..., 0, :].astype(jnp.bfloat16)
+        w = jnp.dot(
+            h.astype(jnp.bfloat16), ix["weights_proj"], preferred_element_type=jnp.float32
+        ) * (nI ** -0.5 * dI ** -0.5)
+        return qI, kI, w
+
+
+def _index_scores(qI: jnp.ndarray, kI: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """qI [..., S, nI, dI], kI [..., L, dI], w [..., S, nI] -> I [..., S, L]
+    float32: ``sum_j w[s, j] * relu(qI[s, j] . kI[l])``."""
+    with jax.named_scope("decoder/lm/attn/index"):
+        dots = jnp.einsum("...sjd,...ld->...jsl", qI, kI, preferred_element_type=jnp.float32)
+        return jnp.sum(jax.nn.relu(dots) * jnp.swapaxes(w, -1, -2)[..., None], axis=-3)
+
+
+def _ordered_bits(x: jnp.ndarray) -> jnp.ndarray:
+    """float32 (no NaN) -> uint32 whose unsigned order is the floats'."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def _kth_largest(u: jnp.ndarray, k: int) -> jnp.ndarray:
+    """u [..., L] uint32 -> [...] the k-th largest of each row, exactly:
+    the largest threshold that k of the row reach, found bit by bit from
+    the top in 32 counting passes (no sort; a row with fewer than k above
+    its least value gets that value)."""
+
+    def bit(i, found):
+        trial = found | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        reach = jnp.sum(u >= trial[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(reach >= k, trial, found)
+
+    return jax.lax.fori_loop(0, 32, bit, jnp.zeros(u.shape[:-1], jnp.uint32))
+
+
+def _select_mask(scores: jnp.ndarray, causal: jnp.ndarray, k: int) -> jnp.ndarray:
+    """scores [S, L] float32, causal [S, L] -> the positions each query
+    attends [S, L]: the visible ones whose score reaches the k-th largest
+    visible score of its row (all of them where fewer than k are visible)."""
+    with jax.named_scope("decoder/lm/attn/select"):
+        u = _ordered_bits(jnp.where(causal, jax.lax.stop_gradient(scores), -jnp.inf))
+        return causal & (u >= _kth_largest(u, k)[..., None])
+
+
+# ---------------------------------------------------------------------------
+# whole sequences, one image at a time: the expanded form in blocks
+# ---------------------------------------------------------------------------
+
+
+def _blocks(S: int):
+    return [(a, min(a + _QUERY_BLOCK, S)) for a in range(0, S, _QUERY_BLOCK)]
+
+
+def attend_sequence(m: Params, config: Config, h: jnp.ndarray, masks):
+    """h [S, H] normed, ONE sequence at positions 0..S-1 -> (the
+    attention's output [S, H], the latents [S, rank + rope], the indexer's
+    keys [S, dI] or None, the blocks' masks).  ``masks``: None in a layer
+    with an indexer (it makes them), else those of the last such layer:
+    one [block, keys up to the block's end] a block of queries."""
+    c = config
+    S, _ = h.shape
+    rank, nope, nh = c.kv_lora_rank, c.qk_nope_head_dim, c.num_attention_heads
+    positions = jnp.arange(S)
+    qr, q = _queries(m, c, h, positions)
+    latents = _latents(m, c, h, positions)
+    with jax.named_scope("decoder/lm/attn/expand"):
+        kv = jnp.einsum(
+            "sc,chd->shd", latents[..., :rank], _kv_b(m, c), preferred_element_type=jnp.float32
+        ).astype(jnp.bfloat16)
+        k_rope = jnp.broadcast_to(latents[:, None, rank:], (S, nh, c.qk_rope_head_dim))
+        keys, values = jnp.concatenate([kv[..., :nope], k_rope], axis=-1), kv[..., nope:]
+    index_keys = None
+    if masks is None:
+        qI, index_keys, w = _index_maps(m["indexer"], c, h, qr, positions)
+        masks = []
+        for a, b in _blocks(S):
+            causal = positions[a:b, None] >= positions[None, :b]
+            if b <= c.index_topk:           # every visible position is among the best
+                masks.append(causal)
+            else:
+                scores = _index_scores(qI[a:b], index_keys[:b], w[a:b])
+                masks.append(_select_mask(scores, causal, c.index_topk))
+    scale = (nope + c.qk_rope_head_dim) ** -0.5
+    ctx = []
+    with jax.named_scope("decoder/lm/attn/scores"):
+        for (a, b), mask in zip(_blocks(S), masks):
+            scores = jnp.einsum(
+                "shd,thd->hst", q[a:b], keys[:b], preferred_element_type=jnp.float32
+            )
+            scores = jnp.where(mask[None], scores * scale, -jnp.inf)
+            # the softmax's division after the weighted sum, as deepseek_v3's
+            weights = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
+            block = jnp.einsum(
+                "hst,thd->shd", weights.astype(jnp.bfloat16), values[:b],
+                preferred_element_type=jnp.float32,
+            ) / jnp.sum(weights, axis=-1).T[..., None]
+            ctx.append(block.astype(jnp.bfloat16))
+    with jax.named_scope("decoder/lm/attn/out"):
+        out = mm(jnp.concatenate(ctx, axis=0).reshape(S, -1), m["o_proj"])
+    return out, latents, index_keys, masks
+
+
+def _ffn(p: Params, config: Config, layer: int, x: jnp.ndarray):
+    """x [T, H] -> (y, tokens per expert [E], experts chosen [T, k],
+    ``HeldPairs``), the last three None in a dense layer."""
+    c = config
+    if not lm_common.is_moe(c, layer):
+        return lm_common.dense_ffn(p, c, x), None, None, None
+    if lm_common.held_experts(c) < c.num_experts:
+        return lm_common.moe_ffn_held(p, c, x, _SUM_EPS)
+    y, sizes, experts = lm_common.moe_ffn(p, c, x, _SUM_EPS)
+    pairs = jnp.int32(experts.size)
+    return y, sizes, experts, HeldPairs(
+        held=pairs, routed=pairs, over=jnp.int32(0),
+        visited=jnp.sum(sizes > 0, dtype=jnp.int32),
+    )
+
+
+def _sum_pairs(held) -> jnp.ndarray:
+    """[3] int32 of a list of ``HeldPairs``: held, routed, over."""
+    if not held:
+        return jnp.zeros((3,), jnp.int32)
+    return jnp.stack([sum(h.held for h in held), sum(h.routed for h in held),
+                      sum(h.over for h in held)]).astype(jnp.int32)
+
+
+def _one_sequence(lm: Params, config: Config, x: jnp.ndarray, tail: int):
+    """x [S, H] -> (hidden of the last ``tail`` positions, latents per
+    layer, indexer keys per full layer, tokens per expert [moe layers, E],
+    experts chosen [S, moe layers * k], pairs [3])."""
+    c = config
+    S = x.shape[0]
+    latents, index_keys, counts, routes, held = [], [], [], [], []
+    masks = None
+    for i in range(c.num_hidden_layers):
+        p = lm["layers"][layer_name(i)]
+        h = rms_norm(x, p["operator_norm"], c.norm_eps)
+        full = c.indexer_types[i] == "full"
+        y, kept, keys, masks = attend_sequence(p["self_attn"], c, h, None if full else masks)
+        x = x + y
+        latents.append(kept)
+        if full:
+            index_keys.append(keys)
+        x, sizes, experts, pairs = _ffn(p, c, i, x)
+        if sizes is not None:
+            counts.append(sizes), routes.append(experts), held.append(pairs)
+    return (
+        x[S - tail:], tuple(latents), tuple(index_keys), lm_common.stack_counts(counts),
+        lm_common.join_routes(routes, (S,)), _sum_pairs(held),
+    )
+
+
+def sequence_forward(lm: Params, config: Config, x: jnp.ndarray, tail: int = 0):
+    """x [B, S, H] bfloat16 -> ``_one_sequence``'s results, image by image:
+    (hidden [B, tail, H], the sequences' state (a ``DsaCache`` of
+    ``[B, S, ..]`` leaves), tokens per expert [moe layers, E], experts
+    chosen [B, S, moe layers * k], pairs [3])."""
+    hidden, latents, index_keys, counts, routes, pairs = jax.lax.map(
+        lambda one: _one_sequence(lm, config, one, tail), x
+    )
+    return (
+        hidden, DsaCache(latents, index_keys), jnp.sum(counts, axis=0), routes,
+        jnp.sum(pairs, axis=0),
+    )
+
+
+def teacher_forced(
+    params: Params, config: Config, contexts: jnp.ndarray, sentences: jnp.ndarray,
+) -> jnp.ndarray:
+    """logits [B, T, V]: the input at caption step t is sentences[:, t-1]
+    (``<start>`` = 0 at t = 0), after the N prefix positions."""
+    lm = params["lm"]
+    x = lm_common.sequence_inputs(params, contexts, sentences)
+    hidden = sequence_forward(lm, config, x, tail=sentences.shape[1])[0]
+    return _head(lm, config, hidden)
+
+
+def prefill(params: Params, config: Config, contexts: jnp.ndarray):
+    """The N prefix positions of each image, once: (the prefix's latents
+    and indexer keys, per image; (tokens per expert, pairs) for
+    ``init_counters``; the experts every position chose [B, N, moe layers * k])."""
+    _, state, counts, routes, pairs = sequence_forward(
+        params["lm"], config, lm_common.prefix(params, contexts)
+    )
+    return state, (counts, pairs), routes
+
+
+# ---------------------------------------------------------------------------
+# one token through the cache: indexer, top-k, the absorbed form under its mask
+# ---------------------------------------------------------------------------
+
+
+def init_counters(prefill_counts, max_len: int) -> DsaCounters:
+    """Step 0's counters, the prefill's counts already in."""
+    counts, pairs = prefill_counts
+    base = lm_common.init_counters(counts, max_len)
+    return DsaCounters(
+        *base, pairs=jnp.stack([pairs, jnp.zeros_like(pairs)]),
+        attended=jnp.zeros((2,), jnp.int32),
+    )
+
+
+def start_beams(config: Config, prefix: DsaCache, K: int, max_len: int, tile) -> DsaCache:
+    """The per-beam cache of the K beams of each image before the first
+    step: empty suffixes of ``max_len`` latents a layer and indexer keys a
+    ``full`` layer, an empty record of routes and of chosen positions.
+    Nothing of the prefix is per beam."""
+    c = config
+    rows = prefix.latents[0].shape[0] * K
+    width = c.kv_lora_rank + c.qk_rope_head_dim
+    zeros = lambda w: jnp.zeros((rows, max_len, w), jnp.bfloat16)  # noqa: E731
+    full = len(_full_layers(c))
+    return DsaCache(
+        latents=tuple(zeros(width) for _ in range(c.num_hidden_layers)),
+        index_keys=tuple(zeros(c.index_head_dim) for _ in range(full)),
+        routes=lm_common.empty_routes(c, rows, max_len),
+        selected=jnp.zeros((rows, max_len * full * _chosen_width(c, max_len)), jnp.int32),
+    )
+
+
+def _choose(scores: jnp.ndarray, k: int):
+    """scores [R, L] float32, -inf where not visible -> (positions [R, k]
+    int32, which of them are visible [R, k], the same choice as a mask
+    [R, L]): ONE top-k a row.  The mask holds the visible positions whose
+    score reaches the top-k's least value: the k positions themselves,
+    unless two float32 scores tie exactly there."""
+    with jax.named_scope("decoder/lm/attn/select"):
+        values, positions = jax.lax.top_k(scores, k)
+        attend = (scores >= values[:, -1:]) & (scores > -jnp.inf)
+        return positions.astype(jnp.int32), values > -jnp.inf, attend
+
+
+def attend_step(
+    m: Params, config: Config, h: jnp.ndarray, prefix, suffix, t: jnp.ndarray,
+    chosen: Optional[tuple],
+):
+    """One token a row through the cache.  h [R, H] normed, at position
+    N + t.  prefix: (latents [B, N, W], indexer keys [B, N, dI] or None),
+    per IMAGE, read in place by its K = R // B rows; suffix: the same per
+    row over T positions, written at t here.  ``chosen``: None in a layer
+    with an indexer, else the last such layer's ``_choose``.  Returns (the
+    attention's output [R, H], the suffix, chosen).  The absorbed form over
+    the latents themselves, one softmax across the chosen prefix and
+    suffix positions; the choice enters as a mask over the image's prefix
+    (its K beams choose K x index_topk > N positions between them: reading
+    the prefix once per image moves fewer bytes than gathering each row's
+    own, and on the chip a gather of 49,152 rows ran at a sixteenth of the
+    memory's rate: PERF.md section 6)."""
+    c = config
+    R = h.shape[0]
+    pre_lat, pre_keys = prefix
+    suf_lat, suf_keys = suffix
+    B, N, _ = pre_lat.shape
+    K, T = R // B, suf_lat.shape[1]
+    nh, rank, nope = c.num_attention_heads, c.kv_lora_rank, c.qk_nope_head_dim
+    position = (N + t)[None]
+    qr, q = _queries(m, c, h[:, None], position)
+    suf_lat = jax.lax.dynamic_update_slice(
+        suf_lat, _latents(m, c, h[:, None], position), (0, t, 0)
+    )
+    if chosen is None:
+        qI, kI, w = _index_maps(m["indexer"], c, h[:, None], qr, position)
+        suf_keys = jax.lax.dynamic_update_slice(suf_keys, kI, (0, t, 0))
+        nI, dI = qI.shape[-2:]
+        over_prefix = _index_scores(
+            qI.reshape(B, K, nI, dI), pre_keys, w.reshape(B, K, nI)
+        ).reshape(R, N)
+        over_suffix = _index_scores(qI, suf_keys, w)[:, 0]
+        over_suffix = jnp.where(jnp.arange(T) <= t, over_suffix, -jnp.inf)
+        chosen = _choose(
+            jnp.concatenate([over_prefix, over_suffix], axis=-1), _chosen_width(c, T)
+        )
+    attend = chosen[2]
+    kv_b = _kv_b(m, c)
+    with jax.named_scope("decoder/lm/attn/absorb"):
+        q_lat = jnp.einsum(
+            "rhd,chd->rhc", q[:, 0, :, :nope], kv_b[..., :nope],
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.bfloat16)
+    with jax.named_scope("decoder/lm/attn/scores"):
+        qc = jnp.concatenate([q_lat, q[:, 0, :, nope:]], axis=-1)        # [R, nh, rank + rope]
+        s_pre = jnp.einsum(
+            "bkhc,bnc->bkhn", qc.reshape(B, K, nh, -1), pre_lat,
+            preferred_element_type=jnp.float32,
+        ).reshape(R, nh, N)
+        s_suf = jnp.einsum("rhc,rtc->rht", qc, suf_lat, preferred_element_type=jnp.float32)
+        scores = jnp.where(
+            attend[:, None], jnp.concatenate([s_pre, s_suf], axis=-1), -jnp.inf
+        ) * ((nope + c.qk_rope_head_dim) ** -0.5)
+        probs = jax.nn.softmax(scores, axis=-1).astype(jnp.bfloat16)
+        mixed = jnp.einsum(
+            "bkhn,bnc->bkhc", probs[..., :N].reshape(B, K, nh, N), pre_lat[..., :rank],
+            preferred_element_type=jnp.float32,
+        ).reshape(R, nh, rank) + jnp.einsum(
+            "rht,rtc->rhc", probs[..., N:], suf_lat[..., :rank],
+            preferred_element_type=jnp.float32,
+        )
+    with jax.named_scope("decoder/lm/attn/absorb"):
+        ctx = jnp.einsum(
+            "rhc,chd->rhd", mixed.astype(jnp.bfloat16), kv_b[..., nope:],
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.bfloat16)
+    with jax.named_scope("decoder/lm/attn/out"):
+        return mm(ctx.reshape(R, -1), m["o_proj"]), (suf_lat, suf_keys), chosen
+
+
+def step(
+    params: Params, config: Config, prefix: DsaCache, cache: DsaCache,
+    counters: DsaCounters, last_word: jnp.ndarray,
+):
+    """One token for each of R = B*K beams.  prefix: the per-image latents
+    and indexer keys; cache: the beams' own; last_word [R] int32 at
+    position N + t.  Returns (cache, counters, logits [R, V] float32)."""
+    c = config
+    lm = params["lm"]
+    x = lm_common.embed(lm, last_word)                      # [R, H]
+    N = prefix.latents[0].shape[1]
+    latents, index_keys, counts, routes, held, records = [], [], [], [], [], []
+    chosen, attended, full = None, jnp.zeros((2,), jnp.int32), 0
+    for i in range(c.num_hidden_layers):
+        p = lm["layers"][layer_name(i)]
+        h = rms_norm(x, p["operator_norm"], c.norm_eps)
+        indexes = c.indexer_types[i] == "full"
+        y, (lat, keys), chosen = attend_step(
+            p["self_attn"], c, h,
+            (prefix.latents[i], prefix.index_keys[full] if indexes else None),
+            (cache.latents[i], cache.index_keys[full] if indexes else None),
+            counters.t, None if indexes else chosen,
+        )
+        if indexes:
+            index_keys.append(keys)
+            records.append(jnp.where(chosen[1], chosen[0], -1))
+            attended = attended + jnp.stack([
+                jnp.sum(chosen[1], dtype=jnp.int32), x.shape[0] * (N + counters.t + 1),
+            ]).astype(jnp.int32)
+            full += 1
+        x = x + y
+        latents.append(lat)
+        x, sizes, experts, pairs = _ffn(p, c, i, x)
+        if sizes is not None:
+            counts.append(sizes), routes.append(experts), held.append(pairs)
+    with jax.named_scope("decoder/lm/attn/select"):
+        selected = lm_common.write_at_step(
+            cache.selected, jnp.concatenate(records, axis=-1), counters.t
+        )
+    base, taken = lm_common.record_step(
+        lm_common.StepCounters(counters.t, counters.moe_counts, counters.step_visits),
+        cache.routes, counts, routes, visited=[h.visited for h in held] or None,
+    )
+    counters = DsaCounters(
+        *base, pairs=counters.pairs.at[1].add(_sum_pairs(held)),
+        attended=counters.attended + attended,
+    )
+    return (
+        DsaCache(tuple(latents), tuple(index_keys), taken, selected), counters,
+        _head(lm, c, x),
+    )
+
+
+def report(config: Config, state, B: int, K: int, T: int) -> dict:
+    """What this decoder adds to ``BeamResult.decoder_stats``: ``state``
+    the search's final ``StepState``."""
+    full = len(_full_layers(config))
+    return {
+        # [B, K, T, full layers, k]: the positions each LIVE beam's tokens
+        # attended, step by step along its own ancestry (-1: not visible)
+        "step_selected": state.beam.selected.reshape(B, K, T, full, -1),
+        # [2, 3] the prefill, the steps: pairs held here, routed, over the rows
+        "moe_pairs": state.shared.pairs,
+        # [2] positions attended, positions visible (steps, full layers)
+        "dsa_attended": state.shared.attended,
+    }

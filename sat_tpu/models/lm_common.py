@@ -21,6 +21,12 @@ which the sources' routers differ: an argument), times
 product (``grouped_matmul``), which computes those pairs and no others.
 A layer whose ``feed_forward`` holds a ``shared`` SwiGLU sends every token
 through it too, beside the routed sum.
+
+An expert layer may hold a SHARE of its experts (``Config.experts_held``
+from ``Config.first_expert``: one chip's of an expert-parallel
+deployment): it routes over all ``num_experts``, computes the pairs that
+land on its own and leaves the others out (``moe_ffn_held``); what the
+absent experts would add is another chip's to compute and nobody's here.
 """
 
 from __future__ import annotations
@@ -50,6 +56,11 @@ class StepCounters(NamedTuple):
 
 def is_moe(config: Config, layer: int) -> bool:
     return layer >= config.num_dense_layers
+
+
+def held_experts(config: Config) -> int:
+    """How many of ``num_experts`` this chip holds (``experts_held``; 0: all)."""
+    return config.experts_held or config.num_experts
 
 
 def layer_name(layer: int) -> str:
@@ -98,8 +109,13 @@ def route(p: Params, config: Config, h: jnp.ndarray, sum_eps: float):
 
 # (k, n) of a grouped product -> its (m, k, n) tiles for a step's few rows
 # an expert and for a prefill's many, each timed on a v5e at the published
-# widths (PERF.md section 6): 2048 x 1792 experts PR 26, 2048 x 768 PR 30
+# widths (PERF.md section 6): 2048 x 1792 experts PR 26, 2048 x 768 PR 30;
+# 6144 x 2048 PR 32, not timed against others: the default's 512-row tile
+# does not fit the kernel's 16 MB beside a 2048 x 1024 tile of a map, and
+# an image's share here is ~128 rows an expert, so row tiles stay small
 _GMM_TILES = {
+    (6144, 2048): ((128, 2048, 1024), (256, 2048, 1024)),
+    (2048, 6144): ((128, 2048, 1024), (256, 2048, 1024)),
     (2048, 1792): ((128, 2048, 1024), (512, 2048, 512)),
     (1792, 2048): ((128, 2048, 1024), (512, 2048, 512)),
     (2048, 768): ((128, 2048, 768), (256, 2048, 768)),
@@ -183,6 +199,97 @@ def moe_ffn(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
         return x + y.astype(x.dtype), sizes, experts
 
 
+# a layer that holds a share of its experts sizes its grouped products for
+# this many times the share a balanced router sends it
+HELD_CAPACITY_FACTOR = 4
+
+
+def held_pair_rows(config: Config, tokens: int) -> int:
+    """Rows of a held share's grouped products for ``tokens`` tokens: the
+    (token, expert) pairs that CAN land on the ``experts_held`` experts
+    here.  A token's k experts are distinct, so at most
+    ``tokens * min(k, held)`` do: the hard bound, and the rows of a step
+    (whose rows may all hold one word, ``<start>`` at step 0, and then all
+    choose alike).  Over many tokens a fitted router sends the share
+    ``tokens * k * held / num_experts``; the rows are
+    ``HELD_CAPACITY_FACTOR`` times that (never under 1024, never over the
+    hard bound).  Pairs beyond the rows are left out and COUNTED
+    (``HeldPairs.over``: it must read 0)."""
+    c = config
+    k, held = c.num_experts_per_tok, held_experts(c)
+    share = -(-tokens * k * held // c.num_experts)
+    return min(tokens * min(k, held), max(HELD_CAPACITY_FACTOR * share, 1024))
+
+
+class HeldPairs(NamedTuple):
+    """What a held share's layer did, int32 scalars."""
+
+    held: jnp.ndarray       # pairs computed here
+    routed: jnp.ndarray     # pairs routed, over all num_experts
+    over: jnp.ndarray       # pairs that landed here beyond the rows: left out
+    visited: jnp.ndarray    # experts here that took a token
+
+
+def moe_ffn_held(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
+    """``moe_ffn`` for a layer that holds experts ``[first_expert,
+    first_expert + experts_held)``: x [T, H] -> (x + the held experts'
+    weighted sum (+ the shared expert's) [T, H], tokens per expert over ALL
+    ``num_experts`` [E] int32, experts chosen [T, k] int32, ``HeldPairs``).
+    The router scores every expert; the pairs that land here are sorted by
+    expert to the front of ``held_pair_rows`` rows and go through the
+    grouped products; the others are left out, here as in the deployment's
+    other chips' absence.  With every expert held it is ``moe_ffn`` to the
+    bit."""
+    c = config
+    T, H = x.shape
+    k, E, held = c.num_experts_per_tok, c.num_experts, held_experts(c)
+    P = held_pair_rows(c, T)
+    with jax.named_scope("decoder/lm/moe/route"):
+        h = rms_norm(x, p["ffn_norm"], c.norm_eps).astype(jnp.bfloat16)
+        experts, weights = route(p["feed_forward"], c, h, sum_eps)
+    f = p["feed_forward"]
+    with jax.named_scope("decoder/lm/moe/dispatch"):
+        flat = experts.reshape(T * k)
+        local = flat - c.first_expert
+        here = (local >= 0) & (local < held)
+        # pairs of the experts here first, by expert; the others after them
+        order = jnp.argsort(jnp.where(here, local, held), stable=True)
+        rows = h[order[:P] // k]                            # [P, H]
+        counts = jnp.sum(
+            flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :],
+            axis=0, dtype=jnp.int32,
+        )
+        landed = jax.lax.dynamic_slice(counts, (c.first_expert,), (held,))
+        # group sizes within the rows: what lies beyond them is cut off
+        ends = jnp.minimum(jnp.cumsum(landed), P)
+        sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+        done = ends[-1]
+    with jax.named_scope("decoder/lm/moe/experts"):
+        hidden = swiglu(
+            grouped_matmul(rows, f["w1"], sizes), grouped_matmul(rows, f["w3"], sizes)
+        )
+        out = grouped_matmul(hidden, f["w2"], sizes)
+    with jax.named_scope("decoder/lm/moe/combine"):
+        back = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32)
+        )
+        # rows past the groups hold nothing the products wrote: masked, not
+        # multiplied by a zero weight
+        computed = (back < done).reshape(T, k)
+        picked = out[jnp.minimum(back, P - 1)].reshape(T, k, H).astype(jnp.float32)
+        y = jnp.sum(jnp.where(computed[..., None], picked * weights[..., None], 0.0), axis=1)
+    if "shared" in f:
+        with jax.named_scope("decoder/lm/moe/shared"):
+            s = f["shared"]
+            y = y + mm(swiglu(mm(h, s["w1"]), mm(h, s["w3"])), s["w2"]).astype(jnp.float32)
+    with jax.named_scope("decoder/lm/moe/combine"):
+        stats = HeldPairs(
+            held=done, routed=jnp.int32(T * k), over=jnp.sum(landed) - done,
+            visited=jnp.sum(sizes > 0, dtype=jnp.int32),
+        )
+        return x + y.astype(x.dtype), counts, experts, stats
+
+
 def dense_ffn(p: Params, config: Config, x: jnp.ndarray) -> jnp.ndarray:
     with jax.named_scope("decoder/lm/dense_ffn"):
         f = p["feed_forward"]
@@ -195,6 +302,11 @@ def ffn(p: Params, config: Config, layer: int, x: jnp.ndarray, sum_eps: float):
     the last two None in a dense layer."""
     if not is_moe(config, layer):
         return dense_ffn(p, config, x), None, None
+    if held_experts(config) < config.num_experts:
+        raise ValueError(
+            "a layer that holds a share of its experts reports what it held: "
+            "call moe_ffn_held"
+        )
     y, sizes, experts = moe_ffn(p, config, x.reshape(-1, x.shape[-1]), sum_eps)
     return y.reshape(x.shape), sizes, experts.reshape(x.shape[:-1] + (-1,))
 
@@ -202,7 +314,7 @@ def ffn(p: Params, config: Config, layer: int, x: jnp.ndarray, sum_eps: float):
 def ffn_params(config: Config, layer: int, linear) -> Params:
     """One layer's ``feed_forward`` leaves; ``linear(*shape)`` draws a map."""
     c = config
-    H, E = c.hidden_size, c.num_experts
+    H, E, held = c.hidden_size, c.num_experts, held_experts(c)
     if not is_moe(c, layer):
         I = c.intermediate_size
         return {"w1": linear(H, I), "w3": linear(H, I), "w2": linear(I, H)}
@@ -210,7 +322,7 @@ def ffn_params(config: Config, layer: int, linear) -> Params:
     return {
         "gate": linear(H, E),
         "expert_bias": jnp.zeros((E,), jnp.float32),
-        "w1": linear(E, H, I), "w3": linear(E, H, I), "w2": linear(E, I, H),
+        "w1": linear(held, H, I), "w3": linear(held, H, I), "w2": linear(held, I, H),
     }
 
 
@@ -286,11 +398,13 @@ def empty_routes(config: Config, rows: int, max_len: int) -> jnp.ndarray:
     return jnp.zeros((rows, max_len * n_moe * config.num_experts_per_tok), jnp.int32)
 
 
-def record_step(counters: StepCounters, taken: jnp.ndarray, counts, routes):
+def record_step(counters: StepCounters, taken: jnp.ndarray, counts, routes, visited=None):
     """After a step over R rows: (the counters with the step's tokens per
     expert ``counts`` (one [E] a moe layer) added and t advanced, the
     record ``taken`` with the step's choices ``routes`` (one [R, k] a moe
-    layer) written at step t)."""
+    layer) written at step t).  ``visited`` (one scalar a moe layer): the
+    experts that took a token, where that is not every expert with a count
+    (a layer that holds a share)."""
     t = counters.t
     moe_counts, step_visits = counters.moe_counts, counters.step_visits
     if counts:
@@ -298,13 +412,22 @@ def record_step(counters: StepCounters, taken: jnp.ndarray, counts, routes):
         moe_counts = moe_counts + sizes
         step_visits = jnp.where(
             jnp.arange(step_visits.shape[1])[None, :] == t,
-            jnp.sum(sizes > 0, axis=1, dtype=jnp.int32)[:, None], step_visits,
+            (
+                jnp.sum(sizes > 0, axis=1, dtype=jnp.int32) if visited is None
+                else jnp.stack(visited)
+            )[:, None], step_visits,
         )
     if routes:
         with jax.named_scope("decoder/lm/moe/route"):
             chosen = join_routes(routes, (taken.shape[0],))    # [R, moe layers * k]
-            width = chosen.shape[1]
-            steps = taken.shape[1] // width
-            at_t = jnp.arange(steps * width) // width == t
-            taken = jnp.where(at_t[None, :], jnp.tile(chosen, (1, steps)), taken)
+            taken = write_at_step(taken, chosen, t)
     return StepCounters(t=t + 1, moe_counts=moe_counts, step_visits=step_visits), taken
+
+
+def write_at_step(record: jnp.ndarray, now: jnp.ndarray, t: jnp.ndarray) -> jnp.ndarray:
+    """record [R, T * w] (step-major lanes), now [R, w]: the record with
+    ``now`` written at step t."""
+    width = now.shape[1]
+    steps = record.shape[1] // width
+    at_t = jnp.arange(steps * width) // width == t
+    return jnp.where(at_t[None, :], jnp.tile(now, (1, steps)), record)

@@ -1346,6 +1346,14 @@ def decode_dataset(
                     "decode/lm_state_mb",
                     float(out.decoder_stats["state_bytes"]) / 1e6,  # sync-ok: decode drain boundary
                 )
+            if out.decoder_stats and "dsa_attended" in out.decoder_stats:
+                # a decoder that selects positions and holds a share of
+                # its experts: positions attended / positions visible over
+                # the steps; pairs computed here / pairs routed
+                attended, visible = np.asarray(out.decoder_stats["dsa_attended"], np.float64)  # sync-ok: decode drain boundary
+                pairs = np.asarray(out.decoder_stats["moe_pairs"], np.float64).sum(axis=0)  # sync-ok: decode drain boundary
+                tel.gauge("decode/lm_dsa_selected_share", float(attended / max(visible, 1.0)))  # sync-ok: host numpy, already drained
+                tel.gauge("decode/lm_moe_held_pair_share", float(pairs[0] / max(pairs[1], 1.0)))  # sync-ok: host numpy, already drained
         with tel.span("decode/drain/detok", b):  # host work after it
             for i, image_file in enumerate(files):
                 if emitted >= dataset.count:           # fake_count padding

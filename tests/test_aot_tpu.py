@@ -462,6 +462,54 @@ def test_mla_beam_program_keeps_the_prefix_latent_and_per_image(monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < int(6.5e9)
 
 
+def _glm52_config():
+    return Config(
+        decoder="glm_moe_dsa", image_size=1024, vocabulary_size=19360, hidden_size=6144,
+        intermediate_size=12288, moe_intermediate_size=2048, num_hidden_layers=5, num_dense_layers=1,
+        num_attention_heads=64, num_experts=256, num_experts_per_tok=8, experts_held=16, first_expert=0,
+        n_shared_experts=1, routed_scaling_factor=2.5, norm_eps=1e-5, rope_theta=8e6,
+        kv_lora_rank=512, q_lora_rank=2048, qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        index_n_heads=32, index_head_dim=128, index_topk=2048,
+        indexer_types=("full", "shared", "shared", "shared", "full"),
+        tie_word_embeddings=False, layer_types=("latent_attention",) * 5,
+    )
+
+
+def test_dsa_beam_program_fits_the_chip_and_never_holds_a_square_of_scores(monkeypatch):
+    """``decoder="glm_moe_dsa"`` at the new cell's batch and the published
+    widths (B = 8 images of 1,024 px: N = 4,096; K = 3; depth 5; 16 of 256
+    experts held; V = 19,360): accepted by the chip's compiler, arguments
+    (7.8 GB of weights) and temporaries under 15.5 GB in all; the held
+    experts through the Pallas grouped product, prefill and step; no
+    ``[.., 64, 4096, 4096]`` scores per head and no per-beam copy of the
+    prefix (``[24,4096,..]``); the prefix the steps read is the per-image
+    latent ``bf16[8,4096,576]`` and the indexer's keys ``bf16[8,4096,128]``;
+    one ``TopK`` over the 24 rows of the vocabulary's slice."""
+    from sat_tpu.ops.beam_search import beam_search_jit
+
+    config = _glm52_config()
+    V, K, N = config.vocabulary_size, 3, config.num_ctx
+    assert N == 4096
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, decoder = _decoder_params(config)
+    compiled = beam_search_jit.lower(
+        decoder, config, _sd((8, N, config.dim_ctx)), 1, beam_size=K, valid_size=V,
+    ).compile()
+    text = compiled.as_text()
+    _assert_the_step_selects_per_row(text, 8, K, V)
+    # three grouped products an expert layer: four layers' in the steps, three in the prefill
+    assert len(re.findall(r"decoder/lm/moe/experts[^\n]*tpu_custom_call|"
+                          r"tpu_custom_call[^\n]*decoder/lm/moe/experts", text)) >= 21
+    shapes = set(re.findall(r"(?:bf16|f32|pred|s32|u32)\[[\d,]+\]", text))
+    assert not [s for s in shapes if re.search(r"\[(\d+,)*4096,4096\]", s) and s.count(",") >= 2], shapes
+    loop = {shape for ln in _loop_lines(text) for shape in re.findall(r"(?:bf16|f32)\[[\d,]+\]", ln)}
+    assert f"bf16[8,{N},576]" in loop and f"bf16[8,{N},128]" in loop
+    assert not [s for s in loop if re.search(rf"\[24,{N},", s)], loop
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > int(7.7e9)
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < int(15.5e9), memory
+
+
 @pytest.mark.parametrize("V", [65536, 128256], ids=["lfm2", "kanana2"])
 def test_beam_step_alone_needs_no_vocabulary_sized_temporary(V):
     """``_expand_step`` by itself over the lm cells' 768 rows of logits:

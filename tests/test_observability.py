@@ -814,6 +814,12 @@ def test_e2e_slo_burn_degrades_health(obs_served, monkeypatch, tmp_path):
             if burning:
                 break
         assert burning == ["serve_p99_ms"], "SLO never flipped to burning"
+        # hold the verdict: with the traffic stopped the 2-s fast window
+        # empties, the engine's next tick (every 0.5 s) logs "ok", and on a
+        # loaded machine that came before check_slo.py below had started
+        # (the driver's run of PR 31's tree, and PR 32's own whole run);
+        # what follows reads the state and the log as they stand now
+        server.slo.stop()
 
         code, _, body = _get(port, "/healthz")
         health = json.loads(body)

@@ -318,6 +318,58 @@ def test_the_latent_attention_s_scopes_reach_op_scopes():
     assert {"prefill_attn", "step_attn", "shared", "other"} <= claimed
 
 
+def test_the_sparse_attention_s_scopes_and_gauges_exist(tmp_path):
+    """``decoder="glm_moe_dsa"``: the indexer's and the selection's scopes
+    name leaf instructions of the beam program in both phases (``index``
+    in the prefill only past ``index_topk`` positions: 36 > 16 here), the
+    rules of benchmark/scopes/lm_dsa*.json claim them, and what the decoder
+    counts of a batch is what the drain's two gauges are set from."""
+    from sat_tpu.models import decoders
+    from sat_tpu.ops.beam_search import beam_search_jit
+
+    config = Config(
+        decoder="glm_moe_dsa", image_size=96, hidden_size=64, intermediate_size=96, moe_intermediate_size=24,
+        num_hidden_layers=3, num_dense_layers=1, num_attention_heads=4, num_experts=8, num_experts_per_tok=3,
+        experts_held=4, first_expert=2, kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=24, n_shared_experts=1, index_n_heads=4, index_head_dim=16, index_topk=16,
+        indexer_types=("full", "shared", "full"), tie_word_embeddings=False,
+        layer_types=("latent_attention",) * 3, vocabulary_size=100, max_caption_length=6, beam_size=3, batch_size=2,
+    )
+    params = decoders.init_params(jax.random.PRNGKey(0), config)
+    contexts = jnp.zeros((2, config.num_ctx, config.dim_ctx))
+    tel = Telemetry(capacity=64)
+    xla_acct.reset()
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    xla_acct.analyze("decode/beam_search", beam_search_jit, params, config, contexts, 1,
+                     beam_size=3, valid_size=100, return_alphas=False, tel=tel)
+    rows = xla_acct.entries()["decode/beam_search"]["op_scopes"]["rows"]
+    xla_acct.reset()
+    ops = [r[3] for r in rows if not r[2]]
+    for scope in [f"beam/prefill.*decoder/lm/attn/{s}" for s in ("q", "latent", "index", "select", "expand", "scores", "out")] + \
+                 [f"beam/loop.*decoder/lm/attn/{s}" for s in ("q", "latent", "index", "select", "absorb", "scores", "out")] + \
+                 ["beam/prefill.*decoder/lm/moe/experts", "beam/loop.*decoder/lm/moe/experts"]:
+        assert any(re.search(scope, o) for o in ops), scope
+    scopes = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "scopes")
+    for name, wanted in (("lm_dsa", {"index", "select", "other"}),
+                         ("lm_dsa_phases", {"prefill_attention", "step_select", "step_held_experts", "other"})):
+        with open(os.path.join(scopes, name + ".json")) as f:
+            rules = [tuple(r) for r in json.load(f)["rules"]]
+        assert wanted <= {_bucket(rules, o) for o in ops}, name
+    out = beam_search_jit(params, config, contexts, 1, beam_size=3, valid_size=100)
+    stats = out.decoder_stats
+    assert {"dsa_attended", "moe_pairs", "step_selected", "state_bytes"} <= set(stats)
+    attended, visible = (float(x) for x in stats["dsa_attended"])
+    assert attended / visible == pytest.approx(6 * 16 / sum(36 + t + 1 for t in range(6)))
+    pairs = np.asarray(stats["moe_pairs"])
+    assert pairs.shape == (2, 3) and (pairs[:, 2] == 0).all() and (pairs[:, 0] <= pairs[:, 1]).all()
+    import inspect
+
+    from sat_tpu import runtime
+
+    drain = inspect.getsource(runtime)
+    assert '"decode/lm_dsa_selected_share"' in drain and '"decode/lm_moe_held_pair_share"' in drain
+
+
 def test_parse_op_scopes_on_a_written_module():
     text = """HloModule jit_f, is_scheduled=true
 
