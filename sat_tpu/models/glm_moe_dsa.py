@@ -34,7 +34,10 @@ Forms.  Whole sequences (``teacher_forced``, ``prefill``) go one image at
 a time (``lax.map``) and one block of ``_QUERY_BLOCK`` queries at a time
 against the keys up to the block's end, in the EXPANDED form: a block's
 scores are ``[heads, block, keys]``, and no ``[.., S, S]`` array per head
-exists.  The selection there is a MASK: ``causal & (I[t, s] >= the
+exists.  The prefill, on the TPU, hands that attention to
+``ops/flash_prefill.py``'s kernel, which keeps each tile of scores in
+VMEM (the same arithmetic; the ``lax`` blocks are what is differentiated
+and what runs anywhere else).  The selection there is a MASK: ``causal & (I[t, s] >= the
 index_topk-th largest of row t)``, the threshold found exactly by 32
 counting passes over the floats' order-preserving bits (``_kth_largest``;
 no sort); a block whose keys number ``index_topk`` or fewer attends all it
@@ -95,6 +98,7 @@ class DsaCounters(NamedTuple):
     # routed, pairs over the rows
     pairs: jnp.ndarray
     attended: jnp.ndarray   # [2] int32: positions attended, positions visible (steps, full layers)
+    fused: jnp.ndarray      # [2] int32: the prefill's query blocks through the fused kernel, in all
 
 
 def _full_layers(config: Config):
@@ -166,20 +170,28 @@ def init_params(rng: jax.Array, config: Config) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def _queries(m: Params, config: Config, h: jnp.ndarray, positions: jnp.ndarray):
+def _queries(m: Params, config: Config, h: jnp.ndarray, positions: jnp.ndarray, by_head=False):
     """h [..., S, H] normed -> (qr [..., S, q_lora_rank], the normed
     bottleneck the indexer reads too; q [..., S, nh, nope + rope], its rope
-    part rotated), bfloat16."""
+    part rotated), bfloat16.  ``by_head`` (h [S, H], one sequence): q
+    [nh, S, nope + rope], each head's rows together, written so by the
+    product itself."""
     c = config
+    nh, nope = c.num_attention_heads, c.qk_nope_head_dim
     with jax.named_scope("decoder/lm/attn/q"):
         qr = rms_norm(mm(h, m["q_a_proj"]), m["q_a_layernorm"], c.norm_eps).astype(jnp.bfloat16)
-        q = mm(qr, m["q_b_proj"]).reshape(
-            h.shape[:-1] + (c.num_attention_heads, c.qk_nope_head_dim + c.qk_rope_head_dim)
-        )
-        q_rope = _rope(q[..., c.qk_nope_head_dim:].astype(jnp.float32), positions, c.rope_theta)
-        return qr, jnp.concatenate(
-            [q[..., : c.qk_nope_head_dim], q_rope.astype(jnp.bfloat16)], axis=-1
-        )
+        if by_head:
+            q = jnp.einsum(
+                "sr,rhd->hsd", qr, m["q_b_proj"].reshape(c.q_lora_rank, nh, -1),
+                preferred_element_type=jnp.float32,
+            ).astype(jnp.bfloat16)
+            q_rope = _rope(
+                q[..., None, nope:].astype(jnp.float32), positions, c.rope_theta
+            )[..., 0, :]
+        else:
+            q = mm(qr, m["q_b_proj"]).reshape(h.shape[:-1] + (nh, nope + c.qk_rope_head_dim))
+            q_rope = _rope(q[..., nope:].astype(jnp.float32), positions, c.rope_theta)
+        return qr, jnp.concatenate([q[..., :nope], q_rope.astype(jnp.bfloat16)], axis=-1)
 
 
 def _index_rope(x: jnp.ndarray, positions: jnp.ndarray, config: Config) -> jnp.ndarray:
@@ -256,24 +268,68 @@ def _blocks(S: int):
     return [(a, min(a + _QUERY_BLOCK, S)) for a in range(0, S, _QUERY_BLOCK)]
 
 
-def attend_sequence(m: Params, config: Config, h: jnp.ndarray, masks):
+def _attend_blocks(q, keys, values, masks, scale: float) -> jnp.ndarray:
+    """q, keys [nh, S, d], values [nh, S, dv] -> [S, nh, dv] bfloat16: a
+    block of queries at a time against the keys up to the block's end,
+    its float32 scores ``[nh, block, keys]`` whole."""
+    ctx = []
+    for (a, b), mask in zip(_blocks(q.shape[1]), masks):
+        scores = jnp.einsum(
+            "hsd,htd->hst", q[:, a:b], keys[:, :b], preferred_element_type=jnp.float32
+        )
+        scores = jnp.where(mask[None], scores * scale, -jnp.inf)
+        # the softmax's division after the weighted sum, as deepseek_v3's
+        weights = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
+        block = jnp.einsum(
+            "hst,htd->shd", weights.astype(jnp.bfloat16), values[:, :b],
+            preferred_element_type=jnp.float32,
+        ) / jnp.sum(weights, axis=-1).T[..., None]
+        ctx.append(block.astype(jnp.bfloat16))
+    return jnp.concatenate(ctx, axis=0)
+
+
+def _one_mask(masks, S: int, k: int):
+    """The blocks' masks as the fused kernel reads them: those that select
+    (more than ``k`` keys visible), each widened to S keys, as ONE
+    [queries from the first such block on, S] array; None where no block
+    selects."""
+    selecting = [jnp.pad(m, ((0, 0), (0, S - m.shape[1]))) for m in masks if m.shape[1] > k]
+    return jnp.concatenate(selecting) if selecting else None
+
+
+def attend_sequence(m: Params, config: Config, h: jnp.ndarray, masks, fused: bool = False):
     """h [S, H] normed, ONE sequence at positions 0..S-1 -> (the
     attention's output [S, H], the latents [S, rank + rope], the indexer's
     keys [S, dI] or None, the blocks' masks).  ``masks``: None in a layer
     with an indexer (it makes them), else those of the last such layer:
-    one [block, keys up to the block's end] a block of queries."""
+    one [block, keys up to the block's end] a block of queries.  ``fused``:
+    scores, mask, softmax and weighted sum in ``ops/flash_prefill.py``'s
+    kernel (the same arithmetic with the scores in VMEM; no gradient), not
+    block by block in ``lax``."""
     c = config
     S, _ = h.shape
-    rank, nope, nh = c.kv_lora_rank, c.qk_nope_head_dim, c.num_attention_heads
+    rank, nope = c.kv_lora_rank, c.qk_nope_head_dim
     positions = jnp.arange(S)
-    qr, q = _queries(m, c, h, positions)
+    qr, q = _queries(m, c, h, positions, by_head=True)
     latents = _latents(m, c, h, positions)
     with jax.named_scope("decoder/lm/attn/expand"):
-        kv = jnp.einsum(
-            "sc,chd->shd", latents[..., :rank], _kv_b(m, c), preferred_element_type=jnp.float32
+        # keys and values [nh, S, d] head-major, as q.  The rotary key all
+        # heads share is ADDED into lanes that ``W_kvb``'s key half, widened
+        # by zero columns, leaves at zero: keys come out of one product
+        # whole, the numbers a concatenation would hold
+        kv_b = _kv_b(m, c)
+        rope = c.qk_rope_head_dim
+        keys = (
+            jnp.einsum(
+                "sc,chd->hsd", latents[:, :rank],
+                jnp.pad(kv_b[..., :nope], ((0, 0), (0, 0), (0, rope))),
+                preferred_element_type=jnp.float32,
+            ) + jnp.pad(latents[:, rank:], ((0, 0), (nope, 0))).astype(jnp.float32)
         ).astype(jnp.bfloat16)
-        k_rope = jnp.broadcast_to(latents[:, None, rank:], (S, nh, c.qk_rope_head_dim))
-        keys, values = jnp.concatenate([kv[..., :nope], k_rope], axis=-1), kv[..., nope:]
+        values = jnp.einsum(
+            "sc,chd->hsd", latents[:, :rank], kv_b[..., nope:],
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.bfloat16)
     index_keys = None
     if masks is None:
         qI, index_keys, w = _index_maps(m["indexer"], c, h, qr, positions)
@@ -286,22 +342,18 @@ def attend_sequence(m: Params, config: Config, h: jnp.ndarray, masks):
                 scores = _index_scores(qI[a:b], index_keys[:b], w[a:b])
                 masks.append(_select_mask(scores, causal, c.index_topk))
     scale = (nope + c.qk_rope_head_dim) ** -0.5
-    ctx = []
     with jax.named_scope("decoder/lm/attn/scores"):
-        for (a, b), mask in zip(_blocks(S), masks):
-            scores = jnp.einsum(
-                "shd,thd->hst", q[a:b], keys[:b], preferred_element_type=jnp.float32
+        if fused:
+            from ..ops import flash_prefill     # ops/__init__ imports models
+
+            ctx = flash_prefill.flash_prefill(
+                q, keys, values, _one_mask(masks, S, c.index_topk), scale=scale,
+                interpret=jax.default_backend() != "tpu",
             )
-            scores = jnp.where(mask[None], scores * scale, -jnp.inf)
-            # the softmax's division after the weighted sum, as deepseek_v3's
-            weights = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
-            block = jnp.einsum(
-                "hst,thd->shd", weights.astype(jnp.bfloat16), values[:b],
-                preferred_element_type=jnp.float32,
-            ) / jnp.sum(weights, axis=-1).T[..., None]
-            ctx.append(block.astype(jnp.bfloat16))
+        else:
+            ctx = _attend_blocks(q, keys, values, masks, scale).reshape(S, -1)
     with jax.named_scope("decoder/lm/attn/out"):
-        out = mm(jnp.concatenate(ctx, axis=0).reshape(S, -1), m["o_proj"])
+        out = mm(ctx, m["o_proj"])
     return out, latents, index_keys, masks
 
 
@@ -329,7 +381,7 @@ def _sum_pairs(held) -> jnp.ndarray:
                       sum(h.over for h in held)]).astype(jnp.int32)
 
 
-def _one_sequence(lm: Params, config: Config, x: jnp.ndarray, tail: int):
+def _one_sequence(lm: Params, config: Config, x: jnp.ndarray, tail: int, fused: bool = False):
     """x [S, H] -> (hidden of the last ``tail`` positions, latents per
     layer, indexer keys per full layer, tokens per expert [moe layers, E],
     experts chosen [S, moe layers * k], pairs [3])."""
@@ -341,7 +393,9 @@ def _one_sequence(lm: Params, config: Config, x: jnp.ndarray, tail: int):
         p = lm["layers"][layer_name(i)]
         h = rms_norm(x, p["operator_norm"], c.norm_eps)
         full = c.indexer_types[i] == "full"
-        y, kept, keys, masks = attend_sequence(p["self_attn"], c, h, None if full else masks)
+        y, kept, keys, masks = attend_sequence(
+            p["self_attn"], c, h, None if full else masks, fused
+        )
         x = x + y
         latents.append(kept)
         if full:
@@ -355,13 +409,15 @@ def _one_sequence(lm: Params, config: Config, x: jnp.ndarray, tail: int):
     )
 
 
-def sequence_forward(lm: Params, config: Config, x: jnp.ndarray, tail: int = 0):
+def sequence_forward(
+    lm: Params, config: Config, x: jnp.ndarray, tail: int = 0, fused: bool = False,
+):
     """x [B, S, H] bfloat16 -> ``_one_sequence``'s results, image by image:
     (hidden [B, tail, H], the sequences' state (a ``DsaCache`` of
     ``[B, S, ..]`` leaves), tokens per expert [moe layers, E], experts
     chosen [B, S, moe layers * k], pairs [3])."""
     hidden, latents, index_keys, counts, routes, pairs = jax.lax.map(
-        lambda one: _one_sequence(lm, config, one, tail), x
+        lambda one: _one_sequence(lm, config, one, tail, fused), x
     )
     return (
         hidden, DsaCache(latents, index_keys), jnp.sum(counts, axis=0), routes,
@@ -382,12 +438,20 @@ def teacher_forced(
 
 def prefill(params: Params, config: Config, contexts: jnp.ndarray):
     """The N prefix positions of each image, once: (the prefix's latents
-    and indexer keys, per image; (tokens per expert, pairs) for
-    ``init_counters``; the experts every position chose [B, N, moe layers * k])."""
-    _, state, counts, routes, pairs = sequence_forward(
-        params["lm"], config, lm_common.prefix(params, contexts)
-    )
-    return state, (counts, pairs), routes
+    and indexer keys, per image; (tokens per expert, pairs, query blocks
+    through the fused kernel and in all) for ``init_counters``; the experts
+    every position chose [B, N, moe layers * k]).  Inference only, so its
+    attention takes the fused kernel where there is one (the TPU) and the
+    sequence is whole query blocks; else the ``lax`` blocks, as
+    ``teacher_forced`` always does (it is differentiated)."""
+    from ..ops import flash_prefill     # ops/__init__ imports models
+
+    x = lm_common.prefix(params, contexts)
+    S = x.shape[1]
+    fused = flash_prefill.available() and S % _QUERY_BLOCK == 0
+    _, state, counts, routes, pairs = sequence_forward(params["lm"], config, x, fused=fused)
+    blocks = len(_blocks(S))
+    return state, (counts, pairs, jnp.array([blocks * fused, blocks], jnp.int32)), routes
 
 
 # ---------------------------------------------------------------------------
@@ -397,11 +461,11 @@ def prefill(params: Params, config: Config, contexts: jnp.ndarray):
 
 def init_counters(prefill_counts, max_len: int) -> DsaCounters:
     """Step 0's counters, the prefill's counts already in."""
-    counts, pairs = prefill_counts
+    counts, pairs, fused = prefill_counts
     base = lm_common.init_counters(counts, max_len)
     return DsaCounters(
         *base, pairs=jnp.stack([pairs, jnp.zeros_like(pairs)]),
-        attended=jnp.zeros((2,), jnp.int32),
+        attended=jnp.zeros((2,), jnp.int32), fused=fused,
     )
 
 
@@ -554,7 +618,7 @@ def step(
     )
     counters = DsaCounters(
         *base, pairs=counters.pairs.at[1].add(_sum_pairs(held)),
-        attended=counters.attended + attended,
+        attended=counters.attended + attended, fused=counters.fused,
     )
     return (
         DsaCache(tuple(latents), tuple(index_keys), taken, selected), counters,
@@ -574,4 +638,7 @@ def report(config: Config, state, B: int, K: int, T: int) -> dict:
         "moe_pairs": state.shared.pairs,
         # [2] positions attended, positions visible (steps, full layers)
         "dsa_attended": state.shared.attended,
+        # [2] of a layer and image: the prefill's query blocks whose scores
+        # stayed in the fused kernel, query blocks in all
+        "prefill_fused_blocks": state.shared.fused,
     }

@@ -1349,11 +1349,16 @@ def decode_dataset(
             if out.decoder_stats and "dsa_attended" in out.decoder_stats:
                 # a decoder that selects positions and holds a share of
                 # its experts: positions attended / positions visible over
-                # the steps; pairs computed here / pairs routed
+                # the steps; pairs computed here / pairs routed; the
+                # prefill's query blocks whose scores stayed in the fused
+                # kernel / all of them (1.0 on the chip, 0.0 where the lax
+                # form ran: a silent fall-back shows here)
                 attended, visible = np.asarray(out.decoder_stats["dsa_attended"], np.float64)  # sync-ok: decode drain boundary
                 pairs = np.asarray(out.decoder_stats["moe_pairs"], np.float64).sum(axis=0)  # sync-ok: decode drain boundary
                 tel.gauge("decode/lm_dsa_selected_share", float(attended / max(visible, 1.0)))  # sync-ok: host numpy, already drained
                 tel.gauge("decode/lm_moe_held_pair_share", float(pairs[0] / max(pairs[1], 1.0)))  # sync-ok: host numpy, already drained
+                fused, blocks = np.asarray(out.decoder_stats["prefill_fused_blocks"], np.float64)  # sync-ok: decode drain boundary
+                tel.gauge("decode/lm_dsa_prefill_fused_share", float(fused / max(blocks, 1.0)))  # sync-ok: host numpy, already drained
         with tel.span("decode/drain/detok", b):  # host work after it
             for i, image_file in enumerate(files):
                 if emitted >= dataset.count:           # fake_count padding

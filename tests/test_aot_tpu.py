@@ -484,7 +484,9 @@ def test_dsa_beam_program_fits_the_chip_and_never_holds_a_square_of_scores(monke
     ``[.., 64, 4096, 4096]`` scores per head and no per-beam copy of the
     prefix (``[24,4096,..]``); the prefix the steps read is the per-image
     latent ``bf16[8,4096,576]`` and the indexer's keys ``bf16[8,4096,128]``;
-    one ``TopK`` over the 24 rows of the vocabulary's slice."""
+    one ``TopK`` over the 24 rows of the vocabulary's slice; the prefill's
+    attention one fused kernel a layer with no float32 block of scores and
+    no copy round it."""
     from sat_tpu.ops.beam_search import beam_search_jit
 
     config = _glm52_config()
@@ -508,6 +510,26 @@ def test_dsa_beam_program_fits_the_chip_and_never_holds_a_square_of_scores(monke
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > int(7.7e9)
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < int(15.5e9), memory
+    # the prefill's attention is ops/flash_prefill.py's kernel, one call a
+    # layer, under the scope lm_dsa_phases.json's prefill_attention claims;
+    # Mosaic takes its tiles at the published widths
+    lines = text.splitlines()
+    fused = [ln for ln in lines if "tpu_custom_call" in ln and "flash_prefill" in ln]
+    assert len(fused) == config.num_hidden_layers
+    assert all(re.search(r"beam/prefill[^\"]*decoder/lm/attn/scores/", ln) for ln in fused)
+    # so no block of float32 scores exists, in either order of its axes
+    scores = [s for s in shapes if re.fullmatch(r"f32\[(64,512|512,64),(\d+)\]", s)
+              and int(s[:-1].rsplit(",", 1)[1]) >= 512]
+    assert not scores, scores
+    # and nothing is transposed or copied round the kernel: queries, keys
+    # and values are written head-major by their own products (a copy of
+    # bf16[4096,64,256] three times a layer and image would cost 0.5 ms)
+    moved = [ln for ln in lines
+             if re.search(r"= bf16\[(4096,64,256|64,4096,256|4096,16384)\]\S* (copy|transpose)\(", ln)]
+    assert not moved, moved[:2]
+    # temporaries: 2.41 GB with the lax blocks (PR 32), 2.25 GB read here
+    # with the kernel (the widest of what is left is the expert layer's)
+    assert memory.temp_size_in_bytes < int(2.35e9), memory
 
 
 @pytest.mark.parametrize("V", [65536, 128256], ids=["lfm2", "kanana2"])

@@ -310,6 +310,32 @@ def test_prefill_then_20_cached_steps_equal_the_full_forward(params, weights, sm
     _close_but_for_flips(cached, want, FORWARD_TOL)
 
 
+@pytest.mark.parametrize("hook,blocks", [(True, [3, 3]), (False, [0, 3])], ids=["fused", "lax"])
+def test_prefill_through_the_fused_kernel_then_20_cached_steps_equal_the_full_forward(
+        params, weights, monkeypatch, hook, blocks):
+    """The prefill's attention through ops/flash_prefill.py (interpret
+    mode, under its test hook; 36 positions = 3 query blocks of 12, the
+    last two under the selection's mask), then 20 cached steps, against the
+    reference's full forward at the ``lax`` form's own tolerance; the
+    counter says which form ran, and without the hook it is the ``lax``
+    blocks."""
+    from sat_tpu.ops import flash_prefill
+
+    monkeypatch.setattr(dsa, "_QUERY_BLOCK", 12)
+    monkeypatch.setattr(flash_prefill, "FORCE_INTERPRET", hook)
+    ctx, tokens = _inputs()
+    cached, prefix, _, counters = _cached_logits(params, CONFIG, ctx, tokens)
+    assert np.asarray(counters.fused).tolist() == blocks
+    want, _, _ = ref.forward(lambda pre: _subtree(weights, pre), MODEL, np.asarray(ctx), np.asarray(tokens))
+    _close_but_for_flips(cached, want, FORWARD_TOL)
+    if hook:    # the two forms of the prefill leave the same latents (layer 0's are made before any attention)
+        monkeypatch.setattr(flash_prefill, "FORCE_INTERPRET", False)
+        plain, _, _ = dsa.prefill(params, CONFIG, ctx)
+        assert np.array_equal(np.asarray(prefix.latents[0], np.float32), np.asarray(plain.latents[0], np.float32))
+        for got, lax_form in zip(prefix.latents[1:], plain.latents[1:]):
+            _close(got, lax_form, PATH_TOL)
+
+
 def test_the_steps_choose_what_the_reference_chooses(params, weights):
     """The record of chosen positions against the reference's S_t at the
     caption's positions, full layer by full layer and step by step."""
@@ -337,8 +363,8 @@ def test_a_shared_layer_attends_the_preceding_full_layer_s_choice(params, monkey
         seen.append((chosen, out[2], "indexer" in m))
         return out
 
-    def attend_sequence(m, config, h, masks):
-        out = seq_(m, config, h, masks)
+    def attend_sequence(m, config, h, masks, fused=False):
+        out = seq_(m, config, h, masks, fused)
         handed.append((masks, out[3]))
         return out
 
@@ -409,7 +435,7 @@ def test_the_reorder_moves_the_indexer_s_keys_with_the_latents():
                          routes=leaf(30), selected=leaf(80))
     shared = dsa.DsaCounters(t=jnp.int32(7), moe_counts=jnp.arange(8).reshape(2, 4),
                              step_visits=jnp.arange(10).reshape(2, 5), pairs=jnp.arange(6).reshape(2, 3),
-                             attended=jnp.arange(2))
+                             attended=jnp.arange(2), fused=jnp.arange(2))
     parent = jnp.array([[2, 0, 1], [1, 1, 0]])
     moved = bs._reorder_beams(bs.StepState(cache, shared), B, K, jnp.arange(B)[:, None], parent)
     want = (jnp.arange(B)[:, None] * K + parent).reshape(-1).astype(jnp.float32)
@@ -556,8 +582,8 @@ def _sequence_masks(params, x):
 def _masks_jit(params, x):
     seen, seq_ = [], dsa.attend_sequence
 
-    def attend_sequence(m, config, h, masks):
-        out = seq_(m, config, h, masks)
+    def attend_sequence(m, config, h, masks, fused=False):
+        out = seq_(m, config, h, masks, fused)
         if masks is None:
             S = h.shape[0]
             seen.append(jnp.concatenate([jnp.pad(b, ((0, 0), (0, S - b.shape[1]))) for b in out[3]]))

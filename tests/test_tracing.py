@@ -318,6 +318,17 @@ def test_the_latent_attention_s_scopes_reach_op_scopes():
     assert {"prefill_attn", "step_attn", "shared", "other"} <= claimed
 
 
+def _dsa_config(**changes):
+    return Config(**{**dict(
+        decoder="glm_moe_dsa", image_size=96, hidden_size=64, intermediate_size=96, moe_intermediate_size=24,
+        num_hidden_layers=3, num_dense_layers=1, num_attention_heads=4, num_experts=8, num_experts_per_tok=3,
+        experts_held=4, first_expert=2, kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=24, n_shared_experts=1, index_n_heads=4, index_head_dim=16, index_topk=16,
+        indexer_types=("full", "shared", "full"), tie_word_embeddings=False,
+        layer_types=("latent_attention",) * 3, vocabulary_size=100, max_caption_length=6, beam_size=3, batch_size=2,
+    ), **changes})
+
+
 def test_the_sparse_attention_s_scopes_and_gauges_exist(tmp_path):
     """``decoder="glm_moe_dsa"``: the indexer's and the selection's scopes
     name leaf instructions of the beam program in both phases (``index``
@@ -327,14 +338,7 @@ def test_the_sparse_attention_s_scopes_and_gauges_exist(tmp_path):
     from sat_tpu.models import decoders
     from sat_tpu.ops.beam_search import beam_search_jit
 
-    config = Config(
-        decoder="glm_moe_dsa", image_size=96, hidden_size=64, intermediate_size=96, moe_intermediate_size=24,
-        num_hidden_layers=3, num_dense_layers=1, num_attention_heads=4, num_experts=8, num_experts_per_tok=3,
-        experts_held=4, first_expert=2, kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16, qk_rope_head_dim=8,
-        v_head_dim=24, n_shared_experts=1, index_n_heads=4, index_head_dim=16, index_topk=16,
-        indexer_types=("full", "shared", "full"), tie_word_embeddings=False,
-        layer_types=("latent_attention",) * 3, vocabulary_size=100, max_caption_length=6, beam_size=3, batch_size=2,
-    )
+    config = _dsa_config()
     params = decoders.init_params(jax.random.PRNGKey(0), config)
     contexts = jnp.zeros((2, config.num_ctx, config.dim_ctx))
     tel = Telemetry(capacity=64)
@@ -368,6 +372,35 @@ def test_the_sparse_attention_s_scopes_and_gauges_exist(tmp_path):
 
     drain = inspect.getsource(runtime)
     assert '"decode/lm_dsa_selected_share"' in drain and '"decode/lm_moe_held_pair_share"' in drain
+    # the lax blocks ran here (the CPU): no query block went through the kernel, and the drain says so
+    assert np.asarray(stats["prefill_fused_blocks"]).tolist() == [0, 1]
+    assert '"decode/lm_dsa_prefill_fused_share"' in drain
+
+
+def test_the_fused_prefill_kernel_s_call_carries_the_scope_its_roofline_share_reads(monkeypatch):
+    """``ops/flash_prefill.py``'s call sits under
+    ``beam/prefill/.../decoder/lm/attn/scores``: the rule of
+    benchmark/scopes/lm_dsa_phases.json that feeds
+    ``lm_dsa_prefill_roofline_share`` claims it, so the share keeps reading
+    the prefill's attention when the kernel is what runs it (traced with the
+    kernel under its test hook; 36 positions in query blocks of 12)."""
+    from sat_tpu.models import decoders, glm_moe_dsa
+    from sat_tpu.ops import flash_prefill
+    from sat_tpu.ops.beam_search import beam_search_jit
+
+    monkeypatch.setattr(glm_moe_dsa, "_QUERY_BLOCK", 12)
+    monkeypatch.setattr(flash_prefill, "FORCE_INTERPRET", True)
+    config = _dsa_config(max_caption_length=4, beam_size=2)
+    params = decoders.init_params(jax.random.PRNGKey(0), config)
+    contexts = jnp.zeros((2, config.num_ctx, config.dim_ctx))
+    text = beam_search_jit.lower(params, config, contexts, 1, beam_size=2, valid_size=100).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*flash_prefill[^"]*)"', text))
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                           "benchmark", "scopes", "lm_dsa_phases.json")) as f:
+        rules = [tuple(r) for r in json.load(f)["rules"]]
+    assert names and {_bucket(rules, n) for n in names} == {"prefill_attention"}, names
+    out = beam_search_jit(params, config, contexts, 1, beam_size=2, valid_size=100)
+    assert np.asarray(out.decoder_stats["prefill_fused_blocks"]).tolist() == [3, 3]
 
 
 def test_parse_op_scopes_on_a_written_module():
