@@ -440,6 +440,111 @@ class StallWatch:
             self._base = self._sample()
 
 
+class DeviceOccupancy:
+    """The loop's own account of the device standing empty: every stretch in
+    which the host KNOWS that nothing is enqueued on the chip, recorded as
+    one ``<family>/device_empty`` span (``family`` = ``train`` | ``decode``;
+    ``arg`` = the step or batch whose dispatch ended the stretch) on the
+    clock, thread and ring of the loop's phase spans, so that a reader
+    intersects the two: ``device_empty`` x ``decode/drain/detok`` is the
+    detokenise time that ran with the device empty.
+
+    ``enqueued(handle, k)`` is called when dispatch k has returned, with an
+    array that program computes; an open stretch ends there.  ``observe()``
+    is called at the phase boundaries the loop already has: if no stretch
+    is open and the newest handle's ``is_ready()`` is true (the runtime's
+    own word that the newest enqueued work is finished: non-blocking, NOT a
+    sync, and no in-order stream is assumed), one opens.  With a stretch
+    open ``observe()`` is one comparison.
+
+    So a stretch is a LOWER bound of a device gap: it opens at the first
+    boundary at which the host could know and closes when the next program
+    is enqueued, not when it starts.  Behind a blocking sync
+    (``train/log_sync``, ``decode/drain/wait``) it opens when the sync
+    returns, so the sync's own tail after the device ran dry (its copies
+    to the host, the drain's slice programs) is not in it; between syncs
+    its resolution is the phase it falls in (PERF.md section 6 has the
+    measured ratio to a trace's gaps).  The stretch is also entered as the
+    profiler's annotation, by hand as ``_Span`` does, with the index it is
+    expected to end at: a trace taken with the host tracer on shows the
+    program's account of the gap on the device's clock beside the gap.
+
+    ``publish()`` sets the gauge ``<family>/device_empty_share``: empty
+    time over the time since the loop's first dispatch (a fraction).
+    Telemetry off: ``NULL_OCCUPANCY``, which never calls ``is_ready()``."""
+
+    def __init__(self, tel, family: str, clock=time.perf_counter_ns) -> None:
+        self._tel, self._clock = tel, clock
+        self._span = family + "/device_empty"
+        self._gauge = family + "/device_empty_share"
+        self._ready = None        # is_ready of the newest handle enqueued
+        self._next = 0            # the index after it: where an open stretch should end
+        self._t_open: Optional[int] = None
+        self._ann = None          # the open stretch's annotation
+        self._t_first: Optional[int] = None
+        self._empty_ns = 0
+
+    def enqueued(self, handle, k: int) -> None:
+        if self._t_open is not None:
+            now = self._clock()
+            t_open = self._end_stretch()
+            self._tel.record(self._span, t_open, now - t_open, k)
+            self._empty_ns += now - t_open
+        elif self._t_first is None:
+            self._t_first = self._clock()
+        self._ready = getattr(handle, "is_ready", None)
+        self._next = k + 1
+
+    def observe(self) -> None:
+        if self._t_open is not None or self._ready is None or not self._ready():
+            return
+        annotate = self._tel.annotate
+        if annotate is not None:
+            self._ann = annotate(self._span, i=self._next)
+            self._ann.__enter__()
+        self._t_open = self._clock()
+
+    def publish(self) -> None:
+        if self._t_first is None:
+            return
+        now = self._clock()
+        empty = self._empty_ns + (0 if self._t_open is None else now - self._t_open)
+        self._tel.gauge(self._gauge, empty / max(now - self._t_first, 1))
+
+    def close(self) -> None:
+        """The loop is over: a stretch that no dispatch ended is dropped."""
+        self._end_stretch()
+        self._ready = None
+
+    def _end_stretch(self) -> Optional[int]:
+        t_open, self._t_open = self._t_open, None
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        return t_open
+
+
+class _NullOccupancy:
+    """``DeviceOccupancy`` with telemetry off: nothing is asked of any handle."""
+
+    __slots__ = ()
+
+    def enqueued(self, handle, k: int) -> None:
+        pass
+
+    def observe(self) -> None:
+        pass
+
+    def publish(self) -> None:
+        pass
+
+    def close(self) -> None:
+        pass
+
+
+NULL_OCCUPANCY = _NullOccupancy()
+
+
 def _telemetry_dir(config: Config) -> str:
     return config.telemetry_dir or os.path.join(config.summary_dir, "telemetry")
 
@@ -703,6 +808,7 @@ def train(
     # the load from the cache
     first_dispatch = tel.span("setup/first_dispatch")
     stall = StallWatch(tel) if tel.enabled else None
+    occupancy = DeviceOccupancy(tel, "train") if tel.enabled else NULL_OCCUPANCY
     import contextlib
 
     final_path: Optional[str] = None
@@ -725,6 +831,7 @@ def train(
             # final beat, which itself runs after the async writer drains —
             # the artifacts see the final step and the final checkpoint
             _stack.callback(_telemetry_finish, tel, config, "train")
+            _stack.callback(occupancy.close)
             if config.heartbeat_interval > 0:
                 from .telemetry.heartbeat import Heartbeat
 
@@ -879,6 +986,7 @@ def train(
                   # between the finer-grained guards still trips the
                   # 'step' deadline (deadlines_from_config docstring)
                   with wd.phase("step"):
+                    occupancy.observe()  # after train/data_wait
                     if config.max_steps and step >= config.max_steps:
                         stopped = True
                         break
@@ -901,6 +1009,7 @@ def train(
                             }
                         )
                         step_rng = jax.random.fold_in(root_rng, step)
+                    occupancy.observe()
                     if tel.enabled and not compile_probed:
                         # AOT cost/memory accounting BEFORE the first
                         # dispatch: lowering reads only avals (donated
@@ -918,6 +1027,7 @@ def train(
                         "dispatch"
                     ), first_dispatch:
                         state, metrics = train_step(state, placed, step_rng)
+                    occupancy.enqueued(state.step, step)
                     first_dispatch = telemetry.NULL_SPAN
                     prof.after_step(step, state)
                     done = step  # the step just dispatched: its spans' arg
@@ -934,8 +1044,10 @@ def train(
                                 k: float(v)  # sync-ok: the loop's ONE log-boundary fetch
                                 for k, v in jax.device_get(metrics).items()
                             }
+                        occupancy.observe()
                         # host IO of the boundary: nothing is queued on
                         # the device behind the sync above while it runs
+                        # (train/device_empty, opened just above, shows it)
                         with tel.span("train/log_io", done):
                             writer.scalars(step, host)
                             if tel.enabled:
@@ -949,6 +1061,7 @@ def train(
                                 for k, v in host.items():
                                     if k.startswith("diag/"):
                                         tel.gauge(k, v)
+                                occupancy.publish()
                                 exporters.append_jsonl(
                                     tel,
                                     os.path.join(
@@ -980,8 +1093,7 @@ def train(
                             # process 0 aggregates.  Black-box journal rides
                             # the same boundary — both are pure host IO.
                             if fleet_plane is not None:
-                                with tel.span("fleet/tick"):
-                                    fleet_plane.tick(step, gather_fn=_fleet_gather)
+                                fleet_plane.tick(step, gather_fn=_fleet_gather)
                             if bb is not None:
                                 bb.journal(step)
                             if sentinel.check(step, host) == "rollback":
@@ -1000,6 +1112,7 @@ def train(
                                     )
                                 rollback = True
                                 break
+                        occupancy.observe()
                     if (
                         config.var_summary_period
                         and step % config.var_summary_period == 0
@@ -1158,10 +1271,12 @@ def decode_dataset(
     tel: the telemetry the caller began for this run (see train); None
     begins it here, so every decode of a sweep starts fresh."""
     # host tracing over the decode loop: data_wait / dispatch / drain per
-    # batch (the drain of batch n overlaps batch n+1's device beam search
-    # — the breakdown shows whether the host decode keeps up)
+    # batch, and the stretches in which the device stood empty meanwhile
+    # (the drain of batch n was meant to overlap batch n+1's beam search;
+    # see the comment above ``prev`` for what it does)
     if tel is None:
         tel = _telemetry_begin(config)
+    occupancy = DeviceOccupancy(tel, "decode") if tel.enabled else NULL_OCCUPANCY
     variables: Dict[str, Any] = {"params": state.params}
     if state.batch_stats:
         variables["batch_stats"] = state.batch_stats
@@ -1286,6 +1401,7 @@ def decode_dataset(
         def run_batch(batch, b):
             with tel.span("decode/dispatch/encode", b), first_dispatch(b):
                 contexts = encode_fn(variables, batch["images"])
+            occupancy.enqueued(contexts, b)  # the device has work again
             beam_kwargs = dict(
                 beam_size=config.beam_size,
                 valid_size=len(vocabulary.words),
@@ -1315,9 +1431,12 @@ def decode_dataset(
     results: List[Dict[str, Any]] = []
     seen = set()
     emitted = 0
-    # depth-1 pipeline: dispatch batch n+1 to the device before fetching
-    # batch n's results, so host-side decode of words/captions overlaps
-    # device-side beam search (np.asarray is the sync point)
+    # meant as a depth-1 pipeline (dispatch batch n+1 before fetching batch
+    # n's results, so that the host-side decode of words/captions overlaps
+    # the device-side beam search); on the device it is depth 0: the drain's
+    # slices of batch n queue BEHIND batch n+1, np.asarray waits both out,
+    # and detokenise, decode/data_wait and the next dispatch then run with
+    # the device empty.  The decode/device_empty spans show it (ROADMAP A6).
     prev: Optional[Tuple[Any, List[str], int]] = None
 
     def drain(out, files, b):
@@ -1359,6 +1478,8 @@ def decode_dataset(
                 tel.gauge("decode/lm_moe_held_pair_share", float(pairs[0] / max(pairs[1], 1.0)))  # sync-ok: host numpy, already drained
                 fused, blocks = np.asarray(out.decoder_stats["prefill_fused_blocks"], np.float64)  # sync-ok: decode drain boundary
                 tel.gauge("decode/lm_dsa_prefill_fused_share", float(fused / max(blocks, 1.0)))  # sync-ok: host numpy, already drained
+        occupancy.observe()
+        occupancy.publish()
         with tel.span("decode/drain/detok", b):  # host work after it
             for i, image_file in enumerate(files):
                 if emitted >= dataset.count:           # fake_count padding
@@ -1385,6 +1506,7 @@ def decode_dataset(
                     ]
                     row["alphas"] = alphas[i, :length]    # [len, N]
                 results.append(row)
+        occupancy.observe()
 
     # profiler window over the decode loop — same knobs and semantics as
     # train's (shared ProfilerWindow), start clamped to the batch count so
@@ -1428,9 +1550,11 @@ def decode_dataset(
                     desc="decode",
                 )
             ):
+                occupancy.observe()  # after decode/data_wait
                 prof.before_step(b)
                 with tel.span("decode/dispatch", b):
                     out = run_batch(batch, b)      # async dispatch
+                occupancy.enqueued(out.words, b)
                 prof.after_step(b, out.words)
                 if prev is not None:
                     with tel.span("decode/drain", prev[2]):  # batch b-1
@@ -1447,6 +1571,7 @@ def decode_dataset(
             with tel.span("decode/drain", prev[2]):
                 drain(*prev)
     finally:
+        occupancy.close()
         if dec_bb is not None:
             from .telemetry import blackbox as _blackbox
 

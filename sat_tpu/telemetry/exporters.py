@@ -282,6 +282,16 @@ def step_breakdown(
         for name in nested
         if name in agg
     }
+    # the loop's own account of the device standing empty (runtime.py
+    # ``DeviceOccupancy``): it OVERLAPS the phases, so it is no phase
+    empty = step_span.split("/")[0] + "/device_empty"
+    if empty in agg:
+        empty_ns = agg[empty][1]
+        report["device_empty"] = {
+            **_stats(*agg[empty], tel.durations_ns(empty)),
+            "ms_per_step": round(empty_ns / steps / 1e6, 4) if steps else 0.0,
+            "share": round(empty_ns / wall_ns, 6) if wall_ns else 0.0,
+        }
     report["counters"] = tel.counters()
     return report
 
@@ -310,6 +320,12 @@ def format_breakdown(report: Dict) -> str:
         lines.append(
             f"  ({name}: nested)        {st['total_s']:9.3f}         "
             f"{fmt(st['p50_ms'])} {fmt(st['p95_ms'])} {fmt(st['max_ms'])}"
+        )
+    empty = report.get("device_empty")
+    if empty is not None:
+        lines.append(
+            f"  device known empty: {empty['ms_per_step']:.3f} ms a step, "
+            f"{100.0 * empty['share']:.1f}% (a lower bound; overlaps the phases)"
         )
     return "\n".join(lines)
 

@@ -531,30 +531,47 @@ def test_config_validates_telemetry_knobs():
 def test_step_loop_instrumentation_under_half_percent_of_a_step():
     """The telemetry calls runtime.train makes per step (data_wait, place
     and dispatch spans, the step gauge and record, each with the step as
-    its arg; log_sync and log_io spans every log_every steps) against a
-    live recorder: <= 0.5% of the train cell's device step."""
+    its arg; log_sync and log_io spans every log_every steps; the device's
+    occupancy asked at each boundary, at its dearest: a device that has
+    run dry at EVERY step, so a stretch a step) against a live recorder:
+    <= 0.5% of the train cell's device step."""
+    from sat_tpu.runtime import DeviceOccupancy
+
+    class Done:
+        @staticmethod
+        def is_ready():
+            return True
 
     def per_step_s(tel, iters, log_every=10):
+        occupancy = DeviceOccupancy(tel, "train")
         t0 = time.perf_counter()
         step_t0 = time.perf_counter_ns()
         for step in range(iters):
-            for name in ("train/data_wait", "train/place", "train/dispatch"):
+            for name in ("train/data_wait", "train/place"):
                 with tel.span(name, step):
                     pass
+                occupancy.observe()
+            with tel.span("train/dispatch", step):
+                pass
+            occupancy.enqueued(Done, step)
             tel.gauge("train/step", step)
             if step % log_every == 0:
                 with tel.span("train/log_sync", step):
                     pass
+                occupancy.observe()
                 with tel.span("train/log_io", step):
-                    pass
+                    occupancy.publish()
+                occupancy.observe()
             now = time.perf_counter_ns()
             tel.record("train/step", step_t0, now - step_t0, step)
             step_t0 = now
         return (time.perf_counter() - t0) / iters
 
     per_step_s(telemetry.enable(capacity=65536), 1000)  # warm
-    on = per_step_s(telemetry.enable(capacity=65536), 5000)
+    tel = telemetry.enable(capacity=65536)
+    on = per_step_s(tel, 5000)
     assert 1e3 * on <= 0.005 * LEDGER_TRAIN_STEP_MS
+    assert tel.aggregates()["train/device_empty"][0] == 4999
 
 
 # ---------------------------------------------------------------------------

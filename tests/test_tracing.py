@@ -155,6 +155,139 @@ def test_stall_watch_records_one_span_and_the_deltas():
 
 
 # ---------------------------------------------------------------------------
+# the loops' account of the device standing empty
+# ---------------------------------------------------------------------------
+
+
+class _Handle:
+    """What the recorder asks of a dispatched array: ``is_ready()``, counted."""
+
+    def __init__(self, ready=False):
+        self.ready, self.asked = ready, 0
+
+    def is_ready(self):
+        self.asked += 1
+        return self.ready
+
+
+class _Clock:
+    def __init__(self):
+        self.now = 1000
+
+    def __call__(self):
+        return self.now
+
+
+def _empty_spans(tel, family="decode"):
+    return _spans(tel).get(family + "/device_empty", [])
+
+
+def test_a_stretch_opens_only_on_a_ready_handle_and_closes_at_the_next_dispatch():
+    tel, clock = Telemetry(capacity=256), _Clock()
+    occ = runtime.DeviceOccupancy(tel, "decode", clock=clock)
+    occ.observe()                              # nothing enqueued yet: nothing to ask
+    busy = _Handle(ready=False)
+    occ.enqueued(busy, 4)
+    clock.now = 2000
+    occ.observe()
+    occ.observe()
+    assert busy.asked == 2 and _empty_spans(tel) == []
+    busy.ready = True                          # the runtime's word that batch 4 is finished
+    clock.now = 3000
+    occ.observe()
+    clock.now = 7500
+    nxt = _Handle()
+    occ.enqueued(nxt, 5)                       # dispatch 5 has returned
+    assert _empty_spans(tel) == [(3000, 4500, 5)]
+    # the handle that closed it is the one asked from now on
+    occ.observe()
+    assert (busy.asked, nxt.asked) == (3, 1) and len(_empty_spans(tel)) == 1
+
+
+def test_an_open_stretch_asks_nothing_and_never_opens_twice():
+    tel, clock = Telemetry(capacity=256), _Clock()
+    occ = runtime.DeviceOccupancy(tel, "train", clock=clock)
+    done = _Handle(ready=True)
+    occ.enqueued(done, 9)
+    occ.observe()
+    for clock.now in (1100, 1200, 1300):       # log_io, data_wait, place: one comparison each
+        occ.observe()
+    assert done.asked == 1
+    clock.now = 1400
+    occ.enqueued(_Handle(), 10)
+    assert _empty_spans(tel, "train") == [(1000, 400, 10)]      # from the FIRST observe, once
+    occ.enqueued(_Handle(), 11)                # no stretch open: a dispatch records nothing
+    assert len(_empty_spans(tel, "train")) == 1
+
+
+def test_the_stretch_is_annotated_by_hand_with_the_index_it_should_end_at():
+    _Annotation.log = []
+    tel = Telemetry(capacity=256)
+    tel.annotate = _Annotation
+    occ = runtime.DeviceOccupancy(tel, "decode")
+    occ.enqueued(_Handle(ready=True), 6)
+    assert _Annotation.log == []
+    occ.observe()
+    with tel.span("decode/drain/detok", 5):    # the annotation outlives the phases inside it
+        pass
+    assert _Annotation.log[0] == ("enter", "decode/device_empty", {"i": 7})
+    occ.enqueued(_Handle(ready=True), 7)
+    assert _Annotation.log[-1] == ("exit", "decode/device_empty", {"i": 7})
+    # the loop ends with a stretch open: its annotation is closed, nothing is recorded
+    occ.observe()
+    occ.close()
+    assert _Annotation.log[-2:] == [("enter", "decode/device_empty", {"i": 8}),
+                                    ("exit", "decode/device_empty", {"i": 8})]
+    assert [a for _, _, a in _empty_spans(tel)] == [7]
+    occ.observe()                              # closed: no handle is asked again
+    assert len(_Annotation.log) == 6
+
+
+def test_the_gauge_is_empty_time_over_time_since_the_first_dispatch():
+    tel, clock = Telemetry(capacity=256), _Clock()
+    occ = runtime.DeviceOccupancy(tel, "train", clock=clock)
+    occ.publish()                              # before any dispatch: no gauge
+    assert "train/device_empty_share" not in tel.gauges()
+    occ.enqueued(_Handle(ready=True), 0)       # first dispatch at 1000
+    clock.now = 1600
+    occ.observe()
+    clock.now = 1800
+    occ.enqueued(_Handle(ready=True), 1)       # 200 empty of 800
+    occ.publish()
+    assert tel.gauges()["train/device_empty_share"] == pytest.approx(0.25)
+    clock.now = 1900
+    occ.observe()
+    clock.now = 2000
+    occ.publish()                              # the open stretch counts as far as it has run
+    assert tel.gauges()["train/device_empty_share"] == pytest.approx(0.3)
+
+
+def test_telemetry_off_asks_no_handle_anything():
+    class Untouchable:
+        def is_ready(self):
+            raise AssertionError("is_ready() with telemetry off")
+
+    null = runtime.NULL_OCCUPANCY
+    null.enqueued(Untouchable(), 0)
+    null.observe()
+    null.publish()
+    null.close()
+    # and that is what both loops hold when telemetry is off
+    import inspect
+
+    source = inspect.getsource(runtime)
+    assert source.count("if tel.enabled else NULL_OCCUPANCY") == 2
+    assert source.count("DeviceOccupancy(tel, ") == 2
+    # a handle that cannot say (a host array from a stubbed program) is never asked
+    tel = Telemetry(capacity=256)
+    occ = runtime.DeviceOccupancy(tel, "decode")
+    occ.enqueued(np.zeros(3), 0)
+    occ.observe()
+    occ.enqueued(np.zeros(3), 1)
+    assert _empty_spans(tel) == []
+
+
+# ---------------------------------------------------------------------------
 # xla.py: op_scopes
 # ---------------------------------------------------------------------------
 
@@ -473,9 +606,12 @@ def _spans(tel):
 
 
 @pytest.fixture(scope="module")
-def traced_runs(coco_fixture, tmp_path_factory):
+def traced(coco_fixture, tmp_path_factory):
     """``--phase=train`` then ``--phase=eval`` through cli.main with
-    ``--telemetry``, as the benchmark's drivers start them."""
+    ``--telemetry``, as the benchmark's drivers start them: spans, gauges
+    and the saved breakdown of each."""
+    import types
+
     from sat_tpu import cli
 
     tmp = tmp_path_factory.mktemp("traced_runs")
@@ -487,13 +623,21 @@ def traced_runs(coco_fixture, tmp_path_factory):
     path = str(tmp / "config.json")
     config.save(path)
     assert cli.main(["--phase=train", "--config", path, "--telemetry"]) == 0
-    train = _spans(telemetry.get())
-    report = json.load(open(os.path.join(config.summary_dir, "telemetry", "breakdown.json")))
-    compile_report = json.load(open(os.path.join(config.summary_dir, "telemetry", "compile_report.json")))
+    tdir = os.path.join(config.summary_dir, "telemetry")
+    out = types.SimpleNamespace(train=_spans(telemetry.get()), train_gauges=telemetry.get().gauges(),
+                                report=json.load(open(os.path.join(tdir, "breakdown.json"))),
+                                compile_report=json.load(open(os.path.join(tdir, "compile_report.json"))),
+                                jsonl=[json.loads(line) for line in open(os.path.join(tdir, "telemetry.jsonl"))])
     assert cli.main(["--phase=eval", "--beam_size=2", "--config", path, "--telemetry"]) == 0
-    decode = _spans(telemetry.get())
+    out.decode, out.decode_gauges = _spans(telemetry.get()), telemetry.get().gauges()
+    out.decode_report = json.load(open(os.path.join(tdir, "breakdown-decode.json")))
     telemetry.disable()
-    return train, report, compile_report, decode
+    return out
+
+
+@pytest.fixture(scope="module")
+def traced_runs(traced):
+    return traced.train, traced.report, traced.compile_report, traced.decode
 
 
 def test_train_spans_carry_the_step(traced_runs):
@@ -567,6 +711,65 @@ def test_decode_spans_carry_the_batch_and_drain_names_the_one_before(traced_runs
     for (s, d, _), (es, ed, _), (bs, bd, _) in zip(decode["decode/dispatch"], decode["decode/dispatch/encode"],
                                                    decode["decode/dispatch/beam"]):
         assert s <= es and es + ed <= bs and bs + bd <= s + d
+
+
+def _ends(spans):
+    return {a: s + d for s, d, a in spans}
+
+
+def test_train_device_empty_opens_behind_every_log_sync_and_ends_at_the_next_dispatch(traced):
+    train = traced.train
+    empty = train["train/device_empty"]
+    dispatched, step_end = _ends(train["train/dispatch"]), _ends(train["train/step"])
+    for (s, d, a), nxt in zip(empty, empty[1:] + [None]):
+        assert d > 0 and s >= dispatched[a - 1]          # what it saw finished was enqueued before
+        assert dispatched[a] <= s + d <= step_end[a]     # closed when dispatch a had returned
+        assert nxt is None or s + d <= nxt[0]            # no two overlap
+    assert len({a for _, _, a in empty}) == len(empty)
+    # the loop's one sync: the step it waited for is finished, so a stretch opens between the sync's
+    # end and the IO behind it, and the next step's dispatch ends it
+    starts = {a: s for s, _, a in empty}
+    io = {a: s for s, _, a in train["train/log_io"]}
+    for k, sync_end in _ends(train["train/log_sync"]).items():
+        if k + 1 in dispatched:
+            assert sync_end <= starts[k + 1] <= io[k], k
+    # device_empty overlaps the phases: it is none of them, and the phases still sum to the step
+    assert "train/device_empty" not in runtime._TRAIN_PHASES + runtime._DECODE_PHASES
+    assert "train/device_empty" not in traced.report["phases"]
+
+
+def test_decode_device_empty_lies_between_a_drain_s_wait_and_the_next_encode(traced):
+    decode = traced.decode
+    empty = decode["decode/device_empty"]
+    assert empty                                         # three batches: the stretch batch 2's encode ended
+    waited, encoded = _ends(decode["decode/drain/wait"]), _ends(decode["decode/dispatch/encode"])
+    beam_start = {a: s for s, _, a in decode["decode/dispatch/beam"]}
+    for (s, d, a), nxt in zip(empty, empty[1:] + [None]):
+        # opened at the first boundary after dispatch a-1: behind the wait of drain a-2 (batch 1: no
+        # drain yet, behind its own data wait)
+        assert d > 0 and s >= (waited[a - 2] if a >= 2 else _ends(decode["decode/data_wait"])[1])
+        assert encoded[a] <= s + d <= beam_start[a]      # closed when encode a had returned
+        assert nxt is None or s + d <= nxt[0]
+    assert "decode/device_empty" not in traced.decode_report["phases"]
+
+
+def test_device_empty_share_reaches_the_gauges_the_jsonl_and_the_breakdown(traced):
+    assert 0.0 < traced.train_gauges["train/device_empty_share"] < 1.0
+    assert 0.0 <= traced.decode_gauges["decode/device_empty_share"] < 1.0
+    # set before the boundary's row is written: every row of telemetry.jsonl has it
+    assert traced.jsonl and all("train/device_empty_share" in row["gauges"] for row in traced.jsonl)
+    for report, span in ((traced.report, "train/device_empty"), (traced.decode_report, "decode/device_empty")):
+        entry = report["device_empty"]
+        assert entry["count"] == len(getattr(traced, span.split("/")[0])[span])
+        assert 0.0 < entry["share"] < 1.0 and entry["ms_per_step"] > 0.0
+        assert entry["total_s"] == pytest.approx(entry["share"] * report["wall_s"], rel=1e-2, abs=2e-6)
+        line = [ln for ln in exporters.format_breakdown(report).splitlines() if "device known empty" in ln]
+        assert len(line) == 1 and "ms a step" in line[0] and "%" in line[0]
+    # a run that recorded none has no such line
+    tel = Telemetry(capacity=256)
+    tel.record("train/step", 0, 100, 0)
+    bare = exporters.step_breakdown(tel, "train/step", runtime._TRAIN_PHASES)
+    assert "device_empty" not in bare and "device known empty" not in exporters.format_breakdown(bare)
 
 
 def test_second_decode_of_a_sweep_starts_fresh(coco_fixture, tmp_path):
