@@ -130,29 +130,81 @@ def init_params(rng: jax.Array, config: Config) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x [..., S, heads, d] float32, positions [S]: the interleaved
-    convention, the pair (x[2i], x[2i+1]) turned by ``pos * theta^(-2i/d)``.
+def _partner(x: jnp.ndarray) -> jnp.ndarray:
+    """The rope's signed swap along the last axis: ``[2i] <- -x[2i+1]``,
+    ``[2i+1] <- x[2i]``, so that a pair turns as ``x * cos + partner * sin``.
     Each element meets its partner by a roll along d, so no array has a
     minor dimension of 2 (a ``[..., d/2, 2]`` view of the pairs costs a
     768-row step a third of its attention on a v5e: PERF.md section 6)."""
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angle = jnp.repeat(positions.astype(jnp.float32)[:, None, None] * inv, 2, axis=-1)  # [S, 1, d]
-    even = jnp.arange(d) % 2 == 0
-    partner = jnp.where(even, -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
-    return x * jnp.cos(angle) + partner * jnp.sin(angle)
+    even = jnp.arange(x.shape[-1]) % 2 == 0
+    return jnp.where(even, -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
+
+
+def _rope_tables(positions: jnp.ndarray, theta: float, d: int, lead: int = 0):
+    """(cos, sin) float32 [S, lead + d] of the interleaved convention: the
+    pair (2i, 2i+1) of the last d lanes turns by ``pos * theta^(-2i/d)``;
+    1 and 0 on the ``lead`` lanes before them, which do not turn."""
+    inv = jnp.repeat(1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)), 2)
+    angle = positions.astype(jnp.float32)[:, None] * inv                            # [S, d]
+    still = ((0, 0), (lead, 0))
+    return (
+        jnp.pad(jnp.cos(angle), still, constant_values=1.0),
+        jnp.pad(jnp.sin(angle), still),
+    )
+
+
+def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """x [..., S, heads, d] float32, positions [S]: the interleaved
+    convention, the pair (x[2i], x[2i+1]) turned by ``pos * theta^(-2i/d)``,
+    its partner met by ``_partner``'s rolls.  The form of one token's rows
+    (the steps) and of the one rotary key; a whole sequence's queries take
+    ``_swapped_columns`` instead."""
+    cos, sin = _rope_tables(positions, theta, x.shape[-1])
+    return x * cos[:, None] + _partner(x) * sin[:, None]
+
+
+def _swapped_columns(w_rope: jnp.ndarray, lead: int = 0) -> jnp.ndarray:
+    """w_rope [..., d]: a map's rotary columns -> ``W_r P`` [..., lead + d],
+    the same columns swapped in pairs with one sign flipped (``_partner`` of
+    them) behind ``lead`` columns of zeros.  ``partner(u W_r) = u (W_r P)``:
+    the same dot products, so after the same rounding the same numbers, and
+    a product makes the rope's partner over whole lanes where a roll of the
+    product's 64-wide output would.  Made once a program from the weights
+    as loaded; no leaf of the tree."""
+    return jnp.pad(_partner(w_rope), ((0, 0),) * (w_rope.ndim - 1) + ((lead, 0),))
 
 
 def _queries(m: Params, config: Config, h: jnp.ndarray, positions: jnp.ndarray):
     """h [..., S, H] normed -> (q_nope [..., S, nh, nope], q_rope
-    [..., S, nh, rope] rotated), bfloat16."""
+    [..., S, nh, rope] rotated), bfloat16.  One product, the rotary part
+    split off and rolled: the form of a step's rows, where reading ``W_q``
+    bounds the time and more columns would cost more than the rope does."""
     c = config
     with jax.named_scope("decoder/lm/attn/q"):
         q = mm(h, m["q_proj"]).reshape(h.shape[:-1] + (c.num_attention_heads, _qk_dim(c)))
         q_nope, q_rope = q[..., : c.qk_nope_head_dim], q[..., c.qk_nope_head_dim:]
         q_rope = _rope(q_rope.astype(jnp.float32), positions, c.rope_theta)
         return q_nope, q_rope.astype(jnp.bfloat16)
+
+
+def _sequence_queries(m: Params, config: Config, h: jnp.ndarray, positions: jnp.ndarray):
+    """``_queries`` for whole sequences, the same numbers: ``W_q``'s nope
+    columns, its rotary columns and their signed swap as three products
+    over the heads' flat width, so the rotation is one multiply-add over
+    ``[.., nh * rope]`` (cos and sin tiled over the heads) and nothing is
+    split or rolled at a 64-wide minor dimension."""
+    c = config
+    nh, nope, rope = c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
+    with jax.named_scope("decoder/lm/attn/q"):
+        w = m["q_proj"].reshape(-1, nh, nope + rope)
+        flat = lambda a: a.reshape(a.shape[0], -1)  # noqa: E731
+        q_nope = mm(h, flat(w[..., :nope]))
+        q_rope = mm(h, flat(w[..., nope:])).astype(jnp.float32)
+        partner = mm(h, flat(_swapped_columns(w[..., nope:]))).astype(jnp.float32)
+        cos, sin = (jnp.tile(t, (1, nh)) for t in _rope_tables(positions, c.rope_theta, rope))
+        q_rope = (q_rope * cos + partner * sin).astype(jnp.bfloat16)
+        by_head = h.shape[:-1] + (nh, -1)
+        return q_nope.reshape(by_head), q_rope.reshape(by_head)
 
 
 def _latents(m: Params, config: Config, h: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
@@ -184,7 +236,7 @@ def attend_expanded(m: Params, config: Config, h: jnp.ndarray):
     B, S, _ = h.shape
     rank, nope = c.kv_lora_rank, c.qk_nope_head_dim
     positions = jnp.arange(S)
-    q_nope, q_rope = _queries(m, c, h, positions)
+    q_nope, q_rope = _sequence_queries(m, c, h, positions)
     latents = _latents(m, c, h, positions)
     with jax.named_scope("decoder/lm/attn/expand"):
         kv = jnp.einsum(

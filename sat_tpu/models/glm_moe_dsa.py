@@ -66,7 +66,7 @@ import jax.numpy as jnp
 
 from ..config import Config
 from . import lm_common
-from .deepseek_v3 import _head, _kv_b, _latents, _rope
+from .deepseek_v3 import _head, _kv_b, _latents, _rope, _rope_tables, _swapped_columns
 from .lm_common import HeldPairs, Params, layer_name, mm, rms_norm
 
 _QUERY_BLOCK = 512          # queries a block of a whole sequence's attention
@@ -170,27 +170,62 @@ def init_params(rng: jax.Array, config: Config) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def _queries(m: Params, config: Config, h: jnp.ndarray, positions: jnp.ndarray, by_head=False):
+def _turned_lanes(config: Config) -> int:
+    """The lanes at a head's end that a whole sequence's rope rewrites: the
+    rotary part widened to whole tiles of 128 lanes, or the head where it
+    is narrower than that (the tests' widths)."""
+    c = config
+    return min(c.qk_nope_head_dim + c.qk_rope_head_dim, -(-c.qk_rope_head_dim // 128) * 128)
+
+
+def _swapped_query_map(m: Params, config: Config) -> jnp.ndarray:
+    """``W_qb``'s rotary columns under the rope's signed swap,
+    [q_lora_rank, nh, turned lanes], zero on the nope lanes among them:
+    ``qr`` times it is the partner of ``q``'s rotary part, in place."""
+    c = config
+    w = m["q_b_proj"].reshape(c.q_lora_rank, c.num_attention_heads, -1)
+    return _swapped_columns(
+        w[..., c.qk_nope_head_dim:], lead=_turned_lanes(c) - c.qk_rope_head_dim
+    )
+
+
+def _queries(
+    m: Params, config: Config, h: jnp.ndarray, positions: jnp.ndarray, by_head=False,
+    swapped=None,
+):
     """h [..., S, H] normed -> (qr [..., S, q_lora_rank], the normed
     bottleneck the indexer reads too; q [..., S, nh, nope + rope], its rope
-    part rotated), bfloat16.  ``by_head`` (h [S, H], one sequence): q
-    [nh, S, nope + rope], each head's rows together, written so by the
-    product itself."""
+    part rotated), bfloat16.  As written: one product, the rotary part
+    split off, rolled and concatenated back; the form of a step's rows,
+    where reading ``W_qb`` bounds the time.  ``by_head`` (h [S, H], one
+    whole sequence): q [nh, S, nope + rope], each head's rows together,
+    written so by the product itself, and the same numbers with no roll
+    and nothing cut inside a tile of lanes: the rope's partner is a second
+    product, ``qr`` times ``swapped`` (``_swapped_query_map``; made here
+    where the caller has not made it once for all its sequences), and the
+    head's last ``_turned_lanes`` turn by one multiply-add, cos 1 and sin
+    0 on the nope lanes among them, written back over ``q`` in place."""
     c = config
-    nh, nope = c.num_attention_heads, c.qk_nope_head_dim
+    nh, nope, rope = c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
     with jax.named_scope("decoder/lm/attn/q"):
         qr = rms_norm(mm(h, m["q_a_proj"]), m["q_a_layernorm"], c.norm_eps).astype(jnp.bfloat16)
         if by_head:
-            q = jnp.einsum(
-                "sr,rhd->hsd", qr, m["q_b_proj"].reshape(c.q_lora_rank, nh, -1),
-                preferred_element_type=jnp.float32,
+            if swapped is None:
+                swapped = _swapped_query_map(m, c)
+            q, partner = (
+                jnp.einsum("sr,rhd->hsd", qr, w, preferred_element_type=jnp.float32)
+                .astype(jnp.bfloat16)
+                for w in (m["q_b_proj"].reshape(c.q_lora_rank, nh, -1), swapped)
+            )
+            turned = swapped.shape[-1]
+            still = nope + rope - turned
+            cos, sin = _rope_tables(positions, c.rope_theta, rope, lead=turned - rope)
+            q_turned = (
+                q[..., still:].astype(jnp.float32) * cos + partner.astype(jnp.float32) * sin
             ).astype(jnp.bfloat16)
-            q_rope = _rope(
-                q[..., None, nope:].astype(jnp.float32), positions, c.rope_theta
-            )[..., 0, :]
-        else:
-            q = mm(qr, m["q_b_proj"]).reshape(h.shape[:-1] + (nh, nope + c.qk_rope_head_dim))
-            q_rope = _rope(q[..., nope:].astype(jnp.float32), positions, c.rope_theta)
+            return qr, jax.lax.dynamic_update_slice(q, q_turned, (0, 0, still))
+        q = mm(qr, m["q_b_proj"]).reshape(h.shape[:-1] + (nh, nope + rope))
+        q_rope = _rope(q[..., nope:].astype(jnp.float32), positions, c.rope_theta)
         return qr, jnp.concatenate([q[..., :nope], q_rope.astype(jnp.bfloat16)], axis=-1)
 
 
@@ -297,7 +332,9 @@ def _one_mask(masks, S: int, k: int):
     return jnp.concatenate(selecting) if selecting else None
 
 
-def attend_sequence(m: Params, config: Config, h: jnp.ndarray, masks, fused: bool = False):
+def attend_sequence(
+    m: Params, config: Config, h: jnp.ndarray, masks, fused: bool = False, swapped=None,
+):
     """h [S, H] normed, ONE sequence at positions 0..S-1 -> (the
     attention's output [S, H], the latents [S, rank + rope], the indexer's
     keys [S, dI] or None, the blocks' masks).  ``masks``: None in a layer
@@ -305,12 +342,16 @@ def attend_sequence(m: Params, config: Config, h: jnp.ndarray, masks, fused: boo
     one [block, keys up to the block's end] a block of queries.  ``fused``:
     scores, mask, softmax and weighted sum in ``ops/flash_prefill.py``'s
     kernel (the same arithmetic with the scores in VMEM; no gradient), not
-    block by block in ``lax``."""
+    block by block in ``lax``.  ``swapped``: the layer's
+    ``_swapped_query_map`` where the caller made it once for all its
+    sequences; the queries come head-major with their rope's partner out
+    of a product (``_queries(by_head=True)``), for kernel and ``lax``
+    blocks alike."""
     c = config
     S, _ = h.shape
     rank, nope = c.kv_lora_rank, c.qk_nope_head_dim
     positions = jnp.arange(S)
-    qr, q = _queries(m, c, h, positions, by_head=True)
+    qr, q = _queries(m, c, h, positions, by_head=True, swapped=swapped)
     latents = _latents(m, c, h, positions)
     with jax.named_scope("decoder/lm/attn/expand"):
         # keys and values [nh, S, d] head-major, as q.  The rotary key all
@@ -381,10 +422,13 @@ def _sum_pairs(held) -> jnp.ndarray:
                       sum(h.over for h in held)]).astype(jnp.int32)
 
 
-def _one_sequence(lm: Params, config: Config, x: jnp.ndarray, tail: int, fused: bool = False):
+def _one_sequence(
+    lm: Params, config: Config, x: jnp.ndarray, tail: int, fused: bool = False, swapped=None,
+):
     """x [S, H] -> (hidden of the last ``tail`` positions, latents per
     layer, indexer keys per full layer, tokens per expert [moe layers, E],
-    experts chosen [S, moe layers * k], pairs [3])."""
+    experts chosen [S, moe layers * k], pairs [3]).  ``swapped``: every
+    layer's ``_swapped_query_map``, or None (each layer makes its own)."""
     c = config
     S = x.shape[0]
     latents, index_keys, counts, routes, held = [], [], [], [], []
@@ -394,7 +438,8 @@ def _one_sequence(lm: Params, config: Config, x: jnp.ndarray, tail: int, fused: 
         h = rms_norm(x, p["operator_norm"], c.norm_eps)
         full = c.indexer_types[i] == "full"
         y, kept, keys, masks = attend_sequence(
-            p["self_attn"], c, h, None if full else masks, fused
+            p["self_attn"], c, h, None if full else masks, fused,
+            swapped=None if swapped is None else swapped[i],
         )
         x = x + y
         latents.append(kept)
@@ -415,9 +460,16 @@ def sequence_forward(
     """x [B, S, H] bfloat16 -> ``_one_sequence``'s results, image by image:
     (hidden [B, tail, H], the sequences' state (a ``DsaCache`` of
     ``[B, S, ..]`` leaves), tokens per expert [moe layers, E], experts
-    chosen [B, S, moe layers * k], pairs [3])."""
+    chosen [B, S, moe layers * k], pairs [3]).  What depends on the
+    weights alone is made here, outside the loop over the images: the
+    layers' ``_swapped_query_map``."""
+    with jax.named_scope("decoder/lm/attn/q"):
+        swapped = tuple(
+            _swapped_query_map(lm["layers"][layer_name(i)]["self_attn"], config)
+            for i in range(config.num_hidden_layers)
+        )
     hidden, latents, index_keys, counts, routes, pairs = jax.lax.map(
-        lambda one: _one_sequence(lm, config, one, tail, fused), x
+        lambda one: _one_sequence(lm, config, one, tail, fused, swapped), x
     )
     return (
         hidden, DsaCache(latents, index_keys), jnp.sum(counts, axis=0), routes,

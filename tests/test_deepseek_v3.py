@@ -121,6 +121,91 @@ def test_rope_turns_interleaved_pairs_and_scores_as_the_reference_s_halves_layou
                                atol=1e-6)
 
 
+def _rope_rolled(x, positions, theta):
+    """``_rope`` as it stood before its partner and its tables got names of
+    their own (PR 38), kept here as the yardstick of "to the bit"."""
+    d = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angle = jnp.repeat(positions.astype(jnp.float32)[:, None, None] * inv, 2, axis=-1)
+    even = jnp.arange(d) % 2 == 0
+    partner = jnp.where(even, -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
+    return x * jnp.cos(angle) + partner * jnp.sin(angle)
+
+
+THETAS = pytest.mark.parametrize("theta", [1e6, 8e6], ids=["kanana2", "glm52"])
+
+
+@THETAS
+def test_rope_is_the_rolled_form_to_the_bit(theta):
+    """The steps' rope and the one rotary key: today's numbers."""
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 56, 3, 8))
+    got = jax.jit(lambda x: ds._rope(x, jnp.arange(56), theta))(x)
+    want = jax.jit(lambda x: _rope_rolled(x, jnp.arange(56), theta))(x)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    cos, sin = ds._rope_tables(jnp.arange(56), theta, 8, lead=5)
+    assert cos.shape == sin.shape == (56, 13)
+    assert (np.asarray(cos)[:, :5] == 1).all() and (np.asarray(sin)[:, :5] == 0).all()
+
+
+def test_the_swapped_columns_are_the_partner_of_the_rotary_columns(params):
+    """``W_r P``: ``W_r``'s own columns swapped in pairs, the one moved to
+    the even place negated, behind ``lead`` columns of zeros; and
+    ``u (W_r P)`` is the partner of ``u W_r`` (exactly: every product of two
+    bfloat16 numbers and every sum of 64 of them is exact in float64)."""
+    w = params["lm"]["layers"]["00"]["self_attn"]["q_proj"].reshape(64, 4, 24)[..., 16:]
+    got = np.asarray(ds._swapped_columns(w, lead=3), np.float64)
+    w = np.asarray(w, np.float64)
+    assert got.shape == (64, 4, 11) and (got[..., :3] == 0).all()
+    np.testing.assert_array_equal(got[..., 3::2], -w[..., 1::2])
+    np.testing.assert_array_equal(got[..., 4::2], w[..., 0::2])
+    np.testing.assert_array_equal(got[..., 3:], np.asarray(ds._partner(jnp.asarray(w, jnp.float32))))
+    u = np.asarray(jax.random.normal(jax.random.PRNGKey(5), (7, 64)).astype(jnp.bfloat16), np.float64)
+    product = np.einsum("sr,rhd->shd", u, w)
+    partner = np.stack([-product[..., 1::2], product[..., 0::2]], axis=-1).reshape(product.shape)
+    np.testing.assert_array_equal(np.einsum("sr,rhd->shd", u, got[..., 3:]), partner)
+
+
+@THETAS
+def test_a_whole_sequence_s_query_is_the_rolled_one_to_the_bit(params, theta):
+    """``_sequence_queries`` (three products over the flat width, the
+    partner out of the swapped columns) against ``_queries`` (one product,
+    split and rolled): the same dot products, the same roundings."""
+    config = dataclasses.replace(CONFIG, rope_theta=theta)
+    m = params["lm"]["layers"]["01"]["self_attn"]
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, 56, 64)).astype(jnp.bfloat16)
+    positions = jnp.arange(56)
+    got = jax.jit(lambda h: ds._sequence_queries(m, config, h, positions))(h)
+    want = jax.jit(lambda h: ds._queries(m, config, h, positions))(h)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype == jnp.bfloat16 and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g, np.float32), np.asarray(w, np.float32))
+
+
+def test_whole_sequences_take_the_swapped_columns_and_the_steps_the_roll(params, monkeypatch):
+    """The two forms part by call site: ``attend_expanded`` (teacher
+    forcing, the prefill) asks ``_sequence_queries``, ``attend_absorbed``
+    (a step's rows, bound by reading ``W_q``) asks ``_queries``."""
+    asked = []
+    for name in ("_queries", "_sequence_queries"):
+        def spy(*args, _name=name, _fn=getattr(ds, name)):
+            asked.append(_name)
+            return _fn(*args)
+        monkeypatch.setattr(ds, name, spy)
+    ctx, tokens = _inputs()
+    jax.eval_shape(lambda: ds.teacher_forced(params, CONFIG, ctx, tokens))
+    assert asked == ["_sequence_queries"] * 4
+    del asked[:]
+    prefix, counts, _ = jax.eval_shape(lambda: ds.prefill(params, CONFIG, ctx))
+    assert asked == ["_sequence_queries"] * 4
+    del asked[:]
+    zeros = lambda tree: jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, a.dtype), tree)  # noqa: E731
+    prefix = zeros(prefix)
+    cache = ds.start_beams(CONFIG, prefix, 1, 20, decoders.tile_beams)
+    jax.eval_shape(lambda: ds.step(params, CONFIG, prefix, cache, ds.init_counters(zeros(counts), 20),
+                                   jnp.zeros((2,), jnp.int32)))
+    assert asked == ["_queries"] * 4
+
+
 @pytest.mark.parametrize("layer,moe", [(0, False), (1, True)], ids=["mla+dense_ffn", "mla+experts+shared"])
 def test_each_layer_kind_against_the_reference(params, weights, layer, moe):
     """One layer of the program (through its whole-sequence, expanded path)

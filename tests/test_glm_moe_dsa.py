@@ -264,6 +264,158 @@ def test_without_its_rope_the_indexer_scores_otherwise(params, monkeypatch):
 # ---------------------------------------------------------------------------
 
 
+# ---------------------------------------------------------------------------
+# the query: whole sequences take the rope's partner out of a product
+# ---------------------------------------------------------------------------
+
+
+def test_the_lanes_a_whole_sequence_s_rope_rewrites():
+    """The rotary part widened to whole tiles of 128 lanes at the head's
+    end: the published head's second tile (its 64 rotary lanes behind 64
+    of nope), the whole head at the tests' widths."""
+    from test_aot_tpu import _glm52_config
+
+    assert dsa._turned_lanes(_glm52_config()) == 128
+    assert dsa._turned_lanes(CONFIG) == 24
+    swapped = dsa._swapped_query_map({"q_b_proj": jnp.ones((48, 4 * 24), jnp.bfloat16)}, CONFIG)
+    assert swapped.shape == (48, 4, 24) and not np.asarray(swapped[..., :16], np.float32).any()
+
+
+@pytest.mark.parametrize("lifted", [False, True], ids=["made_here", "made_by_the_caller"])
+@pytest.mark.parametrize("theta", [8e6, 1e6], ids=["glm52", "kanana2"])
+def test_the_folded_query_is_the_rolled_one_to_the_bit(params, theta, lifted):
+    """``_queries(by_head=True)``, head-major with the partner out of
+    ``qr W_r P``, against the step's form over the same rows (one product,
+    the rotary part split off, rolled and concatenated back): the same dot
+    products and the same roundings, so the same bfloat16 numbers."""
+    config = dataclasses.replace(CONFIG, rope_theta=theta)
+    m = params["lm"]["layers"]["02"]["self_attn"]
+    h = jax.random.normal(jax.random.PRNGKey(3), (56, 64)).astype(jnp.bfloat16)
+    positions = jnp.arange(56)
+    swapped = dsa._swapped_query_map(m, config) if lifted else None
+    qr, q = jax.jit(lambda h: dsa._queries(m, config, h, positions, by_head=True, swapped=swapped))(h)
+    want_qr, want = jax.jit(lambda h: dsa._queries(m, config, h, positions))(h)
+    assert q.shape == (4, 56, 24) and q.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(qr, np.float32), np.asarray(want_qr, np.float32))
+    np.testing.assert_array_equal(np.asarray(q, np.float32), np.asarray(want, np.float32).transpose(1, 0, 2))
+
+
+def test_the_step_s_query_is_the_one_product_rolled_as_before(params):
+    """A step's rows do not take the partner's product (reading ``W_qb``
+    bounds them): ``_queries`` without ``by_head`` is one product, split,
+    turned by ``deepseek_v3._rope``'s rolls and concatenated, to the bit."""
+    from test_deepseek_v3 import _rope_rolled
+
+    m = params["lm"]["layers"]["00"]["self_attn"]
+    h = jax.random.normal(jax.random.PRNGKey(4), (6, 1, 64)).astype(jnp.bfloat16)
+    position = jnp.array([N + 3])
+    qr, q = jax.jit(lambda h: dsa._queries(m, CONFIG, h, position))(h)
+
+    def before(h):
+        qr = lm_common.rms_norm(lm_common.mm(h, m["q_a_proj"]), m["q_a_layernorm"], CONFIG.norm_eps)
+        qr = qr.astype(jnp.bfloat16)
+        q = lm_common.mm(qr, m["q_b_proj"]).reshape(6, 1, 4, 24)
+        turned = _rope_rolled(q[..., 16:].astype(jnp.float32), position, CONFIG.rope_theta)
+        return qr, jnp.concatenate([q[..., :16], turned.astype(jnp.bfloat16)], axis=-1)
+
+    want_qr, want = jax.jit(before)(h)
+    np.testing.assert_array_equal(np.asarray(qr, np.float32), np.asarray(want_qr, np.float32))
+    np.testing.assert_array_equal(np.asarray(q, np.float32), np.asarray(want, np.float32))
+
+
+def _lane_faults(jaxpr, lanes=128):
+    """What the chip pays for in a query's jaxpr, stated where the CPU can
+    see it: a roll; a slice, concatenation or pad along the last axis of a
+    rank-3 array at an offset or width that is no multiple of ``lanes``; a
+    float32 rank-3 array narrower than ``lanes``."""
+    from test_deepseek_v3 import _all_eqns
+
+    faults = []
+    for e in _all_eqns(jaxpr):
+        name = e.primitive.name
+        if "roll" in name or "roll" in str(e.params.get("name", "")):
+            faults.append(f"roll: {e.params.get('name', name)}")
+        shapes = [v.aval.shape for v in list(e.invars) + list(e.outvars) if hasattr(v.aval, "shape")]
+        if name == "slice" and len(shapes[0]) == 3:
+            start, limit = e.params["start_indices"][-1], e.params["limit_indices"][-1]
+            if start % lanes or (limit - start) % lanes:
+                faults.append(f"slice [{start}:{limit}] of {shapes[0]}")
+        if name == "concatenate" and len(shapes[0]) == 3 and e.params["dimension"] == 2:
+            if any(shape[-1] % lanes for shape in shapes[:-1]):
+                faults.append(f"concatenate of {shapes[:-1]}")
+        if name == "dynamic_update_slice" and len(shapes[0]) == 3:
+            start = getattr(e.invars[-1], "val", None)      # a literal, or the fault is not knowing it
+            if start is None or int(start) % lanes or shapes[1][-1] % lanes:
+                faults.append(f"update of {shapes[0]} at {start} by {shapes[1]}")
+        if name == "pad" and len(shapes[0]) == 3:
+            low, high, _ = e.params["padding_config"][-1]
+            if low % lanes or high % lanes:
+                faults.append(f"pad {low, high} of {shapes[0]}")
+        for v in e.outvars:
+            aval = v.aval
+            if getattr(aval, "ndim", 0) == 3 and aval.dtype == jnp.float32 and aval.shape[-1] < lanes:
+                faults.append(f"float32{aval.shape} from {name}")
+    return faults
+
+
+@pytest.mark.parametrize("by_head", [True, False], ids=["folded", "rolled"])
+def test_the_prefill_s_query_cuts_no_tile_of_lanes_and_rolls_nothing(by_head):
+    """``_queries(by_head=True)`` at the published widths (``qr``
+    [4096, 2048], 64 heads of 192 + 64), traced and not run, its swapped
+    map handed in as ``sequence_forward`` hands it: no roll, nothing cut or
+    joined inside a tile of 128 lanes, no float32 array under 128 lanes.
+    The step's form over the same rows is the control: it has all three."""
+    from test_aot_tpu import _glm52_config
+
+    config = _glm52_config()
+    sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa: E731
+    m = {"q_a_proj": sd(6144, 2048), "q_a_layernorm": sd(2048), "q_b_proj": sd(2048, 64 * 256)}
+    positions = jnp.arange(4096)
+    if by_head:
+        jaxpr = jax.make_jaxpr(
+            lambda m, h, swapped: dsa._queries(m, config, h, positions, by_head=True, swapped=swapped)
+        )(m, sd(4096, 6144), sd(2048, 64, 128))
+        assert jaxpr.out_avals[1].shape == (64, 4096, 256)
+        assert not _lane_faults(jaxpr.jaxpr)
+    else:
+        jaxpr = jax.make_jaxpr(lambda m, h: dsa._queries(m, config, h, positions))(m, sd(4096, 6144))
+        faults = " ".join(_lane_faults(jaxpr.jaxpr))
+        assert "roll" in faults and "slice [192:256]" in faults and "float32(4096, 64, 64)" in faults, faults
+
+
+def test_whole_sequences_swap_once_a_layer_and_the_steps_never(params, monkeypatch):
+    """``sequence_forward`` makes every layer's swapped map ONCE, outside
+    its loop over the images, and hands it down (teacher forcing, whose
+    loss and gradient are held against the reference below, and the
+    prefill alike); a step makes none."""
+    made, handed = [], []
+    make, queries = dsa._swapped_query_map, dsa._queries
+
+    def swapped_query_map(m, config):
+        made.append(m)
+        return make(m, config)
+
+    def spy(m, config, h, positions, by_head=False, swapped=None):
+        handed.append((by_head, swapped))
+        return queries(m, config, h, positions, by_head, swapped)
+
+    monkeypatch.setattr(dsa, "_swapped_query_map", swapped_query_map)
+    monkeypatch.setattr(dsa, "_queries", spy)
+    ctx, tokens = _inputs()
+    jax.eval_shape(lambda: dsa.teacher_forced(params, CONFIG, ctx, tokens))
+    assert len(made) == 3 and len(handed) == 3 and all(b and s is not None for b, s in handed)
+    del made[:], handed[:]
+    prefix, counts, _ = jax.eval_shape(lambda: dsa.prefill(params, CONFIG, ctx))
+    assert len(made) == 3 and len(handed) == 3 and all(b and s is not None for b, s in handed)
+    del made[:], handed[:]
+    zeros = lambda tree: jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, a.dtype), tree)  # noqa: E731
+    prefix = zeros(prefix)
+    cache = dsa.start_beams(CONFIG, prefix, 1, 20, decoders.tile_beams)
+    jax.eval_shape(lambda: dsa.step(params, CONFIG, prefix, cache, dsa.init_counters(zeros(counts), 20),
+                                    jnp.zeros((2,), jnp.int32)))
+    assert not made and handed == [(False, None)] * 3
+
+
 @pytest.mark.parametrize("blocks", ["one_block", "blocks_of_8"])
 def test_teacher_forced_logits_against_the_plain_full_forward(params, weights, blocks, monkeypatch):
     if blocks == "blocks_of_8":
@@ -363,8 +515,8 @@ def test_a_shared_layer_attends_the_preceding_full_layer_s_choice(params, monkey
         seen.append((chosen, out[2], "indexer" in m))
         return out
 
-    def attend_sequence(m, config, h, masks, fused=False):
-        out = seq_(m, config, h, masks, fused)
+    def attend_sequence(m, config, h, masks, fused=False, swapped=None):
+        out = seq_(m, config, h, masks, fused, swapped)
         handed.append((masks, out[3]))
         return out
 
@@ -582,8 +734,8 @@ def _sequence_masks(params, x):
 def _masks_jit(params, x):
     seen, seq_ = [], dsa.attend_sequence
 
-    def attend_sequence(m, config, h, masks, fused=False):
-        out = seq_(m, config, h, masks, fused)
+    def attend_sequence(m, config, h, masks, fused=False, swapped=None):
+        out = seq_(m, config, h, masks, fused, swapped)
         if masks is None:
             S = h.shape[0]
             seen.append(jnp.concatenate([jnp.pad(b, ((0, 0), (0, S - b.shape[1]))) for b in out[3]]))
