@@ -52,6 +52,11 @@ class Config:
     # learned sparse attention on top: an indexer scores every earlier
     # position and the index_topk best are attended
     # (models/glm_moe_dsa.py; zai-org/GLM-5.2).
+    # "dots3_note": a stack that MIXES two kinds of that block, each at
+    # widths of its own: "full_attention" layers (the fields glm_moe_dsa
+    # reads, an indexer in every one) and "sliding_attention" layers (the
+    # swa_* fields, the last sliding_window_size positions, no indexer)
+    # (models/dots3_note.py; dots-studio/dots3-note-prev).
     decoder: str = "lstm"
     hidden_size: int = 2048
     intermediate_size: int = 7168          # dense SwiGLU of the leading layers
@@ -68,8 +73,9 @@ class Config:
     routed_scaling_factor: float = 1.0
     rope_theta: float = 1e6
     norm_eps: float = 1e-5
-    # one of "conv" / "full_attention" per layer (lfm2_moe), or
-    # "latent_attention" throughout (deepseek_v3); num_hidden_layers long
+    # one of "conv" / "full_attention" per layer (lfm2_moe), "full_attention"
+    # / "sliding_attention" (dots3_note), or "latent_attention" throughout
+    # (deepseek_v3, glm_moe_dsa); num_hidden_layers long
     layer_types: Tuple[str, ...] = (
         "conv", "conv", "full_attention", "conv", "conv", "conv",
         "full_attention", "conv", "conv", "conv", "full_attention", "conv",
@@ -97,6 +103,24 @@ class Config:
     index_head_dim: int = 128
     index_topk: int = 2048
     indexer_types: Tuple[str, ...] = ()
+    # window layers beside full ones (dots3_note only), named as in the
+    # source: a "sliding_attention" layer is latent attention at these
+    # widths and this rope base over the query's own position and the
+    # sliding_window_size - 1 before it
+    swa_num_attention_heads: int = 64
+    swa_q_lora_rank: int = 1024
+    swa_kv_lora_rank: int = 1024
+    swa_qk_nope_head_dim: int = 192
+    swa_qk_rope_head_dim: int = 64
+    swa_v_head_dim: int = 128
+    swa_rope_theta: float = 5e4
+    sliding_window_size: int = 513
+    # "headwise": one sigmoid gate a head on the attention's output, before
+    # o_proj (the source's attention_gate_type, both kinds of layer)
+    attention_gate: str = "none"
+    # the source's apply_mla_qkv_lora_rescale: the normed query bottleneck
+    # and the normed latent times sqrt(hidden_size / their rank)
+    mla_lora_rescale: bool = False
     # the share of an expert layer this chip holds (expert parallelism):
     # experts [first_expert, first_expert + experts_held) of num_experts;
     # the router still scores all num_experts.  0 = all of them
@@ -574,7 +598,8 @@ class Config:
         same, /root/reference/model.py:16-21)."""
         checks = (
             ("cnn", ("vgg16", "resnet50")),
-            ("decoder", ("lstm", "lfm2_moe", "deepseek_v3", "glm_moe_dsa")),
+            ("decoder", ("lstm", "lfm2_moe", "deepseek_v3", "glm_moe_dsa", "dots3_note")),
+            ("attention_gate", ("none", "headwise")),
             ("phase", ("train", "eval", "test", "serve", "route", "bulk")),
             ("optimizer", ("Adam", "RMSProp", "Momentum", "SGD")),
             ("num_initialize_layers", (1, 2)),
@@ -834,10 +859,10 @@ class Config:
     def _check_lm(self) -> None:
         """A language-model decoder: the stack's fields agree, and what
         these decoders cannot run yet is refused by name (ROADMAP B7-B9)."""
-        kinds = (
-            ("conv", "full_attention") if self.decoder == "lfm2_moe"
-            else ("latent_attention",)
-        )
+        kinds = {
+            "lfm2_moe": ("conv", "full_attention"),
+            "dots3_note": ("full_attention", "sliding_attention"),
+        }.get(self.decoder, ("latent_attention",))
         if len(self.layer_types) != self.num_hidden_layers or any(
             k not in kinds for k in self.layer_types
         ):
@@ -867,19 +892,46 @@ class Config:
                 "Config: qk_rope_head_dim must be even (rotary pairs) and "
                 "n_shared_experts not negative"
             )
+        if self.decoder == "dots3_note":
+            # every full layer computes its own selection: there is no list
+            if self.indexer_types:
+                raise ValueError(
+                    'Config.indexer_types: decoder="dots3_note" has an indexer in '
+                    "every full_attention layer and none elsewhere; leave it empty"
+                )
+            if self.swa_qk_rope_head_dim % 2 or min(
+                self.sliding_window_size, self.swa_num_attention_heads,
+                self.swa_q_lora_rank, self.swa_kv_lora_rank,
+            ) < 1:
+                raise ValueError(
+                    "Config: swa_qk_rope_head_dim must be even (rotary pairs); "
+                    "sliding_window_size, swa_num_attention_heads, swa_q_lora_rank "
+                    "and swa_kv_lora_rank at least 1"
+                )
+        elif self.attention_gate != "none" or self.mla_lora_rescale:
+            # no other stack reads them: refused, not silently left out
+            raise ValueError(
+                "Config.attention_gate / mla_lora_rescale: only "
+                f'decoder="dots3_note" has them; decoder="{self.decoder}" '
+                'takes attention_gate="none" and mla_lora_rescale=False'
+            )
         if self.decoder == "glm_moe_dsa" and (
             len(self.indexer_types) != self.num_hidden_layers
             or any(k not in ("full", "shared") for k in self.indexer_types)
             or self.indexer_types[0] != "full"
-            or self.index_head_dim < self.qk_rope_head_dim
-            or min(self.q_lora_rank, self.index_n_heads, self.index_topk) < 1
         ):
             raise ValueError(
                 f"Config.indexer_types: {self.num_hidden_layers} entries "
                 '(num_hidden_layers), each "full" or "shared", the first '
-                f'"full"; got {self.indexer_types!r}; q_lora_rank, '
-                "index_n_heads and index_topk at least 1, index_head_dim "
-                "no less than qk_rope_head_dim (the rotary part)"
+                f'"full"; got {self.indexer_types!r}'
+            )
+        if self.decoder in ("glm_moe_dsa", "dots3_note") and (
+            self.index_head_dim < self.qk_rope_head_dim
+            or min(self.q_lora_rank, self.index_n_heads, self.index_topk) < 1
+        ):
+            raise ValueError(
+                "Config: q_lora_rank, index_n_heads and index_topk at least 1, "
+                "index_head_dim no less than qk_rope_head_dim (the rotary part)"
             )
         if self.experts_held < 0 or self.experts_held and not (
             0 <= self.first_expert

@@ -20,7 +20,8 @@ exist (``Config.decoder``) and what each gives the rest of the program.
 
 The language-model decoders (``lfm2_moe``: convs and grouped-query
 attention; ``deepseek_v3``: latent attention; ``glm_moe_dsa``: latent
-attention over positions an indexer chooses) are a module each with one
+attention over positions an indexer chooses; ``dots3_note``: such layers
+beside window layers at widths of their own) are a module each with one
 set of entry points, and share one search (``_lm_search``): what differs
 between them is the KIND of leaf their caches hold, which the search never
 looks at.
@@ -36,7 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import Config
-from . import deepseek_v3, glm_moe_dsa, lfm2
+from . import deepseek_v3, dots3_note, glm_moe_dsa, lfm2
 from .decoder import (
     DecoderState,
     decoder_step,
@@ -50,7 +51,10 @@ Params = Dict[str, Any]
 
 # the language-model decoders: a module each, with one set of entry points
 # (init_params, teacher_forced, prefill, start_beams, step)
-_LM = {"lfm2_moe": lfm2, "deepseek_v3": deepseek_v3, "glm_moe_dsa": glm_moe_dsa}
+_LM = {
+    "lfm2_moe": lfm2, "deepseek_v3": deepseek_v3, "glm_moe_dsa": glm_moe_dsa,
+    "dots3_note": dots3_note,
+}
 
 
 class StepState(NamedTuple):
@@ -145,7 +149,7 @@ def search(
         return _lm_search(
             _LM[config.decoder], params, config, contexts, K, T,
             # the latent cache's reason to be is its size: it reports it
-            report_state=config.decoder in ("deepseek_v3", "glm_moe_dsa"),
+            report_state=config.decoder != "lfm2_moe",
         )
 
     # the grid and the hoisted context half of the attention MLP stay per
@@ -211,7 +215,7 @@ def _lm_search(
             # the steps close over per image + the per-beam tree
             stats["state_bytes"] = jnp.float32(_tree_bytes(prefix) + _tree_bytes(state.beam))
         if hasattr(lm, "report"):       # what the stack itself counts besides
-            stats.update(lm.report(config, state, B, K, T))
+            stats.update(lm.report(config, prefix, state, B, K, T))
         return result._replace(decoder_stats=stats)
 
     return Search(step_fn, state0, 0, finish)

@@ -51,6 +51,7 @@ per beam a suffix ``[B*K, T, 576]`` a layer and the record of routes, which
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Any, NamedTuple, Tuple
 
@@ -82,6 +83,55 @@ class LatentCache(NamedTuple):
 
 def _qk_dim(config: Config) -> int:
     return config.qk_nope_head_dim + config.qk_rope_head_dim
+
+
+ATTN_SCOPE = "decoder/lm/attn"
+
+
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class Widths:
+    """One KIND of layer's latent attention as numbers: what the layer
+    functions below read, so that a stack whose layers differ in heads,
+    ranks, head widths and rope base (``models/dots3_note.py``) calls them
+    with one record a kind.  A stack of one kind makes its own from its
+    ``Config`` (``widths``)."""
+
+    heads: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+    theta: float
+    eps: float
+    # what multiplies the normed latent (a source's
+    # ``apply_mla_qkv_lora_rescale``: sqrt(hidden / rank)); 1: nothing does
+    kv_scale: float = 1.0
+    # a second kind of layer in one stack names itself here ("window"):
+    # its device ops are accounted under ``decoder/lm/attn/<segment>/``
+    segment: str = ""
+
+    @property
+    def qk(self) -> int:
+        return self.nope + self.rope
+
+    def named_scope(self, name: str):
+        """``jax.named_scope(name)``: every call site writes the scope a
+        layer of the first kind is accounted under, in full (the rule
+        files under ``benchmark/scopes`` are held against those literals);
+        a record with a ``segment`` puts it behind ``decoder/lm/attn``, so
+        ``.../attn/q`` becomes ``.../attn/window/q`` and no rule written
+        for the one kind takes the other's ops."""
+        if self.segment:
+            name = name.replace(ATTN_SCOPE, ATTN_SCOPE + "/" + self.segment, 1)
+        return jax.named_scope(name)
+
+
+def widths(config: Config) -> Widths:
+    c = config
+    return Widths(
+        heads=c.num_attention_heads, kv_rank=c.kv_lora_rank, nope=c.qk_nope_head_dim,
+        rope=c.qk_rope_head_dim, v=c.v_head_dim, theta=c.rope_theta, eps=c.norm_eps,
+    )
 
 
 def init_params(rng: jax.Array, config: Config) -> Params:
@@ -174,67 +224,64 @@ def _swapped_columns(w_rope: jnp.ndarray, lead: int = 0) -> jnp.ndarray:
     return jnp.pad(_partner(w_rope), ((0, 0),) * (w_rope.ndim - 1) + ((lead, 0),))
 
 
-def _queries(m: Params, config: Config, h: jnp.ndarray, positions: jnp.ndarray):
+def _queries(m: Params, w: Widths, h: jnp.ndarray, positions: jnp.ndarray):
     """h [..., S, H] normed -> (q_nope [..., S, nh, nope], q_rope
     [..., S, nh, rope] rotated), bfloat16.  One product, the rotary part
     split off and rolled: the form of a step's rows, where reading ``W_q``
     bounds the time and more columns would cost more than the rope does."""
-    c = config
-    with jax.named_scope("decoder/lm/attn/q"):
-        q = mm(h, m["q_proj"]).reshape(h.shape[:-1] + (c.num_attention_heads, _qk_dim(c)))
-        q_nope, q_rope = q[..., : c.qk_nope_head_dim], q[..., c.qk_nope_head_dim:]
-        q_rope = _rope(q_rope.astype(jnp.float32), positions, c.rope_theta)
+    with w.named_scope("decoder/lm/attn/q"):
+        q = mm(h, m["q_proj"]).reshape(h.shape[:-1] + (w.heads, w.qk))
+        q_nope, q_rope = q[..., : w.nope], q[..., w.nope:]
+        q_rope = _rope(q_rope.astype(jnp.float32), positions, w.theta)
         return q_nope, q_rope.astype(jnp.bfloat16)
 
 
-def _sequence_queries(m: Params, config: Config, h: jnp.ndarray, positions: jnp.ndarray):
+def _sequence_queries(m: Params, w: Widths, h: jnp.ndarray, positions: jnp.ndarray):
     """``_queries`` for whole sequences, the same numbers: ``W_q``'s nope
     columns, its rotary columns and their signed swap as three products
     over the heads' flat width, so the rotation is one multiply-add over
     ``[.., nh * rope]`` (cos and sin tiled over the heads) and nothing is
     split or rolled at a 64-wide minor dimension."""
-    c = config
-    nh, nope, rope = c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
-    with jax.named_scope("decoder/lm/attn/q"):
-        w = m["q_proj"].reshape(-1, nh, nope + rope)
+    nh, nope, rope = w.heads, w.nope, w.rope
+    with w.named_scope("decoder/lm/attn/q"):
+        w_q = m["q_proj"].reshape(-1, nh, nope + rope)
         flat = lambda a: a.reshape(a.shape[0], -1)  # noqa: E731
-        q_nope = mm(h, flat(w[..., :nope]))
-        q_rope = mm(h, flat(w[..., nope:])).astype(jnp.float32)
-        partner = mm(h, flat(_swapped_columns(w[..., nope:]))).astype(jnp.float32)
-        cos, sin = (jnp.tile(t, (1, nh)) for t in _rope_tables(positions, c.rope_theta, rope))
+        q_nope = mm(h, flat(w_q[..., :nope]))
+        q_rope = mm(h, flat(w_q[..., nope:])).astype(jnp.float32)
+        partner = mm(h, flat(_swapped_columns(w_q[..., nope:]))).astype(jnp.float32)
+        cos, sin = (jnp.tile(t, (1, nh)) for t in _rope_tables(positions, w.theta, rope))
         q_rope = (q_rope * cos + partner * sin).astype(jnp.bfloat16)
         by_head = h.shape[:-1] + (nh, -1)
         return q_nope.reshape(by_head), q_rope.reshape(by_head)
 
 
-def _latents(m: Params, config: Config, h: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
+def _latents(m: Params, w: Widths, h: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
     """h [..., S, H] normed -> ``[c ; k_rope]`` [..., S, rank + rope]
-    bfloat16: the normed latent and the rotated key all heads share."""
-    c = config
-    with jax.named_scope("decoder/lm/attn/latent"):
+    bfloat16: the normed latent (times ``kv_scale`` where the kind has
+    one) and the rotated key all heads share."""
+    with w.named_scope("decoder/lm/attn/latent"):
         raw = mm(h, m["kv_a_proj"])
-        latent = rms_norm(raw[..., : c.kv_lora_rank], m["kv_a_layernorm"], c.norm_eps)
+        latent = rms_norm(raw[..., : w.kv_rank], m["kv_a_layernorm"], w.eps)
+        if w.kv_scale != 1.0:
+            latent = latent * w.kv_scale
         k_rope = _rope(
-            raw[..., None, c.kv_lora_rank:].astype(jnp.float32), positions, c.rope_theta
+            raw[..., None, w.kv_rank:].astype(jnp.float32), positions, w.theta
         )[..., 0, :]
         return jnp.concatenate([latent, k_rope], axis=-1).astype(jnp.bfloat16)
 
 
-def _kv_b(m: Params, config: Config) -> jnp.ndarray:
+def _kv_b(m: Params, w: Widths) -> jnp.ndarray:
     """``W_kvb`` [rank, nh, nope + v]: per head its key map then its value map."""
-    c = config
-    return m["kv_b_proj"].reshape(
-        c.kv_lora_rank, c.num_attention_heads, c.qk_nope_head_dim + c.v_head_dim
-    )
+    return m["kv_b_proj"].reshape(w.kv_rank, w.heads, w.nope + w.v)
 
 
 def attend_expanded(m: Params, config: Config, h: jnp.ndarray):
     """h [B, S, H] normed, at positions 0..S-1 -> (the attention's output
     [B, S, H], the latents [B, S, rank + rope]): keys and values made from
     the latents, causal."""
-    c = config
+    c = widths(config)
     B, S, _ = h.shape
-    rank, nope = c.kv_lora_rank, c.qk_nope_head_dim
+    rank, nope = c.kv_rank, c.nope
     positions = jnp.arange(S)
     q_nope, q_rope = _sequence_queries(m, c, h, positions)
     latents = _latents(m, c, h, positions)
@@ -251,7 +298,7 @@ def attend_expanded(m: Params, config: Config, h: jnp.ndarray):
             "bshd,btd->bhst", q_rope, latents[..., rank:], preferred_element_type=jnp.float32
         )
         causal = positions[:, None] >= positions[None, :]
-        scores = jnp.where(causal, scores * (_qk_dim(c) ** -0.5), -jnp.inf)
+        scores = jnp.where(causal, scores * (c.qk ** -0.5), -jnp.inf)
         # the softmax's division after the weighted sum, over [B, S, nh, v]
         # and not over the [B, nh, S, S] weights: the same float32 sum
         weights = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
@@ -274,11 +321,11 @@ def attend_absorbed(
     row's own, written at t here.  Returns (the attention's output [R, H],
     the suffix with this token's latent in).  One softmax across prefix
     and suffix; neither is expanded."""
-    c = config
+    c = widths(config)
     R = h.shape[0]
     B, N, _ = prefix.shape
     K, T = R // B, suffix.shape[1]
-    nh, rank, nope = c.num_attention_heads, c.kv_lora_rank, c.qk_nope_head_dim
+    nh, rank, nope = c.heads, c.kv_rank, c.nope
     position = (N + t)[None]
     q_nope, q_rope = _queries(m, c, h[:, None], position)
     suffix = jax.lax.dynamic_update_slice(
@@ -298,7 +345,7 @@ def attend_absorbed(
         s_suf = jnp.einsum("rhc,rtc->rht", q, suffix, preferred_element_type=jnp.float32)
         s_suf = jnp.where(jnp.arange(T) <= t, s_suf, -jnp.inf)
         probs = jax.nn.softmax(
-            jnp.concatenate([s_pre, s_suf], axis=-1) * (_qk_dim(c) ** -0.5), axis=-1
+            jnp.concatenate([s_pre, s_suf], axis=-1) * (c.qk ** -0.5), axis=-1
         ).astype(jnp.bfloat16)
         mixed = jnp.einsum(
             "bkhn,bnc->bkhc", probs[..., :N].reshape(B, K, nh, N), prefix[..., :rank],

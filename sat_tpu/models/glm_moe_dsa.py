@@ -59,13 +59,14 @@ one step and are not carried across steps.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..config import Config
-from . import lm_common
+from . import deepseek_v3, lm_common
 from .deepseek_v3 import _head, _kv_b, _latents, _rope, _rope_tables, _swapped_columns
 from .lm_common import HeldPairs, Params, layer_name, mm, rms_norm
 
@@ -101,6 +102,30 @@ class DsaCounters(NamedTuple):
     fused: jnp.ndarray      # [2] int32: the prefill's query blocks through the fused kernel, in all
 
 
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class Widths(deepseek_v3.Widths):
+    """``deepseek_v3.Widths`` and what this block's attention reads
+    besides: the compressed query's bottleneck and, in a layer with an
+    indexer, the indexer's numbers."""
+
+    q_rank: int
+    # what multiplies the normed bottleneck, as ``kv_scale`` the latent.
+    # The indexer reads the bottleneck AFTER it: a positive constant times
+    # a row of I[t, .] leaves the row's order, and so the selection, alone
+    q_scale: float = 1.0
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+
+
+def widths(config: Config) -> Widths:
+    c = config
+    return Widths(
+        **dataclasses.asdict(deepseek_v3.widths(c)), q_rank=c.q_lora_rank,
+        index_heads=c.index_n_heads, index_dim=c.index_head_dim, index_topk=c.index_topk,
+    )
+
+
 def _full_layers(config: Config):
     return [i for i, kind in enumerate(config.indexer_types) if kind == "full"]
 
@@ -115,14 +140,18 @@ def _chosen_width(config: Config, max_len: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-def init_params(rng: jax.Array, config: Config) -> Params:
+def init_params(rng: jax.Array, config: Config, layer_attention=None) -> Params:
     """{'connector': float32 (it trains), 'lm': the stack, bfloat16 but
     for ``expert_bias`` (a float32 buffer)}: ``deepseek_v3.init_params``'s
-    draws over this stack's leaves."""
+    draws over this stack's leaves.  ``layer_attention(layer)`` -> (the
+    layer's ``Widths``, whether it has an indexer, whether it has a
+    headwise gate), for a stack whose layers differ; None: this one's."""
     c = config
-    H, nh, rank = c.hidden_size, c.num_attention_heads, c.kv_lora_rank
+    H = c.hidden_size
     bf16 = jnp.bfloat16
     keys = iter(jax.random.split(rng, 20 * c.num_hidden_layers + 4))
+    if layer_attention is None:
+        layer_attention = lambda i: (widths(c), c.indexer_types[i] == "full", False)  # noqa: E731
 
     def linear(*shape):
         return (0.02 * jax.random.normal(next(keys), shape, jnp.float32)).astype(bf16)
@@ -130,24 +159,27 @@ def init_params(rng: jax.Array, config: Config) -> Params:
     ones = lambda n: jnp.ones((n,), bf16)  # noqa: E731
     layers: Params = {}
     for i in range(c.num_hidden_layers):
+        w, indexer, gate = layer_attention(i)
         p: Params = {"operator_norm": ones(H), "ffn_norm": ones(H)}
         p["self_attn"] = {
-            "q_a_proj": linear(H, c.q_lora_rank),
-            "q_a_layernorm": ones(c.q_lora_rank),
-            "q_b_proj": linear(c.q_lora_rank, nh * (c.qk_nope_head_dim + c.qk_rope_head_dim)),
-            "kv_a_proj": linear(H, rank + c.qk_rope_head_dim),
-            "kv_a_layernorm": ones(rank),
-            "kv_b_proj": linear(rank, nh * (c.qk_nope_head_dim + c.v_head_dim)),
-            "o_proj": linear(nh * c.v_head_dim, H),
+            "q_a_proj": linear(H, w.q_rank),
+            "q_a_layernorm": ones(w.q_rank),
+            "q_b_proj": linear(w.q_rank, w.heads * w.qk),
+            "kv_a_proj": linear(H, w.kv_rank + w.rope),
+            "kv_a_layernorm": ones(w.kv_rank),
+            "kv_b_proj": linear(w.kv_rank, w.heads * (w.nope + w.v)),
+            "o_proj": linear(w.heads * w.v, H),
         }
-        if c.indexer_types[i] == "full":
+        if indexer:
             p["self_attn"]["indexer"] = {
-                "wq_b": linear(c.q_lora_rank, c.index_n_heads * c.index_head_dim),
-                "wk": linear(H, c.index_head_dim),
-                "k_norm_weight": ones(c.index_head_dim),
-                "k_norm_bias": jnp.zeros((c.index_head_dim,), bf16),
-                "weights_proj": linear(H, c.index_n_heads),
+                "wq_b": linear(w.q_rank, w.index_heads * w.index_dim),
+                "wk": linear(H, w.index_dim),
+                "k_norm_weight": ones(w.index_dim),
+                "k_norm_bias": jnp.zeros((w.index_dim,), bf16),
+                "weights_proj": linear(H, w.index_heads),
             }
+        if gate:
+            p["self_attn"]["gate_proj"] = linear(H, w.heads)
         p["feed_forward"] = lm_common.ffn_params(c, i, linear)
         if lm_common.is_moe(c, i) and c.n_shared_experts:
             I = c.n_shared_experts * c.moe_intermediate_size
@@ -170,27 +202,23 @@ def init_params(rng: jax.Array, config: Config) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def _turned_lanes(config: Config) -> int:
+def _turned_lanes(w: Widths) -> int:
     """The lanes at a head's end that a whole sequence's rope rewrites: the
     rotary part widened to whole tiles of 128 lanes, or the head where it
     is narrower than that (the tests' widths)."""
-    c = config
-    return min(c.qk_nope_head_dim + c.qk_rope_head_dim, -(-c.qk_rope_head_dim // 128) * 128)
+    return min(w.qk, -(-w.rope // 128) * 128)
 
 
-def _swapped_query_map(m: Params, config: Config) -> jnp.ndarray:
+def _swapped_query_map(m: Params, w: Widths) -> jnp.ndarray:
     """``W_qb``'s rotary columns under the rope's signed swap,
     [q_lora_rank, nh, turned lanes], zero on the nope lanes among them:
     ``qr`` times it is the partner of ``q``'s rotary part, in place."""
-    c = config
-    w = m["q_b_proj"].reshape(c.q_lora_rank, c.num_attention_heads, -1)
-    return _swapped_columns(
-        w[..., c.qk_nope_head_dim:], lead=_turned_lanes(c) - c.qk_rope_head_dim
-    )
+    w_qb = m["q_b_proj"].reshape(w.q_rank, w.heads, -1)
+    return _swapped_columns(w_qb[..., w.nope:], lead=_turned_lanes(w) - w.rope)
 
 
 def _queries(
-    m: Params, config: Config, h: jnp.ndarray, positions: jnp.ndarray, by_head=False,
+    m: Params, w: Widths, h: jnp.ndarray, positions: jnp.ndarray, by_head=False,
     swapped=None,
 ):
     """h [..., S, H] normed -> (qr [..., S, q_lora_rank], the normed
@@ -205,44 +233,45 @@ def _queries(
     where the caller has not made it once for all its sequences), and the
     head's last ``_turned_lanes`` turn by one multiply-add, cos 1 and sin
     0 on the nope lanes among them, written back over ``q`` in place."""
-    c = config
-    nh, nope, rope = c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
-    with jax.named_scope("decoder/lm/attn/q"):
-        qr = rms_norm(mm(h, m["q_a_proj"]), m["q_a_layernorm"], c.norm_eps).astype(jnp.bfloat16)
+    nh, nope, rope = w.heads, w.nope, w.rope
+    with w.named_scope("decoder/lm/attn/q"):
+        qr = rms_norm(mm(h, m["q_a_proj"]), m["q_a_layernorm"], w.eps)
+        if w.q_scale != 1.0:
+            qr = qr * w.q_scale
+        qr = qr.astype(jnp.bfloat16)
         if by_head:
             if swapped is None:
-                swapped = _swapped_query_map(m, c)
+                swapped = _swapped_query_map(m, w)
             q, partner = (
-                jnp.einsum("sr,rhd->hsd", qr, w, preferred_element_type=jnp.float32)
+                jnp.einsum("sr,rhd->hsd", qr, w_qb, preferred_element_type=jnp.float32)
                 .astype(jnp.bfloat16)
-                for w in (m["q_b_proj"].reshape(c.q_lora_rank, nh, -1), swapped)
+                for w_qb in (m["q_b_proj"].reshape(w.q_rank, nh, -1), swapped)
             )
             turned = swapped.shape[-1]
             still = nope + rope - turned
-            cos, sin = _rope_tables(positions, c.rope_theta, rope, lead=turned - rope)
+            cos, sin = _rope_tables(positions, w.theta, rope, lead=turned - rope)
             q_turned = (
                 q[..., still:].astype(jnp.float32) * cos + partner.astype(jnp.float32) * sin
             ).astype(jnp.bfloat16)
             return qr, jax.lax.dynamic_update_slice(q, q_turned, (0, 0, still))
         q = mm(qr, m["q_b_proj"]).reshape(h.shape[:-1] + (nh, nope + rope))
-        q_rope = _rope(q[..., nope:].astype(jnp.float32), positions, c.rope_theta)
+        q_rope = _rope(q[..., nope:].astype(jnp.float32), positions, w.theta)
         return qr, jnp.concatenate([q[..., :nope], q_rope.astype(jnp.bfloat16)], axis=-1)
 
 
-def _index_rope(x: jnp.ndarray, positions: jnp.ndarray, config: Config) -> jnp.ndarray:
+def _index_rope(x: jnp.ndarray, positions: jnp.ndarray, w: Widths) -> jnp.ndarray:
     """x [..., S, heads, index_head_dim] float32: its first
     ``qk_rope_head_dim`` numbers turned as the attention's rotary key is."""
-    r = config.qk_rope_head_dim
     return jnp.concatenate(
-        [_rope(x[..., :r], positions, config.rope_theta), x[..., r:]], axis=-1
+        [_rope(x[..., :w.rope], positions, w.theta), x[..., w.rope:]], axis=-1
     )
 
 
-def _index_maps(ix: Params, config: Config, h: jnp.ndarray, qr: jnp.ndarray, positions):
+def _index_maps(ix: Params, widths: Widths, h: jnp.ndarray, qr: jnp.ndarray, positions):
     """h [..., S, H] normed, qr its query bottleneck -> (qI [..., S, nI, dI]
     bfloat16, kI [..., S, dI] bfloat16, w [..., S, nI] float32)."""
-    c = config
-    nI, dI = c.index_n_heads, c.index_head_dim
+    c = widths
+    nI, dI = c.index_heads, c.index_dim
     with jax.named_scope("decoder/lm/attn/index"):
         qI = mm(qr, ix["wq_b"]).reshape(h.shape[:-1] + (nI, dI)).astype(jnp.float32)
         qI = _index_rope(qI, positions, c).astype(jnp.bfloat16)
@@ -303,20 +332,23 @@ def _blocks(S: int):
     return [(a, min(a + _QUERY_BLOCK, S)) for a in range(0, S, _QUERY_BLOCK)]
 
 
-def _attend_blocks(q, keys, values, masks, scale: float) -> jnp.ndarray:
+def _attend_blocks(q, keys, values, masks, scale: float, lows=None) -> jnp.ndarray:
     """q, keys [nh, S, d], values [nh, S, dv] -> [S, nh, dv] bfloat16: a
-    block of queries at a time against the keys up to the block's end,
-    its float32 scores ``[nh, block, keys]`` whole."""
+    block of queries at a time against the keys up to the block's end
+    (from ``lows``' entry for the block on, where a window leaves the
+    earlier ones unseen by all of it; else from the first), its float32
+    scores ``[nh, block, keys]`` whole."""
     ctx = []
-    for (a, b), mask in zip(_blocks(q.shape[1]), masks):
+    blocks = _blocks(q.shape[1])
+    for (a, b), mask, low in zip(blocks, masks, lows or (0,) * len(blocks)):
         scores = jnp.einsum(
-            "hsd,htd->hst", q[:, a:b], keys[:, :b], preferred_element_type=jnp.float32
+            "hsd,htd->hst", q[:, a:b], keys[:, low:b], preferred_element_type=jnp.float32
         )
         scores = jnp.where(mask[None], scores * scale, -jnp.inf)
         # the softmax's division after the weighted sum, as deepseek_v3's
         weights = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
         block = jnp.einsum(
-            "hst,htd->shd", weights.astype(jnp.bfloat16), values[:, :b],
+            "hst,htd->shd", weights.astype(jnp.bfloat16), values[:, low:b],
             preferred_element_type=jnp.float32,
         ) / jnp.sum(weights, axis=-1).T[..., None]
         ctx.append(block.astype(jnp.bfloat16))
@@ -332,8 +364,49 @@ def _one_mask(masks, S: int, k: int):
     return jnp.concatenate(selecting) if selecting else None
 
 
+def _expand(m: Params, w: Widths, latents: jnp.ndarray):
+    """latents [S, rank + rope] -> (keys [nh, S, nope + rope], values
+    [nh, S, v]) bfloat16, head-major as ``_queries(by_head=True)``'s q.
+    The rotary key all heads share is ADDED into lanes that ``W_kvb``'s key
+    half, widened by zero columns, leaves at zero: keys come out of one
+    product whole, the numbers a concatenation would hold."""
+    rank, nope, rope = w.kv_rank, w.nope, w.rope
+    with w.named_scope("decoder/lm/attn/expand"):
+        kv_b = _kv_b(m, w)
+        keys = (
+            jnp.einsum(
+                "sc,chd->hsd", latents[:, :rank],
+                jnp.pad(kv_b[..., :nope], ((0, 0), (0, 0), (0, rope))),
+                preferred_element_type=jnp.float32,
+            ) + jnp.pad(latents[:, rank:], ((0, 0), (nope, 0))).astype(jnp.float32)
+        ).astype(jnp.bfloat16)
+        values = jnp.einsum(
+            "sc,chd->hsd", latents[:, :rank], kv_b[..., nope:],
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.bfloat16)
+    return keys, values
+
+
+def _gated_out(m: Params, w: Widths, h: jnp.ndarray, ctx: jnp.ndarray) -> jnp.ndarray:
+    """ctx [rows, nh * v], the heads' outputs side by side -> [rows, H]
+    through ``W_o``; where the layer has a headwise gate (``gate_proj``
+    [H, nh], Qiu et al., arXiv:2505.06708), head h of row t is first
+    multiplied by ``sigmoid(u[t] W_g)[h]``, u = h the layer's normed
+    input."""
+    if "gate_proj" in m:
+        with w.named_scope("decoder/lm/attn/gate"):
+            gate = jax.nn.sigmoid(jnp.dot(
+                h.astype(jnp.bfloat16), m["gate_proj"], preferred_element_type=jnp.float32
+            ))
+            ctx = (
+                ctx.reshape(-1, w.heads, w.v).astype(jnp.float32) * gate[..., None]
+            ).astype(jnp.bfloat16).reshape(ctx.shape)
+    with w.named_scope("decoder/lm/attn/out"):
+        return mm(ctx, m["o_proj"])
+
+
 def attend_sequence(
-    m: Params, config: Config, h: jnp.ndarray, masks, fused: bool = False, swapped=None,
+    m: Params, widths: Widths, h: jnp.ndarray, masks, fused: bool = False, swapped=None,
 ):
     """h [S, H] normed, ONE sequence at positions 0..S-1 -> (the
     attention's output [S, H], the latents [S, rank + rope], the indexer's
@@ -347,30 +420,12 @@ def attend_sequence(
     sequences; the queries come head-major with their rope's partner out
     of a product (``_queries(by_head=True)``), for kernel and ``lax``
     blocks alike."""
-    c = config
+    c = widths
     S, _ = h.shape
-    rank, nope = c.kv_lora_rank, c.qk_nope_head_dim
     positions = jnp.arange(S)
     qr, q = _queries(m, c, h, positions, by_head=True, swapped=swapped)
     latents = _latents(m, c, h, positions)
-    with jax.named_scope("decoder/lm/attn/expand"):
-        # keys and values [nh, S, d] head-major, as q.  The rotary key all
-        # heads share is ADDED into lanes that ``W_kvb``'s key half, widened
-        # by zero columns, leaves at zero: keys come out of one product
-        # whole, the numbers a concatenation would hold
-        kv_b = _kv_b(m, c)
-        rope = c.qk_rope_head_dim
-        keys = (
-            jnp.einsum(
-                "sc,chd->hsd", latents[:, :rank],
-                jnp.pad(kv_b[..., :nope], ((0, 0), (0, 0), (0, rope))),
-                preferred_element_type=jnp.float32,
-            ) + jnp.pad(latents[:, rank:], ((0, 0), (nope, 0))).astype(jnp.float32)
-        ).astype(jnp.bfloat16)
-        values = jnp.einsum(
-            "sc,chd->hsd", latents[:, :rank], kv_b[..., nope:],
-            preferred_element_type=jnp.float32,
-        ).astype(jnp.bfloat16)
+    keys, values = _expand(m, c, latents)
     index_keys = None
     if masks is None:
         qI, index_keys, w = _index_maps(m["indexer"], c, h, qr, positions)
@@ -382,8 +437,8 @@ def attend_sequence(
             else:
                 scores = _index_scores(qI[a:b], index_keys[:b], w[a:b])
                 masks.append(_select_mask(scores, causal, c.index_topk))
-    scale = (nope + c.qk_rope_head_dim) ** -0.5
-    with jax.named_scope("decoder/lm/attn/scores"):
+    scale = c.qk ** -0.5
+    with c.named_scope("decoder/lm/attn/scores"):
         if fused:
             from ..ops import flash_prefill     # ops/__init__ imports models
 
@@ -393,9 +448,7 @@ def attend_sequence(
             )
         else:
             ctx = _attend_blocks(q, keys, values, masks, scale).reshape(S, -1)
-    with jax.named_scope("decoder/lm/attn/out"):
-        out = mm(ctx, m["o_proj"])
-    return out, latents, index_keys, masks
+    return _gated_out(m, c, h, ctx), latents, index_keys, masks
 
 
 def _ffn(p: Params, config: Config, layer: int, x: jnp.ndarray):
@@ -432,13 +485,13 @@ def _one_sequence(
     c = config
     S = x.shape[0]
     latents, index_keys, counts, routes, held = [], [], [], [], []
-    masks = None
+    masks, w = None, widths(c)
     for i in range(c.num_hidden_layers):
         p = lm["layers"][layer_name(i)]
         h = rms_norm(x, p["operator_norm"], c.norm_eps)
         full = c.indexer_types[i] == "full"
         y, kept, keys, masks = attend_sequence(
-            p["self_attn"], c, h, None if full else masks, fused,
+            p["self_attn"], w, h, None if full else masks, fused,
             swapped=None if swapped is None else swapped[i],
         )
         x = x + y
@@ -465,7 +518,7 @@ def sequence_forward(
     layers' ``_swapped_query_map``."""
     with jax.named_scope("decoder/lm/attn/q"):
         swapped = tuple(
-            _swapped_query_map(lm["layers"][layer_name(i)]["self_attn"], config)
+            _swapped_query_map(lm["layers"][layer_name(i)]["self_attn"], widths(config))
             for i in range(config.num_hidden_layers)
         )
     hidden, latents, index_keys, counts, routes, pairs = jax.lax.map(
@@ -551,8 +604,50 @@ def _choose(scores: jnp.ndarray, k: int):
         return positions.astype(jnp.int32), values > -jnp.inf, attend
 
 
+def _absorbed(m: Params, w: Widths, q: jnp.ndarray, pre_lat, suf_lat, attend) -> jnp.ndarray:
+    """The absorbed form for one token a row.  q [R, 1, nh, nope + rope];
+    pre_lat [B, L, rank + rope], latents of each IMAGE, read in place by
+    its K = R // B rows; suf_lat [R, T, rank + rope], each row's own;
+    attend [R or 1, L + T]: the positions a row attends.  Returns the
+    heads' outputs [R, nh * v]: one softmax across the attended prefix and
+    suffix positions; no key or value is expanded."""
+    R, _, nh, _ = q.shape
+    B, L, _ = pre_lat.shape
+    K = R // B
+    rank, nope = w.kv_rank, w.nope
+    kv_b = _kv_b(m, w)
+    with w.named_scope("decoder/lm/attn/absorb"):
+        q_lat = jnp.einsum(
+            "rhd,chd->rhc", q[:, 0, :, :nope], kv_b[..., :nope],
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.bfloat16)
+    with w.named_scope("decoder/lm/attn/scores"):
+        qc = jnp.concatenate([q_lat, q[:, 0, :, nope:]], axis=-1)        # [R, nh, rank + rope]
+        s_pre = jnp.einsum(
+            "bkhc,bnc->bkhn", qc.reshape(B, K, nh, -1), pre_lat,
+            preferred_element_type=jnp.float32,
+        ).reshape(R, nh, L)
+        s_suf = jnp.einsum("rhc,rtc->rht", qc, suf_lat, preferred_element_type=jnp.float32)
+        scores = jnp.where(
+            attend[:, None], jnp.concatenate([s_pre, s_suf], axis=-1), -jnp.inf
+        ) * (w.qk ** -0.5)
+        probs = jax.nn.softmax(scores, axis=-1).astype(jnp.bfloat16)
+        mixed = jnp.einsum(
+            "bkhn,bnc->bkhc", probs[..., :L].reshape(B, K, nh, L), pre_lat[..., :rank],
+            preferred_element_type=jnp.float32,
+        ).reshape(R, nh, rank) + jnp.einsum(
+            "rht,rtc->rhc", probs[..., L:], suf_lat[..., :rank],
+            preferred_element_type=jnp.float32,
+        )
+    with w.named_scope("decoder/lm/attn/absorb"):
+        return jnp.einsum(
+            "rhc,chd->rhd", mixed.astype(jnp.bfloat16), kv_b[..., nope:],
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.bfloat16).reshape(R, -1)
+
+
 def attend_step(
-    m: Params, config: Config, h: jnp.ndarray, prefix, suffix, t: jnp.ndarray,
+    m: Params, widths: Widths, h: jnp.ndarray, prefix, suffix, t: jnp.ndarray,
     chosen: Optional[tuple],
 ):
     """One token a row through the cache.  h [R, H] normed, at position
@@ -561,19 +656,17 @@ def attend_step(
     row over T positions, written at t here.  ``chosen``: None in a layer
     with an indexer, else the last such layer's ``_choose``.  Returns (the
     attention's output [R, H], the suffix, chosen).  The absorbed form over
-    the latents themselves, one softmax across the chosen prefix and
-    suffix positions; the choice enters as a mask over the image's prefix
-    (its K beams choose K x index_topk > N positions between them: reading
-    the prefix once per image moves fewer bytes than gathering each row's
-    own, and on the chip a gather of 49,152 rows ran at a sixteenth of the
-    memory's rate: PERF.md section 6)."""
-    c = config
+    the latents themselves (``_absorbed``); the choice enters as a mask
+    over the image's prefix (its K beams choose K x index_topk > N
+    positions between them: reading the prefix once per image moves fewer
+    bytes than gathering each row's own, and on the chip a gather of 49,152
+    rows ran at a sixteenth of the memory's rate: PERF.md section 6)."""
+    c = widths
     R = h.shape[0]
     pre_lat, pre_keys = prefix
     suf_lat, suf_keys = suffix
     B, N, _ = pre_lat.shape
     K, T = R // B, suf_lat.shape[1]
-    nh, rank, nope = c.num_attention_heads, c.kv_lora_rank, c.qk_nope_head_dim
     position = (N + t)[None]
     qr, q = _queries(m, c, h[:, None], position)
     suf_lat = jax.lax.dynamic_update_slice(
@@ -589,40 +682,10 @@ def attend_step(
         over_suffix = _index_scores(qI, suf_keys, w)[:, 0]
         over_suffix = jnp.where(jnp.arange(T) <= t, over_suffix, -jnp.inf)
         chosen = _choose(
-            jnp.concatenate([over_prefix, over_suffix], axis=-1), _chosen_width(c, T)
+            jnp.concatenate([over_prefix, over_suffix], axis=-1), min(c.index_topk, N + T)
         )
-    attend = chosen[2]
-    kv_b = _kv_b(m, c)
-    with jax.named_scope("decoder/lm/attn/absorb"):
-        q_lat = jnp.einsum(
-            "rhd,chd->rhc", q[:, 0, :, :nope], kv_b[..., :nope],
-            preferred_element_type=jnp.float32,
-        ).astype(jnp.bfloat16)
-    with jax.named_scope("decoder/lm/attn/scores"):
-        qc = jnp.concatenate([q_lat, q[:, 0, :, nope:]], axis=-1)        # [R, nh, rank + rope]
-        s_pre = jnp.einsum(
-            "bkhc,bnc->bkhn", qc.reshape(B, K, nh, -1), pre_lat,
-            preferred_element_type=jnp.float32,
-        ).reshape(R, nh, N)
-        s_suf = jnp.einsum("rhc,rtc->rht", qc, suf_lat, preferred_element_type=jnp.float32)
-        scores = jnp.where(
-            attend[:, None], jnp.concatenate([s_pre, s_suf], axis=-1), -jnp.inf
-        ) * ((nope + c.qk_rope_head_dim) ** -0.5)
-        probs = jax.nn.softmax(scores, axis=-1).astype(jnp.bfloat16)
-        mixed = jnp.einsum(
-            "bkhn,bnc->bkhc", probs[..., :N].reshape(B, K, nh, N), pre_lat[..., :rank],
-            preferred_element_type=jnp.float32,
-        ).reshape(R, nh, rank) + jnp.einsum(
-            "rht,rtc->rhc", probs[..., N:], suf_lat[..., :rank],
-            preferred_element_type=jnp.float32,
-        )
-    with jax.named_scope("decoder/lm/attn/absorb"):
-        ctx = jnp.einsum(
-            "rhc,chd->rhd", mixed.astype(jnp.bfloat16), kv_b[..., nope:],
-            preferred_element_type=jnp.float32,
-        ).astype(jnp.bfloat16)
-    with jax.named_scope("decoder/lm/attn/out"):
-        return mm(ctx.reshape(R, -1), m["o_proj"]), (suf_lat, suf_keys), chosen
+    ctx = _absorbed(m, c, q, pre_lat, suf_lat, chosen[2])
+    return _gated_out(m, c, h, ctx), (suf_lat, suf_keys), chosen
 
 
 def step(
@@ -638,12 +701,13 @@ def step(
     N = prefix.latents[0].shape[1]
     latents, index_keys, counts, routes, held, records = [], [], [], [], [], []
     chosen, attended, full = None, jnp.zeros((2,), jnp.int32), 0
+    w = widths(c)
     for i in range(c.num_hidden_layers):
         p = lm["layers"][layer_name(i)]
         h = rms_norm(x, p["operator_norm"], c.norm_eps)
         indexes = c.indexer_types[i] == "full"
         y, (lat, keys), chosen = attend_step(
-            p["self_attn"], c, h,
+            p["self_attn"], w, h,
             (prefix.latents[i], prefix.index_keys[full] if indexes else None),
             (cache.latents[i], cache.index_keys[full] if indexes else None),
             counters.t, None if indexes else chosen,
@@ -678,9 +742,10 @@ def step(
     )
 
 
-def report(config: Config, state, B: int, K: int, T: int) -> dict:
-    """What this decoder adds to ``BeamResult.decoder_stats``: ``state``
-    the search's final ``StepState``."""
+def report(config: Config, prefix: DsaCache, state, B: int, K: int, T: int) -> dict:
+    """What this decoder adds to ``BeamResult.decoder_stats``: ``prefix``
+    what the steps closed over per image, ``state`` the search's final
+    ``StepState``."""
     full = len(_full_layers(config))
     return {
         # [B, K, T, full layers, k]: the positions each LIVE beam's tokens

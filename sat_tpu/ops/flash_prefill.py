@@ -6,6 +6,7 @@ positions (queries and keys are the same positions):
 
     s[t, j]  = (q[t, h] . k[j, h]) * scale            float32
     seen     = j <= t  and  (t < S - M  or  mask[t - (S - M), j] != 0)
+               and  j > t - window                    (where there is a window)
     out[t,h] = sum_j exp(s[t, j] - max) v[j, h] / sum_j exp(s[t, j] - max)
                over the ``seen`` j
 
@@ -33,7 +34,13 @@ no mask.  One mask for all heads: a program takes ``hb`` heads, which
 share each mask block it fetches, so the mask is read ``heads / hb``
 times, not ``heads`` times.  Key blocks wholly above the diagonal are
 neither fetched (their block index is clamped to the last one needed) nor
-computed.
+computed.  ``window`` (a sliding layer: a query sees itself and the
+``window - 1`` positions before it) bounds the keys from BELOW as the
+diagonal does from above: the band's edge by iota, no mask array, and the
+key tiles wholly below the band neither fetched nor computed either: the
+grid's key axis is as long as the most tiles a query tile's band meets (4
+of 16 at 4,096 positions, a window of 513 and tiles of 512 x 256), and a
+query tile's walk starts at its band's first tile.
 
 A row may see nothing in a whole key block (a selection need not keep the
 keys nearest the query), so masked scores take a finite floor, not -inf:
@@ -108,7 +115,12 @@ def _across(x: jnp.ndarray, n: int) -> jnp.ndarray:
     return jnp.tile(x, (1, n // x.shape[1]))
 
 
-def _kernel(*refs, heads, d_v, bq, bk, scale, first_masked):
+def _first_key_tile(qi, bq: int, bk: int, window: int):
+    """The key tile a query tile's band begins in."""
+    return jnp.maximum(qi * bq - (window - 1), 0) // bk
+
+
+def _kernel(*refs, heads, d_v, bq, bk, scale, first_masked, window):
     """Grid (head group, query tile, key tile), the key tiles innermost.
     Blocks: q [heads, bq, d_qk], k [heads, bk, d_qk], v [heads, bk, d_v],
     mask [bq, bk] int8 (where the call has one), out [bq, heads * d_v];
@@ -121,8 +133,11 @@ def _kernel(*refs, heads, d_v, bq, bk, scale, first_masked):
         q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref = refs
     qi, ki = pl.program_id(1), pl.program_id(2)
     last = ((qi + 1) * bq - 1) // bk          # the key tile the diagonal ends in
+    start = ki == 0
+    if window is not None:                    # the walk starts where the band does
+        ki = ki + _first_key_tile(qi, bq, bk, window)
 
-    @pl.when(ki == 0)
+    @pl.when(start)
     def _():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -133,6 +148,8 @@ def _kernel(*refs, heads, d_v, bq, bk, scale, first_masked):
         rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         seen = cols <= rows
+        if window is not None:
+            seen = seen & (cols > rows - window)
         if mask_ref is not None:
             seen = seen & ((mask_ref[...].astype(jnp.int32) != 0) | (qi < first_masked))
         # made once a tile, added to the scores of each of its heads
@@ -163,7 +180,7 @@ def _kernel(*refs, heads, d_v, bq, bk, scale, first_masked):
             ).astype(o_ref.dtype)
 
 
-@partial(jax.jit, static_argnames=("scale", "tiles", "interpret"))
+@partial(jax.jit, static_argnames=("scale", "tiles", "interpret", "window"))
 def flash_prefill(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -173,12 +190,15 @@ def flash_prefill(
     scale: float,
     tiles: Optional[Tuple[int, int, int]] = None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """q, k [heads, S, d_qk], v [heads, S, d_v] bfloat16, one causal
     sequence; mask [M, S] (int8 or bool; nonzero: query S - M + i attends
     key j, where j is also at or before it) or None -> [S, heads * d_v]
     bfloat16.  ``tiles``: (queries, keys, heads) a program, each dividing
-    what it tiles (and S - M whole query tiles); None takes ``_TILES``."""
+    what it tiles (and S - M whole query tiles); None takes ``_TILES``.
+    ``window``: a query attends its own position and the ``window - 1``
+    before it, no others."""
     heads, S, d_qk = q.shape
     d_v = v.shape[2]
     masked = 0 if mask is None else mask.shape[0]
@@ -190,8 +210,23 @@ def flash_prefill(
         )
     first_masked = (S - masked) // bq if masked else None
 
-    def key_tile(qi, ki):
-        return jnp.minimum(ki, ((qi + 1) * bq - 1) // bk)
+    key_tiles = S // bk
+    if window is None:
+        def key_tile(qi, ki):
+            return jnp.minimum(ki, ((qi + 1) * bq - 1) // bk)
+    else:
+        if window < 1:
+            raise ValueError(f"window {window}: a query sees at least itself")
+        # the most key tiles a query tile's band meets
+        key_tiles = max(
+            ((qi + 1) * bq - 1) // bk - max(qi * bq - (window - 1), 0) // bk + 1
+            for qi in range(S // bq)
+        )
+
+        def key_tile(qi, ki):
+            return jnp.minimum(
+                ki + _first_key_tile(qi, bq, bk, window), ((qi + 1) * bq - 1) // bk
+            )
 
     in_specs = [
         pl.BlockSpec((hb, bq, d_qk), lambda g, qi, ki: (g, qi, 0)),
@@ -216,10 +251,10 @@ def flash_prefill(
     return pl.pallas_call(
         partial(
             _kernel, heads=hb, d_v=d_v, bq=bq, bk=bk, scale=scale,
-            first_masked=first_masked,
+            first_masked=first_masked, window=window,
         ),
         name="flash_prefill",
-        grid=(heads // hb, S // bq, S // bk),
+        grid=(heads // hb, S // bq, key_tiles),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bq, hb * d_v), lambda g, qi, ki: (qi, g)),
         out_shape=jax.ShapeDtypeStruct((S, heads * d_v), jnp.bfloat16),

@@ -1478,6 +1478,14 @@ def decode_dataset(
                 tel.gauge("decode/lm_moe_held_pair_share", float(pairs[0] / max(pairs[1], 1.0)))  # sync-ok: host numpy, already drained
                 fused, blocks = np.asarray(out.decoder_stats["prefill_fused_blocks"], np.float64)  # sync-ok: decode drain boundary
                 tel.gauge("decode/lm_dsa_prefill_fused_share", float(fused / max(blocks, 1.0)))  # sync-ok: host numpy, already drained
+            if out.decoder_stats and "swa_attended" in out.decoder_stats:
+                # a decoder with window layers: the bytes of their leaves
+                # of the state (the kept tail per image + the suffix per
+                # beam), and positions attended / positions visible over
+                # the steps' sliding layers (1.0: nothing slides)
+                tel.gauge("decode/lm_swa_state_mb", float(out.decoder_stats["state_bytes_window"]) / 1e6)  # sync-ok: decode drain boundary
+                attended, visible = np.asarray(out.decoder_stats["swa_attended"], np.float64)  # sync-ok: decode drain boundary
+                tel.gauge("decode/lm_swa_attended_share", float(attended / max(visible, 1.0)))  # sync-ok: host numpy, already drained
         occupancy.observe()
         occupancy.publish()
         with tel.span("decode/drain/detok", b):  # host work after it

@@ -547,6 +547,60 @@ def test_dsa_beam_program_fits_the_chip_and_never_holds_a_square_of_scores(monke
     assert memory.temp_size_in_bytes < int(2.25e9), memory
 
 
+def _dots3_config(experts_held=32):
+    return Config(
+        decoder="dots3_note", image_size=1024, vocabulary_size=19008, hidden_size=5120,
+        intermediate_size=13824, moe_intermediate_size=1536, num_hidden_layers=5, num_dense_layers=1,
+        num_attention_heads=128, num_experts=256, num_experts_per_tok=8, experts_held=experts_held,
+        first_expert=0, n_shared_experts=1, routed_scaling_factor=1.0, norm_eps=1e-5, rope_theta=8e7,
+        kv_lora_rank=512, q_lora_rank=1024, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        index_n_heads=64, index_head_dim=128, index_topk=2048,
+        swa_num_attention_heads=64, swa_q_lora_rank=1024, swa_kv_lora_rank=1024, swa_qk_nope_head_dim=192,
+        swa_qk_rope_head_dim=64, swa_v_head_dim=128, swa_rope_theta=5e4, sliding_window_size=513,
+        attention_gate="headwise", mla_lora_rescale=True, tie_word_embeddings=False,
+        layer_types=("full_attention", "full_attention", "sliding_attention", "sliding_attention",
+                     "sliding_attention"),
+    )
+
+
+def test_dots3_beam_program_fits_the_chip_and_keeps_a_window(monkeypatch):
+    """``decoder="dots3_note"`` at its cell's batch and the published widths
+    (B = 8 images of 1,024 px: N = 4,096; K = 3; depth 5 = full, full,
+    sliding x 3; 32 of 256 experts held; V = 19,008): accepted by the
+    chip's compiler, arguments (8.2 GB of weights) and temporaries under
+    the chip; the prefill's attention one fused kernel a layer, the full
+    layers' at a head of 192 under the indexer's mask, the sliding layers'
+    under the window bound in a scope of their own; the steps read a full
+    layer's prefix whole (``bf16[8,4096,576]``) and a sliding layer's TAIL
+    (``bf16[8,512,1088]``), never its whole prefix and never a copy a beam."""
+    from sat_tpu.ops.beam_search import beam_search_jit
+
+    config = _dots3_config()
+    V, K, N = config.vocabulary_size, 3, config.num_ctx
+    assert N == 4096
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, decoder = _decoder_params(config)
+    compiled = beam_search_jit.lower(
+        decoder, config, _sd((8, N, config.dim_ctx)), 1, beam_size=K, valid_size=V,
+    ).compile()
+    text = compiled.as_text()
+    _assert_the_step_selects_per_row(text, 8, K, V)
+    lines = text.splitlines()
+    fused = [ln for ln in lines if "tpu_custom_call" in ln and "flash_prefill" in ln]
+    assert len(fused) == config.num_hidden_layers
+    windowed = [ln for ln in fused if re.search(r"beam/prefill[^\"]*decoder/lm/attn/window/scores/", ln)]
+    assert len(windowed) == 3
+    assert all(re.search(r"beam/prefill[^\"]*decoder/lm/attn/scores/", ln) for ln in fused if ln not in windowed)
+    loop = {shape for ln in _loop_lines(text) for shape in re.findall(r"(?:bf16|f32)\[[\d,]+\]", ln)}
+    assert f"bf16[8,{N},576]" in loop and f"bf16[8,{N},128]" in loop and "bf16[8,512,1088]" in loop
+    assert not [s for s in loop if re.search(rf"\[(24|8),{N},1088\]|\[24,{N},", s)], loop
+    shapes = set(re.findall(r"(?:bf16|f32|pred|s32|u32)\[[\d,]+\]", text))
+    assert not [s for s in shapes if re.search(r"\[(\d+,)*4096,4096\]", s) and s.count(",") >= 2], shapes
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > int(8.1e9)
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < int(15.5e9), memory
+
+
 @pytest.mark.parametrize("V", [65536, 128256], ids=["lfm2", "kanana2"])
 def test_beam_step_alone_needs_no_vocabulary_sized_temporary(V):
     """``_expand_step`` by itself over the lm cells' 768 rows of logits:

@@ -782,11 +782,16 @@ def test_e2e_continuous_wedge_fails_slots_and_rewarms(served, monkeypatch):
         assert status == 500
         assert "wedged" in payload["error"]
         assert tel.counters().get("serve/wedged_batches", 0) >= 1
-        # recovery: pool re-warmed (cached compiles), health back to ok
+        # recovery: pool re-warmed (cached compiles), health back to ok.
+        # The batcher fails the wedged slots BEFORE it fires the server's
+        # degrade-and-re-warm hook (serve/batcher.py), so right after the
+        # 500 health may still read "ok" from before the wedge: wait for
+        # the re-warm itself, then for health
         deadline = time.time() + 30.0
         while time.time() < deadline:
             code, health = _get(port, "/healthz")
-            if code == 200 and health["status"] == "ok":
+            rewarmed = tel.counters().get("serve/rewarms", 0) > rewarms_before
+            if rewarmed and code == 200 and health["status"] == "ok":
                 break
             time.sleep(0.05)
         assert code == 200 and health["status"] == "ok"

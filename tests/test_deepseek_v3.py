@@ -174,8 +174,8 @@ def test_a_whole_sequence_s_query_is_the_rolled_one_to_the_bit(params, theta):
     m = params["lm"]["layers"]["01"]["self_attn"]
     h = jax.random.normal(jax.random.PRNGKey(4), (2, 56, 64)).astype(jnp.bfloat16)
     positions = jnp.arange(56)
-    got = jax.jit(lambda h: ds._sequence_queries(m, config, h, positions))(h)
-    want = jax.jit(lambda h: ds._queries(m, config, h, positions))(h)
+    got = jax.jit(lambda h: ds._sequence_queries(m, ds.widths(config), h, positions))(h)
+    want = jax.jit(lambda h: ds._queries(m, ds.widths(config), h, positions))(h)
     for g, w in zip(got, want):
         assert g.dtype == w.dtype == jnp.bfloat16 and g.shape == w.shape
         np.testing.assert_array_equal(np.asarray(g, np.float32), np.asarray(w, np.float32))

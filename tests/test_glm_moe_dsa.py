@@ -227,8 +227,8 @@ def test_the_indexer_against_the_reference_s(params, weights):
 
     @jax.jit
     def scores(h):
-        qr, _ = dsa._queries(m["self_attn"], CONFIG, h, positions)
-        return dsa._index_scores(*dsa._index_maps(m["self_attn"]["indexer"], CONFIG, h, qr, positions))
+        qr, _ = dsa._queries(m["self_attn"], dsa.widths(CONFIG), h, positions)
+        return dsa._index_scores(*dsa._index_maps(m["self_attn"]["indexer"], dsa.widths(CONFIG), h, qr, positions))
 
     got = scores(h)
     p = ref._f32(_subtree(weights, "lm/layers/00"))
@@ -249,8 +249,8 @@ def test_without_its_rope_the_indexer_scores_otherwise(params, monkeypatch):
 
     def scores():
         positions = jnp.arange(x.shape[0])
-        qr, _ = dsa._queries(m["self_attn"], CONFIG, h, positions)
-        return np.asarray(dsa._index_scores(*dsa._index_maps(m["self_attn"]["indexer"], CONFIG, h, qr, positions)))
+        qr, _ = dsa._queries(m["self_attn"], dsa.widths(CONFIG), h, positions)
+        return np.asarray(dsa._index_scores(*dsa._index_maps(m["self_attn"]["indexer"], dsa.widths(CONFIG), h, qr, positions)))
 
     turned = scores()
     monkeypatch.setattr(dsa, "_index_rope", lambda x, positions, config: x)
@@ -275,9 +275,9 @@ def test_the_lanes_a_whole_sequence_s_rope_rewrites():
     of nope), the whole head at the tests' widths."""
     from test_aot_tpu import _glm52_config
 
-    assert dsa._turned_lanes(_glm52_config()) == 128
-    assert dsa._turned_lanes(CONFIG) == 24
-    swapped = dsa._swapped_query_map({"q_b_proj": jnp.ones((48, 4 * 24), jnp.bfloat16)}, CONFIG)
+    assert dsa._turned_lanes(dsa.widths(_glm52_config())) == 128
+    assert dsa._turned_lanes(dsa.widths(CONFIG)) == 24
+    swapped = dsa._swapped_query_map({"q_b_proj": jnp.ones((48, 4 * 24), jnp.bfloat16)}, dsa.widths(CONFIG))
     assert swapped.shape == (48, 4, 24) and not np.asarray(swapped[..., :16], np.float32).any()
 
 
@@ -292,9 +292,9 @@ def test_the_folded_query_is_the_rolled_one_to_the_bit(params, theta, lifted):
     m = params["lm"]["layers"]["02"]["self_attn"]
     h = jax.random.normal(jax.random.PRNGKey(3), (56, 64)).astype(jnp.bfloat16)
     positions = jnp.arange(56)
-    swapped = dsa._swapped_query_map(m, config) if lifted else None
-    qr, q = jax.jit(lambda h: dsa._queries(m, config, h, positions, by_head=True, swapped=swapped))(h)
-    want_qr, want = jax.jit(lambda h: dsa._queries(m, config, h, positions))(h)
+    swapped = dsa._swapped_query_map(m, dsa.widths(config)) if lifted else None
+    qr, q = jax.jit(lambda h: dsa._queries(m, dsa.widths(config), h, positions, by_head=True, swapped=swapped))(h)
+    want_qr, want = jax.jit(lambda h: dsa._queries(m, dsa.widths(config), h, positions))(h)
     assert q.shape == (4, 56, 24) and q.dtype == jnp.bfloat16
     np.testing.assert_array_equal(np.asarray(qr, np.float32), np.asarray(want_qr, np.float32))
     np.testing.assert_array_equal(np.asarray(q, np.float32), np.asarray(want, np.float32).transpose(1, 0, 2))
@@ -309,7 +309,7 @@ def test_the_step_s_query_is_the_one_product_rolled_as_before(params):
     m = params["lm"]["layers"]["00"]["self_attn"]
     h = jax.random.normal(jax.random.PRNGKey(4), (6, 1, 64)).astype(jnp.bfloat16)
     position = jnp.array([N + 3])
-    qr, q = jax.jit(lambda h: dsa._queries(m, CONFIG, h, position))(h)
+    qr, q = jax.jit(lambda h: dsa._queries(m, dsa.widths(CONFIG), h, position))(h)
 
     def before(h):
         qr = lm_common.rms_norm(lm_common.mm(h, m["q_a_proj"]), m["q_a_layernorm"], CONFIG.norm_eps)
@@ -373,12 +373,12 @@ def test_the_prefill_s_query_cuts_no_tile_of_lanes_and_rolls_nothing(by_head):
     positions = jnp.arange(4096)
     if by_head:
         jaxpr = jax.make_jaxpr(
-            lambda m, h, swapped: dsa._queries(m, config, h, positions, by_head=True, swapped=swapped)
+            lambda m, h, swapped: dsa._queries(m, dsa.widths(config), h, positions, by_head=True, swapped=swapped)
         )(m, sd(4096, 6144), sd(2048, 64, 128))
         assert jaxpr.out_avals[1].shape == (64, 4096, 256)
         assert not _lane_faults(jaxpr.jaxpr)
     else:
-        jaxpr = jax.make_jaxpr(lambda m, h: dsa._queries(m, config, h, positions))(m, sd(4096, 6144))
+        jaxpr = jax.make_jaxpr(lambda m, h: dsa._queries(m, dsa.widths(config), h, positions))(m, sd(4096, 6144))
         faults = " ".join(_lane_faults(jaxpr.jaxpr))
         assert "roll" in faults and "slice [192:256]" in faults and "float32(4096, 64, 64)" in faults, faults
 
@@ -546,7 +546,7 @@ def test_a_shared_layer_s_output_depends_on_the_choice_it_is_handed(params):
         positions = jnp.tile(jnp.arange(lo, lo + 16, dtype=jnp.int32), (2, 1))
         attend = jnp.tile((jnp.arange(N + 20) >= lo) & (jnp.arange(N + 20) < lo + 16), (2, 1))
         return jax.jit(lambda: dsa.attend_step(
-            m, CONFIG, h, (prefix.latents[1], None), (cache.latents[1], None), jnp.int32(0),
+            m, dsa.widths(CONFIG), h, (prefix.latents[1], None), (cache.latents[1], None), jnp.int32(0),
             (positions, jnp.ones_like(positions, bool), attend))[0])()
 
     a, b = run(0), run(16)
@@ -768,7 +768,12 @@ def test_no_module_but_decoders_tests_the_decoder_field():
                 with open(os.path.join(folder, name)) as f:
                     if "glm_moe_dsa" in f.read():
                         hits.append(os.path.relpath(os.path.join(folder, name), root))
-    assert sorted(hits) == ["config.py", "models/decoders.py", "models/glm_moe_dsa.py"]
+    # models/dots3_note.py imports the module (its full layers are this block at other widths)
+    # and, like it, never looks at Config.decoder
+    assert sorted(hits) == ["config.py", "models/decoders.py", "models/dots3_note.py", "models/glm_moe_dsa.py"]
+    for name in ("models/dots3_note.py", "models/glm_moe_dsa.py"):
+        with open(os.path.join(root, name)) as f:
+            assert "config.decoder" not in f.read(), name
 
 
 # ---------------------------------------------------------------------------

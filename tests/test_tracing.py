@@ -510,6 +510,85 @@ def test_the_sparse_attention_s_scopes_and_gauges_exist(tmp_path):
     assert '"decode/lm_dsa_prefill_fused_share"' in drain
 
 
+def _dots3_config(**changes):
+    return Config(**{**dict(
+        decoder="dots3_note", image_size=96, hidden_size=64, intermediate_size=96, moe_intermediate_size=24,
+        num_hidden_layers=3, num_dense_layers=1, num_attention_heads=4, num_experts=8, num_experts_per_tok=3,
+        experts_held=4, first_expert=2, kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=24, n_shared_experts=1, index_n_heads=4, index_head_dim=16, index_topk=16,
+        swa_num_attention_heads=2, swa_q_lora_rank=40, swa_kv_lora_rank=28, swa_qk_nope_head_dim=24,
+        swa_qk_rope_head_dim=16, swa_v_head_dim=16, sliding_window_size=9, attention_gate="headwise",
+        mla_lora_rescale=True, tie_word_embeddings=False,
+        layer_types=("full_attention", "sliding_attention", "sliding_attention"),
+        vocabulary_size=100, max_caption_length=6, beam_size=3, batch_size=2,
+    ), **changes})
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["lax", "kernel"])
+def test_a_sliding_layer_s_scopes_lie_apart_under_the_mixer_s_and_the_drain_has_its_gauges(monkeypatch, fused):
+    """``decoder="dots3_note"``: a sliding layer's seven scopes name leaf
+    instructions of the beam program under ``decoder/lm/attn/window`` in
+    both phases (with the windowed kernel under its test hook, its call
+    sits under ``window/scores``), the full layer keeps its own and has a
+    gate; the rules of benchmark/scopes/lm_swa*.json claim them, the glm52
+    cell's rule files take none of a sliding layer's for a full layer's,
+    and what the decoder counts of a batch is what the drain's gauges are
+    set from."""
+    from sat_tpu.models import decoders, glm_moe_dsa
+    from sat_tpu.ops import flash_prefill
+    from sat_tpu.ops.beam_search import beam_search_jit
+
+    monkeypatch.setattr(glm_moe_dsa, "_QUERY_BLOCK", 12)
+    monkeypatch.setattr(flash_prefill, "FORCE_INTERPRET", fused)
+    # the hook is no part of a trace's key: a Config of its own a case, so that neither meets the other's trace
+    config = _dots3_config(num_data_workers=8 + fused)
+    params = decoders.init_params(jax.random.PRNGKey(0), config)
+    contexts = jnp.zeros((2, config.num_ctx, config.dim_ctx))
+    tel = Telemetry(capacity=64)
+    xla_acct.reset()
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    xla_acct.analyze("decode/beam_search", beam_search_jit, params, config, contexts, 1,
+                     beam_size=3, valid_size=100, return_alphas=False, tel=tel)
+    rows = xla_acct.entries()["decode/beam_search"]["op_scopes"]["rows"]
+    xla_acct.reset()
+    ops = [r[3] for r in rows if not r[2]]
+    for scope in [f"beam/prefill.*decoder/lm/attn/window/{s}/" for s in ("q", "latent", "expand", "scores", "gate", "out")] + \
+                 [f"beam/loop.*decoder/lm/attn/window/{s}/" for s in ("q", "latent", "absorb", "scores", "gate", "out")] + \
+                 [f"beam/{phase}.*decoder/lm/attn/{s}/" for phase in ("prefill", "loop") for s in ("q", "index", "gate", "out")]:
+        assert any(re.search(scope, o) for o in ops), scope
+    scopes = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "scopes")
+
+    def rules_of(name):
+        with open(os.path.join(scopes, name + ".json")) as f:
+            return [tuple(r) for r in json.load(f)["rules"]]
+
+    for name, wanted in (("lm_swa", {"window_prefill", "window_step", "other"}), ("lm_swa_gate", {"gate", "other"}),
+                         ("lm_swa_phases", {"swa_prefill_attention", "swa_step_attention", "full_prefill_attention", "other"})):
+        assert wanted <= {_bucket(rules_of(name), o) for o in ops}, name
+    window = [o for o in ops if "decoder/lm/attn/window/" in o]
+    assert {_bucket(rules_of("lm_beam_search"), o) for o in window} == {"mixer"}
+    for name in ("lm_dsa", "lm_dsa_phases", "lm_mla_absorb", "lm_mla_query"):
+        assert {_bucket(rules_of(name), o) for o in window} == {"other"}, name
+    # the kernel's call, where it runs: under each kind's own scores scope
+    text = beam_search_jit.lower(params, config, contexts, 1, beam_size=3, valid_size=100).compile().as_text()
+    kernel = set(re.findall(r'op_name="([^"]*flash_prefill[^"]*)"', text))
+    assert sorted({_bucket(rules_of("lm_swa_phases"), o) for o in kernel}) == \
+        (["full_prefill_attention", "swa_prefill_attention"] if fused else [])
+    out = beam_search_jit(params, config, contexts, 1, beam_size=3, valid_size=100)
+    stats = out.decoder_stats
+    assert {"swa_attended", "state_bytes_window", "state_bytes", "prefill_fused_blocks_by_kind"} <= set(stats)
+    attended, visible = (float(x) for x in stats["swa_attended"])
+    assert attended / visible == pytest.approx(6 * 9 / sum(36 + t + 1 for t in range(6)))
+    assert int(stats["state_bytes_window"]) == 2 * 2 * 44 * (2 * 8 + 6 * 6) < int(stats["state_bytes"])
+    assert np.asarray(stats["prefill_fused_blocks_by_kind"]).tolist() == [[3 * fused, 3], [6 * fused, 6]]
+    import inspect
+
+    from sat_tpu import runtime
+
+    drain = inspect.getsource(runtime)
+    assert '"decode/lm_swa_state_mb"' in drain and '"decode/lm_swa_attended_share"' in drain
+
+
 def test_the_fused_prefill_kernel_s_call_carries_the_scope_its_roofline_share_reads(monkeypatch):
     """``ops/flash_prefill.py``'s call sits under
     ``beam/prefill/.../decoder/lm/attn/scores``: the rule of
