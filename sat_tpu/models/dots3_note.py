@@ -79,7 +79,7 @@ class Counters(NamedTuple):
     t: jnp.ndarray
     moe_counts: jnp.ndarray
     step_visits: jnp.ndarray
-    pairs: jnp.ndarray      # [2, 3]: the prefill, the steps: pairs held here, routed, over the rows
+    pairs: jnp.ndarray      # [2, 6]: the prefill, the steps: ``glm_moe_dsa._sum_pairs``
     attended: jnp.ndarray   # [2] positions attended, positions visible (steps, full layers)
     window: jnp.ndarray     # [2] the same of the steps' sliding layers
     # [2, 2] full layers, sliding layers: the prefill's query blocks
@@ -214,7 +214,7 @@ def _one_sequence(
     keeps of the sequence (a full layer its latents [S, 576], a sliding
     layer its last ``_kept`` [.., 1088]), indexer keys per full layer,
     tokens per expert [moe layers, E], experts chosen [S, moe layers * k],
-    pairs [3]).  ``swapped``: every layer's ``_swapped_query_map``, or None."""
+    pairs [6]).  ``swapped``: every layer's ``_swapped_query_map``, or None."""
     c = config
     S = x.shape[0]
     full, sliding = widths(c)
@@ -409,7 +409,8 @@ def report(config: Config, prefix: DsaCache, state, B: int, K: int, T: int) -> d
     )
     return {
         "step_selected": state.beam.selected.reshape(B, K, T, full, -1),
-        "moe_pairs": state.shared.pairs,
+        "moe_pairs": state.shared.pairs[:, :3],
+        "moe_combine": state.shared.pairs[:, 3:],
         "dsa_attended": state.shared.attended,
         # [2] positions attended, positions visible (steps, sliding layers)
         "swa_attended": state.shared.window,

@@ -95,8 +95,9 @@ class DsaCounters(NamedTuple):
     t: jnp.ndarray
     moe_counts: jnp.ndarray
     step_visits: jnp.ndarray
-    # [2, 3] int32, the prefill then the steps: pairs held here, pairs
-    # routed, pairs over the rows
+    # [2, 6] int32, the prefill then the steps: pairs held here, pairs
+    # routed, pairs over the rows | rows the combine fetched, expert-layer
+    # calls whose combine went through the kernel, calls in all
     pairs: jnp.ndarray
     attended: jnp.ndarray   # [2] int32: positions attended, positions visible (steps, full layers)
     fused: jnp.ndarray      # [2] int32: the prefill's query blocks through the fused kernel, in all
@@ -463,16 +464,19 @@ def _ffn(p: Params, config: Config, layer: int, x: jnp.ndarray):
     pairs = jnp.int32(experts.size)
     return y, sizes, experts, HeldPairs(
         held=pairs, routed=pairs, over=jnp.int32(0),
-        visited=jnp.sum(sizes > 0, dtype=jnp.int32),
+        visited=jnp.sum(sizes > 0, dtype=jnp.int32), fetched=pairs, fused=jnp.int32(0),
     )
 
 
 def _sum_pairs(held) -> jnp.ndarray:
-    """[3] int32 of a list of ``HeldPairs``: held, routed, over."""
+    """[6] int32 of a list of ``HeldPairs``, one an expert-layer call:
+    held, routed, over | fetched, calls through the combine's kernel, calls."""
     if not held:
-        return jnp.zeros((3,), jnp.int32)
-    return jnp.stack([sum(h.held for h in held), sum(h.routed for h in held),
-                      sum(h.over for h in held)]).astype(jnp.int32)
+        return jnp.zeros((6,), jnp.int32)
+    return jnp.stack(
+        [sum(getattr(h, name) for h in held) for name in ("held", "routed", "over", "fetched", "fused")]
+        + [jnp.int32(len(held))]
+    ).astype(jnp.int32)
 
 
 def _one_sequence(
@@ -480,7 +484,7 @@ def _one_sequence(
 ):
     """x [S, H] -> (hidden of the last ``tail`` positions, latents per
     layer, indexer keys per full layer, tokens per expert [moe layers, E],
-    experts chosen [S, moe layers * k], pairs [3]).  ``swapped``: every
+    experts chosen [S, moe layers * k], pairs [6]).  ``swapped``: every
     layer's ``_swapped_query_map``, or None (each layer makes its own)."""
     c = config
     S = x.shape[0]
@@ -513,7 +517,7 @@ def sequence_forward(
     """x [B, S, H] bfloat16 -> ``_one_sequence``'s results, image by image:
     (hidden [B, tail, H], the sequences' state (a ``DsaCache`` of
     ``[B, S, ..]`` leaves), tokens per expert [moe layers, E], experts
-    chosen [B, S, moe layers * k], pairs [3]).  What depends on the
+    chosen [B, S, moe layers * k], pairs [6]).  What depends on the
     weights alone is made here, outside the loop over the images: the
     layers' ``_swapped_query_map``."""
     with jax.named_scope("decoder/lm/attn/q"):
@@ -752,7 +756,10 @@ def report(config: Config, prefix: DsaCache, state, B: int, K: int, T: int) -> d
         # attended, step by step along its own ancestry (-1: not visible)
         "step_selected": state.beam.selected.reshape(B, K, T, full, -1),
         # [2, 3] the prefill, the steps: pairs held here, routed, over the rows
-        "moe_pairs": state.shared.pairs,
+        "moe_pairs": state.shared.pairs[:, :3],
+        # [2, 3] the prefill, the steps: rows of the grouped products the
+        # combine fetched, expert-layer calls through its kernel, calls
+        "moe_combine": state.shared.pairs[:, 3:],
         # [2] positions attended, positions visible (steps, full layers)
         "dsa_attended": state.shared.attended,
         # [2] of a layer and image: the prefill's query blocks whose scores

@@ -228,6 +228,38 @@ class HeldPairs(NamedTuple):
     routed: jnp.ndarray     # pairs routed, over all num_experts
     over: jnp.ndarray       # pairs that landed here beyond the rows: left out
     visited: jnp.ndarray    # experts here that took a token
+    fetched: jnp.ndarray    # rows of the grouped products the combine read
+    fused: jnp.ndarray      # 1 where the combine went through ops/moe_combine.py's kernel
+
+
+def _combine_lax(out, order, weights, done):
+    """``ops/moe_combine.py``'s contract by ``lax``: the sort inverted by a
+    scatter over all T * k pairs, a row gathered for EVERY pair, the ones
+    nothing wrote masked (not multiplied by a zero weight), summed over k."""
+    (P, H), (T, k) = out.shape, weights.shape
+    back = jnp.zeros((T * k,), jnp.int32).at[order].set(
+        jnp.arange(T * k, dtype=jnp.int32)
+    )
+    computed = (back < done).reshape(T, k)
+    picked = out[jnp.minimum(back, P - 1)].reshape(T, k, H).astype(jnp.float32)
+    return jnp.sum(jnp.where(computed[..., None], picked * weights[..., None], 0.0), axis=1)
+
+
+def _combine_held(out, order, weights, done):
+    """out [P, H] bfloat16 (the rows the grouped products wrote, sorted by
+    expert, then rows nothing wrote), order [T * k] (row r < ``done`` is
+    pair ``order[r]``'s), weights [T, k] float32 -> (y [T, H] float32: each
+    token's computed pairs, weighed and summed; the rows fetched; whether
+    the kernel ran).  The shapes and the backend choose: the kernel, which
+    fetches the computed rows and no others, where ``moe_combine.takes``
+    (on the TPU, a prefill's pairs); else the ``lax`` form, a row for every
+    routed pair.  Both are differentiable, so no caller says which."""
+    from ..ops import moe_combine       # ops/__init__ imports models
+
+    (P, H), (T, k) = out.shape, weights.shape
+    if moe_combine.takes(T, k, H, P):
+        return moe_combine.moe_combine(out, order, weights, done), done, jnp.int32(1)
+    return _combine_lax(out, order, weights, done), jnp.int32(T * k), jnp.int32(0)
 
 
 def moe_ffn_held(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
@@ -239,7 +271,9 @@ def moe_ffn_held(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
     expert to the front of ``held_pair_rows`` rows and go through the
     grouped products; the others are left out, here as in the deployment's
     other chips' absence.  With every expert held it is ``moe_ffn`` to the
-    bit."""
+    bit where the combine is the ``lax`` form (every backend but the TPU,
+    and a step's few pairs there); through ``ops/moe_combine.py``'s kernel
+    (``_combine_held``) to the float32 rounding of the k-term sum."""
     c = config
     T, H = x.shape
     k, E, held = c.num_experts_per_tok, c.num_experts, held_experts(c)
@@ -270,14 +304,7 @@ def moe_ffn_held(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
         )
         out = grouped_matmul(hidden, f["w2"], sizes)
     with jax.named_scope("decoder/lm/moe/combine"):
-        back = jnp.zeros((T * k,), jnp.int32).at[order].set(
-            jnp.arange(T * k, dtype=jnp.int32)
-        )
-        # rows past the groups hold nothing the products wrote: masked, not
-        # multiplied by a zero weight
-        computed = (back < done).reshape(T, k)
-        picked = out[jnp.minimum(back, P - 1)].reshape(T, k, H).astype(jnp.float32)
-        y = jnp.sum(jnp.where(computed[..., None], picked * weights[..., None], 0.0), axis=1)
+        y, fetched, fused = _combine_held(out, order, weights, done)
     if "shared" in f:
         with jax.named_scope("decoder/lm/moe/shared"):
             s = f["shared"]
@@ -285,7 +312,7 @@ def moe_ffn_held(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
     with jax.named_scope("decoder/lm/moe/combine"):
         stats = HeldPairs(
             held=done, routed=jnp.int32(T * k), over=jnp.sum(landed) - done,
-            visited=jnp.sum(sizes > 0, dtype=jnp.int32),
+            visited=jnp.sum(sizes > 0, dtype=jnp.int32), fetched=fetched, fused=fused,
         )
         return x + y.astype(x.dtype), counts, experts, stats
 

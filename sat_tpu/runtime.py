@@ -1478,6 +1478,14 @@ def decode_dataset(
                 tel.gauge("decode/lm_moe_held_pair_share", float(pairs[0] / max(pairs[1], 1.0)))  # sync-ok: host numpy, already drained
                 fused, blocks = np.asarray(out.decoder_stats["prefill_fused_blocks"], np.float64)  # sync-ok: decode drain boundary
                 tel.gauge("decode/lm_dsa_prefill_fused_share", float(fused / max(blocks, 1.0)))  # sync-ok: host numpy, already drained
+                # the expert layers' combine: the prefill's calls through
+                # ops/moe_combine.py's kernel / all of them (1.0 on the chip,
+                # 0.0 where the lax form ran), and the rows of the grouped
+                # products it fetched, prefill and steps / pairs routed (the
+                # kernel fetches the pairs held, the lax form every pair)
+                combine = np.asarray(out.decoder_stats["moe_combine"], np.float64)  # sync-ok: decode drain boundary
+                tel.gauge("decode/lm_moe_combine_fused_share", float(combine[0, 1] / max(combine[0, 2], 1.0)))  # sync-ok: host numpy, already drained
+                tel.gauge("decode/lm_moe_combine_rows_share", float(combine[:, 0].sum() / max(pairs[1], 1.0)))  # sync-ok: host numpy, already drained
             if out.decoder_stats and "swa_attended" in out.decoder_stats:
                 # a decoder with window layers: the bytes of their leaves
                 # of the state (the kept tail per image + the suffix per

@@ -508,6 +508,14 @@ def test_the_sparse_attention_s_scopes_and_gauges_exist(tmp_path):
     # the lax blocks ran here (the CPU): no query block went through the kernel, and the drain says so
     assert np.asarray(stats["prefill_fused_blocks"]).tolist() == [0, 1]
     assert '"decode/lm_dsa_prefill_fused_share"' in drain
+    # and the lax combine (tests/test_moe_combine.py forces the kernel): no
+    # call of the prefill's expert layers went through it, and every call
+    # fetched a row for every routed pair, so the two gauges the drain
+    # makes of these would read 0.0 and 1.0
+    combine = np.asarray(stats["moe_combine"])              # [prefill | steps, rows fetched | through the kernel | calls]
+    assert combine.shape == (2, 3) and (combine[:, 1] == 0).all() and (combine[:, 2] > 0).all()
+    assert (combine[:, 0] == pairs[:, 1]).all()
+    assert '"decode/lm_moe_combine_fused_share"' in drain and '"decode/lm_moe_combine_rows_share"' in drain
 
 
 def _dots3_config(**changes):
@@ -613,6 +621,45 @@ def test_the_fused_prefill_kernel_s_call_carries_the_scope_its_roofline_share_re
     assert names and {_bucket(rules, n) for n in names} == {"prefill_attention"}, names
     out = beam_search_jit(params, config, contexts, 1, beam_size=2, valid_size=100)
     assert np.asarray(out.decoder_stats["prefill_fused_blocks"]).tolist() == [3, 3]
+
+
+@pytest.mark.parametrize("decoder", ["glm_moe_dsa", "dots3_note"])
+def test_the_combine_s_kernel_carries_the_scope_the_route_bucket_reads(decoder, monkeypatch):
+    """``ops/moe_combine.py``'s call sits under
+    ``beam/prefill/.../decoder/lm/moe/combine``: the rule of
+    benchmark/scopes/lm_beam_search.json that feeds
+    ``lm_moe_route_device_ms`` claims it, so the bucket keeps reading the
+    combine when the kernel is what runs it (traced with the kernel under
+    its test hook, a prefill's line lowered to the toy's 36 positions x 3,
+    a stream of 128 lanes); and what the search then reports is what the
+    drain's two gauges are made of: every call of the prefill through the
+    kernel, the held pairs fetched there and every routed pair in the
+    steps."""
+    from sat_tpu.models import decoders
+    from sat_tpu.ops import moe_combine
+    from sat_tpu.ops.beam_search import beam_search_jit
+
+    monkeypatch.setattr(moe_combine, "FORCE_INTERPRET", True)
+    monkeypatch.setattr(moe_combine, "_MIN_PAIRS", 36 * 3)
+    config = (_dsa_config if decoder == "glm_moe_dsa" else _dots3_config)(
+        hidden_size=128, max_caption_length=4, beam_size=2
+    )
+    params = decoders.init_params(jax.random.PRNGKey(0), config)
+    contexts = jnp.zeros((2, config.num_ctx, config.dim_ctx))
+    text = beam_search_jit.lower(params, config, contexts, 1, beam_size=2, valid_size=100).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*moe_combine[^"]*)"', text))
+    assert names and all(re.search(r"beam/prefill.*decoder/lm/moe/combine/", n) for n in names), names
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                           "benchmark", "scopes", "lm_beam_search.json")) as f:
+        rules = [tuple(r) for r in json.load(f)["rules"]]
+    assert {_bucket(rules, n) for n in names} == {"route"}, names
+    out = beam_search_jit(params, config, contexts, 1, beam_size=2, valid_size=100)
+    pairs, combine = np.asarray(out.decoder_stats["moe_pairs"]), np.asarray(out.decoder_stats["moe_combine"])
+    assert combine[0, 1] == combine[0, 2] > 0 and combine[1, 1] == 0     # fused_share 1.0; the steps keep the lax form
+    rows_share = combine[:, 0].sum() / pairs[:, 1].sum()
+    held_share = pairs[:, 0].sum() / pairs[:, 1].sum()
+    assert combine[0, 0] == pairs[0, 0] and combine[1, 0] == pairs[1, 1]
+    assert held_share < rows_share < 1.0        # the held share + the steps' pairs not held
 
 
 def test_parse_op_scopes_on_a_written_module():
