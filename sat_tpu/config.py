@@ -57,6 +57,13 @@ class Config:
     # reads, an indexer in every one) and "sliding_attention" layers (the
     # swa_* fields, the last sliding_window_size positions, no indexer)
     # (models/dots3_note.py; dots-studio/dots3-note-prev).
+    # "cohere2_moe": a PARALLEL block (one LayerNorm, attention and the
+    # expert layer both on the normed input, one residual add) over
+    # grouped-query attention of head_dim-wide heads: "sliding_attention"
+    # layers with rope over the last sliding_window_size positions beside
+    # "full_attention" layers with no positional term; n_shared_experts
+    # shared experts AVERAGED beside the routed sum; a tied head times
+    # logit_scale (models/cohere2_moe.py; CohereLabs/command-a-plus-05-2026).
     decoder: str = "lstm"
     hidden_size: int = 2048
     intermediate_size: int = 7168          # dense SwiGLU of the leading layers
@@ -128,6 +135,11 @@ class Config:
     first_expert: int = 0
     # lfm2_moe's head IS its embedding; the others read this
     tie_word_embeddings: bool = True
+    # cohere2_moe only, named as in the source: a head's width where it is
+    # not hidden_size / num_attention_heads (0: it is), and the factor on
+    # the logits
+    head_dim: int = 0
+    logit_scale: float = 1.0
     # train_cnn's twin for the language-model stack: frozen by default,
     # so the connector alone trains and Adam holds slots for it alone
     train_lm: bool = False
@@ -598,7 +610,9 @@ class Config:
         same, /root/reference/model.py:16-21)."""
         checks = (
             ("cnn", ("vgg16", "resnet50")),
-            ("decoder", ("lstm", "lfm2_moe", "deepseek_v3", "glm_moe_dsa", "dots3_note")),
+            ("decoder", (
+                "lstm", "lfm2_moe", "deepseek_v3", "glm_moe_dsa", "dots3_note", "cohere2_moe",
+            )),
             ("attention_gate", ("none", "headwise")),
             ("phase", ("train", "eval", "test", "serve", "route", "bulk")),
             ("optimizer", ("Adam", "RMSProp", "Momentum", "SGD")),
@@ -862,6 +876,7 @@ class Config:
         kinds = {
             "lfm2_moe": ("conv", "full_attention"),
             "dots3_note": ("full_attention", "sliding_attention"),
+            "cohere2_moe": ("full_attention", "sliding_attention"),
         }.get(self.decoder, ("latent_attention",))
         if len(self.layer_types) != self.num_hidden_layers or any(
             k not in kinds for k in self.layer_types
@@ -887,10 +902,29 @@ class Config:
                     'Config.tie_word_embeddings=False: decoder="lfm2_moe" '
                     "has no head but its embedding"
                 )
+        elif self.decoder == "cohere2_moe":
+            if (
+                self.num_attention_heads % self.num_key_value_heads
+                or (self.head_dim or self.hidden_size // self.num_attention_heads) % 2
+                or self.sliding_window_size < 1 or self.n_shared_experts < 0
+                or self.num_dense_layers or not self.tie_word_embeddings
+            ):
+                raise ValueError(
+                    'Config: decoder="cohere2_moe" takes num_attention_heads in '
+                    "num_key_value_heads groups, an even head (rotary pairs), "
+                    "sliding_window_size at least 1, n_shared_experts not negative, "
+                    "num_dense_layers=0 (every layer an expert layer) and "
+                    "tie_word_embeddings=True (no head but its embedding)"
+                )
         elif self.qk_rope_head_dim % 2 or self.n_shared_experts < 0:
             raise ValueError(
                 "Config: qk_rope_head_dim must be even (rotary pairs) and "
                 "n_shared_experts not negative"
+            )
+        if self.decoder != "cohere2_moe" and (self.head_dim or self.logit_scale != 1.0):
+            raise ValueError(
+                'Config.head_dim / logit_scale: only decoder="cohere2_moe" reads '
+                f'them; decoder="{self.decoder}" takes head_dim=0 and logit_scale=1.0'
             )
         if self.decoder == "dots3_note":
             # every full layer computes its own selection: there is no list

@@ -69,6 +69,7 @@ from ..config import Config
 from . import deepseek_v3, lm_common
 from .deepseek_v3 import _head, _kv_b, _latents, _rope, _rope_tables, _swapped_columns
 from .lm_common import HeldPairs, Params, layer_name, mm, rms_norm
+from .lm_common import sum_pairs as _sum_pairs
 
 _QUERY_BLOCK = 512          # queries a block of a whole sequence's attention
 _INDEX_NORM_EPS = 1e-6      # the indexer key's LayerNorm
@@ -466,17 +467,6 @@ def _ffn(p: Params, config: Config, layer: int, x: jnp.ndarray):
         held=pairs, routed=pairs, over=jnp.int32(0),
         visited=jnp.sum(sizes > 0, dtype=jnp.int32), fetched=pairs, fused=jnp.int32(0),
     )
-
-
-def _sum_pairs(held) -> jnp.ndarray:
-    """[6] int32 of a list of ``HeldPairs``, one an expert-layer call:
-    held, routed, over | fetched, calls through the combine's kernel, calls."""
-    if not held:
-        return jnp.zeros((6,), jnp.int32)
-    return jnp.stack(
-        [sum(getattr(h, name) for h in held) for name in ("held", "routed", "over", "fetched", "fused")]
-        + [jnp.int32(len(held))]
-    ).astype(jnp.int32)
 
 
 def _one_sequence(

@@ -1,6 +1,7 @@
 """What the language-model caption decoders share (``models/lfm2.py``,
-``models/deepseek_v3.py``): the connector and the embedding, RMSNorm, the
-bfloat16 product, SwiGLU, the dense ffn, the mixture of experts (router,
+``models/deepseek_v3.py``, the stacks built on them and
+``models/cohere2_moe.py``): the connector and the embedding, RMSNorm and
+LayerNorm, the bfloat16 product, SwiGLU, the dense ffn, the mixture of experts (router,
 the sort by expert, the grouped product, the weighted un-sort, a shared
 expert where the layer has one), the counters a step carries and the
 record of chosen experts.  Each stack keeps what is its own: its sequence
@@ -20,7 +21,12 @@ which the sources' routers differ: an argument), times
 (token, expert) pairs are sorted by expert and go through a grouped
 product (``grouped_matmul``), which computes those pairs and no others.
 A layer whose ``feed_forward`` holds a ``shared`` SwiGLU sends every token
-through it too, beside the routed sum.
+through it too, beside the routed sum.  The expert layer proper
+(``moe_experts``, ``moe_experts_held``) takes an already normed input and
+returns what it adds, so that a block with ONE norm and ONE add for
+attention and feed-forward alike can call it; ``moe_ffn`` and
+``moe_ffn_held`` are the serial block's: their own norm before, the
+residual add after.
 
 An expert layer may hold a SHARE of its experts (``Config.experts_held``
 from ``Config.first_expert``: one chip's of an expert-parallel
@@ -79,6 +85,16 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
     return x * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)
 
 
+def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
+    """LayerNorm with a weight and no bias (the mean taken out, then the
+    variance's root): float32 in and out of the statistics; the caller
+    casts."""
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(x * x, axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)
+
+
 def mm(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     """bfloat16 operands, float32 accumulation, bfloat16 result."""
     return jnp.dot(
@@ -112,7 +128,14 @@ def route(p: Params, config: Config, h: jnp.ndarray, sum_eps: float):
 # widths (PERF.md section 6): 2048 x 1792 experts PR 26, 2048 x 768 PR 30;
 # 6144 x 2048 PR 32, not timed against others: the default's 512-row tile
 # does not fit the kernel's 16 MB beside a 2048 x 1024 tile of a map, and
-# an image's share here is ~128 rows an expert, so row tiles stay small
+# an image's share here is ~128 rows an expert, so row tiles stay small;
+# 4096 x 4096 PR 43 (16 experts; ms a call: a step's 96 rows over 8 experts
+# (128, 4096, 512) 0.381 | (32, 2048, 1024) 0.395 | (128, 2048, 1024) 0.426
+# | (128, 2048, 512) 0.466; an image's 9,216 pairs in 36,864 rows
+# (256, 2048, 1024) 3.075 | (256, 1024, 1024) 3.101 | (512, 512, 2048) 3.212
+# | (512, 1024, 1024) 3.219 | (512, 2048, 512) 3.325 | (1024, 512, 1024)
+# 4.577; the fall-back's (512, 2048, 1024) and (128, 4096, 1024) do not fit
+# the kernel's 16 MB)
 _GMM_TILES = {
     (6144, 2048): ((128, 2048, 1024), (256, 2048, 1024)),
     (2048, 6144): ((128, 2048, 1024), (256, 2048, 1024)),
@@ -120,6 +143,7 @@ _GMM_TILES = {
     (1792, 2048): ((128, 2048, 1024), (512, 2048, 512)),
     (2048, 768): ((128, 2048, 768), (256, 2048, 768)),
     (768, 2048): ((128, 768, 2048), (512, 768, 2048)),
+    (4096, 4096): ((128, 4096, 512), (256, 2048, 1024)),
 }
 
 
@@ -159,19 +183,32 @@ def grouped_matmul(rows: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray) -> jnp
     return out[:P] if pad else out
 
 
-def moe_ffn(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
-    """x [T, H] -> (x + experts' weighted sum [T, H], tokens per expert
-    [E] int32, experts chosen [T, k] int32).  Only the T*k routed pairs
-    are computed, grouped by expert; no capacity, nothing dropped.  With a
-    ``shared`` expert in the layer, every token's pass through it is added
-    to the routed sum."""
+def shared_experts(f: Params, h: jnp.ndarray, mean_of: int = 1) -> jnp.ndarray:
+    """The layer's shared branch for every token, float32: ONE SwiGLU as
+    wide as all the shared experts side by side (``shared/w1``, ``w3``
+    [H, n * I], ``w2`` [n * I, H]), whose output IS the sum of the n
+    experts' outputs.  ``mean_of`` = n where the source averages them: the
+    sum divided by n, once, after the product (exact where n is a power of
+    two)."""
+    with jax.named_scope("decoder/lm/moe/shared"):
+        s = f["shared"]
+        y = mm(swiglu(mm(h, s["w1"]), mm(h, s["w3"])), s["w2"]).astype(jnp.float32)
+        return y if mean_of == 1 else y / mean_of
+
+
+def moe_experts(f: Params, config: Config, h: jnp.ndarray, sum_eps: float):
+    """The expert layer on an already NORMED h [T, H] bfloat16 -> (y [T, H]
+    float32: the experts' weighted sum, + the shared branch where the layer
+    has one; tokens per expert [E] int32; experts chosen [T, k] int32).  No
+    norm and no residual add: a serial block (``moe_ffn``) wraps it in its
+    own, a parallel block shares one of each with its attention.  Only the
+    T*k routed pairs are computed, grouped by expert; no capacity, nothing
+    dropped."""
     c = config
-    T, H = x.shape
+    T, H = h.shape
     k, E = c.num_experts_per_tok, c.num_experts
     with jax.named_scope("decoder/lm/moe/route"):
-        h = rms_norm(x, p["ffn_norm"], c.norm_eps).astype(jnp.bfloat16)
-        experts, weights = route(p["feed_forward"], c, h, sum_eps)
-    f = p["feed_forward"]
+        experts, weights = route(f, c, h, sum_eps)
     with jax.named_scope("decoder/lm/moe/dispatch"):
         flat = experts.reshape(T * k)
         order = jnp.argsort(flat, stable=True)           # pairs, by expert
@@ -192,9 +229,17 @@ def moe_ffn(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
         picked = out[back].reshape(T, k, H).astype(jnp.float32)
         y = jnp.sum(picked * weights[..., None], axis=1)
     if "shared" in f:
-        with jax.named_scope("decoder/lm/moe/shared"):
-            s = f["shared"]
-            y = y + mm(swiglu(mm(h, s["w1"]), mm(h, s["w3"])), s["w2"]).astype(jnp.float32)
+        y = y + shared_experts(f, h)
+    return y, sizes, experts
+
+
+def moe_ffn(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
+    """The serial block's expert layer: x [T, H] -> (x +
+    ``moe_experts(ffn_norm(x))`` [T, H], tokens per expert [E] int32,
+    experts chosen [T, k] int32)."""
+    with jax.named_scope("decoder/lm/moe/route"):
+        h = rms_norm(x, p["ffn_norm"], config.norm_eps).astype(jnp.bfloat16)
+    y, sizes, experts = moe_experts(p["feed_forward"], config, h, sum_eps)
     with jax.named_scope("decoder/lm/moe/combine"):
         return x + y.astype(x.dtype), sizes, experts
 
@@ -232,6 +277,17 @@ class HeldPairs(NamedTuple):
     fused: jnp.ndarray      # 1 where the combine went through ops/moe_combine.py's kernel
 
 
+def sum_pairs(held) -> jnp.ndarray:
+    """[6] int32 of a list of ``HeldPairs``, one an expert-layer call:
+    held, routed, over | fetched, calls through the combine's kernel, calls."""
+    if not held:
+        return jnp.zeros((6,), jnp.int32)
+    return jnp.stack(
+        [sum(getattr(h, name) for h in held) for name in ("held", "routed", "over", "fetched", "fused")]
+        + [jnp.int32(len(held))]
+    ).astype(jnp.int32)
+
+
 def _combine_lax(out, order, weights, done):
     """``ops/moe_combine.py``'s contract by ``lax``: the sort inverted by a
     scatter over all T * k pairs, a row gathered for EVERY pair, the ones
@@ -262,26 +318,21 @@ def _combine_held(out, order, weights, done):
     return _combine_lax(out, order, weights, done), jnp.int32(T * k), jnp.int32(0)
 
 
-def moe_ffn_held(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
-    """``moe_ffn`` for a layer that holds experts ``[first_expert,
-    first_expert + experts_held)``: x [T, H] -> (x + the held experts'
-    weighted sum (+ the shared expert's) [T, H], tokens per expert over ALL
-    ``num_experts`` [E] int32, experts chosen [T, k] int32, ``HeldPairs``).
-    The router scores every expert; the pairs that land here are sorted by
-    expert to the front of ``held_pair_rows`` rows and go through the
-    grouped products; the others are left out, here as in the deployment's
-    other chips' absence.  With every expert held it is ``moe_ffn`` to the
-    bit where the combine is the ``lax`` form (every backend but the TPU,
-    and a step's few pairs there); through ``ops/moe_combine.py``'s kernel
-    (``_combine_held``) to the float32 rounding of the k-term sum."""
+def moe_experts_held(f: Params, config: Config, h: jnp.ndarray, sum_eps: float, shared_mean_of: int = 1):
+    """``moe_experts`` for a layer that holds experts ``[first_expert,
+    first_expert + experts_held)``: h [T, H] normed bfloat16 -> (y [T, H]
+    float32: the held experts' weighted sum (+ the shared branch), tokens
+    per expert over ALL ``num_experts`` [E] int32, experts chosen [T, k]
+    int32, ``HeldPairs``).  The router scores every expert; the pairs that
+    land here are sorted by expert to the front of ``held_pair_rows`` rows
+    and go through the grouped products; the others are left out, here as
+    in the deployment's other chips' absence."""
     c = config
-    T, H = x.shape
+    T, H = h.shape
     k, E, held = c.num_experts_per_tok, c.num_experts, held_experts(c)
     P = held_pair_rows(c, T)
     with jax.named_scope("decoder/lm/moe/route"):
-        h = rms_norm(x, p["ffn_norm"], c.norm_eps).astype(jnp.bfloat16)
-        experts, weights = route(p["feed_forward"], c, h, sum_eps)
-    f = p["feed_forward"]
+        experts, weights = route(f, c, h, sum_eps)
     with jax.named_scope("decoder/lm/moe/dispatch"):
         flat = experts.reshape(T * k)
         local = flat - c.first_expert
@@ -306,14 +357,27 @@ def moe_ffn_held(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
     with jax.named_scope("decoder/lm/moe/combine"):
         y, fetched, fused = _combine_held(out, order, weights, done)
     if "shared" in f:
-        with jax.named_scope("decoder/lm/moe/shared"):
-            s = f["shared"]
-            y = y + mm(swiglu(mm(h, s["w1"]), mm(h, s["w3"])), s["w2"]).astype(jnp.float32)
+        y = y + shared_experts(f, h, shared_mean_of)
     with jax.named_scope("decoder/lm/moe/combine"):
         stats = HeldPairs(
             held=done, routed=jnp.int32(T * k), over=jnp.sum(landed) - done,
             visited=jnp.sum(sizes > 0, dtype=jnp.int32), fetched=fetched, fused=fused,
         )
+    return y, counts, experts, stats
+
+
+def moe_ffn_held(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
+    """The serial block's expert layer at a held share: x [T, H] -> (x +
+    ``moe_experts_held(ffn_norm(x))`` [T, H], tokens per expert [E],
+    experts chosen [T, k], ``HeldPairs``).  With every expert held it is
+    ``moe_ffn`` to the bit where the combine is the ``lax`` form (every
+    backend but the TPU, and a step's few pairs there); through
+    ``ops/moe_combine.py``'s kernel (``_combine_held``) to the float32
+    rounding of the k-term sum."""
+    with jax.named_scope("decoder/lm/moe/route"):
+        h = rms_norm(x, p["ffn_norm"], config.norm_eps).astype(jnp.bfloat16)
+    y, counts, experts, stats = moe_experts_held(p["feed_forward"], config, h, sum_eps)
+    with jax.named_scope("decoder/lm/moe/combine"):
         return x + y.astype(x.dtype), counts, experts, stats
 
 
@@ -346,11 +410,14 @@ def ffn_params(config: Config, layer: int, linear) -> Params:
         I = c.intermediate_size
         return {"w1": linear(H, I), "w3": linear(H, I), "w2": linear(I, H)}
     I = c.moe_intermediate_size
-    return {
+    f = {
         "gate": linear(H, E),
         "expert_bias": jnp.zeros((E,), jnp.float32),
         "w1": linear(held, H, I), "w3": linear(held, H, I), "w2": linear(held, I, H),
     }
+    if not c.use_expert_bias:       # a router with no selection bias has no such leaf
+        del f["expert_bias"]
+    return f
 
 
 def connector_params(key: jax.Array, config: Config) -> Params:

@@ -20,8 +20,12 @@ maximum and a running sum (online softmax); no float32 score reaches HBM,
 where the ``lax`` form writes a block of them and reads it back three
 times.
 
-Shapes, not models.  ``q``, ``k`` ``[heads, S, d_qk]`` and ``v``
-``[heads, S, d_v]`` are head-major, each head's rows together (the layout
+Shapes, not models.  ``q`` ``[heads, S, d_qk]``, ``k``
+``[heads / group, S, d_qk]`` and ``v`` ``[heads / group, S, d_v]`` are
+head-major (``group`` = 1: a key/value head a query head; more: query head
+``h`` reads key/value head ``h // group``, grouped queries, and a key/value
+block is fetched once for the heads of a program that share it, never
+replicated), each head's rows together (the layout
 the einsum that makes them is asked for: a free choice of its output, where
 a ``[bq, 1, d]`` block of ``[S, heads, d]`` is no legal tile and a
 transposing copy of three such arrays costs more than the kernel saves);
@@ -120,9 +124,10 @@ def _first_key_tile(qi, bq: int, bk: int, window: int):
     return jnp.maximum(qi * bq - (window - 1), 0) // bk
 
 
-def _kernel(*refs, heads, d_v, bq, bk, scale, first_masked, window):
+def _kernel(*refs, heads, kv_heads, d_v, bq, bk, scale, first_masked, window):
     """Grid (head group, query tile, key tile), the key tiles innermost.
-    Blocks: q [heads, bq, d_qk], k [heads, bk, d_qk], v [heads, bk, d_v],
+    Blocks: q [heads, bq, d_qk], k [kv_heads, bk, d_qk], v [kv_heads, bk,
+    d_v] (the program's query head h reads ``h * kv_heads // heads``),
     mask [bq, bk] int8 (where the call has one), out [bq, heads * d_v];
     scratch per head: the running maximum and sum [heads, bq, lanes], the
     accumulator [heads, bq, d_v], float32."""
@@ -156,8 +161,9 @@ def _kernel(*refs, heads, d_v, bq, bk, scale, first_masked, window):
         bias = jnp.where(seen, 0.0, _NEG_INF)
 
         def one_head(h, _):
+            kv = h if kv_heads == heads else h * kv_heads // heads
             s = jax.lax.dot_general(
-                q_ref[h], k_ref[h], (((1,), (1,)), ((), ())),
+                q_ref[h], k_ref[kv], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * scale + bias
             m_prev, l_prev = m_ref[h], l_ref[h]
@@ -167,7 +173,7 @@ def _kernel(*refs, heads, d_v, bq, bk, scale, first_masked, window):
             l_ref[h] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
             m_ref[h] = m_next
             acc_ref[h] = acc_ref[h] * _across(alpha, d_v) + jnp.dot(
-                p.astype(jnp.bfloat16), v_ref[h], preferred_element_type=jnp.float32,
+                p.astype(jnp.bfloat16), v_ref[kv], preferred_element_type=jnp.float32,
             )
 
         jax.lax.fori_loop(0, heads, one_head, None, unroll=_UNROLL)
@@ -192,8 +198,9 @@ def flash_prefill(
     interpret: bool = False,
     window: Optional[int] = None,
 ) -> jnp.ndarray:
-    """q, k [heads, S, d_qk], v [heads, S, d_v] bfloat16, one causal
-    sequence; mask [M, S] (int8 or bool; nonzero: query S - M + i attends
+    """q [heads, S, d_qk], k [heads / group, S, d_qk], v [heads / group, S,
+    d_v] bfloat16 (``group`` from the shapes: 1, or grouped queries), one
+    causal sequence; mask [M, S] (int8 or bool; nonzero: query S - M + i attends
     key j, where j is also at or before it) or None -> [S, heads * d_v]
     bfloat16.  ``tiles``: (queries, keys, heads) a program, each dividing
     what it tiles (and S - M whole query tiles); None takes ``_TILES``.
@@ -201,6 +208,7 @@ def flash_prefill(
     before it, no others."""
     heads, S, d_qk = q.shape
     d_v = v.shape[2]
+    group, uneven = divmod(heads, k.shape[0])
     masked = 0 if mask is None else mask.shape[0]
     bq, bk, hb = tiles or _tiles(S, S - masked, heads)
     if S % bq or S % bk or heads % hb or (S - masked) % bq:
@@ -208,6 +216,20 @@ def flash_prefill(
             f"tiles {(bq, bk, hb)} do not divide {S} positions ({masked} under the "
             f"mask) and {heads} heads"
         )
+    if uneven or k.shape[0] != v.shape[0] or (hb % group and group % hb):
+        raise ValueError(
+            f"{heads} query heads, {hb} a program, over {k.shape[0]} key and "
+            f"{v.shape[0]} value heads: a program's heads share whole key/value heads"
+        )
+    # key/value heads a program: those its query heads read, one at least;
+    # program g's begin at key/value head g * hb // group
+    kvb = max(hb // group, 1)
+    if group == 1:
+        def kv_block(g):
+            return g
+    else:
+        def kv_block(g):
+            return g * hb // (group * kvb)
     first_masked = (S - masked) // bq if masked else None
 
     key_tiles = S // bk
@@ -230,8 +252,8 @@ def flash_prefill(
 
     in_specs = [
         pl.BlockSpec((hb, bq, d_qk), lambda g, qi, ki: (g, qi, 0)),
-        pl.BlockSpec((hb, bk, d_qk), lambda g, qi, ki: (g, key_tile(qi, ki), 0)),
-        pl.BlockSpec((hb, bk, d_v), lambda g, qi, ki: (g, key_tile(qi, ki), 0)),
+        pl.BlockSpec((kvb, bk, d_qk), lambda g, qi, ki: (kv_block(g), key_tile(qi, ki), 0)),
+        pl.BlockSpec((kvb, bk, d_v), lambda g, qi, ki: (kv_block(g), key_tile(qi, ki), 0)),
     ]
     operands = [q, k, v]
     if masked:
@@ -250,7 +272,7 @@ def flash_prefill(
     lanes = _LANES if bk % _LANES == 0 and d_v % _LANES == 0 else 1
     return pl.pallas_call(
         partial(
-            _kernel, heads=hb, d_v=d_v, bq=bq, bk=bk, scale=scale,
+            _kernel, heads=hb, kv_heads=kvb, d_v=d_v, bq=bq, bk=bk, scale=scale,
             first_masked=first_masked, window=window,
         ),
         name="flash_prefill",

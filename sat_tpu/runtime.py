@@ -1466,23 +1466,25 @@ def decode_dataset(
                     float(out.decoder_stats["state_bytes"]) / 1e6,  # sync-ok: decode drain boundary
                 )
             if out.decoder_stats and "dsa_attended" in out.decoder_stats:
-                # a decoder that selects positions and holds a share of
-                # its experts: positions attended / positions visible over
-                # the steps; pairs computed here / pairs routed; the
-                # prefill's query blocks whose scores stayed in the fused
-                # kernel / all of them (1.0 on the chip, 0.0 where the lax
-                # form ran: a silent fall-back shows here)
+                # a decoder that selects positions: positions attended /
+                # positions visible over the steps; the prefill's query
+                # blocks whose scores stayed in the fused kernel / all of
+                # them (1.0 on the chip, 0.0 where the lax form ran: a
+                # silent fall-back shows here)
                 attended, visible = np.asarray(out.decoder_stats["dsa_attended"], np.float64)  # sync-ok: decode drain boundary
-                pairs = np.asarray(out.decoder_stats["moe_pairs"], np.float64).sum(axis=0)  # sync-ok: decode drain boundary
                 tel.gauge("decode/lm_dsa_selected_share", float(attended / max(visible, 1.0)))  # sync-ok: host numpy, already drained
-                tel.gauge("decode/lm_moe_held_pair_share", float(pairs[0] / max(pairs[1], 1.0)))  # sync-ok: host numpy, already drained
                 fused, blocks = np.asarray(out.decoder_stats["prefill_fused_blocks"], np.float64)  # sync-ok: decode drain boundary
                 tel.gauge("decode/lm_dsa_prefill_fused_share", float(fused / max(blocks, 1.0)))  # sync-ok: host numpy, already drained
-                # the expert layers' combine: the prefill's calls through
-                # ops/moe_combine.py's kernel / all of them (1.0 on the chip,
-                # 0.0 where the lax form ran), and the rows of the grouped
-                # products it fetched, prefill and steps / pairs routed (the
-                # kernel fetches the pairs held, the lax form every pair)
+            if out.decoder_stats and "moe_pairs" in out.decoder_stats:
+                # a decoder that holds a share of its experts: pairs
+                # computed here / pairs routed.  The expert layers' combine:
+                # the prefill's calls through ops/moe_combine.py's kernel /
+                # all of them (1.0 on the chip, 0.0 where the lax form ran),
+                # and the rows of the grouped products it fetched, prefill
+                # and steps / pairs routed (the kernel fetches the pairs
+                # held, the lax form every pair)
+                pairs = np.asarray(out.decoder_stats["moe_pairs"], np.float64).sum(axis=0)  # sync-ok: decode drain boundary
+                tel.gauge("decode/lm_moe_held_pair_share", float(pairs[0] / max(pairs[1], 1.0)))  # sync-ok: host numpy, already drained
                 combine = np.asarray(out.decoder_stats["moe_combine"], np.float64)  # sync-ok: decode drain boundary
                 tel.gauge("decode/lm_moe_combine_fused_share", float(combine[0, 1] / max(combine[0, 2], 1.0)))  # sync-ok: host numpy, already drained
                 tel.gauge("decode/lm_moe_combine_rows_share", float(combine[:, 0].sum() / max(pairs[1], 1.0)))  # sync-ok: host numpy, already drained

@@ -8,10 +8,11 @@ costs the others nothing before the chip says so.
 ``tree`` (default: this checkout) is put first on ``sys.path``.  The
 programs and their shapes are ``tests/test_aot_tpu.py``'s: the LSTM's
 (B = 64), ``lfm2_moe`` (B = 256, one period), ``deepseek_v3`` at kanana2's
-widths (B = 256) and ``glm_moe_dsa`` at GLM-5.2's (B = 8 images of 1,024
-px).  ``_strip_metadata`` drops the Mosaic kernels' serialized bodies with
+widths (B = 256), ``glm_moe_dsa`` at GLM-5.2's and ``dots3_note`` at
+dots3-note-prev's (B = 8 images of 1,024 px each).  ``_strip_metadata`` drops the Mosaic kernels' serialized bodies with
 the source locations they embed, so the fused prefill kernel is held
-beside them by its jaxpr (which prints no location) at the glm52 shape.
+beside them by its jaxpr (which prints no location) at the glm52 shape and
+at the dots3 sliding layers' (a window, no mask).
 """
 
 import hashlib
@@ -46,6 +47,7 @@ PROGRAMS = {
                        num_experts=128, num_experts_per_tok=6, routed_scaling_factor=2.448, norm_eps=1e-6,
                        tie_word_embeddings=False, layer_types=("latent_attention",) * 5), 256),
     "glm52": (aot._glm52_config(), 8),
+    "dots3": (aot._dots3_config(), 8),
 }
 
 
@@ -66,3 +68,7 @@ kernel = jax.make_jaxpr(
     lambda q, k, v, m: flash_prefill.flash_prefill(q, k, v, m, scale=0.0625)
 )(sd(64, 4096, 256), sd(64, 4096, 256), sd(64, 4096, 256), sd(2048, 4096, dtype=jnp.int8))
 print("flash_prefill_glm52_jaxpr", sha(str(kernel)), flush=True)
+windowed = jax.make_jaxpr(
+    lambda q, k, v: flash_prefill.flash_prefill(q, k, v, None, scale=0.0625, window=513)
+)(sd(64, 4096, 256), sd(64, 4096, 256), sd(64, 4096, 128))
+print("flash_prefill_dots3_window_jaxpr", sha(str(windowed)), flush=True)

@@ -675,6 +675,63 @@ def test_dots3_beam_program_fits_the_chip_and_keeps_a_window(monkeypatch):
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < int(15.5e9), memory
 
 
+def _command_a_config():
+    return Config(
+        decoder="cohere2_moe", image_size=1536, vocabulary_size=32768, hidden_size=4096,
+        moe_intermediate_size=4096, num_hidden_layers=4, num_dense_layers=0, num_attention_heads=128,
+        num_key_value_heads=8, head_dim=128, num_experts=128, num_experts_per_tok=8, experts_held=16,
+        first_expert=0, n_shared_experts=4, use_expert_bias=False, routed_scaling_factor=1.0, norm_eps=1e-5,
+        rope_theta=5e4, sliding_window_size=4096, tie_word_embeddings=True,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",),
+    )
+
+
+def test_command_a_beam_program_fits_the_chip_and_keeps_caches_of_two_lengths(monkeypatch):
+    """``decoder="cohere2_moe"`` at its cell's batch and the published widths
+    (B = 4 images of 1,536 px: N = 9,216; K = 3; depth 4 = sliding x 3,
+    full; 16 of 128 experts of 4,096 held; V = 32,768): accepted by the
+    chip's compiler (the grouped products at tiles that fit its 16 MB, the
+    combine's kernel at a tile of y for 9,216 tokens), arguments (9.5 GB of
+    weights) and temporaries under the chip; the prefill's attention ONE
+    grouped kernel a sliding layer under its window bound, fed keys and
+    values of 8 heads, never 128; NONE for the full layer, whose output
+    nothing reads at a prefix position (it is the last layer of a parallel
+    block: its keys, values and routes alone are live); the steps read the
+    full layer's prefix whole (``bf16[4,9216,1024]``) and a sliding layer's
+    TAIL (``bf16[4,4095,1024]``), never a copy a beam."""
+    from sat_tpu.ops.beam_search import beam_search_jit
+
+    config = _command_a_config()
+    V, K, N = config.vocabulary_size, 3, config.num_ctx
+    assert N == 9216
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, decoder = _decoder_params(config)
+    compiled = beam_search_jit.lower(
+        decoder, config, _sd((4, N, config.dim_ctx)), 1, beam_size=K, valid_size=V,
+    ).compile()
+    text = compiled.as_text()
+    _assert_the_step_selects_per_row(text, 4, K, V)
+    lines = text.splitlines()
+    fused = [ln for ln in lines if "tpu_custom_call" in ln and "flash_prefill" in ln]
+    assert len(fused) == 3
+    assert all(re.search(r"beam/prefill[^\"]*decoder/lm/attn/window/scores/", ln) for ln in fused)
+    assert all("bf16[8,9216,128]" in ln and "bf16[128,9216,128]" in ln for ln in fused)
+    loop = {shape for ln in _loop_lines(text) for shape in re.findall(r"(?:bf16|f32)\[[\d,]+\]", ln)}
+    assert f"bf16[4,{N},1024]" in loop and "bf16[4,4095,1024]" in loop
+    assert not [s for s in loop if re.search(rf"\[12,({N}|4095),", s)], loop
+    shapes = set(re.findall(r"(?:bf16|f32|pred|s32|u32)\[[\d,]+\]", text))
+    assert not [s for s in shapes if re.search(rf"\[(\d+,)*{N},{N}\]", s)], shapes
+    # three grouped products an expert layer: four layers' in the steps, three in the prefill; the
+    # combine's kernel in the prefill's three
+    assert len(re.findall(r"decoder/lm/moe/experts[^\n]*tpu_custom_call|"
+                          r"tpu_custom_call[^\n]*decoder/lm/moe/experts", text)) == 21
+    assert len([ln for ln in lines if "tpu_custom_call" in ln and "moe_combine" in ln]) == 3
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > int(9.4e9)
+    assert memory.temp_size_in_bytes < int(4.0e9), memory
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < int(15.5e9), memory
+
+
 @pytest.mark.parametrize("V", [65536, 128256], ids=["lfm2", "kanana2"])
 def test_beam_step_alone_needs_no_vocabulary_sized_temporary(V):
     """``_expand_step`` by itself over the lm cells' 768 rows of logits:
