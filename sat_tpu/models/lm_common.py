@@ -125,7 +125,7 @@ def route(p: Params, config: Config, h: jnp.ndarray, sum_eps: float):
 
 # (k, n) of a grouped product -> its (m, k, n) tiles for a step's few rows
 # an expert and for a prefill's many, each timed on a v5e at the published
-# widths (PERF.md section 6): 2048 x 1792 experts PR 26, 2048 x 768 PR 30;
+# widths (PERF.md section 6): 2048 x 1792 experts PR 44, 2048 x 768 PR 30;
 # 6144 x 2048 PR 32, not timed against others: the default's 512-row tile
 # does not fit the kernel's 16 MB beside a 2048 x 1024 tile of a map, and
 # an image's share here is ~128 rows an expert, so row tiles stay small;
@@ -135,12 +135,47 @@ def route(p: Params, config: Config, h: jnp.ndarray, sum_eps: float):
 # (256, 2048, 1024) 3.075 | (256, 1024, 1024) 3.101 | (512, 512, 2048) 3.212
 # | (512, 1024, 1024) 3.219 | (512, 2048, 512) 3.325 | (1024, 512, 1024)
 # 4.577; the fall-back's (512, 2048, 1024) and (128, 4096, 1024) do not fit
-# the kernel's 16 MB)
+# the kernel's 16 MB).
+# 2048 x 1792 and 1792 x 2048, PR 44 (32 experts), timed inside their
+# consumers (``lfm2.step`` whole at 768 rows, 3,072 pairs routed ~96 an
+# expert; three layers of the prefill at 200,704 pairs), every candidate the
+# kernel's 16 MB took; PR 26's tiles marked *.  The kernel computes a ragged
+# last tile whole and MASKS both operands in every visit where k sits under
+# a wider tile, so tiles that divide the product win; a step fetches an
+# expert's map behind ONE visit's product, so it wants the map in one tile
+# (row tiles past 96 do not fit beside it: 112 rows 16.09 MB).  ms a call,
+# w1 / w3 a step: (96, 2048, 1792) 0.438 | (64, 2048, 1792) 0.444 |
+# (80, 2048, 1792) 0.446 | (256, 2048, 896) 0.448 |
+# (128, 2048, 896) 0.453 | (32, 2048, 1792) 0.455 | (64, 2048, 896) 0.471 |
+# (128, 2048, 1024)* 0.472 | (32, 2048, 896) 0.493 | (64, 2048, 1024) 0.497
+# | (256, 2048, 1024) 0.501 | (128, 2048, 512) 0.502 | (256, 2048, 256)
+# 0.510 | (128, 2048, 256) 0.521 | (256, 2048, 512) 0.526 | (128, 1024, 896)
+# 0.535 | (128, 1024, 1792) 0.535 | (64, 2048, 512) 0.538 | (32, 2048, 512)
+# 0.579 | (64, 2048, 256) 0.580 | (32, 2048, 1024) 0.628 | (32, 2048, 256)
+# 0.642 | (512, 2048, 896) 0.727 | (64, 1024, 1792) 0.761;
+# w2 a step: (96, 1792, 2048) 0.439 | (64, 1792, 2048) 0.446 |
+# (80, 1792, 2048) 0.447 | (256, 1792, 1024) 0.452 |
+# (128, 1792, 1024) 0.458 | (256, 896, 2048) 0.467 | (128, 2048, 1024)*
+# 0.474 | (64, 1792, 1024) 0.480 | (256, 1792, 512) 0.481 | (128, 1792, 512)
+# 0.496 | (256, 896, 1024) 0.502 | (64, 1792, 512) 0.528 | (128, 896, 2048)
+# 0.532 | (32, 1792, 2048) 0.539 | (128, 896, 1024) 0.539 | (32, 1792, 512)
+# 0.563 | (256, 896, 512) 0.571 | (32, 1792, 1024) 0.580 | (128, 896, 512)
+# 0.588 | (512, 1792, 1024) 0.729 | (64, 896, 2048) 0.760 | (64, 896, 1024)
+# 0.771 | (64, 896, 512) 0.806 | (32, 896, 2048) 1.221 | (32, 896, 1024)
+# 1.239 | (32, 896, 512) 1.294;
+# w1 / w3 a prefill: (256, 2048, 896) 8.18 | (512, 2048, 896) 8.25 |
+# (512, 512, 1792) 9.18 | (512, 1024, 896) 9.21 | (256, 2048, 1024) 9.32 |
+# (512, 2048, 512)* 9.58 | (1024, 512, 896) 9.65 | (512, 1024, 1024) 10.14 |
+# (512, 2048, 256) 10.18 | (256, 1024, 1792) 10.26 | (256, 1024, 1024) 12.09;
+# w2 a prefill: (256, 1792, 1024) 8.24 | (512, 1792, 1024) 8.28 |
+# (512, 1792, 512) 8.42 | (256, 2048, 1024) 9.30 | (512, 896, 1024) 9.34 |
+# (512, 2048, 512)* 9.56 | (1024, 896, 512) 9.95 | (512, 1024, 1024) 10.01 |
+# (256, 896, 2048) 10.35 | (256, 1024, 1024) 13.07
 _GMM_TILES = {
     (6144, 2048): ((128, 2048, 1024), (256, 2048, 1024)),
     (2048, 6144): ((128, 2048, 1024), (256, 2048, 1024)),
-    (2048, 1792): ((128, 2048, 1024), (512, 2048, 512)),
-    (1792, 2048): ((128, 2048, 1024), (512, 2048, 512)),
+    (2048, 1792): ((96, 2048, 1792), (256, 2048, 896)),
+    (1792, 2048): ((96, 1792, 2048), (256, 1792, 1024)),
     (2048, 768): ((128, 2048, 768), (256, 2048, 768)),
     (768, 2048): ((128, 768, 2048), (512, 768, 2048)),
     (4096, 4096): ((128, 4096, 512), (256, 2048, 1024)),

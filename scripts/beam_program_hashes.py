@@ -9,7 +9,8 @@ costs the others nothing before the chip says so.
 programs and their shapes are ``tests/test_aot_tpu.py``'s: the LSTM's
 (B = 64), ``lfm2_moe`` (B = 256, one period), ``deepseek_v3`` at kanana2's
 widths (B = 256), ``glm_moe_dsa`` at GLM-5.2's and ``dots3_note`` at
-dots3-note-prev's (B = 8 images of 1,024 px each).  ``_strip_metadata`` drops the Mosaic kernels' serialized bodies with
+dots3-note-prev's (B = 8 images of 1,024 px each), ``cohere2_moe`` at
+command-a-plus's (B = 4 images of 1,536 px).  ``_strip_metadata`` drops the Mosaic kernels' serialized bodies with
 the source locations they embed, so the fused prefill kernel is held
 beside them by its jaxpr (which prints no location) at the glm52 shape and
 at the dots3 sliding layers' (a window, no mask).
@@ -48,6 +49,7 @@ PROGRAMS = {
                        tie_word_embeddings=False, layer_types=("latent_attention",) * 5), 256),
     "glm52": (aot._glm52_config(), 8),
     "dots3": (aot._dots3_config(), 8),
+    "command_a": (aot._command_a_config(), 4),
 }
 
 

@@ -554,14 +554,46 @@ def test_embedding_and_head_round_trip_the_checkpoint_bit_exactly(tmp_path, para
 
 
 def test_the_grouped_product_s_tiles_come_from_its_own_shape():
-    """The two timed widths keep the tiles that won; lfm2's are PR 26's,
-    unchanged (its compiled program depends on them); a width never timed
-    gets tiles that divide it."""
+    """The 768-wide experts' tiles divide their product (every timed width
+    is held to its tuples below); a width never timed gets tiles that
+    divide it."""
     tiling = lm_common._gmm_tiling
-    for k, n in ((2048, 1792), (1792, 2048)):
-        assert tiling(3072, k, n) == (128, 2048, 1024) and tiling(200704, k, n) == (512, 2048, 512)
     for pairs in (4608, 301056):
         for k, n in ((2048, 768), (768, 2048)):
             tm, tk, tn = tiling(pairs, k, n)
             assert k % tk == 0 and n % tn == 0 and tm in ((256, 512) if pairs >= 8192 else (128,))
     assert tiling(100, 64, 24) == (128, 64, 24) and tiling(100, 4096, 1536) == (128, 2048, 512)
+
+
+# (k, n) of a timed product -> (the pairs of its cell's step and prefill, the
+# tiles PR 43's tree gave each): PR 44 swept the 1,792-wide experts' alone
+_TILED = {
+    (6144, 2048): ((192, 8192), ((128, 2048, 1024), (256, 2048, 1024))),
+    (2048, 6144): ((192, 8192), ((128, 2048, 1024), (256, 2048, 1024))),
+    (2048, 1792): ((3072, 200704), None),
+    (1792, 2048): ((3072, 200704), None),
+    (2048, 768): ((4608, 301056), ((128, 2048, 768), (256, 2048, 768))),
+    (768, 2048): ((4608, 301056), ((128, 768, 2048), (512, 768, 2048))),
+    (4096, 4096): ((96, 36864), ((128, 4096, 512), (256, 2048, 1024))),
+}
+
+
+@pytest.mark.parametrize("prefill", [False, True], ids=["step", "prefill"])
+@pytest.mark.parametrize("kn", sorted(_TILED), ids=lambda kn: f"{kn[0]}x{kn[1]}")
+def test_a_timed_width_s_tiles_in_both_regimes(kn, prefill):
+    """Every key of the table, at its own cell's pairs.  The five widths
+    PR 44 did not sweep return the parent's tuples, letter for letter
+    (their cells' programs must not move).  The 1,792-wide experts' tiles
+    DIVIDE the product: whole lanes of 128, no ragged output tile
+    (1,792 under 1,024 or 512 ran a seventh more lanes than it has), no
+    contraction under a wider tile (1,792 under 2,048 masked both operands
+    in every visit), and a row tile that leaves the cell's rows unpadded."""
+    assert sorted(_TILED) == sorted(lm_common._GMM_TILES)
+    (k, n), (pairs, kept) = kn, _TILED[kn]
+    tm, tk, tn = lm_common._gmm_tiling(pairs[prefill], k, n)
+    if kept is not None:
+        assert (tm, tk, tn) == kept[prefill]
+        return
+    assert tk % 128 == 0 and tn % 128 == 0 and k % tk == 0 and n % tn == 0
+    assert tm % 16 == 0 and pairs[prefill] % tm == 0
+    assert (tk, tn) == (k, n) or prefill     # a step's tile takes the expert's map whole

@@ -19,7 +19,10 @@ choices are compared exactly.
 
 import dataclasses
 import importlib
+import importlib.util
+import json
 import os
+import subprocess
 import sys
 
 import jax
@@ -34,7 +37,7 @@ from reference import params_lfm2  # noqa: E402
 from reference.params import nest  # noqa: E402
 
 from sat_tpu.config import Config  # noqa: E402
-from sat_tpu.models import lfm2  # noqa: E402
+from sat_tpu.models import lfm2, lm_common  # noqa: E402
 from sat_tpu.models.captioner import compute_loss  # noqa: E402
 
 bs = importlib.import_module("sat_tpu.ops.beam_search")  # ops/__init__ exports a function of that name
@@ -178,6 +181,76 @@ def test_uneven_routing_drops_nothing(params):
     with jax.default_matmul_precision("highest"):
         want, _ = ref.expert_ffn(rp, h, MODEL, "f32")
     _close(y.astype(jnp.float32) - x.astype(jnp.float32), want, LAYER_TOL)
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1792), (1792, 2048)], ids=["w1_w3", "w2"])
+def test_the_grouped_product_at_the_published_expert_widths_against_a_loop(k, n):
+    """``grouped_matmul`` off the TPU (``ragged_dot``) at the 1,792-wide
+    experts' two shapes against one plain product an expert, on sizes no
+    tile divides: an empty expert, one of a single row, the rest uneven."""
+    sizes = np.array([0, 1, 17, 5, 40, 9], np.int32)
+    rng = np.random.default_rng(k)
+    rows = jnp.asarray(rng.standard_normal((int(sizes.sum()), k), np.float32), jnp.bfloat16)
+    w = jnp.asarray(0.02 * rng.standard_normal((len(sizes), k, n), np.float32), jnp.bfloat16)
+    got = lm_common.grouped_matmul(rows, w, jnp.asarray(sizes))
+    assert got.shape == (rows.shape[0], n) and got.dtype == jnp.bfloat16
+    ends = np.cumsum(sizes)
+    want = np.concatenate([
+        np.asarray(rows[end - size:end], np.float32) @ np.asarray(w[e], np.float32)
+        for e, (size, end) in enumerate(zip(sizes, ends))
+    ])
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, atol=2 ** -7 * np.abs(want).max())
+
+
+_SWEEP = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "scripts", "gmm_tile_sweep.py")
+
+
+def _tile_sweep(*args):
+    return subprocess.run([sys.executable, _SWEEP, *args], capture_output=True, text=True, timeout=420,
+                          env=dict(os.environ, JAX_PLATFORMS="cpu"))
+
+
+def test_the_tile_sweep_rehearses_on_the_cpu_and_reports_no_device_time(tmp_path):
+    """``scripts/gmm_tile_sweep.py`` (the tool that filled the 1,792-wide
+    rows of ``_GMM_TILES``) at toy widths: both consumers compile and run
+    under two pairs of tiles each, the standing pair first; off the chip a
+    run has its wall time and NO ms a call."""
+    out = tmp_path / "sweep.json"
+    proc = _tile_sweep("--rehearse", "--out", str(out))
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    results = json.loads(out.read_text())["results"]
+    assert [r["regime"] for r in results] == ["prefill", "prefill", "step", "step"]
+    standing = lm_common._GMM_TILES
+    assert tuple(results[0]["w13"]) == standing[(2048, 1792)][1] and tuple(results[0]["w2"]) == standing[(1792, 2048)][1]
+    assert all(r["wall_ms"] > 0 and "ms_a_call" not in r and "module_ms" not in r for r in results)
+
+
+def test_the_tile_sweep_reads_each_program_s_grouped_products_from_the_trace():
+    """A program's ``gmm`` calls are the ops that start inside its module's
+    interval, by the width of their output; any other op is left out."""
+    from types import SimpleNamespace as ns
+
+    spec = importlib.util.spec_from_file_location("gmm_tile_sweep", _SWEEP)
+    sweep = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sweep)
+    event = lambda name, start, dur: ns(name=name, start_ns=start, duration_ns=dur)  # noqa: E731
+    gmm = lambda i, n, start, dur: event(f"%gmm.{i} = bf16[3072,{n}]{{1,0:T(8,128)(2,1)}} custom-call(s32[] %a)", start, dur)  # noqa: E731
+    device = ns(name="/device:TPU:0", lines=[
+        ns(name="XLA Modules", events=[event("jit_step_00_a(123)", 1000, 1000), event("jit_step_01_b(9)", 3000, 500),
+                                        event("jit_step_00_a(123)", 5000, 900)]),
+        ns(name="XLA Ops", events=[gmm(1, 1792, 1100, 50), gmm(3, 2048, 1300, 70), gmm(1, 1792, 3100, 40),
+                                   event("%fusion.2 = bf16[3072,1792]{1,0} fusion(bf16[] %gmm.1)", 1400, 99),
+                                   gmm(1, 1792, 5100, 54), gmm(9, 1792, 9000, 1)]),
+    ])
+    got = sweep.gmm_ns_by_module([ns(name="/host:CPU", lines=[]), device])
+    assert got == {"jit_step_00_a": {"runs": [1000, 900], "calls": {1792: [50, 54], 2048: [70]}},
+                   "jit_step_01_b": {"runs": [500], "calls": {1792: [40]}}}
+    assert sweep.gmm_ns_by_module([ns(name="/host:CPU", lines=[])]) == {}
+
+
+def test_the_tile_sweep_gives_no_time_without_a_chip():
+    proc = _tile_sweep()
+    assert proc.returncode != 0 and "a time comes from the chip" in proc.stderr
 
 
 def test_the_reorder_moves_conv_and_key_value_state_together_and_nothing_else():
