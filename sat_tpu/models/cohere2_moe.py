@@ -35,10 +35,12 @@ divided by n after the product: the same sum as n experts apart, one
 product where n would stand.
 
 Forms.  Whole sequences go one image at a time (``lax.map``).  A sliding
-layer's queries and keys turn as ``x * cos + partner(x) * sin``, the
-partner of a whole sequence made by a second product with the map's
-columns swapped in pairs (``deepseek_v3._swapped_columns``: whole lanes,
-no roll of a 16,384-wide array), of one token's rows by the roll.
+layer's queries and keys turn as ``x * cos + partner(x) * sin``.  The
+rope covers the whole head and d = 128 is one register's lanes, so a whole
+sequence's partner is taken from the product it already is, head-major
+``[heads, S, d]``, by a product with the ``[d, d]`` signed permutation
+(exact: one nonzero term a sum; no second product by ``W_q`` and ``W_k``,
+no roll, slice or relayout of a lane); one token's rows take the roll.
 Attention on the TPU runs in ``ops/flash_prefill.py``'s kernel in its
 GROUPED form (keys and values ``[nkv, S, d]``, never replicated over the
 group; ``window=`` in the sliding layers, none in the full ones, no mask);
@@ -71,7 +73,7 @@ import jax.numpy as jnp
 
 from ..config import Config
 from . import lm_common
-from .deepseek_v3 import _rope, _rope_tables, _swapped_columns
+from .deepseek_v3 import _partner, _rope, _rope_tables
 from .lm_common import Params, layer_name, layer_norm, mm
 from .lm_common import sum_pairs as _sum_pairs
 
@@ -205,29 +207,41 @@ def _block(p: Params, config: Config, x: jnp.ndarray, attend):
 def _sequence_qkv(m: Params, config: Config, layer: int, u: jnp.ndarray):
     """u [S, H] normed, ONE sequence at positions 0..S-1 -> q [nh, S, d],
     k, v [kv, S, d] bfloat16, head-major as the kernel takes them; a
-    sliding layer's q and k turned."""
+    sliding layer's q and k turned, ``x * cos + partner(x) * sin`` in
+    float32, rounded once.  Three products a layer (q, k, v): the partner
+    comes from the product x itself, ``x @ P`` inside each head's d lanes,
+    P the ``[d, d]`` signed permutation (``_partner`` of the identity:
+    entries 0, 1, -1), so every sum has ONE nonzero term and the partner is
+    exact on the bfloat16 x.  ``deepseek_v3._sequence_queries`` multiplies
+    by the map's swapped columns instead, and is right to: its rope covers
+    64 lanes of a head, a third of ``W_q``'s columns, and what costs there
+    is a 64-wide minor dimension.  Here the rope covers the WHOLE head, so
+    that product would be all of ``W_q`` and ``W_k`` a second time (6.8 ms
+    a layer and image on a v5e for q, where this swap with its
+    multiply-add takes 1.3; the rolls of ``_partner`` on the whole
+    ``[nh, S, d]`` 6.2: PERF.md section 6), and d = 128 is one vector
+    register's lanes: the small product moves nothing across them."""
     c = config
     H = u.shape[-1]
     d, nh, kv = _head_dim(c), c.num_attention_heads, c.num_key_value_heads
-    w_q, w_k, w_v = (m[name].reshape(H, n, d) for name, n in (("q_proj", nh), ("k_proj", kv), ("v_proj", kv)))
 
-    def product(w):
+    def product(name, n):
+        w = m[name].reshape(H, n, d)
         return jnp.einsum("sh,hnd->nsd", u, w, preferred_element_type=jnp.float32).astype(jnp.bfloat16)
 
     with _scope(c, layer, "qkv"):
-        q, k, v = product(w_q), product(w_k), product(w_v)
+        q, k, v = product("q_proj", nh), product("k_proj", kv), product("v_proj", kv)
     if not _turns(c, layer):
         return q, k, v
     with _scope(c, layer, "rope"):
         cos, sin = _rope_tables(jnp.arange(u.shape[0]), c.rope_theta, d)
+        swap = _partner(jnp.eye(d, dtype=jnp.bfloat16))
 
-        def turned(x, w):
-            partner = product(_swapped_columns(w))
-            return (
-                x.astype(jnp.float32) * cos + partner.astype(jnp.float32) * sin
-            ).astype(jnp.bfloat16)
+        def turned(x):
+            partner = jnp.einsum("nsd,de->nse", x, swap, preferred_element_type=jnp.float32)
+            return (x.astype(jnp.float32) * cos + partner * sin).astype(jnp.bfloat16)
 
-        return turned(q, w_q), turned(k, w_k), v
+        return turned(q), turned(k), v
 
 
 def _blocks(S: int):

@@ -260,21 +260,123 @@ def test_a_step_s_window_at_the_prefix_s_boundary(params, layer, t):
         assert int(seen) == 9 and np.array_equal(np.asarray(whole, np.float32), np.asarray(got, np.float32))
 
 
-def test_a_whole_sequence_s_rope_by_a_second_product_is_the_roll_s():
+def _second_product_qkv(m, config, layer, u):
+    """``_sequence_qkv`` as it stood until PR 45, the reference of the
+    tests below: a sliding layer's partner by a SECOND product with the
+    maps' columns swapped in pairs (``deepseek_v3._swapped_columns``)."""
+    from sat_tpu.models.deepseek_v3 import _swapped_columns
+
+    c = config
+    H, d = u.shape[-1], c2._head_dim(c)
+    w_q, w_k, w_v = (m[name].reshape(H, n, d) for name, n in (
+        ("q_proj", c.num_attention_heads), ("k_proj", c.num_key_value_heads), ("v_proj", c.num_key_value_heads)))
+
+    def product(w):
+        return jnp.einsum("sh,hnd->nsd", u, w, preferred_element_type=jnp.float32).astype(jnp.bfloat16)
+
+    q, k, v = product(w_q), product(w_k), product(w_v)
+    if not c2._turns(c, layer):
+        return q, k, v
+    cos, sin = c2._rope_tables(jnp.arange(u.shape[0]), c.rope_theta, d)
+
+    def turned(x, w):
+        partner = product(_swapped_columns(w))
+        return (x.astype(jnp.float32) * cos + partner.astype(jnp.float32) * sin).astype(jnp.bfloat16)
+
+    return turned(q, w_q), turned(k, w_k), v
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint16)
+
+
+@pytest.mark.parametrize("layer", [0, 3], ids=["sliding", "full"])
+@pytest.mark.parametrize("d,heads,kv", [(16, 8, 2), (128, 4, 2)], ids=["toy_d16", "published_d128"])
+def test_a_whole_sequence_s_rope_takes_its_partner_from_the_product_it_has(d, heads, kv, layer):
     """A sliding layer's queries and keys of a whole sequence
-    (``x * cos + (u W P) * sin``) against the rows' form (the roll of
-    ``u W``): the same dot products, so the same numbers but for the
-    bfloat16 rounding of the partner."""
-    weights = _weights(MODEL)
-    first, last = (jax.tree_util.tree_map(jnp.asarray, _subtree(weights, f"lm/layers/{i}/self_attn")) for i in ("00", "03"))
-    u = jax.random.normal(jax.random.PRNGKey(3), (24, 64)).astype(jnp.bfloat16)
-    q, k, _ = c2._sequence_qkv(first, CONFIG, 0, u)
-    for got, w, heads in ((q, first["q_proj"], 8), (k, first["k_proj"], 2)):
-        rows = c2._rope(lm_common.mm(u, w).reshape(24, heads, 16).astype(jnp.float32), jnp.arange(24), 100.0)
-        _close(jnp.swapaxes(got, 0, 1), rows, 2e-2)     # two bfloat16 roundings: of the product, of its partner
-    plain, _, _ = c2._sequence_qkv(last, CONFIG, 3, u)      # the full layer: no positional term
-    assert np.array_equal(np.asarray(jnp.swapaxes(plain, 0, 1).reshape(24, 128), np.float32),
-                          np.asarray(lm_common.mm(u, last["q_proj"]), np.float32))
+    (``x * cos + (x P) * sin``, P the signed swap inside a head's d lanes)
+    are, BIT FOR BIT, what the second product by the maps' swapped columns
+    gave (``x * cos + (u W P) * sin``): the partner was the bfloat16
+    rounding of the same dot products, and the swap moves those same
+    bfloat16 values.  u and the maps are small multiples of powers of two,
+    so a dot product is exact in float32 in whatever order a backend sums
+    it (with free values the CPU's two products round apart by one ulp on
+    one element in 10,000 at d = 128: PERF.md section 6).  And within two
+    bfloat16 roundings of the rows' form (``_rope``'s roll of ``u W``); a
+    full layer's are the plain products."""
+    config = Config(**{**TOY, "head_dim": d, "num_attention_heads": heads, "num_key_value_heads": kv})
+    S, H = 24, config.hidden_size
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+
+    def exact(key, shape, most, over):
+        return (jax.random.randint(key, shape, -most, most + 1).astype(jnp.float32) / over).astype(jnp.bfloat16)
+
+    m = {name: exact(key, (H, n * d), 64, 64) for name, n, key in (
+        ("q_proj", heads, keys[0]), ("k_proj", kv, keys[1]), ("v_proj", kv, keys[2]))}
+    u = exact(keys[3], (S, H), 16, 8)
+    got = c2._sequence_qkv(m, config, layer, u)
+    want = _second_product_qkv(m, config, layer, u)
+    assert [x.shape for x in got] == [(heads, S, d), (kv, S, d), (kv, S, d)]
+    for x, y in zip(got, want):
+        assert x.dtype == jnp.bfloat16 and np.array_equal(_bits(x), _bits(y))
+    for x, name, n in ((got[0], "q_proj", heads), (got[1], "k_proj", kv)):
+        plain = lm_common.mm(u, m[name])
+        if layer == 3:      # the full layer: no positional term
+            assert np.array_equal(_bits(jnp.swapaxes(x, 0, 1).reshape(S, n * d)), _bits(plain))
+        else:
+            rows = c2._rope(plain.reshape(S, n, d).astype(jnp.float32), jnp.arange(S), config.rope_theta)
+            _close(jnp.swapaxes(x, 0, 1), rows, 2e-2)     # two bfloat16 roundings: of the product, of the sum
+            assert float(jnp.max(jnp.abs(jnp.swapaxes(x, 0, 1).astype(jnp.float32) - plain.reshape(S, n, d)))) > 0.1
+
+
+def test_a_sliding_layer_s_whole_sequence_multiplies_by_each_map_once_at_the_published_shape():
+    """Traced at the published shape (9,216 positions of 4,096; 128 query
+    and 8 key/value heads of 128), nothing compiled: THREE products by the
+    layer's maps, one by ``W_q`` [4096, 16384], one each by ``W_k`` and
+    ``W_v`` [4096, 1024], where five stood (``W_q`` and ``W_k`` a second
+    time for the rope's partner); what the rope adds is two products by
+    the [128, 128] signed permutation, 1/32 of a map's.  A full layer has
+    the three alone."""
+    from test_aot_tpu import _command_a_config
+    from test_deepseek_v3 import _all_eqns
+
+    config = _command_a_config()
+    S, H = config.num_ctx, config.hidden_size
+    sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa: E731
+    m = {"q_proj": sd(H, 16384), "k_proj": sd(H, 1024), "v_proj": sd(H, 1024)}
+
+    def products(layer):
+        traced = jax.make_jaxpr(lambda m, u: c2._sequence_qkv(m, config, layer, u))(m, sd(S, H))
+        assert [x.shape for x in traced.out_avals] == [(128, S, 128), (8, S, 128), (8, S, 128)]
+        return sorted(
+            tuple(v.aval.shape for v in e.invars) for e in _all_eqns(traced.jaxpr) if e.primitive.name == "dot_general"
+        )
+
+    maps = [((H, 8, 128), (S, H)), ((H, 8, 128), (S, H)), ((H, 128, 128), (S, H))]
+    assert (S, H) == (9216, 4096) and products(3) == maps
+    assert products(0) == [((8, S, 128), (128, 128)), ((128, S, 128), (128, 128))] + maps
+
+
+def test_the_gradient_through_a_whole_sequence_is_the_second_product_s(params, monkeypatch):
+    """``teacher_forced`` differentiated (the connector trains through the
+    sliding layers' rope): the swap inside the head is a product as the
+    maps' are, and its gradient the second product's within two paths'
+    rounding."""
+    ctx, tokens = _inputs(seed=4)
+
+    def loss(connector):
+        logits = c2.teacher_forced({**params, "connector": connector}, CONFIG, ctx, tokens)
+        picked = jnp.take_along_axis(jax.nn.log_softmax(logits), tokens[..., None], axis=-1)
+        return -jnp.mean(picked)
+
+    got = jax.grad(loss)(params["connector"])
+    monkeypatch.setattr(c2, "_sequence_qkv", _second_product_qkv)
+    want = jax.grad(loss)(params["connector"])
+    leaves = jax.tree_util.tree_leaves_with_path(got)
+    assert leaves
+    for (path, g), w in zip(leaves, jax.tree_util.tree_leaves(want)):
+        assert float(jnp.max(jnp.abs(w))) > 0, path
+        _close(g, w, PATH_TOL)
 
 
 # ---------------------------------------------------------------------------
