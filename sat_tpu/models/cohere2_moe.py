@@ -26,9 +26,10 @@ input, one residual add:
 This module holds only what is its own: the parallel block, the two kinds
 of grouped-query layer in their two forms, the cache of two lengths and the
 tied head.  Connector, embedding, LayerNorm, products, the router and the
-expert layer at a held share (``moe_experts_held``, on the normed ``u``)
-are ``lm_common``'s; the rope's tables and its signed swap
-``deepseek_v3``'s (the same interleaved convention).  The shared branch is
+expert layer at a held share (``moe_experts``, on the normed ``u``) are
+``lm_common``'s, as is the ``lax`` form of a whole sequence's attention;
+the rope's tables and its signed swap ``deepseek_v3``'s (the same
+interleaved convention).  The shared branch is
 kept as ONE SwiGLU ``n * moe_intermediate_size`` wide (``shared/w1``,
 ``w3`` side by side, ``w2`` stacked), whose output is the n experts' sum,
 divided by n after the product: the same sum as n experts apart, one
@@ -45,7 +46,8 @@ Attention on the TPU runs in ``ops/flash_prefill.py``'s kernel in its
 GROUPED form (keys and values ``[nkv, S, d]``, never replicated over the
 group; ``window=`` in the sliding layers, none in the full ones, no mask);
 elsewhere, and where it is differentiated, in ``lax`` blocks of
-``_QUERY_BLOCK`` queries, a sliding layer's against its band's keys alone.
+``lm_common.QUERY_BLOCK`` queries, a sliding layer's against its band's
+keys alone.
 One token through the cache is lfm2's grouped form: every beam of an image
 reads the image's keys and values in place (``bkhgd,bnhd->bkhgn``), never
 tiled over the beams.
@@ -72,12 +74,12 @@ import jax
 import jax.numpy as jnp
 
 from ..config import Config
+from ..ops import flash_prefill
 from . import lm_common
 from .deepseek_v3 import _partner, _rope, _rope_tables
 from .lm_common import Params, layer_name, layer_norm, mm
 from .lm_common import sum_pairs as _sum_pairs
 
-_QUERY_BLOCK = 512      # queries a block of a whole sequence's attention (the lax form)
 _SUM_EPS = 0.0          # the source divides the chosen scores by their sum, nothing added
 
 
@@ -138,39 +140,23 @@ def _scope(config: Config, layer: int, part: str):
 
 
 def init_params(rng: jax.Array, config: Config) -> Params:
-    """{'connector': float32 (it trains), 'lm': the stack, bfloat16}.
-    Normal(0.02) linear maps, unit norm weights: a starting point for the
-    connector's training, not the source's weights (a checkpoint carries
-    those)."""
+    """``lm_common.init_stack``'s tree over this stack's layers: ONE norm a
+    layer and grouped-query attention with no bias, ``norm``."""
     c = config
     H, d, nh, kv = c.hidden_size, _head_dim(c), c.num_attention_heads, c.num_key_value_heads
-    bf16 = jnp.bfloat16
-    keys = iter(jax.random.split(rng, 12 * c.num_hidden_layers + 4))
 
-    def linear(*shape):
-        return (0.02 * jax.random.normal(next(keys), shape, jnp.float32)).astype(bf16)
-
-    ones = lambda n: jnp.ones((n,), bf16)  # noqa: E731
-    layers: Params = {}
-    for i in range(c.num_hidden_layers):
-        p: Params = {
+    def layer_params(layer, linear, ones):
+        return {
             "input_norm": ones(H),
             "self_attn": {
                 "q_proj": linear(H, nh * d), "k_proj": linear(H, kv * d),
                 "v_proj": linear(H, kv * d), "o_proj": linear(nh * d, H),
             },
-            "feed_forward": lm_common.ffn_params(c, i, linear),
         }
-        if c.n_shared_experts:
-            I = c.n_shared_experts * c.moe_intermediate_size
-            p["feed_forward"]["shared"] = {
-                "w1": linear(H, I), "w3": linear(H, I), "w2": linear(I, H),
-            }
-        layers[layer_name(i)] = p
-    return {
-        "connector": lm_common.connector_params(next(keys), c),
-        "lm": {"embed_tokens": linear(c.vocabulary_size, H), "norm": ones(H), "layers": layers},
-    }
+
+    return lm_common.init_stack(
+        rng, c, layer_params, keys_per_layer=12, norm="norm", connector_first=True
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -183,7 +169,7 @@ def _experts(p: Params, config: Config, u: jnp.ndarray):
     it adds [T, H] float32, tokens per expert [E], experts chosen [T, k],
     ``HeldPairs``): the routed experts held here and the shared experts'
     mean."""
-    return lm_common.moe_experts_held(
+    return lm_common.moe_experts(
         p["feed_forward"], config, u, _SUM_EPS, shared_mean_of=config.n_shared_experts or 1
     )
 
@@ -244,40 +230,6 @@ def _sequence_qkv(m: Params, config: Config, layer: int, u: jnp.ndarray):
         return turned(q), turned(k), v
 
 
-def _blocks(S: int):
-    return [(a, min(a + _QUERY_BLOCK, S)) for a in range(0, S, _QUERY_BLOCK)]
-
-
-def _attend_blocks(q, k, v, scale: float, window) -> jnp.ndarray:
-    """q [nh, S, d], k, v [kv, S, d] -> [S, nh * d] bfloat16 by ``lax``: a
-    block of queries at a time against the keys of its band (from
-    ``window - 1`` before the block's first query, or from the first, to
-    the block's last), the group's heads against their one key/value head,
-    float32 scores ``[kv, group, block, keys]`` whole."""
-    nh, S, d = q.shape
-    kv = k.shape[0]
-    q = q.reshape(kv, nh // kv, S, d)
-    positions = jnp.arange(S)
-    ctx = []
-    for a, b in _blocks(S):
-        low = 0 if window is None else max(a - (window - 1), 0)
-        ahead = positions[a:b, None] - positions[None, low:b]
-        seen = ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
-        scores = jnp.einsum(
-            "hgsd,htd->hgst", q[:, :, a:b], k[:, low:b], preferred_element_type=jnp.float32
-        )
-        scores = jnp.where(seen, scores * scale, -jnp.inf)
-        # the softmax's division after the weighted sum, as the kernel's; a
-        # group's heads go through the second product as rows of ONE head
-        weights = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
-        block = jnp.einsum(
-            "hqt,htd->hqd", weights.astype(jnp.bfloat16).reshape(kv, -1, b - low), v[:, low:b],
-            preferred_element_type=jnp.float32,
-        ).reshape(kv, nh // kv, b - a, d) / jnp.sum(weights, axis=-1)[..., None]
-        ctx.append(jnp.transpose(block, (2, 0, 1, 3)).astype(jnp.bfloat16).reshape(b - a, nh * d))
-    return jnp.concatenate(ctx, axis=0)
-
-
 def attend_sequence(m: Params, config: Config, layer: int, u: jnp.ndarray, fused: bool = False):
     """The attention branch over ONE sequence u [S, H] (normed, positions
     0..S-1) -> (its output [S, H], (keys [S, kv * d], values [S, kv * d])).
@@ -290,14 +242,13 @@ def attend_sequence(m: Params, config: Config, layer: int, u: jnp.ndarray, fused
     window = c.sliding_window_size if _sliding(c, layer) else None
     with _scope(c, layer, "scores"):
         if fused:
-            from ..ops import flash_prefill     # ops/__init__ imports models
-
             ctx = flash_prefill.flash_prefill(
                 q, k, v, None, scale=d ** -0.5, window=window,
                 interpret=jax.default_backend() != "tpu",
             )
         else:
-            ctx = _attend_blocks(q, k, v, d ** -0.5, window)
+            lows, masks = lm_common.causal_blocks(S, window)
+            ctx = lm_common.attend_blocks(q, k, v, masks, d ** -0.5, lows)
     with _scope(c, layer, "out"):
         flat = lambda x: jnp.swapaxes(x, 0, 1).reshape(S, -1)  # noqa: E731
         return mm(ctx, m["o_proj"]), (flat(k), flat(v))
@@ -439,16 +390,14 @@ def prefill(params: Params, config: Config, contexts: jnp.ndarray):
     kernel and in all, by kind) for ``init_counters``; the experts every
     position chose [B, N, layers * k]).  The fused kernel on the TPU (or
     under the tests' hook) where the prefix is whole blocks of queries."""
-    from ..ops import flash_prefill     # ops/__init__ imports models
-
     x = lm_common.prefix(params, contexts)
     S = x.shape[1]
     if S != config.num_ctx:
         raise ValueError(f"a prefix of {S} positions where Config.num_ctx is {config.num_ctx}")
-    fused = flash_prefill.available() and S % _QUERY_BLOCK == 0
+    fused = flash_prefill.available() and S % lm_common.QUERY_BLOCK == 0
     _, state, counts, routes, pairs = sequence_forward(params["lm"], config, x, fused=fused)
     sliding = sum(_sliding(config, i) for i in range(config.num_hidden_layers))
-    by_kind = len(_blocks(S)) * jnp.array([config.num_hidden_layers - sliding, sliding], jnp.int32)
+    by_kind = len(lm_common.query_blocks(S)) * jnp.array([config.num_hidden_layers - sliding, sliding], jnp.int32)
     return state, (counts, pairs, jnp.stack([by_kind * fused, by_kind], axis=1)), routes
 
 

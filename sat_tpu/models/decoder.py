@@ -40,6 +40,7 @@ from ..config import Config
 from ..nn.layers import DROPOUT_MASK
 from ..nn.layers import dropout as _nn_dropout
 from ..nn.layers import fc_kernel_init
+from ..ops import pallas_attention
 
 Params = Dict[str, Any]
 
@@ -377,8 +378,6 @@ def attend_with_precomputed(
     else:
         t2 = _dense(p["fc_1b"], output, activation="tanh", dtype=dt)  # [B*K, da]
         if config.use_pallas_attention:
-            from ..ops import pallas_attention
-
             # Interpret mode is a test vehicle only — off TPU the XLA branch
             # below is the fast mathematically-identical fallback.
             if jax.default_backend() == "tpu" or pallas_attention.FORCE_INTERPRET:

@@ -135,44 +135,24 @@ def widths(config: Config) -> Widths:
 
 
 def init_params(rng: jax.Array, config: Config) -> Params:
-    """{'connector': float32 (it trains), 'lm': the stack, bfloat16 but
-    for ``expert_bias`` (a float32 buffer)}.  Normal(0.02) linear maps,
-    unit norm weights: a starting point for the connector's training, not
-    the source's weights (a checkpoint carries those)."""
+    """``lm_common.init_stack``'s tree over this stack's layers: latent
+    attention with an uncompressed query in every one, ``norm``."""
     c = config
     H, nh, rank = c.hidden_size, c.num_attention_heads, c.kv_lora_rank
-    bf16 = jnp.bfloat16
-    keys = iter(jax.random.split(rng, 12 * c.num_hidden_layers + 4))
 
-    def linear(*shape):
-        return (0.02 * jax.random.normal(next(keys), shape, jnp.float32)).astype(bf16)
-
-    ones = lambda n: jnp.ones((n,), bf16)  # noqa: E731
-    layers: Params = {}
-    for i in range(c.num_hidden_layers):
-        p: Params = {"operator_norm": ones(H), "ffn_norm": ones(H)}
-        p["self_attn"] = {
-            "q_proj": linear(H, nh * _qk_dim(c)),
-            "kv_a_proj": linear(H, rank + c.qk_rope_head_dim),
-            "kv_a_layernorm": ones(rank),
-            "kv_b_proj": linear(rank, nh * (c.qk_nope_head_dim + c.v_head_dim)),
-            "o_proj": linear(nh * c.v_head_dim, H),
+    def layer_params(layer, linear, ones):
+        return {
+            "operator_norm": ones(H), "ffn_norm": ones(H),
+            "self_attn": {
+                "q_proj": linear(H, nh * _qk_dim(c)),
+                "kv_a_proj": linear(H, rank + c.qk_rope_head_dim),
+                "kv_a_layernorm": ones(rank),
+                "kv_b_proj": linear(rank, nh * (c.qk_nope_head_dim + c.v_head_dim)),
+                "o_proj": linear(nh * c.v_head_dim, H),
+            },
         }
-        p["feed_forward"] = lm_common.ffn_params(c, i, linear)
-        if lm_common.is_moe(c, i) and c.n_shared_experts:
-            I = c.n_shared_experts * c.moe_intermediate_size
-            p["feed_forward"]["shared"] = {
-                "w1": linear(H, I), "w3": linear(H, I), "w2": linear(I, H),
-            }
-        layers[layer_name(i)] = p
-    lm: Params = {
-        "embed_tokens": linear(c.vocabulary_size, H),
-        "norm": ones(H),
-        "layers": layers,
-    }
-    if not c.tie_word_embeddings:
-        lm["lm_head"] = linear(H, c.vocabulary_size)
-    return {"connector": lm_common.connector_params(next(keys), c), "lm": lm}
+
+    return lm_common.init_stack(rng, c, layer_params, keys_per_layer=12, norm="norm")
 
 
 # ---------------------------------------------------------------------------
@@ -382,7 +362,7 @@ def sequence_forward(lm: Params, config: Config, x: jnp.ndarray):
         y, kept = attend_expanded(p["self_attn"], c, h)
         x = x + y
         latents.append(kept)
-        x, sizes, experts = _ffn(p, c, i, x)
+        x, sizes, experts, _ = _ffn(p, c, i, x)
         if sizes is not None:
             counts.append(sizes)
             routes.append(experts)
@@ -467,7 +447,7 @@ def step(
         )
         x = x + y
         latents.append(suffix)
-        x, sizes, experts = _ffn(p, c, i, x)
+        x, sizes, experts, _ = _ffn(p, c, i, x)
         if sizes is not None:
             counts.append(sizes)
             routes.append(experts)

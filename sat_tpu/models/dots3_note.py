@@ -62,10 +62,11 @@ import jax
 import jax.numpy as jnp
 
 from ..config import Config
+from ..ops import flash_prefill
 from . import glm_moe_dsa as dsa
 from . import lm_common
 from .deepseek_v3 import _head, _latents
-from .glm_moe_dsa import DsaCache, Widths, _ffn, _sum_pairs
+from .glm_moe_dsa import DsaCache, Widths, _sum_pairs
 from .lm_common import Params, layer_name, rms_norm
 
 # a sliding layer's device ops: under decoder/lm/attn/window/ (the mixer
@@ -126,27 +127,18 @@ def init_params(rng: jax.Array, config: Config) -> Params:
     """``glm_moe_dsa.init_params``'s tree and draws, each layer's attention
     at its kind's widths: an indexer in every full layer, a ``gate_proj``
     [H, heads] in both kinds where ``attention_gate`` is "headwise"."""
-    kinds, gate = widths(config), config.attention_gate == "headwise"
-    return dsa.init_params(
-        rng, config, lambda i: (kinds[_sliding(config, i)], not _sliding(config, i), gate)
+    c = config
+    kinds, gate = widths(c), c.attention_gate == "headwise"
+    return lm_common.init_stack(
+        rng, c, lambda i, linear, ones: dsa.layer_params(
+            c.hidden_size, kinds[_sliding(c, i)], linear, ones, indexer=not _sliding(c, i), gate=gate
+        ), keys_per_layer=20, norm="norm",
     )
 
 
 # ---------------------------------------------------------------------------
 # a sliding layer: whole sequences over the band, one token over the tail
 # ---------------------------------------------------------------------------
-
-
-def _band(S: int, window: int):
-    """Per block of queries: (the first key its band reaches back to, the
-    band as a mask [block, keys from there to the block's end])."""
-    lows, masks = [], []
-    positions = jnp.arange(S)
-    for a, b in dsa._blocks(S):
-        low = max(a - (window - 1), 0)
-        ahead = positions[a:b, None] - positions[None, low:b]
-        lows.append(low), masks.append((ahead >= 0) & (ahead < window))
-    return lows, masks
 
 
 def attend_window(
@@ -165,15 +157,13 @@ def attend_window(
     scale = w.qk ** -0.5
     with w.named_scope("decoder/lm/attn/scores"):
         if fused:
-            from ..ops import flash_prefill     # ops/__init__ imports models
-
             ctx = flash_prefill.flash_prefill(
                 q, keys, values, None, scale=scale, window=window,
                 interpret=jax.default_backend() != "tpu",
             )
         else:
-            lows, masks = _band(S, window)
-            ctx = dsa._attend_blocks(q, keys, values, masks, scale, lows).reshape(S, -1)
+            lows, masks = lm_common.causal_blocks(S, window)
+            ctx = lm_common.attend_blocks(q, keys, values, masks, scale, lows)
     return dsa._gated_out(m, w, h, ctx), latents
 
 
@@ -233,7 +223,7 @@ def _one_sequence(
             index_keys.append(keys)
         x = x + y
         latents.append(kept)
-        x, sizes, experts, pairs = _ffn(p, c, i, x)
+        x, sizes, experts, pairs = lm_common.ffn(p, c, i, x, dsa._SUM_EPS)
         if sizes is not None:
             counts.append(sizes), routes.append(experts), held.append(pairs)
     return (
@@ -282,15 +272,13 @@ def prefill(params: Params, config: Config, contexts: jnp.ndarray):
     the fused kernel and in all, by kind) for ``init_counters``; the experts
     every position chose [B, N, moe layers * k]).  The fused kernel where
     ``glm_moe_dsa.prefill`` takes it."""
-    from ..ops import flash_prefill     # ops/__init__ imports models
-
     x = lm_common.prefix(params, contexts)
     S = x.shape[1]
     if S != config.num_ctx:
         raise ValueError(f"a prefix of {S} positions where Config.num_ctx is {config.num_ctx}")
-    fused = flash_prefill.available() and S % dsa._QUERY_BLOCK == 0
+    fused = flash_prefill.available() and S % lm_common.QUERY_BLOCK == 0
     _, state, counts, routes, pairs = sequence_forward(params["lm"], config, x, fused=fused)
-    blocks, full = len(dsa._blocks(S)), len(_full_layers(config))
+    blocks, full = len(lm_common.query_blocks(S)), len(_full_layers(config))
     by_kind = blocks * jnp.array([full, config.num_hidden_layers - full], jnp.int32)
     return state, (counts, pairs, jnp.stack([by_kind * fused, by_kind], axis=1)), routes
 
@@ -372,7 +360,7 @@ def step(
             ).astype(jnp.int32)
         x = x + y
         latents.append(lat)
-        x, sizes, experts, pairs = _ffn(p, c, i, x)
+        x, sizes, experts, pairs = lm_common.ffn(p, c, i, x, dsa._SUM_EPS)
         if sizes is not None:
             counts.append(sizes), routes.append(experts), held.append(pairs)
     with jax.named_scope("decoder/lm/attn/select"):

@@ -27,14 +27,15 @@ imported from there, the expert layer from ``models/lm_common.py``.
   bfloat16 with float32 accumulation (the source's float8 and its Hadamard
   rotation of ``qI`` and ``kI``, orthogonal and so without effect on
   ``qI . kI`` in exact arithmetic, are not taken);
-* an expert layer that holds a share of its experts
-  (``lm_common.moe_ffn_held``) and reports what it held.
+* an expert layer that holds a share of its experts (``lm_common.ffn``)
+  and reports what it held.
 
 Forms.  Whole sequences (``teacher_forced``, ``prefill``) go one image at
-a time (``lax.map``) and one block of ``_QUERY_BLOCK`` queries at a time
-against the keys up to the block's end, in the EXPANDED form: a block's
-scores are ``[heads, block, keys]``, and no ``[.., S, S]`` array per head
-exists.  The prefill, on the TPU, hands that attention to
+a time (``lax.map``) and one block of ``lm_common.QUERY_BLOCK`` queries at
+a time against the keys up to the block's end, in the EXPANDED form
+(``lm_common.attend_blocks``): a block's scores are
+``[heads, block, keys]``, and no ``[.., S, S]`` array per head exists.
+The prefill, on the TPU, hands that attention to
 ``ops/flash_prefill.py``'s kernel, which keeps each tile of scores in
 VMEM (the same arithmetic; the ``lax`` blocks are what is differentiated
 and what runs anywhere else).  The selection there is a MASK: ``causal & (I[t, s] >= the
@@ -66,12 +67,12 @@ import jax
 import jax.numpy as jnp
 
 from ..config import Config
+from ..ops import flash_prefill
 from . import deepseek_v3, lm_common
 from .deepseek_v3 import _head, _kv_b, _latents, _rope, _rope_tables, _swapped_columns
-from .lm_common import HeldPairs, Params, layer_name, mm, rms_norm
+from .lm_common import Params, layer_name, mm, rms_norm
 from .lm_common import sum_pairs as _sum_pairs
 
-_QUERY_BLOCK = 512          # queries a block of a whole sequence's attention
 _INDEX_NORM_EPS = 1e-6      # the indexer key's LayerNorm
 _SUM_EPS = 1e-20            # DeepSeek-V3's router, in the sum of chosen scores
 
@@ -142,61 +143,42 @@ def _chosen_width(config: Config, max_len: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-def init_params(rng: jax.Array, config: Config, layer_attention=None) -> Params:
-    """{'connector': float32 (it trains), 'lm': the stack, bfloat16 but
-    for ``expert_bias`` (a float32 buffer)}: ``deepseek_v3.init_params``'s
-    draws over this stack's leaves.  ``layer_attention(layer)`` -> (the
-    layer's ``Widths``, whether it has an indexer, whether it has a
-    headwise gate), for a stack whose layers differ; None: this one's."""
-    c = config
-    H = c.hidden_size
-    bf16 = jnp.bfloat16
-    keys = iter(jax.random.split(rng, 20 * c.num_hidden_layers + 4))
-    if layer_attention is None:
-        layer_attention = lambda i: (widths(c), c.indexer_types[i] == "full", False)  # noqa: E731
-
-    def linear(*shape):
-        return (0.02 * jax.random.normal(next(keys), shape, jnp.float32)).astype(bf16)
-
-    ones = lambda n: jnp.ones((n,), bf16)  # noqa: E731
-    layers: Params = {}
-    for i in range(c.num_hidden_layers):
-        w, indexer, gate = layer_attention(i)
-        p: Params = {"operator_norm": ones(H), "ffn_norm": ones(H)}
-        p["self_attn"] = {
-            "q_a_proj": linear(H, w.q_rank),
-            "q_a_layernorm": ones(w.q_rank),
-            "q_b_proj": linear(w.q_rank, w.heads * w.qk),
-            "kv_a_proj": linear(H, w.kv_rank + w.rope),
-            "kv_a_layernorm": ones(w.kv_rank),
-            "kv_b_proj": linear(w.kv_rank, w.heads * (w.nope + w.v)),
-            "o_proj": linear(w.heads * w.v, H),
-        }
-        if indexer:
-            p["self_attn"]["indexer"] = {
-                "wq_b": linear(w.q_rank, w.index_heads * w.index_dim),
-                "wk": linear(H, w.index_dim),
-                "k_norm_weight": ones(w.index_dim),
-                "k_norm_bias": jnp.zeros((w.index_dim,), bf16),
-                "weights_proj": linear(H, w.index_heads),
-            }
-        if gate:
-            p["self_attn"]["gate_proj"] = linear(H, w.heads)
-        p["feed_forward"] = lm_common.ffn_params(c, i, linear)
-        if lm_common.is_moe(c, i) and c.n_shared_experts:
-            I = c.n_shared_experts * c.moe_intermediate_size
-            p["feed_forward"]["shared"] = {
-                "w1": linear(H, I), "w3": linear(H, I), "w2": linear(I, H),
-            }
-        layers[layer_name(i)] = p
-    lm: Params = {
-        "embed_tokens": linear(c.vocabulary_size, H),
-        "norm": ones(H),
-        "layers": layers,
+def layer_params(H: int, w: Widths, linear, ones, indexer: bool, gate: bool = False) -> Params:
+    """ONE layer's norms and its attention's leaves at the widths ``w``
+    (``lm_common.init_stack``'s ``linear`` and ``ones``): the compressed
+    query, the latent, an indexer's maps in a layer that has one, a
+    headwise ``gate_proj`` [H, heads] in a stack that gates."""
+    m = {
+        "q_a_proj": linear(H, w.q_rank),
+        "q_a_layernorm": ones(w.q_rank),
+        "q_b_proj": linear(w.q_rank, w.heads * w.qk),
+        "kv_a_proj": linear(H, w.kv_rank + w.rope),
+        "kv_a_layernorm": ones(w.kv_rank),
+        "kv_b_proj": linear(w.kv_rank, w.heads * (w.nope + w.v)),
+        "o_proj": linear(w.heads * w.v, H),
     }
-    if not c.tie_word_embeddings:
-        lm["lm_head"] = linear(H, c.vocabulary_size)
-    return {"connector": lm_common.connector_params(next(keys), c), "lm": lm}
+    if indexer:
+        m["indexer"] = {
+            "wq_b": linear(w.q_rank, w.index_heads * w.index_dim),
+            "wk": linear(H, w.index_dim),
+            "k_norm_weight": ones(w.index_dim),
+            "k_norm_bias": jnp.zeros((w.index_dim,), jnp.bfloat16),
+            "weights_proj": linear(H, w.index_heads),
+        }
+    if gate:
+        m["gate_proj"] = linear(H, w.heads)
+    return {"operator_norm": ones(H), "ffn_norm": ones(H), "self_attn": m}
+
+
+def init_params(rng: jax.Array, config: Config) -> Params:
+    """``lm_common.init_stack``'s tree over this stack's layers: an indexer
+    in the ``full`` ones, ``norm``."""
+    c, w = config, widths(config)
+    return lm_common.init_stack(
+        rng, c, lambda i, linear, ones: layer_params(
+            c.hidden_size, w, linear, ones, indexer=c.indexer_types[i] == "full"
+        ), keys_per_layer=20, norm="norm",
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -330,33 +312,6 @@ def _select_mask(scores: jnp.ndarray, causal: jnp.ndarray, k: int) -> jnp.ndarra
 # ---------------------------------------------------------------------------
 
 
-def _blocks(S: int):
-    return [(a, min(a + _QUERY_BLOCK, S)) for a in range(0, S, _QUERY_BLOCK)]
-
-
-def _attend_blocks(q, keys, values, masks, scale: float, lows=None) -> jnp.ndarray:
-    """q, keys [nh, S, d], values [nh, S, dv] -> [S, nh, dv] bfloat16: a
-    block of queries at a time against the keys up to the block's end
-    (from ``lows``' entry for the block on, where a window leaves the
-    earlier ones unseen by all of it; else from the first), its float32
-    scores ``[nh, block, keys]`` whole."""
-    ctx = []
-    blocks = _blocks(q.shape[1])
-    for (a, b), mask, low in zip(blocks, masks, lows or (0,) * len(blocks)):
-        scores = jnp.einsum(
-            "hsd,htd->hst", q[:, a:b], keys[:, low:b], preferred_element_type=jnp.float32
-        )
-        scores = jnp.where(mask[None], scores * scale, -jnp.inf)
-        # the softmax's division after the weighted sum, as deepseek_v3's
-        weights = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
-        block = jnp.einsum(
-            "hst,htd->shd", weights.astype(jnp.bfloat16), values[:, low:b],
-            preferred_element_type=jnp.float32,
-        ) / jnp.sum(weights, axis=-1).T[..., None]
-        ctx.append(block.astype(jnp.bfloat16))
-    return jnp.concatenate(ctx, axis=0)
-
-
 def _one_mask(masks, S: int, k: int):
     """The blocks' masks as the fused kernel reads them: those that select
     (more than ``k`` keys visible), each widened to S keys, as ONE
@@ -432,7 +387,7 @@ def attend_sequence(
     if masks is None:
         qI, index_keys, w = _index_maps(m["indexer"], c, h, qr, positions)
         masks = []
-        for a, b in _blocks(S):
+        for a, b in lm_common.query_blocks(S):
             causal = positions[a:b, None] >= positions[None, :b]
             if b <= c.index_topk:           # every visible position is among the best
                 masks.append(causal)
@@ -442,31 +397,13 @@ def attend_sequence(
     scale = c.qk ** -0.5
     with c.named_scope("decoder/lm/attn/scores"):
         if fused:
-            from ..ops import flash_prefill     # ops/__init__ imports models
-
             ctx = flash_prefill.flash_prefill(
                 q, keys, values, _one_mask(masks, S, c.index_topk), scale=scale,
                 interpret=jax.default_backend() != "tpu",
             )
         else:
-            ctx = _attend_blocks(q, keys, values, masks, scale).reshape(S, -1)
+            ctx = lm_common.attend_blocks(q, keys, values, masks, scale)
     return _gated_out(m, c, h, ctx), latents, index_keys, masks
-
-
-def _ffn(p: Params, config: Config, layer: int, x: jnp.ndarray):
-    """x [T, H] -> (y, tokens per expert [E], experts chosen [T, k],
-    ``HeldPairs``), the last three None in a dense layer."""
-    c = config
-    if not lm_common.is_moe(c, layer):
-        return lm_common.dense_ffn(p, c, x), None, None, None
-    if lm_common.held_experts(c) < c.num_experts:
-        return lm_common.moe_ffn_held(p, c, x, _SUM_EPS)
-    y, sizes, experts = lm_common.moe_ffn(p, c, x, _SUM_EPS)
-    pairs = jnp.int32(experts.size)
-    return y, sizes, experts, HeldPairs(
-        held=pairs, routed=pairs, over=jnp.int32(0),
-        visited=jnp.sum(sizes > 0, dtype=jnp.int32), fetched=pairs, fused=jnp.int32(0),
-    )
 
 
 def _one_sequence(
@@ -492,7 +429,7 @@ def _one_sequence(
         latents.append(kept)
         if full:
             index_keys.append(keys)
-        x, sizes, experts, pairs = _ffn(p, c, i, x)
+        x, sizes, experts, pairs = lm_common.ffn(p, c, i, x, _SUM_EPS)
         if sizes is not None:
             counts.append(sizes), routes.append(experts), held.append(pairs)
     return (
@@ -543,13 +480,11 @@ def prefill(params: Params, config: Config, contexts: jnp.ndarray):
     attention takes the fused kernel where there is one (the TPU) and the
     sequence is whole query blocks; else the ``lax`` blocks, as
     ``teacher_forced`` always does (it is differentiated)."""
-    from ..ops import flash_prefill     # ops/__init__ imports models
-
     x = lm_common.prefix(params, contexts)
     S = x.shape[1]
-    fused = flash_prefill.available() and S % _QUERY_BLOCK == 0
+    fused = flash_prefill.available() and S % lm_common.QUERY_BLOCK == 0
     _, state, counts, routes, pairs = sequence_forward(params["lm"], config, x, fused=fused)
-    blocks = len(_blocks(S))
+    blocks = len(lm_common.query_blocks(S))
     return state, (counts, pairs, jnp.array([blocks * fused, blocks], jnp.int32)), routes
 
 
@@ -715,7 +650,7 @@ def step(
             full += 1
         x = x + y
         latents.append(lat)
-        x, sizes, experts, pairs = _ffn(p, c, i, x)
+        x, sizes, experts, pairs = lm_common.ffn(p, c, i, x, _SUM_EPS)
         if sizes is not None:
             counts.append(sizes), routes.append(experts), held.append(pairs)
     with jax.named_scope("decoder/lm/attn/select"):

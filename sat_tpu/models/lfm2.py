@@ -89,24 +89,15 @@ def _head_dim(config: Config) -> int:
 
 
 def init_params(rng: jax.Array, config: Config) -> Params:
-    """{'connector': float32 (it trains), 'lm': the stack, bfloat16 but
-    for ``expert_bias`` (a float32 buffer)}.  Normal(0.02) linear maps,
-    unit norm weights: a starting point for the connector's training, not
-    the source's weights (a checkpoint carries those)."""
+    """``lm_common.init_stack``'s tree over this stack's layers: a conv
+    or an attention mixer a layer, no shared expert, ``embedding_norm``."""
     c = config
     H = c.hidden_size
     hd, kv = _head_dim(c), c.num_key_value_heads
-    bf16 = jnp.bfloat16
-    keys = iter(jax.random.split(rng, 8 * c.num_hidden_layers + 4))
 
-    def linear(*shape):
-        return (0.02 * jax.random.normal(next(keys), shape, jnp.float32)).astype(bf16)
-
-    ones = lambda n: jnp.ones((n,), bf16)  # noqa: E731
-    layers: Params = {}
-    for i, kind in enumerate(c.layer_types):
+    def layer_params(layer, linear, ones):
         p: Params = {"operator_norm": ones(H), "ffn_norm": ones(H)}
-        if kind == "conv":
+        if c.layer_types[layer] == "conv":
             p["conv"] = {
                 "in_proj": linear(H, 3 * H),
                 "conv": linear(c.conv_L_cache, H),
@@ -118,16 +109,12 @@ def init_params(rng: jax.Array, config: Config) -> Params:
                 "v_proj": linear(H, kv * hd), "out_proj": linear(H, H),
                 "q_layernorm": ones(hd), "k_layernorm": ones(hd),
             }
-        p["feed_forward"] = lm_common.ffn_params(c, i, linear)
-        layers[layer_name(i)] = p
-    return {
-        "connector": lm_common.connector_params(next(keys), c),
-        "lm": {
-            "embed_tokens": linear(c.vocabulary_size, H),
-            "embedding_norm": ones(H),
-            "layers": layers,
-        },
-    }
+        return p
+
+    return lm_common.init_stack(
+        rng, c, layer_params, keys_per_layer=8, norm="embedding_norm", shared=False,
+        connector_first=True,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -218,7 +205,7 @@ def sequence_forward(lm: Params, config: Config, x: jnp.ndarray):
                 x = x + _mm(ctx.reshape(B, S, H), m["out_proj"])
                 keys.append(k.reshape(B, S, kv * hd))
                 values.append(v.reshape(B, S, kv * hd))
-        x, sizes, experts = _ffn(p, c, i, x)
+        x, sizes, experts, _ = _ffn(p, c, i, x)
         if sizes is not None:
             counts.append(sizes)
             routes.append(experts)
@@ -362,7 +349,7 @@ def step(
                 keys.append(sk)
                 values.append(sv)
                 attn_i += 1
-        x, sizes, experts = _ffn(p, c, i, x)
+        x, sizes, experts, _ = _ffn(p, c, i, x)
         if sizes is not None:
             counts.append(sizes)
             routes.append(experts)

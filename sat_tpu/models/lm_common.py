@@ -22,17 +22,25 @@ which the sources' routers differ: an argument), times
 product (``grouped_matmul``), which computes those pairs and no others.
 A layer whose ``feed_forward`` holds a ``shared`` SwiGLU sends every token
 through it too, beside the routed sum.  The expert layer proper
-(``moe_experts``, ``moe_experts_held``) takes an already normed input and
-returns what it adds, so that a block with ONE norm and ONE add for
-attention and feed-forward alike can call it; ``moe_ffn`` and
-``moe_ffn_held`` are the serial block's: their own norm before, the
-residual add after.
+(``moe_experts``) takes an already normed input and returns what it adds,
+so that a block with ONE norm and ONE add for attention and feed-forward
+alike can call it; ``moe_ffn`` is the serial block's: its own norm before,
+the residual add after; ``ffn`` a serial stack's one entry a layer, dense
+or expert.
 
 An expert layer may hold a SHARE of its experts (``Config.experts_held``
 from ``Config.first_expert``: one chip's of an expert-parallel
 deployment): it routes over all ``num_experts``, computes the pairs that
-land on its own and leaves the others out (``moe_ffn_held``); what the
-absent experts would add is another chip's to compute and nobody's here.
+land on its own and leaves the others out; what the absent experts would
+add is another chip's to compute and nobody's here.  Every expert held
+(``experts_held`` 0) is the plain case of the same lines: every pair lands
+here, and the rows are the pairs.
+
+Also here, because every stack repeated them: the draws of a stack's
+parameters (``init_stack``; a stack gives one layer's mixer leaves and
+norms) and the blocked ``lax`` attention of a whole sequence
+(``attend_blocks``: the tests' reference of ``ops/flash_prefill.py``, the
+form off the chip and the one that is differentiated).
 """
 
 from __future__ import annotations
@@ -43,6 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import Config
+from ..ops import moe_combine
 
 Params = Dict[str, Any]
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -231,54 +240,6 @@ def shared_experts(f: Params, h: jnp.ndarray, mean_of: int = 1) -> jnp.ndarray:
         return y if mean_of == 1 else y / mean_of
 
 
-def moe_experts(f: Params, config: Config, h: jnp.ndarray, sum_eps: float):
-    """The expert layer on an already NORMED h [T, H] bfloat16 -> (y [T, H]
-    float32: the experts' weighted sum, + the shared branch where the layer
-    has one; tokens per expert [E] int32; experts chosen [T, k] int32).  No
-    norm and no residual add: a serial block (``moe_ffn``) wraps it in its
-    own, a parallel block shares one of each with its attention.  Only the
-    T*k routed pairs are computed, grouped by expert; no capacity, nothing
-    dropped."""
-    c = config
-    T, H = h.shape
-    k, E = c.num_experts_per_tok, c.num_experts
-    with jax.named_scope("decoder/lm/moe/route"):
-        experts, weights = route(f, c, h, sum_eps)
-    with jax.named_scope("decoder/lm/moe/dispatch"):
-        flat = experts.reshape(T * k)
-        order = jnp.argsort(flat, stable=True)           # pairs, by expert
-        rows = h[order // k]                                # [T*k, H]
-        sizes = jnp.sum(
-            flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :],
-            axis=0, dtype=jnp.int32,
-        )
-    with jax.named_scope("decoder/lm/moe/experts"):
-        hidden = swiglu(
-            grouped_matmul(rows, f["w1"], sizes), grouped_matmul(rows, f["w3"], sizes)
-        )
-        out = grouped_matmul(hidden, f["w2"], sizes)
-    with jax.named_scope("decoder/lm/moe/combine"):
-        back = jnp.zeros((T * k,), jnp.int32).at[order].set(
-            jnp.arange(T * k, dtype=jnp.int32)
-        )
-        picked = out[back].reshape(T, k, H).astype(jnp.float32)
-        y = jnp.sum(picked * weights[..., None], axis=1)
-    if "shared" in f:
-        y = y + shared_experts(f, h)
-    return y, sizes, experts
-
-
-def moe_ffn(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
-    """The serial block's expert layer: x [T, H] -> (x +
-    ``moe_experts(ffn_norm(x))`` [T, H], tokens per expert [E] int32,
-    experts chosen [T, k] int32)."""
-    with jax.named_scope("decoder/lm/moe/route"):
-        h = rms_norm(x, p["ffn_norm"], config.norm_eps).astype(jnp.bfloat16)
-    y, sizes, experts = moe_experts(p["feed_forward"], config, h, sum_eps)
-    with jax.named_scope("decoder/lm/moe/combine"):
-        return x + y.astype(x.dtype), sizes, experts
-
-
 # a layer that holds a share of its experts sizes its grouped products for
 # this many times the share a balanced router sends it
 HELD_CAPACITY_FACTOR = 4
@@ -345,23 +306,26 @@ def _combine_held(out, order, weights, done):
     fetches the computed rows and no others, where ``moe_combine.takes``
     (on the TPU, a prefill's pairs); else the ``lax`` form, a row for every
     routed pair.  Both are differentiable, so no caller says which."""
-    from ..ops import moe_combine       # ops/__init__ imports models
-
     (P, H), (T, k) = out.shape, weights.shape
     if moe_combine.takes(T, k, H, P):
         return moe_combine.moe_combine(out, order, weights, done), done, jnp.int32(1)
     return _combine_lax(out, order, weights, done), jnp.int32(T * k), jnp.int32(0)
 
 
-def moe_experts_held(f: Params, config: Config, h: jnp.ndarray, sum_eps: float, shared_mean_of: int = 1):
-    """``moe_experts`` for a layer that holds experts ``[first_expert,
-    first_expert + experts_held)``: h [T, H] normed bfloat16 -> (y [T, H]
-    float32: the held experts' weighted sum (+ the shared branch), tokens
-    per expert over ALL ``num_experts`` [E] int32, experts chosen [T, k]
-    int32, ``HeldPairs``).  The router scores every expert; the pairs that
+def moe_experts(f: Params, config: Config, h: jnp.ndarray, sum_eps: float, shared_mean_of: int = 1):
+    """The expert layer on an already NORMED h [T, H] bfloat16, holding
+    experts ``[first_expert, first_expert + experts_held)`` (all of them
+    where ``experts_held`` is 0) -> (y [T, H] float32: the held experts'
+    weighted sum, + the shared branch where the layer has one; tokens per
+    expert over ALL ``num_experts`` [E] int32; experts chosen [T, k] int32;
+    ``HeldPairs``).  No norm and no residual add: a serial block
+    (``moe_ffn``) wraps it in its own, a parallel block shares one of each
+    with its attention.  The router scores every expert; the pairs that
     land here are sorted by expert to the front of ``held_pair_rows`` rows
-    and go through the grouped products; the others are left out, here as
-    in the deployment's other chips' absence."""
+    and go through the grouped products, those and no others: no capacity
+    among the experts here, nothing dropped; the pairs of experts
+    elsewhere are left out, here as in the deployment's other chips'
+    absence."""
     c = config
     T, H = h.shape
     k, E, held = c.num_experts_per_tok, c.num_experts, held_experts(c)
@@ -401,17 +365,13 @@ def moe_experts_held(f: Params, config: Config, h: jnp.ndarray, sum_eps: float, 
     return y, counts, experts, stats
 
 
-def moe_ffn_held(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
-    """The serial block's expert layer at a held share: x [T, H] -> (x +
-    ``moe_experts_held(ffn_norm(x))`` [T, H], tokens per expert [E],
-    experts chosen [T, k], ``HeldPairs``).  With every expert held it is
-    ``moe_ffn`` to the bit where the combine is the ``lax`` form (every
-    backend but the TPU, and a step's few pairs there); through
-    ``ops/moe_combine.py``'s kernel (``_combine_held``) to the float32
-    rounding of the k-term sum."""
+def moe_ffn(p: Params, config: Config, x: jnp.ndarray, sum_eps: float):
+    """The serial block's expert layer: x [T, H] -> (x +
+    ``moe_experts(ffn_norm(x))`` [T, H], tokens per expert [E], experts
+    chosen [T, k], ``HeldPairs``)."""
     with jax.named_scope("decoder/lm/moe/route"):
         h = rms_norm(x, p["ffn_norm"], config.norm_eps).astype(jnp.bfloat16)
-    y, counts, experts, stats = moe_experts_held(p["feed_forward"], config, h, sum_eps)
+    y, counts, experts, stats = moe_experts(p["feed_forward"], config, h, sum_eps)
     with jax.named_scope("decoder/lm/moe/combine"):
         return x + y.astype(x.dtype), counts, experts, stats
 
@@ -424,21 +384,20 @@ def dense_ffn(p: Params, config: Config, x: jnp.ndarray) -> jnp.ndarray:
 
 
 def ffn(p: Params, config: Config, layer: int, x: jnp.ndarray, sum_eps: float):
-    """x [..., H] -> (y, tokens per expert [E], experts chosen [..., k]),
-    the last two None in a dense layer."""
+    """A serial block's feed-forward, dense or expert layer: x [..., H] ->
+    (y, tokens per expert [E], experts chosen [..., k], ``HeldPairs``), the
+    last three None in a dense layer."""
     if not is_moe(config, layer):
-        return dense_ffn(p, config, x), None, None
-    if held_experts(config) < config.num_experts:
-        raise ValueError(
-            "a layer that holds a share of its experts reports what it held: "
-            "call moe_ffn_held"
-        )
-    y, sizes, experts = moe_ffn(p, config, x.reshape(-1, x.shape[-1]), sum_eps)
-    return y.reshape(x.shape), sizes, experts.reshape(x.shape[:-1] + (-1,))
+        return dense_ffn(p, config, x), None, None, None
+    y, counts, experts, stats = moe_ffn(p, config, x.reshape(-1, x.shape[-1]), sum_eps)
+    return y.reshape(x.shape), counts, experts.reshape(x.shape[:-1] + (-1,)), stats
 
 
-def ffn_params(config: Config, layer: int, linear) -> Params:
-    """One layer's ``feed_forward`` leaves; ``linear(*shape)`` draws a map."""
+def ffn_params(config: Config, layer: int, linear, shared: bool = True) -> Params:
+    """One layer's ``feed_forward`` leaves; ``linear(*shape)`` draws a map.
+    ``shared``: an expert layer's ``shared`` SwiGLU,
+    ``n_shared_experts * moe_intermediate_size`` wide, where the stack has
+    that branch and the Config any such expert."""
     c = config
     H, E, held = c.hidden_size, c.num_experts, held_experts(c)
     if not is_moe(c, layer):
@@ -452,6 +411,9 @@ def ffn_params(config: Config, layer: int, linear) -> Params:
     }
     if not c.use_expert_bias:       # a router with no selection bias has no such leaf
         del f["expert_bias"]
+    if shared and c.n_shared_experts:
+        I = c.n_shared_experts * c.moe_intermediate_size
+        f["shared"] = {"w1": linear(H, I), "w3": linear(H, I), "w2": linear(I, H)}
     return f
 
 
@@ -462,6 +424,48 @@ def connector_params(key: jax.Array, config: Config) -> Params:
         "kernel": 0.02 * jax.random.normal(key, (config.dim_ctx, H), jnp.float32),
         "bias": jnp.zeros((H,), jnp.float32),
     }
+
+
+def init_stack(
+    rng: jax.Array, config: Config, layer_params, *, keys_per_layer: int, norm: str,
+    shared: bool = True, connector_first: bool = False,
+) -> Params:
+    """{'connector': float32 (it trains), 'lm': the stack, bfloat16 but
+    for ``expert_bias`` (a float32 buffer)}.  Normal(0.02) linear maps,
+    unit norm weights: a starting point for the connector's training, not
+    a source's weights (a checkpoint carries those).  The stack gives
+    ``layer_params(layer, linear, ones)``: ONE layer's norms and its
+    mixer's leaves (``linear(*shape)`` draws a map, ``ones(n)`` is a
+    norm's weight); the layer's ``feed_forward`` (``ffn_params``), the
+    embedding, the final norm under the stack's name for it, an untied
+    ``lm_head`` and the connector are drawn here.  ``keys_per_layer`` (the
+    stream is that many a layer + 4) and ``connector_first`` (its key
+    before the embedding's, not after the head's) keep each stack's draws
+    in the order they had when each stack made its own, so a seed gives
+    the weights it gave."""
+    c = config
+    H = c.hidden_size
+    keys = iter(jax.random.split(rng, keys_per_layer * c.num_hidden_layers + 4))
+
+    def linear(*shape):
+        return (0.02 * jax.random.normal(next(keys), shape, jnp.float32)).astype(jnp.bfloat16)
+
+    def ones(n):
+        return jnp.ones((n,), jnp.bfloat16)
+
+    layers: Params = {}
+    for i in range(c.num_hidden_layers):
+        p = layer_params(i, linear, ones)
+        p["feed_forward"] = ffn_params(c, i, linear, shared)
+        layers[layer_name(i)] = p
+    if connector_first:
+        connector = connector_params(next(keys), c)
+    lm: Params = {"embed_tokens": linear(c.vocabulary_size, H), norm: ones(H), "layers": layers}
+    if not c.tie_word_embeddings:
+        lm["lm_head"] = linear(H, c.vocabulary_size)
+    if not connector_first:
+        connector = connector_params(next(keys), c)
+    return {"connector": connector, "lm": lm}
 
 
 def prefix(params: Params, contexts: jnp.ndarray) -> jnp.ndarray:
@@ -491,6 +495,66 @@ def sequence_inputs(params: Params, contexts: jnp.ndarray, sentences: jnp.ndarra
     return jnp.concatenate(
         [prefix(params, contexts), embed(params["lm"], words_in)], axis=1
     )
+
+
+# ---------------------------------------------------------------------------
+# a whole sequence's attention by ``lax``, a block of queries at a time
+# ---------------------------------------------------------------------------
+
+# queries a block; a prefill whose sequence is whole blocks of them takes
+# ``ops/flash_prefill.py``'s kernel on the TPU, and not these
+QUERY_BLOCK = 512
+
+
+def query_blocks(S: int):
+    return [(a, min(a + QUERY_BLOCK, S)) for a in range(0, S, QUERY_BLOCK)]
+
+
+def causal_blocks(S: int, window=None):
+    """Per block of queries of a causal sequence: (the first key the block
+    reaches back to, what its queries see as a mask [block, keys from there
+    to the block's end]).  ``window``: a query sees itself and the
+    ``window - 1`` positions before it, so a block's keys start that far
+    before its first query; None: every position up to its own, from the
+    first key."""
+    lows, masks = [], []
+    positions = jnp.arange(S)
+    for a, b in query_blocks(S):
+        low = 0 if window is None else max(a - (window - 1), 0)
+        ahead = positions[a:b, None] - positions[None, low:b]
+        lows.append(low)
+        masks.append(ahead >= 0 if window is None else (ahead >= 0) & (ahead < window))
+    return lows, masks
+
+
+def attend_blocks(q, keys, values, masks, scale: float, lows=None) -> jnp.ndarray:
+    """q [nh, S, d], keys [kv, S, d], values [kv, S, dv] -> [S, nh * dv]
+    bfloat16: ``nh // kv`` query heads against each key/value head (grouped
+    queries; kv = nh where every head has its own, as latent attention's
+    expanded form).  A block of queries at a time against the keys up to
+    the block's end, from ``lows``' entry for the block on (where a window
+    leaves the earlier ones unseen by all of it; else from the first),
+    under the block's entry of ``masks`` [block, those keys]; its float32
+    scores ``[kv, group, block, keys]`` whole, one softmax a row."""
+    nh, S, d = q.shape
+    kv, dv = values.shape[0], values.shape[-1]
+    q = q.reshape(kv, nh // kv, S, d)
+    blocks = query_blocks(S)
+    ctx = []
+    for (a, b), mask, low in zip(blocks, masks, lows or (0,) * len(blocks)):
+        scores = jnp.einsum(
+            "hgsd,htd->hgst", q[:, :, a:b], keys[:, low:b], preferred_element_type=jnp.float32
+        )
+        scores = jnp.where(mask, scores * scale, -jnp.inf)
+        # the softmax's division after the weighted sum, as the kernel's; a
+        # group's heads go through the second product as rows of ONE head
+        weights = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
+        block = jnp.einsum(
+            "hqt,htd->hqd", weights.astype(jnp.bfloat16).reshape(kv, -1, b - low),
+            values[:, low:b], preferred_element_type=jnp.float32,
+        ).reshape(kv, nh // kv, b - a, dv) / jnp.sum(weights, axis=-1)[..., None]
+        ctx.append(jnp.transpose(block, (2, 0, 1, 3)).astype(jnp.bfloat16).reshape(b - a, nh * dv))
+    return jnp.concatenate(ctx, axis=0)
 
 
 # ---------------------------------------------------------------------------

@@ -118,3 +118,38 @@ def make_coco_fixture(
         "val_img_dir": val_img_dir,
         "config": config,
     }
+
+
+def plain_moe_ffn(p, config, x, sum_eps):
+    """The serial expert layer with EVERY expert held, written out plainly
+    (PR 41's lines of ``lm_common.moe_ffn``): the reference the one expert
+    layer is held against, to the bit off the chip.  x [T, H] -> (x + the
+    experts' weighted sum (+ the shared SwiGLU), tokens per expert [E],
+    experts chosen [T, k]).  ``p["feed_forward"]`` holds all ``num_experts``
+    maps, whatever ``config.experts_held`` says.  jax and the program are
+    imported here: this module is also the jax-free tests'."""
+    import jax.numpy as jnp
+
+    from sat_tpu.models import lm_common
+
+    c = config
+    T, H = x.shape
+    k, E = c.num_experts_per_tok, c.num_experts
+    h = lm_common.rms_norm(x, p["ffn_norm"], c.norm_eps).astype(jnp.bfloat16)
+    experts, weights = lm_common.route(p["feed_forward"], c, h, sum_eps)
+    f = p["feed_forward"]
+    flat = experts.reshape(T * k)
+    order = jnp.argsort(flat, stable=True)
+    rows = h[order // k]
+    sizes = jnp.sum(flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32)
+    hidden = lm_common.swiglu(
+        lm_common.grouped_matmul(rows, f["w1"], sizes), lm_common.grouped_matmul(rows, f["w3"], sizes))
+    out = lm_common.grouped_matmul(hidden, f["w2"], sizes)
+    back = jnp.zeros((T * k,), jnp.int32).at[order].set(jnp.arange(T * k, dtype=jnp.int32))
+    picked = out[back].reshape(T, k, H).astype(jnp.float32)
+    y = jnp.sum(picked * weights[..., None], axis=1)
+    if "shared" in f:
+        s = f["shared"]
+        y = y + lm_common.mm(lm_common.swiglu(lm_common.mm(h, s["w1"]), lm_common.mm(h, s["w3"])),
+                             s["w2"]).astype(jnp.float32)
+    return x + y.astype(x.dtype), sizes, experts

@@ -13,7 +13,7 @@ import pytest
 
 from sat_tpu.config import Config
 from sat_tpu.models.decoder import decoder_step, init_decoder_params, init_state
-from sat_tpu.ops import beam_search, greedy_decode
+from sat_tpu.ops.beam_search import beam_search, greedy_decode
 
 
 def tiny_config(**kw) -> Config:

@@ -38,9 +38,10 @@ from sat_tpu.models import cohere2_moe as c2  # noqa: E402
 from sat_tpu.models import decoders, lm_common  # noqa: E402
 from sat_tpu.ops import flash_prefill  # noqa: E402
 
+from fixtures import plain_moe_ffn  # noqa: E402
 from test_glm_moe_dsa import FORWARD_TOL, LAYER_TOL, PATH_TOL, _close  # noqa: E402
 
-bs = importlib.import_module("sat_tpu.ops.beam_search")  # ops/__init__ exports a function of that name
+bs = importlib.import_module("sat_tpu.ops.beam_search")
 
 KINDS = ("sliding_attention", "sliding_attention", "sliding_attention", "full_attention")
 TOY = dict(
@@ -82,7 +83,7 @@ def params(weights):
 def small_blocks(monkeypatch):
     """Whole sequences in blocks of 8 queries: seven blocks over 56, a
     sliding layer's band two blocks wide."""
-    monkeypatch.setattr(c2, "_QUERY_BLOCK", 8)
+    monkeypatch.setattr(lm_common, "QUERY_BLOCK", 8)
 
 
 def _inputs(seed=0, B=2, T=20, n=N):
@@ -197,7 +198,8 @@ def test_the_grouped_kernel_against_the_lax_form_and_every_score(S, window, tile
     got = flash_prefill.flash_prefill(q, k, v, None, scale=scale, tiles=tiles, interpret=True, window=window)
     want = _plain_grouped(*(np.asarray(x, np.float32) for x in (q, k, v)), window, scale)
     _close(got, want, 2e-2)     # bfloat16 weights in the second product
-    _close(got, c2._attend_blocks(q, k, v, scale, window), 1e-2)
+    lows, masks = lm_common.causal_blocks(S, window)
+    _close(got, lm_common.attend_blocks(q, k, v, masks, scale, lows), 1e-2)
 
 
 def test_the_grouped_kernel_refuses_heads_that_split_a_key_value_head():
@@ -387,7 +389,7 @@ def test_the_gradient_through_a_whole_sequence_is_the_second_product_s(params, m
 @pytest.mark.parametrize("blocks", ["one_block", "blocks_of_8"])
 def test_teacher_forced_logits_against_the_plain_full_forward(params, weights, blocks, monkeypatch):
     if blocks == "blocks_of_8":
-        monkeypatch.setattr(c2, "_QUERY_BLOCK", 8)
+        monkeypatch.setattr(lm_common, "QUERY_BLOCK", 8)
     ctx, tokens = _inputs()
     want, routes = _reference(weights, ctx, tokens)
     got = jax.jit(lambda p, c, t: c2.teacher_forced(p, CONFIG, c, t))(params, ctx, tokens)
@@ -435,7 +437,7 @@ def test_prefill_through_the_grouped_kernel_then_20_cached_steps_equal_the_full_
     config = Config(**toy)
     weights = _weights(_model(toy))
     params = jax.tree_util.tree_map(jnp.asarray, nest(weights, "params/decoder"))
-    monkeypatch.setattr(c2, "_QUERY_BLOCK", 8)
+    monkeypatch.setattr(lm_common, "QUERY_BLOCK", 8)
     monkeypatch.setattr(flash_prefill, "FORCE_INTERPRET", hook)
     monkeypatch.setattr(flash_prefill, "_TILES", (8, 8, 4))
     ctx, tokens = _inputs(seed=4, n=64)
@@ -506,7 +508,7 @@ def test_the_eight_shares_add_up_to_the_reference_s_uncut_layer():
     for first in range(0, 16, 2):
         held = {**f, **{w: f[w][first:first + 2] for w in ("w1", "w3", "w2")}}
         config = Config(**{**toy, "experts_held": 2, "first_expert": first})
-        share = jax.jit(lambda f, u, config=config: lm_common.moe_experts_held(f, config, u, 0.0, shared_mean_of=4))
+        share = jax.jit(lambda f, u, config=config: lm_common.moe_experts(f, config, u, 0.0, shared_mean_of=4))
         y, _, experts, pairs = share(held, u)
         y_routed = share({k: v for k, v in held.items() if k != "shared"}, u)[0]
         routed, shared_part = routed + y_routed, y - y_routed
@@ -519,69 +521,41 @@ def test_the_eight_shares_add_up_to_the_reference_s_uncut_layer():
     assert np.array_equal(np.asarray(summed / 4), np.asarray(lm_common.shared_experts(f, u, 4)))
 
 
-def _parent_moe_ffn(p, config, x, sum_eps):
-    """``lm_common.moe_ffn`` as it stood before the norm and the add moved
-    out of the expert layer (PR 41's lines)."""
-    c = config
-    T, H = x.shape
-    k, E = c.num_experts_per_tok, c.num_experts
-    h = lm_common.rms_norm(x, p["ffn_norm"], c.norm_eps).astype(jnp.bfloat16)
-    experts, weights = lm_common.route(p["feed_forward"], c, h, sum_eps)
-    f = p["feed_forward"]
-    flat = experts.reshape(T * k)
-    order = jnp.argsort(flat, stable=True)
-    rows = h[order // k]
-    sizes = jnp.sum(flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32)
-    hidden = lm_common.swiglu(
-        lm_common.grouped_matmul(rows, f["w1"], sizes), lm_common.grouped_matmul(rows, f["w3"], sizes))
-    out = lm_common.grouped_matmul(hidden, f["w2"], sizes)
-    back = jnp.zeros((T * k,), jnp.int32).at[order].set(jnp.arange(T * k, dtype=jnp.int32))
-    picked = out[back].reshape(T, k, H).astype(jnp.float32)
-    y = jnp.sum(picked * weights[..., None], axis=1)
-    if "shared" in f:
-        s = f["shared"]
-        y = y + lm_common.mm(lm_common.swiglu(lm_common.mm(h, s["w1"]), lm_common.mm(h, s["w3"])),
-                             s["w2"]).astype(jnp.float32)
-    return x + y.astype(x.dtype), sizes, experts
-
-
 @pytest.mark.parametrize("held,shared", [(0, True), (0, False), (4, True), (4, False)],
                          ids=["all-shared", "all", "share-shared", "share"])
 def test_the_serial_callers_get_the_parent_s_result_to_the_bit(held, shared):
-    """``moe_ffn`` and ``moe_ffn_held`` wrap the expert layer in their own
-    norm and their own add again: x + what ``moe_experts`` /
-    ``moe_experts_held`` return on ``ffn_norm(x)``, to the bit what the
-    functions returned before the split (the parent's lines above; with
-    every expert held ``moe_ffn_held`` is ``moe_ffn``, as it was)."""
+    """``moe_ffn`` wraps the ONE expert layer in its own norm and its own
+    add: x + what ``moe_experts`` returns on ``ffn_norm(x)``.  With every
+    expert held (``experts_held`` 0) that is, to the bit, the plain layer
+    written out (``fixtures.plain_moe_ffn``); at a share, what the held
+    experts and the shared one add to it."""
     config = Config(**{**TOY, "decoder": "deepseek_v3", "layer_types": ("latent_attention",) * 4, "head_dim": 0,
                        "use_expert_bias": True, "experts_held": held, "first_expert": 4 if held else 0})
     keys = iter(jax.random.split(jax.random.PRNGKey(2), 12))
     linear = lambda *shape: (0.2 * jax.random.normal(next(keys), shape)).astype(jnp.bfloat16)  # noqa: E731
-    f = lm_common.ffn_params(config, 0, linear)
+    f = lm_common.ffn_params(config, 0, linear, shared=False)
     f["expert_bias"] = 0.1 * jax.random.normal(next(keys), (16,))
     if shared:
         f["shared"] = {"w1": linear(64, 48), "w3": linear(64, 48), "w2": linear(48, 64)}
     p = {"ffn_norm": (1 + 0.1 * jax.random.normal(next(keys), (64,))).astype(jnp.bfloat16), "feed_forward": f}
     x = jax.random.normal(next(keys), (40, 64)).astype(jnp.bfloat16)
+    got, counts, experts, pairs = jax.jit(lambda p, x: lm_common.moe_ffn(p, config, x, 1e-20))(p, x)
+    u = lm_common.rms_norm(x, p["ffn_norm"], config.norm_eps).astype(jnp.bfloat16)
+    y, counts2, experts2, _ = lm_common.moe_experts(f, config, u, 1e-20)
+    assert np.array_equal(np.asarray(x + y.astype(x.dtype), np.float32), np.asarray(got, np.float32))
+    assert np.array_equal(counts, counts2) and np.array_equal(experts, experts2) and int(pairs.over) == 0
     if held:
-        got, counts, experts, pairs = jax.jit(lambda p, x: lm_common.moe_ffn_held(p, config, x, 1e-20))(p, x)
-        u = lm_common.rms_norm(x, p["ffn_norm"], config.norm_eps).astype(jnp.bfloat16)
-        y, counts2, experts2, _ = lm_common.moe_experts_held(f, config, u, 1e-20)
-        assert np.array_equal(np.asarray(x + y.astype(x.dtype), np.float32), np.asarray(got, np.float32))
-        assert np.array_equal(counts, counts2) and np.array_equal(experts, experts2) and int(pairs.over) == 0
         # against the uncut layer: what the four held experts add, and the shared expert's
         whole = {**p, "feed_forward": {**f, **{w: jnp.zeros((16,) + f[w].shape[1:], f[w].dtype).at[4:8].set(f[w])
                                                for w in ("w1", "w3", "w2")}}}
-        want, _, chosen = _parent_moe_ffn(whole, config.replace(experts_held=0, first_expert=0), x, 1e-20)
+        want, _, chosen = plain_moe_ffn(whole, config, x, 1e-20)
         assert np.array_equal(experts, chosen)
         _close(got, want, 1e-2)     # the zero experts' pairs add exact zeros, in another order of the k-term sum
     else:
-        got, sizes, experts = jax.jit(lambda p, x: lm_common.moe_ffn(p, config, x, 1e-20))(p, x)
-        want, sizes2, experts2 = jax.jit(lambda p, x: _parent_moe_ffn(p, config, x, 1e-20))(p, x)
+        want, sizes, chosen = jax.jit(lambda p, x: plain_moe_ffn(p, config, x, 1e-20))(p, x)
         assert np.array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
-        assert np.array_equal(sizes, sizes2) and np.array_equal(experts, experts2)
-        every = jax.jit(lambda p, x: lm_common.moe_ffn_held(p, config, x, 1e-20))(p, x)
-        assert np.array_equal(np.asarray(every[0], np.float32), np.asarray(want, np.float32))
+        assert np.array_equal(counts, sizes) and np.array_equal(experts, chosen)
+        assert (int(pairs.held), int(pairs.routed), int(pairs.fetched)) == (120, 120, 120)
 
 
 # ---------------------------------------------------------------------------

@@ -38,7 +38,7 @@ from sat_tpu.models import deepseek_v3 as ds  # noqa: E402
 from sat_tpu.models import decoders, lm_common  # noqa: E402
 from sat_tpu.models.captioner import compute_loss  # noqa: E402
 
-bs = importlib.import_module("sat_tpu.ops.beam_search")  # ops/__init__ exports a function of that name
+bs = importlib.import_module("sat_tpu.ops.beam_search")
 
 TOY = dict(
     decoder="deepseek_v3", cnn="vgg16", image_size=32, hidden_size=64, intermediate_size=96,
@@ -342,8 +342,8 @@ def test_the_shared_expert_adds_exactly_its_own_output(params):
     # maps x16 (a power of two: bfloat16-exact) so the sums stand clear of the residual's rounding
     x = (0.5 * jax.random.normal(jax.random.PRNGKey(8), (40, CONFIG.hidden_size))).astype(jnp.bfloat16)
     zero = jnp.zeros_like(x)
-    with_, sizes, experts = ds._ffn(p, CONFIG, 2, zero + x)
-    without, sizes0, experts0 = ds._ffn(bare, CONFIG, 2, zero + x)
+    with_, sizes, experts, _ = ds._ffn(p, CONFIG, 2, zero + x)
+    without, sizes0, experts0, _ = ds._ffn(bare, CONFIG, 2, zero + x)
     assert np.array_equal(sizes, sizes0) and np.array_equal(experts, experts0)
     s = p["feed_forward"]["shared"]
     h = lm_common.rms_norm(x, p["ffn_norm"], CONFIG.norm_eps).astype(jnp.bfloat16)
@@ -364,7 +364,8 @@ def test_uneven_routing_with_an_empty_expert_drops_nothing(params):
     big = {k: p["feed_forward"][k] * 8 for k in ("w1", "w3", "w2")}
     p["feed_forward"] = {**p["feed_forward"], **big, "expert_bias": bias}
     x = (0.5 * jax.random.normal(jax.random.PRNGKey(5), (64, CONFIG.hidden_size))).astype(jnp.bfloat16)
-    y, sizes, experts = lm_common.moe_ffn(p, CONFIG, x, 1e-20)
+    y, sizes, experts, pairs = lm_common.moe_ffn(p, CONFIG, x, 1e-20)
+    assert (int(pairs.held), int(pairs.over)) == (64 * 3, 0)
     sizes = np.asarray(sizes)
     assert sizes[3] == 64 and sizes[6] == 0 and sizes.sum() == 64 * 3
     rp = ref._f32({**p["feed_forward"], "expert_bias": bias})

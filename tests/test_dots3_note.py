@@ -37,7 +37,7 @@ from sat_tpu.ops import flash_prefill  # noqa: E402
 
 from test_glm_moe_dsa import FORWARD_TOL, LAYER_TOL, PATH_TOL, _close, _close_but_for_flips  # noqa: E402
 
-bs = importlib.import_module("sat_tpu.ops.beam_search")  # ops/__init__ exports a function of that name
+bs = importlib.import_module("sat_tpu.ops.beam_search")
 
 KINDS = ("full_attention", "full_attention", "sliding_attention", "sliding_attention", "sliding_attention")
 TOY = dict(
@@ -83,7 +83,7 @@ def params(weights):
 def small_blocks(monkeypatch):
     """Whole sequences in blocks of 8 queries: seven blocks over 56, a
     sliding layer's band two blocks wide."""
-    monkeypatch.setattr(dsa, "_QUERY_BLOCK", 8)
+    monkeypatch.setattr(lm_common, "QUERY_BLOCK", 8)
 
 
 def _inputs(seed=0, B=2, T=20):
@@ -212,8 +212,8 @@ def test_the_windowed_kernel_against_the_lax_band_and_every_score(S, window, til
     got = flash_prefill.flash_prefill(q, k, v, None, scale=scale, tiles=tiles, interpret=True, window=window)
     want = _plain_band(*(np.asarray(x, np.float32) for x in (q, k, v)), window, scale)
     _close(got, want, 2e-2)     # bfloat16 weights in the second product
-    lows, masks = d3._band(S, window)
-    lax_form = dsa._attend_blocks(q, k, v, masks, scale, lows).reshape(S, -1)
+    lows, masks = lm_common.causal_blocks(S, window)
+    lax_form = lm_common.attend_blocks(q, k, v, masks, scale, lows)
     _close(got, lax_form, 1e-2)
 
 
@@ -235,7 +235,7 @@ def test_the_windowed_kernel_visits_the_band_s_key_tiles_alone():
 
 
 def test_a_band_of_the_lax_form_is_two_blocks_wide(small_blocks):
-    lows, masks = d3._band(56, 9)
+    lows, masks = lm_common.causal_blocks(56, 9)
     assert lows == [0, 0, 8, 16, 24, 32, 40] and [m.shape for m in masks] == [(8, 8)] + [(8, 16)] * 6
     assert all(int(m.sum(-1).max()) == 9 for m in masks[1:]) and int(masks[0].sum()) == 36
 
@@ -268,7 +268,7 @@ def test_a_step_s_window_at_the_prefix_s_boundary(params, t):
 @pytest.mark.parametrize("blocks", ["one_block", "blocks_of_8"])
 def test_teacher_forced_logits_against_the_plain_full_forward(params, weights, blocks, monkeypatch):
     if blocks == "blocks_of_8":
-        monkeypatch.setattr(dsa, "_QUERY_BLOCK", 8)
+        monkeypatch.setattr(lm_common, "QUERY_BLOCK", 8)
     ctx, tokens = _inputs()
     got = d3.teacher_forced(params, CONFIG, ctx, tokens)
     want, routes, selections = _reference(weights, ctx, tokens)
@@ -321,7 +321,7 @@ def test_prefill_through_the_fused_kernel_then_20_cached_steps_equal_the_full_fo
     full layers under the selection's mask, the sliding layers under the
     window bound; then 20 cached steps, against the reference's full
     forward.  The counter says which form ran, by kind."""
-    monkeypatch.setattr(dsa, "_QUERY_BLOCK", 12)
+    monkeypatch.setattr(lm_common, "QUERY_BLOCK", 12)
     monkeypatch.setattr(flash_prefill, "FORCE_INTERPRET", hook)
     ctx, tokens = _inputs()
     cached, prefix, _, counters = _cached_logits(params, CONFIG, ctx, tokens)
@@ -401,7 +401,7 @@ def test_the_eight_shares_add_up_to_the_reference_s_uncut_layer():
     for first in range(0, 16, 2):
         held = {**p, "feed_forward": {**f, **{w: f[w][first:first + 2] for w in ("w1", "w3", "w2")}}}
         config = Config(**{**toy, "experts_held": 2, "first_expert": first})
-        share = jax.jit(lambda p, x, config=config: lm_common.moe_ffn_held(p, config, x, 1e-20))
+        share = jax.jit(lambda p, x, config=config: lm_common.moe_ffn(p, config, x, 1e-20))
         y, _, experts, pairs = share(held, x)
         alone = {**held, "feed_forward": {k: v for k, v in held["feed_forward"].items() if k != "shared"}}
         y_routed = share(alone, x)[0]

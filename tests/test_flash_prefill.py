@@ -1,7 +1,7 @@
 """ops/flash_prefill.py (causal attention of a whole sequence under a mask
 all heads share, the scores in VMEM under an online softmax) in interpret
 mode on the CPU, against the ``lax`` blocked form it stands in for
-(``models/glm_moe_dsa.py`` ``_attend_blocks``: a block's float32 scores
+(``models/lm_common.py`` ``attend_blocks``: a block's float32 scores
 whole, one softmax a row) on the same inputs, at toy widths with
 ``d_qk != d_v``.
 
@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 from sat_tpu.models import glm_moe_dsa as dsa
+from sat_tpu.models import lm_common
 from sat_tpu.ops import flash_prefill as fp
 
 HEADS, D_QK, D_V, BLOCK, TOPK = 4, 48, 24, 8, 16
@@ -39,7 +40,7 @@ def _masks(index_scores, S, topk=TOPK):
     block sees ``topk`` keys or fewer, else the ``topk`` best visible."""
     positions = jnp.arange(S)
     masks = []
-    for a, b in dsa._blocks(S):
+    for a, b in lm_common.query_blocks(S):
         causal = positions[a:b, None] >= positions[None, :b]
         masks.append(causal if b <= topk else dsa._select_mask(index_scores[a:b, :b], causal, topk))
     return masks
@@ -47,7 +48,7 @@ def _masks(index_scores, S, topk=TOPK):
 
 def _both(q, k, v, masks, tiles=None):
     S = q.shape[1]
-    want = dsa._attend_blocks(q, k, v, masks, SCALE).reshape(S, -1)
+    want = lm_common.attend_blocks(q, k, v, masks, SCALE)
     got = fp.flash_prefill(
         q, k, v, dsa._one_mask(masks, S, TOPK), scale=SCALE, tiles=tiles, interpret=True
     )
@@ -63,7 +64,7 @@ def _assert_close(got, want):
 
 @pytest.fixture(autouse=True)
 def blocks_of_8(monkeypatch):
-    monkeypatch.setattr(dsa, "_QUERY_BLOCK", BLOCK)
+    monkeypatch.setattr(lm_common, "QUERY_BLOCK", BLOCK)
 
 
 @pytest.mark.parametrize("case,S,tiles", [

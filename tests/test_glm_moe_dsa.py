@@ -37,7 +37,9 @@ from sat_tpu.models import decoders, lm_common  # noqa: E402
 from sat_tpu.models import glm_moe_dsa as dsa  # noqa: E402
 from sat_tpu.models.captioner import compute_loss  # noqa: E402
 
-bs = importlib.import_module("sat_tpu.ops.beam_search")  # ops/__init__ exports a function of that name
+from fixtures import plain_moe_ffn  # noqa: E402
+
+bs = importlib.import_module("sat_tpu.ops.beam_search")
 
 TOY = dict(
     decoder="glm_moe_dsa", cnn="vgg16", image_size=96, hidden_size=64, intermediate_size=96,
@@ -84,7 +86,7 @@ def params(weights):
 @pytest.fixture
 def small_blocks(monkeypatch):
     """Whole sequences in blocks of 8 queries: seven blocks over 56."""
-    monkeypatch.setattr(dsa, "_QUERY_BLOCK", 8)
+    monkeypatch.setattr(lm_common, "QUERY_BLOCK", 8)
 
 
 def _inputs(seed=0, B=2, T=20):
@@ -419,7 +421,7 @@ def test_whole_sequences_swap_once_a_layer_and_the_steps_never(params, monkeypat
 @pytest.mark.parametrize("blocks", ["one_block", "blocks_of_8"])
 def test_teacher_forced_logits_against_the_plain_full_forward(params, weights, blocks, monkeypatch):
     if blocks == "blocks_of_8":
-        monkeypatch.setattr(dsa, "_QUERY_BLOCK", 8)
+        monkeypatch.setattr(lm_common, "QUERY_BLOCK", 8)
     ctx, tokens = _inputs()
     got = dsa.teacher_forced(params, CONFIG, ctx, tokens)
     want, _, selections = ref.forward(lambda pre: _subtree(weights, pre), MODEL, np.asarray(ctx), np.asarray(tokens))
@@ -434,7 +436,7 @@ def test_teacher_forced_logits_against_the_plain_full_forward(params, weights, b
 def test_the_blocks_of_a_sequence_change_nothing(params, monkeypatch):
     ctx, tokens = _inputs(seed=4)
     whole = dsa.teacher_forced(params, CONFIG, ctx, tokens)
-    monkeypatch.setattr(dsa, "_QUERY_BLOCK", 8)
+    monkeypatch.setattr(lm_common, "QUERY_BLOCK", 8)
     blocked = dsa.teacher_forced(params, CONFIG, ctx, tokens)
     _close_but_for_flips(blocked, whole, PATH_TOL, share=0.9)
 
@@ -473,7 +475,7 @@ def test_prefill_through_the_fused_kernel_then_20_cached_steps_equal_the_full_fo
     blocks."""
     from sat_tpu.ops import flash_prefill
 
-    monkeypatch.setattr(dsa, "_QUERY_BLOCK", 12)
+    monkeypatch.setattr(lm_common, "QUERY_BLOCK", 12)
     monkeypatch.setattr(flash_prefill, "FORCE_INTERPRET", hook)
     ctx, tokens = _inputs()
     cached, prefix, _, counters = _cached_logits(params, CONFIG, ctx, tokens)
@@ -619,14 +621,14 @@ def test_the_shares_add_up_to_the_uncut_layer(T):
     weights = _weights(_model(toy))
     params = jax.tree_util.tree_map(jnp.asarray, nest(weights, "params/decoder"))
     p, x = _layer_and_tokens(params, T)
-    want, counts, experts = jax.jit(lambda p, x: lm_common.moe_ffn(p, whole, x, 1e-20))(p, x)
+    want, counts, experts = jax.jit(lambda p, x: plain_moe_ffn(p, whole, x, 1e-20))(p, x)
     routed, shared_part = jnp.zeros((T, 64), jnp.float32), None
     seen = 0
     for first in (0, 4, 8, 12):
         f = p["feed_forward"]
         held = {**p, "feed_forward": {**f, **{w: f[w][first:first + 4] for w in ("w1", "w3", "w2")}}}
         config = Config(**{**toy, "experts_held": 4, "first_expert": first})
-        share = jax.jit(lambda p, x, config=config: lm_common.moe_ffn_held(p, config, x, 1e-20))
+        share = jax.jit(lambda p, x, config=config: lm_common.moe_ffn(p, config, x, 1e-20))
         y, counts_, experts_, pairs = share(held, x)
         assert np.array_equal(counts_, counts) and np.array_equal(experts_, experts)
         alone = {**held, "feed_forward": {k: v for k, v in held["feed_forward"].items() if k != "shared"}}
@@ -643,7 +645,7 @@ def test_the_shares_add_up_to_the_uncut_layer(T):
 
 def test_a_share_against_the_reference_s_share(params, weights):
     p, x = _layer_and_tokens(params, 40)
-    got, _, experts, _ = lm_common.moe_ffn_held(p, CONFIG, x, 1e-20)
+    got, _, experts, _ = lm_common.moe_ffn(p, CONFIG, x, 1e-20)
     pf = ref._f32(_subtree(weights, "lm/layers/01"))
     with jax.default_matmul_precision("highest"):
         want, chosen = ref.ffn(pf, x.astype(jnp.float32), True, ref._Static(MODEL))
@@ -652,16 +654,21 @@ def test_a_share_against_the_reference_s_share(params, weights):
 
 
 def test_every_expert_held_is_today_s_layer_to_the_bit():
+    """``experts_held`` 0 is the one expert layer's plain case: to the bit
+    the layer with no share written out (``fixtures.plain_moe_ffn``), every
+    routed pair held, fetched and none over."""
     toy = {**TOY, "experts_held": 0, "first_expert": 0}
     config = Config(**toy)
     params = jax.tree_util.tree_map(jnp.asarray, nest(_weights(_model(toy)), "params/decoder"))
     for T in (24, 300):
         p, x = _layer_and_tokens(params, T)
-        want, counts, experts = lm_common.moe_ffn(p, config, x, 1e-20)
-        got, counts_, experts_, pairs = lm_common.moe_ffn_held(p, config, x, 1e-20)
+        want, counts, experts = plain_moe_ffn(p, config, x, 1e-20)
+        got, counts_, experts_, pairs = lm_common.moe_ffn(p, config, x, 1e-20)
         assert np.array_equal(np.asarray(got).view(np.uint16), np.asarray(want).view(np.uint16))
         assert np.array_equal(counts_, counts) and np.array_equal(experts_, experts)
         assert (int(pairs.held), int(pairs.routed), int(pairs.over)) == (T * 3, T * 3, 0)
+        assert (int(pairs.fetched), int(pairs.fused)) == (T * 3, 0)
+        assert int(pairs.visited) == int((counts > 0).sum())
 
 
 def test_the_rows_of_a_share_and_what_bounds_them():
@@ -676,12 +683,12 @@ def test_pairs_over_the_rows_are_counted_and_left_out(params, monkeypatch):
     """Rows for 5 pairs where more land: the counter says how many were
     left out, and the output is the layer's with exactly those left out."""
     p, x = _layer_and_tokens(params, 40)
-    _, counts, experts, sound = lm_common.moe_ffn_held(p, CONFIG, x, 1e-20)
+    _, counts, experts, sound = lm_common.moe_ffn(p, CONFIG, x, 1e-20)
     landed = int(counts[4:8].sum())
     assert int(sound.held) == landed > 5 and int(sound.over) == 0
     assert int(sound.visited) == int((counts[4:8] > 0).sum())
     monkeypatch.setattr(lm_common, "held_pair_rows", lambda config, tokens: 5)
-    y, _, _, cut = lm_common.moe_ffn_held(p, CONFIG, x, 1e-20)
+    y, _, _, cut = lm_common.moe_ffn(p, CONFIG, x, 1e-20)
     assert (int(cut.held), int(cut.over), int(cut.routed)) == (5, landed - 5, 120)
     assert np.isfinite(np.asarray(y, np.float32)).all()
 

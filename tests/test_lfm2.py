@@ -40,7 +40,7 @@ from sat_tpu.config import Config  # noqa: E402
 from sat_tpu.models import lfm2, lm_common  # noqa: E402
 from sat_tpu.models.captioner import compute_loss  # noqa: E402
 
-bs = importlib.import_module("sat_tpu.ops.beam_search")  # ops/__init__ exports a function of that name
+bs = importlib.import_module("sat_tpu.ops.beam_search")
 
 TOY = dict(
     decoder="lfm2_moe", cnn="vgg16", image_size=32, hidden_size=64, intermediate_size=96,
@@ -173,7 +173,8 @@ def test_uneven_routing_drops_nothing(params):
     big = {k: p["feed_forward"][k] * 8 for k in ("w1", "w3", "w2")}
     p["feed_forward"] = {**p["feed_forward"], **big, "expert_bias": bias}
     x = (0.5 * jax.random.normal(jax.random.PRNGKey(5), (64, CONFIG.hidden_size))).astype(jnp.bfloat16)
-    y, sizes, experts = lfm2.moe_ffn(p, CONFIG, x)
+    y, sizes, experts, pairs = lfm2.moe_ffn(p, CONFIG, x)
+    assert (int(pairs.held), int(pairs.over), int(pairs.visited)) == (64 * 2, 0, int((np.asarray(sizes) > 0).sum()))
     sizes = np.asarray(sizes)
     assert sizes[3] == 64 and sizes[6] == 0 and sizes.sum() == 64 * 2       # half of all pairs; none
     rp = ref._f32({**p["feed_forward"], "expert_bias": bias})

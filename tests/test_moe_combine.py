@@ -141,7 +141,10 @@ def test_the_shapes_and_the_backend_choose_the_form(monkeypatch):
     assert not moe_combine.takes(24, 8, 5120, 192)                  # a step's 192 pairs
     assert not moe_combine.takes(4096, 8, 5120 + 64, 16384)         # no whole lane tiles
     assert not moe_combine.takes(1 << 20, 8, 128, 16384)            # no tile of y fits the core
-    assert not moe_combine.takes(50176, 6, 2048, 301056)            # more rows than SMEM lists
+    # the two cells where every expert is held keep the lax form: their prefill's
+    # pairs are more rows than SMEM lists, their steps' fewer than the kernel takes
+    assert not moe_combine.takes(50176, 6, 2048, 301056) and not moe_combine.takes(50176, 4, 2048, 200704)
+    assert not moe_combine.takes(768, 6, 2048, 4608) and not moe_combine.takes(768, 4, 2048, 3072)
     # the widest tile that divides H and fits: H / 2 at the published shapes
     assert (moe_combine._tile(4096, 5120), moe_combine._tile(4096, 6144)) == (2560, 3072)
     assert moe_combine._tile(4116, 6144) == 2048
@@ -187,7 +190,7 @@ def _layer(config, bias=None):
 
 @pytest.mark.parametrize("crowded", [False, True], ids=["balanced", "over_the_rows"])
 def test_the_held_layer_is_the_same_layer_through_the_kernel(crowded, monkeypatch):
-    """``moe_ffn_held`` at a prefill's pairs (2,048 tokens x 4 = 8,192)
+    """``moe_ffn`` at a share and a prefill's pairs (2,048 tokens x 4 = 8,192)
     with the kernel forced (interpret mode here) against the ``lax``
     combine: the same counts, choices and ``HeldPairs`` but for what the
     combine fetched, the same output to a rounding of the layer's term in
@@ -201,7 +204,7 @@ def test_the_held_layer_is_the_same_layer_through_the_kernel(crowded, monkeypatc
     x = (0.5 * jax.random.normal(jax.random.PRNGKey(1), (T, 256))).astype(jnp.bfloat16)
 
     def layer():        # a trace of its own each time: the hook is no part of a cache's key
-        return jax.jit(lambda p, x: lm_common.moe_ffn_held(p, config, x, 1e-20))(p, x)
+        return jax.jit(lambda p, x: lm_common.moe_ffn(p, config, x, 1e-20))(p, x)
 
     want, counts, experts, plain = layer()
     monkeypatch.setattr(moe_combine, "FORCE_INTERPRET", True)

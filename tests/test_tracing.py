@@ -542,11 +542,11 @@ def test_a_sliding_layer_s_scopes_lie_apart_under_the_mixer_s_and_the_drain_has_
     cell's rule files take none of a sliding layer's for a full layer's,
     and what the decoder counts of a batch is what the drain's gauges are
     set from."""
-    from sat_tpu.models import decoders, glm_moe_dsa
+    from sat_tpu.models import decoders, lm_common
     from sat_tpu.ops import flash_prefill
     from sat_tpu.ops.beam_search import beam_search_jit
 
-    monkeypatch.setattr(glm_moe_dsa, "_QUERY_BLOCK", 12)
+    monkeypatch.setattr(lm_common, "QUERY_BLOCK", 12)
     monkeypatch.setattr(flash_prefill, "FORCE_INTERPRET", fused)
     # the hook is no part of a trace's key: a Config of its own a case, so that neither meets the other's trace
     config = _dots3_config(num_data_workers=8 + fused)
@@ -604,11 +604,11 @@ def test_the_fused_prefill_kernel_s_call_carries_the_scope_its_roofline_share_re
     ``lm_dsa_prefill_roofline_share`` claims it, so the share keeps reading
     the prefill's attention when the kernel is what runs it (traced with the
     kernel under its test hook; 36 positions in query blocks of 12)."""
-    from sat_tpu.models import decoders, glm_moe_dsa
+    from sat_tpu.models import decoders, lm_common
     from sat_tpu.ops import flash_prefill
     from sat_tpu.ops.beam_search import beam_search_jit
 
-    monkeypatch.setattr(glm_moe_dsa, "_QUERY_BLOCK", 12)
+    monkeypatch.setattr(lm_common, "QUERY_BLOCK", 12)
     monkeypatch.setattr(flash_prefill, "FORCE_INTERPRET", True)
     config = _dsa_config(max_caption_length=4, beam_size=2)
     params = decoders.init_params(jax.random.PRNGKey(0), config)
