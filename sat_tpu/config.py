@@ -64,6 +64,17 @@ class Config:
     # "full_attention" layers with no positional term; n_shared_experts
     # shared experts AVERAGED beside the routed sum; a tied head times
     # logit_scale (models/cohere2_moe.py; CohereLabs/command-a-plus-05-2026).
+    # "qwen3_next": a serial block with (1 + w) RMSNorms over two kinds of
+    # mixer: "linear_attention" layers (Gated DeltaNet: the linear_* fields,
+    # a matrix state [value heads, key dim, value dim] a sequence that every
+    # token decays and rewrites, after a causal depthwise conv) beside
+    # "full_attention" layers (grouped queries of head_dim-wide heads, q/k
+    # norms, rope on the first partial_rotary_factor of a head, a sigmoid
+    # gate on the attention's output out of q_proj); every layer an expert
+    # layer under a softmax router (scoring_func) beside ONE shared expert
+    # of shared_expert_intermediate_size behind a sigmoid gate
+    # (shared_expert_gate); an untied head (models/qwen3_next.py;
+    # Qwen/Qwen3-Next-80B-A3B-Instruct).
     decoder: str = "lstm"
     hidden_size: int = 2048
     intermediate_size: int = 7168          # dense SwiGLU of the leading layers
@@ -81,7 +92,8 @@ class Config:
     rope_theta: float = 1e6
     norm_eps: float = 1e-5
     # one of "conv" / "full_attention" per layer (lfm2_moe), "full_attention"
-    # / "sliding_attention" (dots3_note), or "latent_attention" throughout
+    # / "sliding_attention" (dots3_note, cohere2_moe), "linear_attention" /
+    # "full_attention" (qwen3_next), or "latent_attention" throughout
     # (deepseek_v3, glm_moe_dsa); num_hidden_layers long
     layer_types: Tuple[str, ...] = (
         "conv", "conv", "full_attention", "conv", "conv", "conv",
@@ -140,6 +152,25 @@ class Config:
     # the logits
     head_dim: int = 0
     logit_scale: float = 1.0
+    # qwen3_next only (head_dim above is its full layers' too), named as in
+    # the source: the Gated DeltaNet layers' key and value heads (a key
+    # head serves linear_num_value_heads / linear_num_key_heads value
+    # heads), the taps of their causal depthwise conv, the share of a full
+    # layer's head that the rope turns, and the width of the one shared
+    # expert
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 0
+    partial_rotary_factor: float = 1.0
+    shared_expert_intermediate_size: int = 0
+    # the router's score over all num_experts ("sigmoid": each expert's own;
+    # "softmax": over all of them) and whether the shared branch is
+    # multiplied by sigmoid(u w_g) (lm_common.route / shared_experts read
+    # these; qwen3_next sets both)
+    scoring_func: str = "sigmoid"
+    shared_expert_gate: bool = False
     # train_cnn's twin for the language-model stack: frozen by default,
     # so the connector alone trains and Adam holds slots for it alone
     train_lm: bool = False
@@ -612,7 +643,9 @@ class Config:
             ("cnn", ("vgg16", "resnet50")),
             ("decoder", (
                 "lstm", "lfm2_moe", "deepseek_v3", "glm_moe_dsa", "dots3_note", "cohere2_moe",
+                "qwen3_next",
             )),
+            ("scoring_func", ("sigmoid", "softmax")),
             ("attention_gate", ("none", "headwise")),
             ("phase", ("train", "eval", "test", "serve", "route", "bulk")),
             ("optimizer", ("Adam", "RMSProp", "Momentum", "SGD")),
@@ -877,6 +910,7 @@ class Config:
             "lfm2_moe": ("conv", "full_attention"),
             "dots3_note": ("full_attention", "sliding_attention"),
             "cohere2_moe": ("full_attention", "sliding_attention"),
+            "qwen3_next": ("linear_attention", "full_attention"),
         }.get(self.decoder, ("latent_attention",))
         if len(self.layer_types) != self.num_hidden_layers or any(
             k not in kinds for k in self.layer_types
@@ -916,15 +950,63 @@ class Config:
                     "num_dense_layers=0 (every layer an expert layer) and "
                     "tie_word_embeddings=True (no head but its embedding)"
                 )
+        elif self.decoder == "qwen3_next":
+            rotary = self.partial_rotary_factor * self.head_dim
+            if (
+                min(
+                    self.linear_num_key_heads, self.linear_key_head_dim,
+                    self.linear_value_head_dim, self.head_dim,
+                    self.shared_expert_intermediate_size,
+                ) < 1
+                or self.linear_conv_kernel_dim < 2
+                or self.linear_num_value_heads < 1
+                or self.linear_num_value_heads % self.linear_num_key_heads
+                or self.num_attention_heads % self.num_key_value_heads
+                or not 0 < self.partial_rotary_factor <= 1
+                or rotary != int(rotary) or int(rotary) % 2
+                or self.num_dense_layers or self.tie_word_embeddings
+                or self.use_expert_bias or self.n_shared_experts != 1
+            ):
+                raise ValueError(
+                    'Config: decoder="qwen3_next" takes linear_num_key_heads, '
+                    "linear_key_head_dim, linear_value_head_dim, head_dim and "
+                    "shared_expert_intermediate_size at least 1, "
+                    "linear_conv_kernel_dim at least 2, linear_num_value_heads a "
+                    "multiple of linear_num_key_heads, num_attention_heads in "
+                    "num_key_value_heads groups, partial_rotary_factor x head_dim "
+                    "an even number of lanes within the head, num_dense_layers=0 "
+                    "(every layer an expert layer), tie_word_embeddings=False (an "
+                    "untied head), use_expert_bias=False (no selection bias) and "
+                    "n_shared_experts=1 (the one gated shared expert)"
+                )
         elif self.qk_rope_head_dim % 2 or self.n_shared_experts < 0:
             raise ValueError(
                 "Config: qk_rope_head_dim must be even (rotary pairs) and "
                 "n_shared_experts not negative"
             )
-        if self.decoder != "cohere2_moe" and (self.head_dim or self.logit_scale != 1.0):
+        if (self.head_dim and self.decoder not in ("cohere2_moe", "qwen3_next")) or (
+            self.logit_scale != 1.0 and self.decoder != "cohere2_moe"
+        ):
             raise ValueError(
                 'Config.head_dim / logit_scale: only decoder="cohere2_moe" reads '
-                f'them; decoder="{self.decoder}" takes head_dim=0 and logit_scale=1.0'
+                'them (and decoder="qwen3_next" head_dim); '
+                f'decoder="{self.decoder}" takes head_dim=0 and logit_scale=1.0'
+            )
+        if self.decoder != "qwen3_next" and (
+            self.linear_num_key_heads or self.linear_num_value_heads
+            or self.linear_key_head_dim or self.linear_value_head_dim
+            or self.linear_conv_kernel_dim or self.partial_rotary_factor != 1.0
+            or self.shared_expert_intermediate_size
+            or self.scoring_func != "sigmoid" or self.shared_expert_gate
+        ):
+            raise ValueError(
+                "Config.linear_num_key_heads / linear_num_value_heads / "
+                "linear_key_head_dim / linear_value_head_dim / "
+                "linear_conv_kernel_dim / partial_rotary_factor / "
+                "shared_expert_intermediate_size / scoring_func / "
+                'shared_expert_gate: only decoder="qwen3_next" reads them; '
+                f'decoder="{self.decoder}" takes 0, partial_rotary_factor=1.0, '
+                'scoring_func="sigmoid" and shared_expert_gate=False'
             )
         if self.decoder == "dots3_note":
             # every full layer computes its own selection: there is no list

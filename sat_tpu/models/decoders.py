@@ -22,7 +22,9 @@ The language-model decoders (``lfm2_moe``: convs and grouped-query
 attention; ``deepseek_v3``: latent attention; ``glm_moe_dsa``: latent
 attention over positions an indexer chooses; ``dots3_note``: such layers
 beside window layers at widths of their own; ``cohere2_moe``: a parallel
-block over grouped-query window and full layers) are a module each with one
+block over grouped-query window and full layers; ``qwen3_next``: Gated
+DeltaNet layers, whose per-beam leaf is a float32 matrix state that every
+token rewrites whole, beside gated grouped-query layers) are a module each with one
 set of entry points, and share one search (``_lm_search``): what differs
 between them is the KIND of leaf their caches hold, which the search never
 looks at.
@@ -38,7 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import Config
-from . import cohere2_moe, deepseek_v3, dots3_note, glm_moe_dsa, lfm2
+from . import cohere2_moe, deepseek_v3, dots3_note, glm_moe_dsa, lfm2, qwen3_next
 from .decoder import (
     DecoderState,
     decoder_step,
@@ -54,7 +56,7 @@ Params = Dict[str, Any]
 # (init_params, teacher_forced, prefill, start_beams, step)
 _LM = {
     "lfm2_moe": lfm2, "deepseek_v3": deepseek_v3, "glm_moe_dsa": glm_moe_dsa,
-    "dots3_note": dots3_note, "cohere2_moe": cohere2_moe,
+    "dots3_note": dots3_note, "cohere2_moe": cohere2_moe, "qwen3_next": qwen3_next,
 }
 
 

@@ -13,7 +13,8 @@ bfloat16; norms, softmax and the whole router (product, sigmoid, bias,
 choice: a ``hidden_size x num_experts`` product at ``HIGHEST``, so that
 near-ties do not flip against the float32 reference) in float32.
 
-The expert layer: sigmoid scores, the ``num_experts_per_tok`` largest of
+The expert layer: sigmoid scores (a softmax over all the experts where
+``Config.scoring_func`` says so), the ``num_experts_per_tok`` largest of
 ``score + expert_bias`` chosen, the scores at the chosen (the bias selects
 and never weighs) divided by their sum + ``sum_eps`` (the one constant in
 which the sources' routers differ: an argument), times
@@ -21,7 +22,8 @@ which the sources' routers differ: an argument), times
 (token, expert) pairs are sorted by expert and go through a grouped
 product (``grouped_matmul``), which computes those pairs and no others.
 A layer whose ``feed_forward`` holds a ``shared`` SwiGLU sends every token
-through it too, beside the routed sum.  The expert layer proper
+through it too, beside the routed sum (times ``sigmoid(u w_g)``, ``w_g``
+the leaf ``shared/gate``, where ``Config.shared_expert_gate``).  The expert layer proper
 (``moe_experts``) takes an already normed input and returns what it adds,
 so that a block with ONE norm and ONE add for attention and feed-forward
 alike can call it; ``moe_ffn`` is the serial block's: its own norm before,
@@ -123,7 +125,7 @@ def route(p: Params, config: Config, h: jnp.ndarray, sum_eps: float):
     logits = jnp.dot(
         h.astype(jnp.float32), p["gate"].astype(jnp.float32), precision=HIGHEST
     )
-    scores = jax.nn.sigmoid(logits)
+    scores = jax.nn.softmax(logits, axis=-1) if c.scoring_func == "softmax" else jax.nn.sigmoid(logits)
     choose = scores + p["expert_bias"] if c.use_expert_bias else scores
     _, experts = jax.lax.top_k(choose, c.num_experts_per_tok)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
@@ -180,6 +182,26 @@ def route(p: Params, config: Config, h: jnp.ndarray, sum_eps: float):
 # (512, 1792, 512) 8.42 | (256, 2048, 1024) 9.30 | (512, 896, 1024) 9.34 |
 # (512, 2048, 512)* 9.56 | (1024, 896, 512) 9.95 | (512, 1024, 1024) 10.01 |
 # (256, 896, 2048) 10.35 | (256, 1024, 1024) 13.07
+# 2048 x 512 and 512 x 2048, PR 47 (256 experts held of 512), timed inside
+# their consumers (the four layers' expert halves of ``qwen3_next.step`` at
+# 384 rows: 1,920 pairs here, 7.5 an expert; three layers' over a prefill
+# pass of 32 x 196 positions: 31,360 pairs here), ``scripts/gmm_tile_sweep.py
+# --stack qwen3_next``; the fall-back's tiles marked *.  A step fetches 256
+# maps of 2 MB a product behind a handful of rows each: 0.736 ms is 89% of
+# the memory's rate for those bytes, and the row tile hardly matters; a
+# prefill's 122 rows an expert want a row tile near them, not 512.  ms a
+# call, w1 / w3 a step: (128, 2048, 512)* 0.737 | (64, 2048, 512) 0.743 |
+# (128, 2048, 256) 0.751 | (32, 2048, 512) 0.758 | (128, 1024, 512) 0.764 |
+# (32, 2048, 256) 0.793 | (256, 2048, 512) 0.800 | (16, 2048, 512) 0.804 |
+# (32, 1024, 512) 0.852; w2 a step: (128, 512, 2048) 0.736 | (64, 512, 2048)
+# 0.743 | (32, 512, 2048) 0.760 | (128, 512, 1024)* 0.764 | (256, 512, 2048)
+# 0.803 | (16, 512, 2048) 0.807 | (32, 512, 1024) 0.809 | (32, 256, 2048)
+# 0.851 | (128, 512, 512) 0.905; w1 / w3 a prefill: (256, 2048, 512) 1.203 |
+# (128, 2048, 512) 1.243 | (64, 2048, 512) 1.271 | (256, 2048, 256) 1.316 |
+# (256, 1024, 512) 1.642 | (128, 1024, 512) 1.761 | (512, 2048, 512)* 1.785;
+# w2 a prefill: (64, 512, 2048) 1.363 | (256, 512, 2048) 1.366 |
+# (128, 512, 2048) 1.412 | (256, 512, 1024) 1.485 | (128, 512, 1024) 1.607 |
+# (512, 512, 2048) 1.855 | (512, 512, 1024)* 1.940
 _GMM_TILES = {
     (6144, 2048): ((128, 2048, 1024), (256, 2048, 1024)),
     (2048, 6144): ((128, 2048, 1024), (256, 2048, 1024)),
@@ -188,6 +210,8 @@ _GMM_TILES = {
     (2048, 768): ((128, 2048, 768), (256, 2048, 768)),
     (768, 2048): ((128, 768, 2048), (512, 768, 2048)),
     (4096, 4096): ((128, 4096, 512), (256, 2048, 1024)),
+    (2048, 512): ((128, 2048, 512), (256, 2048, 512)),
+    (512, 2048): ((128, 512, 2048), (256, 512, 2048)),
 }
 
 
@@ -238,6 +262,15 @@ def shared_experts(f: Params, h: jnp.ndarray, mean_of: int = 1) -> jnp.ndarray:
         s = f["shared"]
         y = mm(swiglu(mm(h, s["w1"]), mm(h, s["w3"])), s["w2"]).astype(jnp.float32)
         return y if mean_of == 1 else y / mean_of
+
+
+def shared_gate(f: Params, h: jnp.ndarray) -> jnp.ndarray:
+    """What a GATED shared branch is multiplied by, a token: ``sigmoid(h
+    w_g)`` [T, 1] float32, ``w_g`` the leaf ``shared/gate`` [H, 1]."""
+    with jax.named_scope("decoder/lm/moe/shared"):
+        return jax.nn.sigmoid(jnp.dot(
+            h.astype(jnp.float32), f["shared"]["gate"].astype(jnp.float32), precision=HIGHEST
+        ))
 
 
 # a layer that holds a share of its experts sizes its grouped products for
@@ -356,7 +389,8 @@ def moe_experts(f: Params, config: Config, h: jnp.ndarray, sum_eps: float, share
     with jax.named_scope("decoder/lm/moe/combine"):
         y, fetched, fused = _combine_held(out, order, weights, done)
     if "shared" in f:
-        y = y + shared_experts(f, h, shared_mean_of)
+        shared = shared_experts(f, h, shared_mean_of)
+        y = y + (shared * shared_gate(f, h) if c.shared_expert_gate else shared)
     with jax.named_scope("decoder/lm/moe/combine"):
         stats = HeldPairs(
             held=done, routed=jnp.int32(T * k), over=jnp.sum(landed) - done,
@@ -396,8 +430,10 @@ def ffn(p: Params, config: Config, layer: int, x: jnp.ndarray, sum_eps: float):
 def ffn_params(config: Config, layer: int, linear, shared: bool = True) -> Params:
     """One layer's ``feed_forward`` leaves; ``linear(*shape)`` draws a map.
     ``shared``: an expert layer's ``shared`` SwiGLU,
-    ``n_shared_experts * moe_intermediate_size`` wide, where the stack has
-    that branch and the Config any such expert."""
+    ``n_shared_experts * moe_intermediate_size`` wide (or
+    ``shared_expert_intermediate_size`` where the source gives that), where
+    the stack has that branch and the Config any such expert; its ``gate``
+    [H, 1] where ``shared_expert_gate``."""
     c = config
     H, E, held = c.hidden_size, c.num_experts, held_experts(c)
     if not is_moe(c, layer):
@@ -412,8 +448,10 @@ def ffn_params(config: Config, layer: int, linear, shared: bool = True) -> Param
     if not c.use_expert_bias:       # a router with no selection bias has no such leaf
         del f["expert_bias"]
     if shared and c.n_shared_experts:
-        I = c.n_shared_experts * c.moe_intermediate_size
+        I = c.shared_expert_intermediate_size or c.n_shared_experts * c.moe_intermediate_size
         f["shared"] = {"w1": linear(H, I), "w3": linear(H, I), "w2": linear(I, H)}
+        if c.shared_expert_gate:
+            f["shared"]["gate"] = linear(H, 1)
     return f
 
 

@@ -1496,6 +1496,11 @@ def decode_dataset(
                 tel.gauge("decode/lm_swa_state_mb", float(out.decoder_stats["state_bytes_window"]) / 1e6)  # sync-ok: decode drain boundary
                 attended, visible = np.asarray(out.decoder_stats["swa_attended"], np.float64)  # sync-ok: decode drain boundary
                 tel.gauge("decode/lm_swa_attended_share", float(attended / max(visible, 1.0)))  # sync-ok: host numpy, already drained
+            if out.decoder_stats and "state_bytes_recurrent" in out.decoder_stats:
+                # a decoder whose layers keep a recurrent state: the bytes
+                # of those leaves of the per-beam tree (the matrix state
+                # and the conv's taps), which every step rewrites whole
+                tel.gauge("decode/lm_gdn_state_mb", float(out.decoder_stats["state_bytes_recurrent"]) / 1e6)  # sync-ok: decode drain boundary
         occupancy.observe()
         occupancy.publish()
         with tel.span("decode/drain/detok", b):  # host work after it

@@ -10,7 +10,9 @@ programs and their shapes are ``tests/test_aot_tpu.py``'s: the LSTM's
 (B = 64), ``lfm2_moe`` (B = 256, one period), ``deepseek_v3`` at kanana2's
 widths (B = 256), ``glm_moe_dsa`` at GLM-5.2's and ``dots3_note`` at
 dots3-note-prev's (B = 8 images of 1,024 px each), ``cohere2_moe`` at
-command-a-plus's (B = 4 images of 1,536 px).  ``_strip_metadata`` drops the Mosaic kernels' serialized bodies with
+command-a-plus's (B = 4 images of 1,536 px), ``qwen3_next`` at
+Qwen3-Next-80B-A3B's (B = 128; a tree from before that decoder prints
+"absent" for it).  ``_strip_metadata`` drops the Mosaic kernels' serialized bodies with
 the source locations they embed, so the fused prefill kernel is held
 beside them by its jaxpr (which prints no location) at the glm52 shape and
 at the dots3 sliding layers' (a window, no mask).
@@ -51,6 +53,10 @@ PROGRAMS = {
     "dots3": (aot._dots3_config(), 8),
     "command_a": (aot._command_a_config(), 4),
 }
+if hasattr(aot, "_qwen3_next_config"):
+    PROGRAMS["qwen3_next"] = (aot._qwen3_next_config(), 128)
+else:
+    print("qwen3_next absent", flush=True)
 
 
 def sha(text: str) -> str:

@@ -2,7 +2,7 @@
 INSIDE their consumers: the sweep that filled ``lm_common._GMM_TILES``'s
 rows for 2,048 x 1,792 and 1,792 x 2,048 (PR 44; PERF.md section 6).
 
-    chiprun -- python scripts/gmm_tile_sweep.py [--only step|prefill]
+    chiprun -- python scripts/gmm_tile_sweep.py [--stack lfm2|qwen3_next] [--only step|prefill]
         [--skew 0.04] [--same-word] [--w13 64,2048,1792 ...] [--w2 ...]
     JAX_PLATFORMS=cpu python scripts/gmm_tile_sweep.py --rehearse   # toy widths: the control flow only
 
@@ -20,8 +20,15 @@ output's shape), each program run twice, in opposite orders; the wall time
 a run is printed beside them.  The result goes to ``--out`` (under
 ``chiprun_out/``).
 
-Another stack's widths: give its two keys of ``_GMM_TILES`` and its
-consumers in ``consumers`` below; the rest reads shapes.
+``--stack qwen3_next`` (PR 47: the rows for 2,048 x 512 and 512 x 2,048,
+256 experts held of 512): its consumers are the four layers' expert halves
+of ``qwen3_next.step`` over 384 rows (norm, the softmax router, the
+dispatch, the three grouped products, the combine, the gated shared expert,
+the residual add; the DeltaNet state, 2.4 GB a program, is left out: forty
+programs would not compile inside a call's time with it) and three layers'
+over one prefill pass of 32 x 196 positions.  Another stack's widths: give
+its two keys of ``_GMM_TILES``, its candidates and its consumers in
+``STACKS`` below; the rest reads shapes.
 """
 
 import argparse
@@ -41,7 +48,7 @@ import numpy as np  # noqa: E402
 
 import xtrace  # noqa: E402
 from sat_tpu.config import Config  # noqa: E402
-from sat_tpu.models import lfm2, lm_common  # noqa: E402
+from sat_tpu.models import lfm2, lm_common, qwen3_next  # noqa: E402
 
 W13, W2 = (2048, 1792), (1792, 2048)
 CANDIDATES = {   # regime -> (w1 / w3 tiles, w2 tiles)
@@ -76,7 +83,7 @@ def published(layers: int = 9) -> Config:
     return Config(**{**model, "layer_types": model["layer_types"][:layers], "num_hidden_layers": layers})
 
 
-def kernel_takes(pairs: int, kn, tiles) -> bool:
+def kernel_takes(pairs: int, kn, tiles, experts: int = 32) -> bool:
     """Whether the kernel alone compiles under ``tiles`` (its 16 MB)."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
@@ -84,7 +91,8 @@ def kernel_takes(pairs: int, kn, tiles) -> bool:
     sd = jax.ShapeDtypeStruct
     try:
         jax.jit(lambda r, w, s: gmm(r, w, s, preferred_element_type=jnp.bfloat16, tiling=tiles)).lower(
-            sd((pairs + -pairs % tiles[0], k), jnp.bfloat16), sd((32, k, n), jnp.bfloat16), sd((32,), jnp.int32)
+            sd((pairs + -pairs % tiles[0], k), jnp.bfloat16), sd((experts, k, n), jnp.bfloat16),
+            sd((experts,), jnp.int32)
         ).compile()
         return True
     except Exception as e:  # noqa: BLE001  the compiler's refusal, whatever its class
@@ -171,8 +179,68 @@ def consumers(rehearse: bool, skew: float, same_word: bool):
     return programs, pairs
 
 
+QWEN3_NEXT_CANDIDATES = {
+    "step": (
+        [(tm, 2048, 512) for tm in (16, 32, 64, 128, 256)] + [(32, 2048, 256), (128, 2048, 256), (32, 1024, 512),
+                                                                 (128, 1024, 512)],
+        [(tm, 512, 2048) for tm in (16, 32, 64, 128, 256)] + [(32, 512, 1024), (128, 512, 1024), (128, 512, 512),
+                                                                 (32, 256, 2048)],
+    ),
+    "prefill": (
+        [(64, 2048, 512), (128, 2048, 512), (256, 2048, 512), (512, 2048, 512), (128, 1024, 512), (256, 1024, 512),
+         (256, 2048, 256)],
+        [(64, 512, 2048), (128, 512, 2048), (256, 512, 2048), (512, 512, 2048), (128, 512, 1024), (256, 512, 1024),
+         (512, 512, 1024)],
+    ),
+}
+QWEN3_NEXT_TOY = dict(
+    decoder="qwen3_next", image_size=32, hidden_size=64, moe_intermediate_size=24, num_hidden_layers=4,
+    num_dense_layers=0, num_attention_heads=4, num_key_value_heads=2, head_dim=16, partial_rotary_factor=0.5,
+    linear_num_key_heads=2, linear_num_value_heads=4, linear_key_head_dim=16, linear_value_head_dim=8,
+    linear_conv_kernel_dim=4, num_experts=16, num_experts_per_tok=3, experts_held=8, n_shared_experts=1,
+    shared_expert_intermediate_size=20, shared_expert_gate=True, scoring_func="softmax", use_expert_bias=False,
+    tie_word_embeddings=False, layer_types=("linear_attention",) * 3 + ("full_attention",), vocabulary_size=96,
+)
+
+
+def consumers_qwen3_next(rehearse: bool, skew: float, same_word: bool):
+    """``consumers`` for the qwen3_next stack: the layers' expert halves
+    (``qwen3_next._experts``) over a step's rows and over one prefill pass."""
+    if rehearse:
+        config = Config(**QWEN3_NEXT_TOY)
+    else:
+        with open(os.path.join(ROOT, "benchmark/configs/sat-qwen3-next-80b-a3b.json")) as f:
+            model = json.load(f)["model"]
+        config = Config(**{k: tuple(v) if isinstance(v, list) else v for k, v in model.items()})
+    B = 4 if rehearse else 128
+    R, H, N = B * 3, config.hidden_size, config.num_ctx
+    block = min(B, qwen3_next.SEQUENCE_BLOCK)
+    rng = np.random.default_rng(47)
+    normal = lambda scale, *shape: jnp.asarray(scale * rng.standard_normal(shape, np.float32), jnp.bfloat16)  # noqa: E731
+    layers = jax.jit(lambda: qwen3_next.init_params(jax.random.PRNGKey(47), config))()["lm"]["layers"]
+    layers = {name: {k: v for k, v in p.items() if k in ("post_attention_layernorm", "feed_forward")}
+              for name, p in layers.items()}
+
+    def halves(n_layers):
+        def run(layers, x):
+            counts = []
+            for i in range(n_layers):
+                x, sizes, _, _ = qwen3_next._experts(layers[lm_common.layer_name(i)], config, x)
+                counts.append(sizes)
+            return x, lm_common.StepCounters(jnp.int32(0), jnp.stack(counts), jnp.zeros((n_layers, 1), jnp.int32))
+        return run
+
+    rows = jnp.tile(normal(1.0, 1, H), (R, 1)) if same_word else normal(1.0, R, H)
+    programs = {"step": (halves(config.num_hidden_layers), (layers, rows), 8),
+                "prefill": (halves(3), (layers, normal(1.0, block * N, H)), 3)}
+    pairs = {"step": lm_common.held_pair_rows(config, R), "prefill": lm_common.held_pair_rows(config, block * N)}
+    return programs, pairs
+
+
 def main(argv=None) -> int:
+    global W13, W2, CANDIDATES
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--stack", choices=("lfm2", "qwen3_next"), default="lfm2")
     ap.add_argument("--only", choices=sorted(CANDIDATES))
     ap.add_argument("--w13", nargs="*", help="tiles as m,k,n in place of the regime's list (with --only)")
     ap.add_argument("--w2", nargs="*")
@@ -185,7 +253,16 @@ def main(argv=None) -> int:
         ap.error("--w13 / --w2 name one regime's tiles: give --only")
     if not args.rehearse and jax.default_backend() != "tpu":
         raise SystemExit("a time comes from the chip: run under chiprun, or --rehearse")
-    programs, pairs = consumers(args.rehearse, args.skew, args.same_word)
+    experts = 32
+    if args.stack == "qwen3_next":
+        W13, W2, CANDIDATES, experts = (2048, 512), (512, 2048), QWEN3_NEXT_CANDIDATES, 256
+        if args.rehearse:
+            W13, W2, experts = (64, 24), (24, 64), 8
+        for key in (W13, W2):       # a width without a row starts from what ``_gmm_tiling`` falls back to
+            lm_common._GMM_TILES.setdefault(key, (lm_common._gmm_tiling(0, *key), lm_common._gmm_tiling(8192, *key)))
+        programs, pairs = consumers_qwen3_next(args.rehearse, args.skew, args.same_word)
+    else:
+        programs, pairs = consumers(args.rehearse, args.skew, args.same_word)
     table = {key: lm_common._GMM_TILES[key] for key in (W13, W2)}
 
     step, operands, _ = programs["step"]
@@ -202,7 +279,8 @@ def main(argv=None) -> int:
             lists = [[tuple(map(int, t.split(","))) for t in given or []] for given in (args.w13, args.w2)]
         if args.rehearse:                # one candidate of each beside the standing pair
             lists = [tiles[:1] for tiles in lists]
-        w13s, w2s = ([t for t in dict.fromkeys(tiles) if args.rehearse or kernel_takes(pairs[regime], kn, t)] or [own]
+        w13s, w2s = ([t for t in dict.fromkeys(tiles)
+                      if args.rehearse or kernel_takes(pairs[regime], kn, t, experts)] or [own]
                      for tiles, kn, own in zip(lists, (W13, W2), standing))
         plans += [(regime,) + standing] + [
             (regime, w13s[i % len(w13s)], w2s[i % len(w2s)]) for i in range(max(len(w13s), len(w2s)))]

@@ -745,6 +745,66 @@ def test_command_a_beam_program_fits_the_chip_and_keeps_caches_of_two_lengths(mo
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < int(15.5e9), memory
 
 
+def _qwen3_next_config():
+    return Config(
+        decoder="qwen3_next", image_size=224, vocabulary_size=75968, hidden_size=2048,
+        moe_intermediate_size=512, num_hidden_layers=4, num_dense_layers=0, num_attention_heads=16,
+        num_key_value_heads=2, head_dim=256, partial_rotary_factor=0.25, linear_num_key_heads=16,
+        linear_num_value_heads=32, linear_key_head_dim=128, linear_value_head_dim=128,
+        linear_conv_kernel_dim=4, num_experts=512, num_experts_per_tok=10, experts_held=256, first_expert=0,
+        n_shared_experts=1, shared_expert_intermediate_size=512, shared_expert_gate=True,
+        scoring_func="softmax", use_expert_bias=False, routed_scaling_factor=1.0, norm_eps=1e-6,
+        rope_theta=1e7, tie_word_embeddings=False,
+        layer_types=("linear_attention",) * 3 + ("full_attention",),
+    )
+
+
+def test_qwen3_next_beam_program_fits_the_chip_and_carries_two_copies_of_its_state(monkeypatch):
+    """``decoder="qwen3_next"`` at its cell's batch and the published widths
+    (B = 128 images of 224 px: N = 196; K = 3: 384 rows; depth 4 = Gated
+    DeltaNet x 3, gated attention; 256 of 512 experts of 512 held; V =
+    75,968): accepted by the chip's compiler; arguments (7.4 GB of weights)
+    and temporaries under the chip.  The loop carries each DeltaNet layer's
+    state as ``f32[384,32,128,128]`` (805 MB a layer: float32, a row a
+    beam), and the temporaries hold TWO copies of the three (the updated
+    state and the reorder's gather: 4.83 GB) and no third; the full
+    layer's prefix stays per image (``bf16[128,196,..]``), never a copy a
+    beam; the prefill goes 32 images a pass, so its expert layers' combine
+    is ``ops/moe_combine.py``'s kernel (62,720 rows) and nothing of it is
+    sized by the whole batch's 250,880 pairs."""
+    from sat_tpu.ops.beam_search import beam_search_jit
+
+    config = _qwen3_next_config()
+    V, K, N, B = config.vocabulary_size, 3, config.num_ctx, 128
+    assert N == 196
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, decoder = _decoder_params(config)
+    compiled = beam_search_jit.lower(
+        decoder, config, _sd((B, N, config.dim_ctx)), 1, beam_size=K, valid_size=V,
+    ).compile()
+    text = compiled.as_text()
+    _assert_the_step_selects_per_row(text, B, K, V)
+    # the search's loop carries the three layers' states, float32, a row a beam
+    loops = [ln for ln in text.splitlines() if re.search(r" while\(", ln) and "f32[384,32,128,128]" in ln]
+    assert loops and max(ln.count("f32[384,32,128,128]") for ln in loops) == 3, loops
+    loop = {shape for ln in _loop_lines(text) for shape in re.findall(r"(?:bf16|f32)\[[\d,]+\]", ln)}
+    assert "f32[384,32,128,128]" in loop and "bf16[384,32,128,128]" not in loop
+    assert [s for s in loop if s.startswith(f"bf16[{B},{N},")] and not [s for s in loop if f"[{B * K},{N}," in s]
+    lines = text.splitlines()
+    # three grouped products an expert layer: four layers' in the steps, three in the prefill's pass (nothing
+    # reads the last layer's output at a prefix position: its routes alone are live); the combine's kernel there
+    assert len([ln for ln in lines if "tpu_custom_call" in ln and "moe_combine" in ln]) == 3
+    shapes = set(re.findall(r"(?:bf16|f32)\[[\d,]+\]", text))
+    assert not [s for s in shapes if re.search(r"\[250880,(10,)?2048\]", s)], shapes
+    assert len(re.findall(r"decoder/lm/moe/experts[^\n]*tpu_custom_call|"
+                          r"tpu_custom_call[^\n]*decoder/lm/moe/experts", text)) == 21
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > int(7.3e9)
+    state = 3 * B * K * 32 * 128 * 128 * 4
+    assert 2 * state < memory.temp_size_in_bytes < 2 * state + int(0.8e9), memory      # two copies, no third
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < int(15.5e9), memory
+
+
 @pytest.mark.parametrize("V", [65536, 128256], ids=["lfm2", "kanana2"])
 def test_beam_step_alone_needs_no_vocabulary_sized_temporary(V):
     """``_expand_step`` by itself over the lm cells' 768 rows of logits:

@@ -567,7 +567,7 @@ def test_the_grouped_product_s_tiles_come_from_its_own_shape():
 
 
 # (k, n) of a timed product -> (the pairs of its cell's step and prefill, the
-# tiles PR 43's tree gave each): PR 44 swept the 1,792-wide experts' alone
+# tiles PR 43's tree gave each): PR 44 swept the 1,792-wide experts' alone, PR 47 added the 512-wide
 _TILED = {
     (6144, 2048): ((192, 8192), ((128, 2048, 1024), (256, 2048, 1024))),
     (2048, 6144): ((192, 8192), ((128, 2048, 1024), (256, 2048, 1024))),
@@ -576,6 +576,9 @@ _TILED = {
     (2048, 768): ((4608, 301056), ((128, 2048, 768), (256, 2048, 768))),
     (768, 2048): ((4608, 301056), ((128, 768, 2048), (512, 768, 2048))),
     (4096, 4096): ((96, 36864), ((128, 4096, 512), (256, 2048, 1024))),
+    # PR 47: the 512-wide experts (256 held of 512), the rows of a step's 384 tokens and of a prefill pass
+    (2048, 512): ((3840, 62720), ((128, 2048, 512), (256, 2048, 512))),
+    (512, 2048): ((3840, 62720), ((128, 512, 2048), (256, 512, 2048))),
 }
 
 
