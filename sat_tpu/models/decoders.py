@@ -11,8 +11,12 @@ exist (``Config.decoder``) and what each gives the rest of the program.
 ``search``          what ``ops/beam_search.run_search`` needs of a decoder:
                     a state from the image (the LSTM's initial carry; a
                     language model's prefill), one step over ``[B*K]``
-                    rows, which leaves are per beam (a plain tree, or
-                    ``StepState.beam``), which are carried unreordered
+                    rows, which leaves are per beam and follow it (a
+                    plain tree, or ``StepState.beam``: the search gathers
+                    them by parent), which are per beam and stay where
+                    the step wrote them (``StepState.at_source``: the
+                    search hands over ``source`` and the STEP reads them
+                    at the parent's row), which are carried unreordered
                     (``StepState.shared``) and which are per image
                     (closed over, never tiled), and what the decoder
                     itself reports of the batch (``Search.finish``)
@@ -27,7 +31,9 @@ DeltaNet layers, whose per-beam leaf is a float32 matrix state that every
 token rewrites whole, beside gated grouped-query layers) are a module each with one
 set of entry points, and share one search (``_lm_search``): what differs
 between them is the KIND of leaf their caches hold, which the search never
-looks at.
+looks at, and whether a module names fields of its cache in ``AT_SOURCE``
+(``qwen3_next`` alone: the matrix state), which the search then leaves in
+place.
 
 No other module tests ``Config.decoder``.
 """
@@ -53,7 +59,8 @@ from .decoder import (
 Params = Dict[str, Any]
 
 # the language-model decoders: a module each, with one set of entry points
-# (init_params, teacher_forced, prefill, start_beams, step)
+# (init_params, teacher_forced, prefill, start_beams, step; optional:
+# report, AT_SOURCE)
 _LM = {
     "lfm2_moe": lfm2, "deepseek_v3": deepseek_v3, "glm_moe_dsa": glm_moe_dsa,
     "dots3_note": dots3_note, "cohere2_moe": cohere2_moe, "qwen3_next": qwen3_next,
@@ -61,14 +68,29 @@ _LM = {
 
 
 class StepState(NamedTuple):
-    """A decoder's loop-carried state where not all of it is per beam.
-    A decoder whose whole state is per beam (the LSTM's ``DecoderState``)
-    hands the search that tree itself.  What is per IMAGE and never
-    changes (an image prefix's keys and values) is no state at all: the
-    step function closes over it, ``[B, ...]``, untiled."""
+    """A decoder's loop-carried state where not all of it is per beam, or
+    not all of what is per beam follows its beam.  A decoder whose whole
+    state is per beam (the LSTM's ``DecoderState``) hands the search that
+    tree itself.  What is per IMAGE and never changes (an image prefix's
+    keys and values) is no state at all: the step function closes over it,
+    ``[B, ...]``, untiled.
+
+    Three kinds of leaf, and who moves which: ``beam`` the search gathers
+    by parent after every step; ``shared`` nobody moves; ``at_source`` the
+    search leaves in the slots the step wrote and says in ``source`` which
+    row each slot's beam now descends from, and the STEP reads the leaf
+    there: ``new[r] = f(old[source[r]], inputs[r])``.  That is for a leaf
+    every step rewrites whole (a recurrent matrix state): a gather of it is
+    a second pass over what the step passes over anyway.  A decoder with no
+    such leaf leaves both fields None and gets the program it got."""
 
     beam: Any     # leaves [B*K, ...]: reordered by parent every step
     shared: Any   # counters and the like: carried, never reordered
+    at_source: Any = None   # leaves [B*K, ...]: never moved, read by the step at ``source``
+    # [B*K] int32, ``b * K + parent[b, k]``: the row of the LAST step's
+    # ``at_source`` leaves that slot (b, k) descends from; the rows' own
+    # index before the first step.  Written by the search (``_reorder_beams``)
+    source: Any = None
 
 
 class Search(NamedTuple):
@@ -191,15 +213,28 @@ def _lm_search(
     B = contexts.shape[0]
     with jax.named_scope("beam/prefill"):
         prefix, counts, prefix_routes = lm.prefill(params, config, contexts)
-    cache = lm.start_beams(config, prefix, K, T, tile_beams)
-    state0 = StepState(beam=cache, shared=lm.init_counters(counts, T))
+    # the fields of the beams' cache that this stack's step reads at a
+    # source row (none: every leaf follows its beam)
+    at_source = getattr(lm, "AT_SOURCE", ())
+
+    def split(cache, counters) -> StepState:
+        if not at_source:
+            return StepState(beam=cache, shared=counters)
+        return StepState(
+            beam=cache._replace(source=None, **{name: None for name in at_source}), shared=counters,
+            at_source={name: getattr(cache, name) for name in at_source}, source=cache.source,
+        )
+
+    def join(state: StepState):
+        """The stack's own view of its cache: one tree, ``source`` in it."""
+        return state.beam._replace(source=state.source, **state.at_source) if at_source else state.beam
+
+    state0 = split(lm.start_beams(config, prefix, K, T, tile_beams), lm.init_counters(counts, T))
 
     def step_fn(state, last_word):
-        cache, counters, logits = lm.step(
-            params, config, prefix, state.beam, state.shared, last_word
-        )
+        cache, counters, logits = lm.step(params, config, prefix, join(state), state.shared, last_word)
         alpha = jnp.zeros((last_word.shape[0], 0), jnp.float32)
-        return StepState(beam=cache, shared=counters), logits, alpha
+        return split(cache, counters), logits, alpha
 
     def finish(result, state):
         stats = {
@@ -218,7 +253,9 @@ def _lm_search(
             # the steps close over per image + the per-beam tree
             stats["state_bytes"] = jnp.float32(_tree_bytes(prefix) + _tree_bytes(state.beam))
         if hasattr(lm, "report"):       # what the stack itself counts besides
-            stats.update(lm.report(config, prefix, state, B, K, T))
+            # (of its whole cache: what is read at a source row is resolved
+            # by the LAST step's sources there, for the rows it reports)
+            stats.update(lm.report(config, prefix, state._replace(beam=join(state)), B, K, T))
         return result._replace(decoder_stats=stats)
 
     return Search(step_fn, state0, 0, finish)

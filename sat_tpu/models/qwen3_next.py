@@ -50,21 +50,27 @@ is solved by (blocked) forward substitution for the corrected values and
 keys; between chunks S is carried with the chunk's total decay.  A
 sequence pads to whole chunks with ``beta = 0``, ``g = 0``, ``k = 0``,
 which leave S as it was.  Its products are float32 at ``HIGHEST``.  One
-token a row (``step``) is the recurrence itself: S float32, read twice and
-written once a step; its products with k and q are float32 multiplies and
-sums on the vector unit, exact (no matrix unit, no rounding of an operand):
-``S^T k`` and ``S^T q`` of the decayed state ride ONE pass over S, and
+token a row (``step``) is the recurrence itself (``ops/gdn_step.py``): S
+float32; its products with k and q are float32 multiplies and sums on the
+vector unit, exact (no matrix unit, no rounding of an operand): ``S^T k``
+and ``S^T q`` of the decayed state ride ONE pass over S, and
 ``o_t = exp(g) S^T q + (k . q) d_t`` is the updated state's product by q
-without a third.
+without a third.  On the TPU that pass is one kernel that reads a row's S
+where its PARENT's row lies and writes it in place: S is read once and
+written once a layer and step; elsewhere ``lax`` gathers S by the same
+sources first.
 
 The cache (``HybridCache``).  Per image, closed over by the step: a full
 layer's keys and values of the prefix ``[B, N, nkv * d]``, read in place by
 the image's beams (lfm2's grouped step).  Per beam, moved by the search's
-one tree-wide reorder: a DeltaNet layer's S ``[R, nv, dk, dv]`` float32 and
-its conv taps ``[R, L - 1, conv width]`` (both start as the prefix's, tiled
-a row a beam), a full layer's suffix keys and values ``[R, T, nkv * d]``,
-the record of routes.  S does not grow with the sequence and is rewritten
-whole by every token: it IS the step's traffic.
+one tree-wide reorder: a DeltaNet layer's conv taps ``[R, L - 1, conv
+width]``, a full layer's suffix keys and values ``[R, T, nkv * d]``, the
+record of routes.  Per beam and NOT moved (``AT_SOURCE``): a DeltaNet
+layer's S ``[R, nv, dk, dv]`` float32 (it and the taps start as the
+prefix's, tiled a row a beam): S does not grow with the sequence and is
+rewritten whole by every token, it IS the step's traffic, so the search
+hands over ``source`` (the row each slot's beam descends from) and the
+update reads S there.
 
 Precision: ``lm_common``'s (bfloat16 parameters and products with float32
 accumulation, a bfloat16 residual stream; norms, softmax, the router and
@@ -81,6 +87,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import Config
+from ..ops import gdn_step as gdn_step_op
 from . import lm_common
 from .lm_common import HIGHEST, Params, layer_name, mm
 from .lm_common import sum_pairs as _sum_pairs
@@ -103,13 +110,22 @@ class HybridCache(NamedTuple):
     ``state`` and ``conv`` are where the beams start (tiled by
     ``start_beams``, not read by a step), ``keys`` and ``values`` the
     per-image cache the steps close over.  As the beams' own ``[B*K, ...]``:
-    all of it, reordered by parent."""
+    all of it; the search moves every leaf to the slot it gave its beam but
+    ``state`` (``AT_SOURCE``), which stays in the slots the last step wrote
+    and is read at ``source``."""
 
     state: Tuple[jnp.ndarray, ...]      # per DeltaNet layer [R, nv, dk, dv] STATE_DTYPE
     conv: Tuple[jnp.ndarray, ...]       # per DeltaNet layer [R, L - 1, conv width]: before the conv
     keys: Tuple[jnp.ndarray, ...]       # per full layer [R, positions, nkv * d]
     values: Tuple[jnp.ndarray, ...]
     routes: Any = None      # [R, T * layers * k] int32: ``lm_common.empty_routes``
+    source: Any = None      # [R] int32: the row of ``state`` each row descends from (the search's)
+
+
+# the fields of the beams' cache that the search leaves where they lie
+# (``decoders.StepState.at_source``): a step computes
+# ``new[r] = f(old[source[r]], inputs[r])``
+AT_SOURCE = ("state",)
 
 
 class Counters(NamedTuple):
@@ -119,6 +135,9 @@ class Counters(NamedTuple):
     moe_counts: jnp.ndarray
     step_visits: jnp.ndarray
     pairs: jnp.ndarray      # [2, 6]: the prefill, the steps: ``lm_common.sum_pairs``
+    # [3] float32: state updates (a layer and step) through ``ops/gdn_step.py``'s
+    # kernel, state updates in all, rows whose source was another slot
+    fold: jnp.ndarray
 
 
 def _linear(config: Config, layer: int) -> bool:
@@ -339,29 +358,24 @@ def gdn_sequence(m: Params, config: Config, u: jnp.ndarray):
     return _gdn_output(m, c, o, z), state.astype(STATE_DTYPE), padded[:, S:]
 
 
-def gdn_step(m: Params, config: Config, u: jnp.ndarray, state: jnp.ndarray, taps: jnp.ndarray):
+def gdn_step(
+    m: Params, config: Config, u: jnp.ndarray, state: jnp.ndarray, taps: jnp.ndarray, source: jnp.ndarray, K: int,
+):
     """One token a row through a Gated DeltaNet mixer: u [R, H] normed,
-    ``state`` [R, nv, dk, dv], ``taps`` [R, L - 1, conv width] -> (its
-    output [R, H], the state and the taps after the token)."""
+    ``taps`` [R, L - 1, conv width] the rows' own; ``state`` [R, nv, dk,
+    dv] as the last step wrote it, row r's at ``source[r]`` (one of its
+    image's K rows) -> (its output [R, H], the state and the taps after the
+    token, whether ``ops/gdn_step.py``'s kernel made the update)."""
     c = config
     nk, nv, dk, dv, r, _ = _gdn_dims(c)
-    R = u.shape[0]
     mixed, z, beta, g = _gdn_inputs(m, c, u)
     with jax.named_scope("decoder/lm/attn/gdn/conv"):
         window = jnp.concatenate([taps, mixed[:, None]], axis=1)                     # [R, L, width], oldest first
         conv = jax.nn.silu(jnp.sum(window.astype(jnp.float32) * m["conv1d"].astype(jnp.float32), axis=1))
     q, k, v = _gdn_heads(c, conv)
     with jax.named_scope("decoder/lm/attn/gdn/state"):
-        s = state.astype(jnp.float32).reshape(R, nk, r, dk, dv)
-        decay = jnp.exp(g).reshape(R, nk, r)
-        # both products of the state as it came, in ONE pass over it
-        s_k = jnp.sum(s * k[:, :, None, :, None], axis=-2) * decay[..., None]       # [R, nk, r, dv]
-        s_q = jnp.sum(s * q[:, :, None, :, None], axis=-2) * decay[..., None]
-        d = beta.reshape(R, nk, r)[..., None] * (v.reshape(R, nk, r, dv) - s_k)
-        s = s * decay[..., None, None] + k[:, :, None, :, None] * d[..., None, :]
-        o = s_q + jnp.sum(q * k, axis=-1)[:, :, None, None] * d                     # the new state's product by q
-        state = s.reshape(R, nv, dk, dv).astype(STATE_DTYPE)
-    return _gdn_output(m, c, o.reshape(R, nv, dv), z), state, window[:, 1:]
+        state, o, fused = gdn_step_op.gdn_step(state, source, q, k, v, beta, jnp.exp(g), K=K, dtype=STATE_DTYPE)
+    return _gdn_output(m, c, o, z), state, window[:, 1:], fused
 
 
 # ---------------------------------------------------------------------------
@@ -571,14 +585,15 @@ def init_counters(prefill_counts, max_len: int) -> Counters:
     """Step 0's counters, the prefill's counts already in."""
     counts, pairs = prefill_counts
     base = lm_common.init_counters(counts, max_len)
-    return Counters(*base, pairs=jnp.stack([pairs, jnp.zeros_like(pairs)]))
+    return Counters(*base, pairs=jnp.stack([pairs, jnp.zeros_like(pairs)]), fold=jnp.zeros((3,), jnp.float32))
 
 
 def start_beams(config: Config, prefix: HybridCache, K: int, max_len: int, tile) -> HybridCache:
     """The per-beam cache of the K beams of each image before the first
     step: the prefix's states and taps ``tile``d to a row a beam (they
     start per image and then differ per beam), an empty suffix of
-    ``max_len`` keys and values a full layer, an empty record of routes."""
+    ``max_len`` keys and values a full layer, an empty record of routes,
+    every row its own source."""
     c = config
     rows = jax.tree_util.tree_leaves(prefix)[0].shape[0] * K
     width = c.num_key_value_heads * c.head_dim
@@ -586,6 +601,7 @@ def start_beams(config: Config, prefix: HybridCache, K: int, max_len: int, tile)
     return HybridCache(
         state=tuple(tile(x, K) for x in prefix.state), conv=tuple(tile(x, K) for x in prefix.conv),
         keys=empty, values=empty, routes=lm_common.empty_routes(c, rows, max_len),
+        source=jnp.arange(rows, dtype=jnp.int32),
     )
 
 
@@ -595,20 +611,26 @@ def step(
 ):
     """One token for each of R = B*K beams.  prefix: what ``prefill`` kept
     per image (its keys and values are read, its states and taps are not);
-    cache: the beams' own; last_word [R] int32 at position N + t.  Returns
-    (cache, counters, logits [R, V] float32)."""
+    cache: the beams' own, every leaf in its row but ``state``, which lies
+    as the last step wrote it and is read at ``cache.source``; the cache
+    returned holds every row's state in its row (``source`` the identity,
+    until the search says where the next step's rows come from);
+    last_word [R] int32 at position N + t.  Returns (cache, counters,
+    logits [R, V] float32)."""
     c = config
     lm = params["lm"]
     x = lm_common.embed(lm, last_word)                      # [R, H]
     t = counters.t
-    state, conv, keys, values, counts, routes, held = [], [], [], [], [], [], []
+    R = last_word.shape[0]
+    K = R // jax.tree_util.tree_leaves(prefix)[0].shape[0]
+    state, conv, keys, values, counts, routes, held, fused = [], [], [], [], [], [], [], []
     for i in range(c.num_hidden_layers):
         p = lm["layers"][layer_name(i)]
         u = _mixer_input(p, c, x)
         if _linear(c, i):
             j = len(state)
-            y, s, taps = gdn_step(p["linear_attn"], c, u, cache.state[j], cache.conv[j])
-            state.append(s), conv.append(taps)
+            y, s, taps, kernel = gdn_step(p["linear_attn"], c, u, cache.state[j], cache.conv[j], cache.source, K)
+            state.append(s), conv.append(taps), fused.append(kernel)
         else:
             j = len(keys)
             y, (k, v) = attend_step(
@@ -621,8 +643,13 @@ def step(
         lm_common.StepCounters(t, counters.moe_counts, counters.step_visits),
         cache.routes, counts, routes, visited=[h.visited for h in held],
     )
-    counters = Counters(*base, pairs=counters.pairs.at[1].add(_sum_pairs(held)))
-    cache = HybridCache(tuple(state), tuple(conv), tuple(keys), tuple(values), taken)
+    rows = jnp.arange(R, dtype=cache.source.dtype)
+    moved = jnp.sum(cache.source != rows)
+    counters = Counters(
+        *base, pairs=counters.pairs.at[1].add(_sum_pairs(held)),
+        fold=counters.fold + jnp.stack([jnp.float32(sum(fused)), jnp.float32(len(fused)), moved.astype(jnp.float32)]),
+    )
+    cache = HybridCache(tuple(state), tuple(conv), tuple(keys), tuple(values), taken, rows)
     return cache, counters, _head(lm, c, x)
 
 
@@ -633,10 +660,13 @@ def _bytes(leaves) -> int:
 def report(config: Config, prefix: HybridCache, state, B: int, K: int, T: int) -> dict:
     """What this decoder adds to ``BeamResult.decoder_stats``: ``prefix``
     what ``prefill`` kept per image, ``state`` the search's final
-    ``StepState``."""
+    ``StepState``, its ``beam`` the whole ``HybridCache``: ``beam.state``
+    as the last step wrote it, ``beam.source`` where the search's last
+    choice put each beam's."""
     beam = state.beam
     recurrent = _bytes(beam.state) + _bytes(beam.conv)
     rows = min(B, REPORT_STATE_IMAGES)
+    first = beam.source.reshape(B, K)[:rows, 0]             # live beam 0's row of the state, image by image
     return {
         # [prefill | steps, held | routed | over] pairs; the combine's
         # [prefill | steps, rows fetched | calls through the kernel | calls]
@@ -646,14 +676,18 @@ def report(config: Config, prefix: HybridCache, state, B: int, K: int, T: int) -
         # (the prefix's states and taps only START the beams: nothing holds
         # them through the loop), per beam the whole tree
         "state_bytes": jnp.float32(
-            _bytes(prefix.keys) + _bytes(prefix.values) + _bytes(jax.tree_util.tree_leaves(beam))
+            _bytes(prefix.keys) + _bytes(prefix.values) + _bytes(jax.tree_util.tree_leaves(beam._replace(source=None)))
         ),
         # of that, S and the conv taps of the per-beam tree
         "state_bytes_recurrent": jnp.float32(recurrent),
         # [images, DeltaNet layers, nv, dk, dv]: S of live beam 0 of the
         # batch's first images as the last step left it (after the words
-        # of that beam's own ancestry)
+        # of that beam's own ancestry): a gather of these rows alone
         "final_state": jnp.stack(
-            [s.reshape((B, K) + s.shape[1:])[:rows, 0] for s in beam.state], axis=1
+            [s[first] for s in beam.state], axis=1
         ).astype(jnp.float32) if beam.state else jnp.zeros((rows, 0), jnp.float32),
+        # [state updates through ``ops/gdn_step.py``'s kernel, state
+        # updates in all (a layer and step), rows whose source was another
+        # slot] over the steps
+        "gdn_fold": state.shared.fold,
     }

@@ -55,6 +55,16 @@ or a sliced column lower to a full stable sort of the vocabulary); no
 step sorts the vocabulary or holds an array per beam × vocabulary
 (tests/test_aot_tpu.py pins both).
 
+The decoder's state is opaque to the search but for who moves which leaf
+(``models/decoders.py`` ``StepState``): a plain tree or ``StepState.beam``
+follows its beam by ONE tree-wide gather by parent a step
+(``_reorder_beams``); ``StepState.shared`` is carried as it is;
+``StepState.at_source`` is never moved: for those leaves the search writes
+``StepState.source`` (``b * K + parent[b, k]``) where it gathers the
+others, and the decoder's next step reads them at that row (a recurrent
+matrix state that every step rewrites whole is then passed over once a
+step, not gathered and passed over again).
+
 Greedy decoding is the beam_size=1 special case of the same program.
 
 Two drivers run the SAME expansion math (``_expand_step``):
@@ -130,13 +140,19 @@ class BeamResult(NamedTuple):
 
 def _reorder_beams(state, B: int, K: int, batch_idx, parent):
     """ONE tree-wide per-parent gather: every per-beam leaf ``[B*K, ...]``
-    follows its beam to the slot the search gave it."""
+    follows its beam to the slot the search gave it.  The leaves a decoder
+    keeps in ``StepState.at_source`` stay where its step wrote them: the
+    search hands over ``source``, the flat row each slot descends from, and
+    the decoder's next step reads them there."""
 
     def gather(x):
         return x.reshape((B, K) + x.shape[1:])[batch_idx, parent].reshape(x.shape)
 
     if isinstance(state, StepState):
-        return state._replace(beam=jax.tree_util.tree_map(gather, state.beam))
+        state = state._replace(beam=jax.tree_util.tree_map(gather, state.beam))
+        if state.source is not None:
+            state = state._replace(source=(batch_idx * K + parent).reshape(B * K).astype(state.source.dtype))
+        return state
     return jax.tree_util.tree_map(gather, state)
 
 
@@ -372,7 +388,8 @@ def run_search(
     alpha [B*K, Na]) — one decoder step over the flattened beam batch.
     state0: the initial state already tiled to [B*K, ...] rows: a tree of
     per-beam leaves (the LSTM's DecoderState), or a StepState whose
-    ``shared`` part rides along unreordered.
+    ``shared`` part rides along unreordered and whose ``at_source`` part
+    stays where the step wrote it, named by ``source``.
     alpha_width: Na of step_fn's alpha (the LOCAL context-block width
     under context parallelism); required when return_alphas is set.
     early_exit: stop the while_loop as soon as no image's result can

@@ -1501,6 +1501,12 @@ def decode_dataset(
                 # of those leaves of the per-beam tree (the matrix state
                 # and the conv's taps), which every step rewrites whole
                 tel.gauge("decode/lm_gdn_state_mb", float(out.decoder_stats["state_bytes_recurrent"]) / 1e6)  # sync-ok: decode drain boundary
+                # of the steps' updates of that state (a layer and step),
+                # those made in place by ops/gdn_step.py's kernel, the
+                # search's reorder folded into their read (1.0 on the chip,
+                # 0.0 where the lax form gathered the state first)
+                fused, updates, _ = np.asarray(out.decoder_stats["gdn_fold"], np.float64)  # sync-ok: decode drain boundary
+                tel.gauge("decode/lm_gdn_fold_share", float(fused / max(updates, 1.0)))  # sync-ok: host numpy, already drained
         occupancy.observe()
         occupancy.publish()
         with tel.span("decode/drain/detok", b):  # host work after it

@@ -772,3 +772,53 @@ def test_step_parity_notices_a_narrower_or_a_raw_selection(
     monkeypatch.setattr(_bs, "_top_rows", patched)
     with pytest.raises(AssertionError, match=first_seen_in):
         _assert_same_step(_run_step(_bs._expand_step, K, inputs), want)
+
+
+@pytest.mark.parametrize("decoder", ["lstm", "lfm2_moe"])
+def test_a_decoder_with_no_leaf_read_at_a_source_row_gets_the_state_tree_it_got(decoder):
+    """``StepState.at_source`` / ``source`` are for a decoder that says so
+    of a leaf (``qwen3_next``'s recurrent state).  The LSTM still hands the
+    search its plain ``DecoderState``; a language model with none still a
+    ``StepState`` of ``beam`` and ``shared`` alone, the two new fields None
+    before and after a reorder, so its program has the leaves it had, all
+    of ``beam`` gathered by parent."""
+    from sat_tpu.models import decoders
+    from sat_tpu.models.decoder import DecoderState
+
+    B, K = 2, 3
+    if decoder == "lstm":
+        cfg, params, contexts = setup(B=B)
+    else:
+        from test_lfm2 import CONFIG as cfg
+
+        params = jax.eval_shape(lambda: decoders.init_params(jax.random.PRNGKey(0), cfg))
+        params = jax.tree_util.tree_map(lambda x: jnp.zeros(x.shape, x.dtype), params)
+        contexts = jnp.ones((B, cfg.num_ctx, cfg.dim_ctx), jnp.float32)
+    state0 = decoders.search(params, cfg, contexts, K, cfg.max_caption_length).state0
+
+    def numbered(x):     # every per-beam leaf holds its row's number
+        return (jnp.zeros_like(x) + jnp.arange(B * K).reshape((B * K,) + (1,) * (x.ndim - 1))).astype(x.dtype)
+
+    if decoder == "lstm":
+        state0 = jax.tree_util.tree_map(numbered, state0)
+    else:
+        state0 = state0._replace(beam=jax.tree_util.tree_map(numbered, state0.beam))
+    parent = jnp.array([[2, 0, 1], [1, 1, 0]])
+    rows = (jnp.arange(B)[:, None] * K + parent).reshape(-1)
+    moved = _bs._reorder_beams(state0, B, K, jnp.arange(B)[:, None], parent)
+    assert jax.tree_util.tree_structure(moved) == jax.tree_util.tree_structure(state0)
+    if decoder == "lstm":
+        assert isinstance(state0, DecoderState) and isinstance(moved, DecoderState)
+        beams, moved_beams = state0, moved
+    else:
+        assert isinstance(state0, decoders.StepState)
+        assert state0.at_source is None and state0.source is None
+        assert moved.at_source is None and moved.source is None
+        assert jax.tree_util.tree_structure(state0) == jax.tree_util.tree_structure(
+            decoders.StepState(state0.beam, state0.shared)
+        )
+        for a, b in zip(jax.tree_util.tree_leaves(moved.shared), jax.tree_util.tree_leaves(state0.shared)):
+            assert np.array_equal(a, b)
+        beams, moved_beams = state0.beam, moved.beam
+    for a, b in zip(jax.tree_util.tree_leaves(moved_beams), jax.tree_util.tree_leaves(beams)):
+        assert np.array_equal(np.asarray(a), np.asarray(b)[np.asarray(rows)])

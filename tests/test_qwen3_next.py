@@ -255,7 +255,10 @@ def test_a_step_through_state_and_taps_is_the_whole_sequence_s_last_position(par
     want, want_state, want_taps = sequence(m, u)
     _, state, taps = sequence(m, u[:, :S])
     assert state.shape == (2, NV, DK, DV) and state.dtype == jnp.float32 and taps.shape == (2, 3, WIDTH)
-    got, state, taps = jax.jit(lambda m, u, s, t: qn.gdn_step(m, CONFIG, u, s, t))(m, u[:, S], state, taps)
+    got, state, taps, fused = jax.jit(
+        lambda m, u, s, t: qn.gdn_step(m, CONFIG, u, s, t, jnp.arange(2), 1)
+    )(m, u[:, S], state, taps)
+    assert not fused            # off the TPU the ``lax`` form
     _close(got, want[:, -1], PATH_TOL)
     _close(state, want_state, 1e-4)
     assert np.array_equal(np.asarray(taps, np.float32), np.asarray(want_taps, np.float32))
@@ -364,20 +367,30 @@ def test_the_two_shares_and_the_gated_shared_expert_once_add_up_to_the_uncut_lay
 # ---------------------------------------------------------------------------
 
 
-def test_the_reorder_swaps_a_beam_s_state_taps_keys_and_values_alike():
+def test_the_reorder_swaps_a_beam_s_taps_keys_and_values_and_names_its_state_s_source():
+    """What follows its beam is gathered by parent; the leaves a decoder
+    keeps ``at_source`` are not touched, and ``source`` says which row of
+    them each slot descends from."""
     B, K = 2, 3
     rows = jnp.arange(B * K, dtype=jnp.float32)
     leaf = lambda *shape: rows.reshape((B * K,) + (1,) * len(shape)) + jnp.zeros((B * K,) + shape)  # noqa: E731
-    cache = qn.HybridCache(state=(leaf(NV, DK, DV),) * 3, conv=(leaf(3, WIDTH),) * 3, keys=(leaf(5, 32),),
+    cache = qn.HybridCache(state=None, conv=(leaf(3, WIDTH),) * 3, keys=(leaf(5, 32),),
                            values=(leaf(5, 32),), routes=leaf(30))
     shared = qn.Counters(t=jnp.int32(7), moe_counts=jnp.arange(8).reshape(2, 4),
-                         step_visits=jnp.arange(10).reshape(2, 5), pairs=jnp.arange(12).reshape(2, 6))
+                         step_visits=jnp.arange(10).reshape(2, 5), pairs=jnp.arange(12).reshape(2, 6),
+                         fold=jnp.zeros((3,)))
+    held = {"state": (leaf(NV, DK, DV),) * 3}
     parent = jnp.array([[2, 0, 1], [1, 1, 0]])
-    moved = bs._reorder_beams(bs.StepState(cache, shared), B, K, jnp.arange(B)[:, None], parent)
-    want = (jnp.arange(B)[:, None] * K + parent).reshape(-1).astype(jnp.float32)
+    moved = bs._reorder_beams(
+        bs.StepState(cache, shared, held, jnp.arange(B * K, dtype=jnp.int32)), B, K, jnp.arange(B)[:, None], parent
+    )
+    want = (jnp.arange(B)[:, None] * K + parent).reshape(-1)
     for x in jax.tree_util.tree_leaves(moved.beam):
-        assert np.array_equal(np.asarray(x).reshape(B * K, -1)[:, 0], np.asarray(want))
+        assert np.array_equal(np.asarray(x).reshape(B * K, -1)[:, 0], np.asarray(want, np.float32))
     assert np.array_equal(moved.shared.pairs, shared.pairs) and int(moved.shared.t) == 7
+    assert moved.source.dtype == jnp.int32 and np.array_equal(moved.source, want)
+    for x in moved.at_source["state"]:
+        assert np.array_equal(np.asarray(x), np.asarray(held["state"][0]))
 
 
 def test_the_search_serves_what_the_reference_scores_and_hands_back_its_state(params, weights):
@@ -389,15 +402,7 @@ def test_the_search_serves_what_the_reference_scores_and_hands_back_its_state(pa
     state's bytes by kind of leaf."""
     ctx, _ = _inputs(seed=2, B=4)
     T, K = 8, 3
-
-    @jax.jit
-    def run(params, ctx):
-        search = decoders.search(params, CONFIG, ctx, K, T)
-        result, state = bs.run_search(CONFIG, search.step_fn, search.state0, 4, 1, beam_size=K, max_len=T,
-                                      valid_size=100, early_exit=False, return_state=True)
-        return search.finish(result, state), state
-
-    out, state = run(params, ctx)
+    out, state = _searched(params, ctx, K, T, early_exit=False)
     stats = out.decoder_stats
     assert stats["step_routes"].shape == (4, K, T, 12) and stats["prefix_routes"].shape == (4, N, 12)
     pairs = np.asarray(stats["moe_pairs"])
@@ -408,8 +413,9 @@ def test_the_search_serves_what_the_reference_scores_and_hands_back_its_state(pa
     assert int(stats["state_bytes"]) == recurrent + full + 4 * K * T * 12 * 4
     assert stats["final_state"].shape == (4, 3, NV, DK, DV)
     # the beams did swap: some step's parents are no identity
-    live = np.asarray(state.beam.state[0]).reshape(4, K, -1)
+    live = np.asarray(state.at_source["state"][0]).reshape(4, K, -1)
     assert not np.allclose(live[:, 0], live[:, 1])
+    assert state.beam.state is None and np.asarray(stats["gdn_fold"]).tolist()[:2] == [0.0, 3.0 * T]
     words, lengths = np.asarray(out.words[:, 0]), np.asarray(out.lengths[:, 0])
     logits, _, states = _reference(weights, ctx, words)
     logp = jax.nn.log_softmax(logits, axis=-1)
@@ -423,8 +429,69 @@ def test_the_search_serves_what_the_reference_scores_and_hands_back_its_state(pa
     got = np.moveaxis(np.asarray(stats["final_state"]), 1, 0)[:, never_ended]
     assert ref.state_gap(got, states[:, never_ended]) < 2e-2
     # another beam's state is another: the gap is the state's own size
-    other = np.stack([np.asarray(s).reshape(4, K, NV, DK, DV)[never_ended, 1] for s in state.beam.state])
+    second = np.asarray(state.source).reshape(4, K)[never_ended, 1]
+    other = np.stack([np.asarray(s)[second] for s in state.at_source["state"]])
     assert ref.state_gap(other, states[:, never_ended]) > 0.1
+
+
+def _searched(params, ctx, K, T, early_exit):
+    def run(params, ctx):
+        search = decoders.search(params, CONFIG, ctx, K, T)
+        result, state = bs.run_search(CONFIG, search.step_fn, search.state0, ctx.shape[0], 1, beam_size=K, max_len=T,
+                                      valid_size=100, early_exit=early_exit, return_steps=True, return_state=True)
+        return search.finish(result, state), state
+
+    return jax.jit(run)(params, ctx)
+
+
+@pytest.mark.parametrize("early_exit", [False, True], ids=["all_steps", "early_exit"])
+def test_the_search_through_the_kernel_is_the_search_through_the_lax_form(params, monkeypatch, early_exit):
+    """The whole search with the state read at its source row by
+    ``ops/gdn_step.py``'s kernel (interpreted) against the same search in
+    ``lax`` (the state gathered by source, then the recurrence): the same
+    words, lengths and routes, scores and final state to rounding; the
+    counter says which form made the updates and how many rows came from
+    another slot."""
+    from sat_tpu.ops import gdn_step as gs
+
+    ctx, _ = _inputs(seed=2, B=4)
+    T, K = 8, 3
+    want, want_state = _searched(params, ctx, K, T, early_exit)
+    monkeypatch.setattr(gs, "FORCE_INTERPRET", True)
+    got, got_state = _searched(params, ctx, K, T, early_exit)
+    steps = int(got.steps_run)
+    assert steps == int(want.steps_run) and (early_exit or steps == T)
+    assert np.array_equal(got.words, want.words) and np.array_equal(got.lengths, want.lengths)
+    assert np.array_equal(got.decoder_stats["step_routes"], want.decoder_stats["step_routes"])
+    assert np.array_equal(got_state.source, want_state.source)
+    np.testing.assert_allclose(got.log_scores, want.log_scores, atol=1e-4)
+    _close(got.decoder_stats["final_state"], want.decoder_stats["final_state"], EXACT_TOL)
+    fold, fold_lax = np.asarray(got.decoder_stats["gdn_fold"]), np.asarray(want.decoder_stats["gdn_fold"])
+    assert fold[:2].tolist() == [3.0 * steps, 3.0 * steps] and fold_lax[:2].tolist() == [0.0, 3.0 * steps]
+    # step 0 names every row its own; from step 1 on every image's beams descend from beam 0 at least
+    assert fold[2] == fold_lax[2] and 4 * (K - 1) <= fold[2] <= 4 * K * (steps - 1)
+    import inspect
+
+    from sat_tpu import runtime
+
+    assert '"decode/lm_gdn_fold_share"' in inspect.getsource(runtime)      # the drain's gauge of the first two
+
+
+def test_the_final_state_is_read_at_the_last_step_s_sources(params):
+    """``final_state`` is live beam 0's AFTER the search's last choice: the
+    state lies where the last step wrote it, and beam 0 of an image
+    descends from the row ``source`` names, which need not be row 0 of the
+    image (read unresolved, another beam's state would come back)."""
+    ctx, _ = _inputs(seed=2, B=4)
+    T, K = 8, 3
+    out, state = _searched(params, ctx, K, T, False)
+    first = np.asarray(state.source).reshape(4, K)[:, 0]
+    assert (first != np.arange(4) * K).any() and (first // K == np.arange(4)).all()
+    final = np.asarray(out.decoder_stats["final_state"])
+    for layer, s in enumerate(state.at_source["state"]):
+        assert np.array_equal(final[:, layer], np.asarray(s)[first])
+        swapped = first != np.arange(4) * K
+        assert not np.allclose(final[swapped, layer], np.asarray(s)[np.arange(4) * K][swapped])
 
 
 def test_the_prefix_s_keys_stay_per_image_and_the_state_is_per_beam(params):
@@ -432,7 +499,8 @@ def test_the_prefix_s_keys_stay_per_image_and_the_state_is_per_beam(params):
 
     def first_logits(params, ctx):
         search = decoders.search(params, CONFIG, ctx, 3, 20)
-        return search.step_fn(search.state0, jnp.zeros((6,), jnp.int32))[1], search.state0.beam
+        state0 = search.state0
+        return search.step_fn(state0, jnp.zeros((6,), jnp.int32))[1], state0.beam._replace(**state0.at_source)
 
     _, beam = jax.jit(first_logits)(params, ctx)
     assert [x.shape for x in beam.state] == [(6, NV, DK, DV)] * 3 and [x.shape for x in beam.keys] == [(6, 20, 32)]
