@@ -44,12 +44,21 @@ share are ``lm_common``'s.
 
 Forms.  A Gated DeltaNet layer over a whole sequence (``prefill``,
 ``teacher_forced``) runs the CHUNKED form of the recurrence in chunks of
-``CHUNK`` = 64 positions, as the public ``torch_chunk_gated_delta_rule``:
-within a chunk the decay-masked ``K_beta K^T`` strictly below the diagonal
-is solved by (blocked) forward substitution for the corrected values and
-keys; between chunks S is carried with the chunk's total decay.  A
-sequence pads to whole chunks with ``beta = 0``, ``g = 0``, ``k = 0``,
-which leave S as it was.  Its products are float32 at ``HIGHEST``.  One
+``gdn_chunk.CHUNK`` = 64 positions, as the public ``torch_chunk_gated_delta_rule``
+(``ops/gdn_chunk.py`` has the equations): within a chunk the decay-masked
+``K_beta K^T`` strictly below the diagonal is solved by (blocked) forward
+substitution for the corrected values and keys; between chunks S is carried
+with the chunk's total decay.  Its products are float32 at ``HIGHEST``, S
+float32, in both of its forms.  ``prefill`` on the TPU (heads of whole lane
+tiles: ``gdn_chunk.takes``) runs it as ONE Pallas kernel a layer, a
+(sequence, key head) a program: q, k and v are read where the conv left
+them (the heads' l2 norms are the kernel's), a chunk's scores, its solve
+and S stay in VMEM, the 196 positions are three chunks of 64 and one of 8.
+Everywhere else it is the ``lax`` form (whole-batch einsums, a scan over
+the chunks; a sequence pads to whole chunks with ``beta = 0``, ``g = 0``,
+``k = 0``, which leave S as it was): every other backend, heads the kernel
+refuses, and ``teacher_forced`` on every backend, because ``train_lm``
+differentiates it and a ``pallas_call`` has no transpose.  One
 token a row (``step``) is the recurrence itself (``ops/gdn_step.py``): S
 float32; its products with k and q are float32 multiplies and sums on the
 vector unit, exact (no matrix unit, no rounding of an operand): ``S^T k``
@@ -87,14 +96,14 @@ import jax
 import jax.numpy as jnp
 
 from ..config import Config
+from ..ops import gdn_chunk as gdn_chunk_op
 from ..ops import gdn_step as gdn_step_op
 from . import lm_common
-from .lm_common import HIGHEST, Params, layer_name, mm
+from .lm_common import Params, layer_name, mm
 from .lm_common import sum_pairs as _sum_pairs
 
 _SUM_EPS = 0.0          # the source divides the chosen scores by their sum, nothing added
 _L2_EPS = 1e-6          # the public Gated DeltaNet layer's, under the root of a head's sum of squares
-CHUNK = 64              # positions a chunk of the chunked rule (a power of two)
 STATE_DTYPE = jnp.float32       # what a row's S is kept in between steps
 # sequences a pass of a whole-sequence forward: 32 x 196 positions x 10
 # choices are 62,720 pairs, the most whose combine ``ops/moe_combine.py``
@@ -138,6 +147,8 @@ class Counters(NamedTuple):
     # [3] float32: state updates (a layer and step) through ``ops/gdn_step.py``'s
     # kernel, state updates in all, rows whose source was another slot
     fold: jnp.ndarray
+    # [2] float32: the prefill's DeltaNet layers through ``ops/gdn_chunk.py``'s kernel, in all
+    chunk: jnp.ndarray
 
 
 def _linear(config: Config, layer: int) -> bool:
@@ -257,92 +268,17 @@ def _gdn_output(m: Params, config: Config, o: jnp.ndarray, z: jnp.ndarray) -> jn
 
 
 # ---------------------------------------------------------------------------
-# Gated DeltaNet over a whole sequence: the chunked rule
+# Gated DeltaNet over a whole sequence: the chunked rule (``ops/gdn_chunk.py``)
 # ---------------------------------------------------------------------------
 
 
-def _mm32(spec: str, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    return jnp.einsum(spec, a, b, precision=HIGHEST, preferred_element_type=jnp.float32)
-
-
-def unit_lower_inverse(a: jnp.ndarray) -> jnp.ndarray:
-    """a [..., C, C] strictly lower triangular (C a power of two) ->
-    ``(I + a)^-1``: forward substitution in blocks.  The diagonal blocks'
-    inverses double in size a level: of ``[[M11, 0], [M21, M22]]`` it is
-    ``[[M11^-1, 0], [-M22^-1 M21 M11^-1, M22^-1]]``."""
-    C = a.shape[-1]
-    lead = a.shape[:-2]
-    inv = jnp.ones(lead + (C, 1, 1), a.dtype)        # 1 x 1 blocks of a unit diagonal
-    s = 1
-    while s < C:
-        n = C // (2 * s)
-        blocks = a.reshape(lead + (n, 2, s, n, 2, s))[..., :, 1, :, :, 0, :]     # [.., n, s, n, s]
-        m21 = jnp.moveaxis(jnp.diagonal(blocks, axis1=-4, axis2=-2), -1, -3)    # [.., n, s, s]
-        inv = inv.reshape(lead + (n, 2, s, s))
-        inv11, inv22 = inv[..., 0, :, :], inv[..., 1, :, :]
-        x21 = -_mm32("...ij,...jk->...ik", _mm32("...ij,...jk->...ik", inv22, m21), inv11)
-        inv = jnp.concatenate([
-            jnp.concatenate([inv11, jnp.zeros_like(inv11)], axis=-1),
-            jnp.concatenate([x21, inv22], axis=-1),
-        ], axis=-2)                                                              # [.., n, 2s, 2s]
-        s *= 2
-    return inv[..., 0, :, :]
-
-
-def chunk_gated_delta_rule(q, k, v, g, beta, state=None):
-    """The recurrence of the module docstring over whole sequences, in
-    chunks of ``CHUNK``: q, k [B, S, nk, dk], v [B, S, nv, dv], g, beta
-    [B, S, nv], float32 (q and k normed, q scaled); ``state`` [B, nv, dk,
-    dv] before position 0 (None: zero) -> (o [B, S, nv, dv], the state
-    after position S - 1), float32.  A key head's products with itself and
-    with its query are taken once for the r value heads it serves."""
-    B, S, nk, dk = q.shape
-    nv, dv = v.shape[2:]
-    r, C = nv // nk, CHUNK
-    pad = -S % C
-    if pad:     # beta = 0, g = 0, k = 0 leave S as it was; q = 0 gives an output nothing reads
-        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) for x in (q, k, v, g, beta))
-    n = (S + pad) // C
-    # [B, key head, (value head of it,) chunk, position, ..]
-    q, k = (jnp.transpose(x.reshape(B, n, C, nk, dk), (0, 3, 1, 2, 4)) for x in (q, k))
-    v = jnp.transpose(v.reshape(B, n, C, nk, r, dv), (0, 3, 4, 1, 2, 5))
-    g, beta = (jnp.transpose(x.reshape(B, n, C, nk, r), (0, 3, 4, 1, 2)) for x in (g, beta))
-    total = jnp.cumsum(g, axis=-1)                                  # a chunk's decay up to each position
-    ahead = jnp.arange(C)[:, None] - jnp.arange(C)[None, :]
-    # exp(total_i - total_j) at j <= i (the exponent masked first: above the diagonal it may be large)
-    decay = jnp.exp(jnp.where(ahead >= 0, total[..., :, None] - total[..., None, :], -jnp.inf))
-    kk = _mm32("bhnid,bhnjd->bhnij", k, k)[:, :, None]              # [B, nk, 1, n, C, C]
-    qk = _mm32("bhnid,bhnjd->bhnij", q, k)[:, :, None]
-    solve = unit_lower_inverse(jnp.where(ahead > 0, beta[..., None] * kk * decay, 0.0))
-    value = _mm32("bhrnij,bhrnjd->bhrnid", solve, v * beta[..., None])            # the corrected values
-    k_decayed = _mm32("bhrnij,bhrnjd->bhrnid", solve, k[:, :, None] * (beta * jnp.exp(total))[..., None])
-    within = qk * decay                                             # a chunk's own scores, the diagonal in
-    q_in = q[:, :, None] * jnp.exp(total)[..., None]                # [B, nk, r, n, C, dk]
-    k_out = k[:, :, None] * jnp.exp(total[..., -1:] - total)[..., None]
-    last = jnp.exp(total[..., -1])                                  # [B, nk, r, n]: a chunk's total decay
-
-    def one_chunk(s, xs):
-        value_i, k_decayed_i, within_i, q_in_i, k_out_i, last_i = xs
-        v_new = value_i - _mm32("bhrik,bhrkd->bhrid", k_decayed_i, s)
-        o = _mm32("bhrik,bhrkd->bhrid", q_in_i, s) + _mm32("bhrij,bhrjd->bhrid", within_i, v_new)
-        s = s * last_i[..., None, None] + _mm32("bhrik,bhrid->bhrkd", k_out_i, v_new)
-        return s, o
-
-    s0 = (
-        jnp.zeros((B, nk, r, dk, dv), jnp.float32) if state is None
-        else state.astype(jnp.float32).reshape(B, nk, r, dk, dv)
-    )
-    chunks = tuple(jnp.moveaxis(x, 3, 0) for x in (value, k_decayed, within, q_in, k_out, last))
-    s, o = jax.lax.scan(one_chunk, s0, chunks)                      # o [n, B, nk, r, C, dv]
-    o = jnp.transpose(o, (1, 0, 4, 2, 3, 5)).reshape(B, n * C, nv, dv)[:, :S]
-    return o, s.reshape(B, nv, dk, dv)
-
-
-def gdn_sequence(m: Params, config: Config, u: jnp.ndarray):
+def gdn_sequence(m: Params, config: Config, u: jnp.ndarray, fused: bool = False):
     """A Gated DeltaNet mixer over whole sequences u [B, S, H] (normed,
     positions 0..S-1) -> (its output [B, S, H], the state after the last
     position [B, nv, dk, dv] ``STATE_DTYPE``, the last L - 1 positions of
-    what goes through the conv [B, L - 1, conv width])."""
+    what goes through the conv [B, L - 1, conv width]).  ``fused``: the
+    chunked rule in ``ops/gdn_chunk.py``'s kernel (the caller has asked
+    ``takes``), else in ``lax``."""
     c = config
     L = c.linear_conv_kernel_dim
     S = u.shape[1]
@@ -352,9 +288,16 @@ def gdn_sequence(m: Params, config: Config, u: jnp.ndarray):
         conv = jax.nn.silu(sum(
             padded[:, j:j + S].astype(jnp.float32) * m["conv1d"][j].astype(jnp.float32) for j in range(L)
         ))
-    q, k, v = _gdn_heads(c, conv)
-    with jax.named_scope("decoder/lm/attn/gdn/scan"):
-        o, state = chunk_gated_delta_rule(q, k, v, g, beta)
+    if fused:       # the heads' l2 norms are the kernel's: it reads q, k and v where the conv left them
+        with jax.named_scope("decoder/lm/attn/gdn/scan"):
+            o, state = gdn_chunk_op.gdn_chunk_kernel(
+                conv, g, beta, heads=_gdn_dims(c)[:4], eps=_L2_EPS, dtype=STATE_DTYPE,
+                interpret=jax.default_backend() != "tpu",
+            )
+    else:
+        q, k, v = _gdn_heads(c, conv)
+        with jax.named_scope("decoder/lm/attn/gdn/scan"):
+            o, state = gdn_chunk_op.gdn_chunk_lax(q, k, v, g, beta)
     return _gdn_output(m, c, o, z), state.astype(STATE_DTYPE), padded[:, S:]
 
 
@@ -507,18 +450,18 @@ def _add(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
         return x + y
 
 
-def sequence_forward(lm: Params, config: Config, x: jnp.ndarray):
+def sequence_forward(lm: Params, config: Config, x: jnp.ndarray, fused: bool = False):
     """x [B, S, H] bfloat16 at positions 0..S-1 -> (hidden after the last
     layer [B, S, H], the sequences' ``HybridCache`` (a DeltaNet layer's
     final state and taps, a full layer's keys and values of every
     position), tokens per expert [layers, E], experts chosen
     [B, S, layers * k], pairs [6]), ``SEQUENCE_BLOCK`` sequences at a time
-    where B is whole blocks of them."""
+    where B is whole blocks of them.  ``fused``: ``gdn_sequence``'s."""
     B = x.shape[0]
     if B <= SEQUENCE_BLOCK or B % SEQUENCE_BLOCK:
-        return _sequence_block(lm, config, x)
+        return _sequence_block(lm, config, x, fused)
     blocks = x.reshape((B // SEQUENCE_BLOCK, SEQUENCE_BLOCK) + x.shape[1:])
-    hidden, cache, counts, routes, pairs = jax.lax.map(lambda one: _sequence_block(lm, config, one), blocks)
+    hidden, cache, counts, routes, pairs = jax.lax.map(lambda one: _sequence_block(lm, config, one, fused), blocks)
     whole = lambda y: y.reshape((B,) + y.shape[2:])  # noqa: E731
     return (
         whole(hidden), jax.tree_util.tree_map(whole, cache), jnp.sum(counts, axis=0), whole(routes),
@@ -526,7 +469,7 @@ def sequence_forward(lm: Params, config: Config, x: jnp.ndarray):
     )
 
 
-def _sequence_block(lm: Params, config: Config, x: jnp.ndarray):
+def _sequence_block(lm: Params, config: Config, x: jnp.ndarray, fused: bool):
     """``sequence_forward`` over one block of sequences x [b, S, H]."""
     c = config
     B, S, _ = x.shape
@@ -535,7 +478,7 @@ def _sequence_block(lm: Params, config: Config, x: jnp.ndarray):
         p = lm["layers"][layer_name(i)]
         u = _mixer_input(p, c, x)
         if _linear(c, i):
-            y, s, taps = gdn_sequence(p["linear_attn"], c, u)
+            y, s, taps = gdn_sequence(p["linear_attn"], c, u, fused)
             state.append(s), conv.append(taps)
         else:
             y, k, v = attend_sequence(p["self_attn"], c, u)
@@ -557,7 +500,8 @@ def teacher_forced(
     params: Params, config: Config, contexts: jnp.ndarray, sentences: jnp.ndarray,
 ) -> jnp.ndarray:
     """logits [B, T, V]: the input at caption step t is sentences[:, t-1]
-    (``<start>`` = 0 at t = 0), after the N prefix positions."""
+    (``<start>`` = 0 at t = 0), after the N prefix positions.  The chunked
+    rule stays in ``lax`` here on every backend: it is differentiated."""
     lm = params["lm"]
     N = contexts.shape[1]
     x = lm_common.sequence_inputs(params, contexts, sentences)
@@ -569,11 +513,16 @@ def prefill(params: Params, config: Config, contexts: jnp.ndarray):
     """The N prefix positions of each image, once: (the prefix's
     ``HybridCache`` over ``[B, ...]`` rows, (tokens per expert, pairs) for
     ``init_counters``, the experts every position chose
-    [B, N, layers * k])."""
+    [B, N, layers * k]).  The DeltaNet layers' chunked rule takes
+    ``ops/gdn_chunk.py``'s kernel where there is one (the TPU, these
+    heads) and the ``lax`` form elsewhere."""
+    nk, nv, dk, dv, _, _ = _gdn_dims(config)
+    fused = gdn_chunk_op.takes(contexts.shape[1], nk, nv, dk, dv)
     _, cache, counts, routes, pairs = sequence_forward(
-        params["lm"], config, lm_common.prefix(params, contexts)
+        params["lm"], config, lm_common.prefix(params, contexts), fused
     )
-    return cache, (counts, pairs), routes
+    layers = len(cache.state)
+    return cache, (counts, pairs, jnp.array([layers * fused, layers], jnp.float32)), routes
 
 
 # ---------------------------------------------------------------------------
@@ -583,9 +532,11 @@ def prefill(params: Params, config: Config, contexts: jnp.ndarray):
 
 def init_counters(prefill_counts, max_len: int) -> Counters:
     """Step 0's counters, the prefill's counts already in."""
-    counts, pairs = prefill_counts
+    counts, pairs, chunk = prefill_counts
     base = lm_common.init_counters(counts, max_len)
-    return Counters(*base, pairs=jnp.stack([pairs, jnp.zeros_like(pairs)]), fold=jnp.zeros((3,), jnp.float32))
+    return Counters(
+        *base, pairs=jnp.stack([pairs, jnp.zeros_like(pairs)]), fold=jnp.zeros((3,), jnp.float32), chunk=chunk,
+    )
 
 
 def start_beams(config: Config, prefix: HybridCache, K: int, max_len: int, tile) -> HybridCache:
@@ -648,6 +599,7 @@ def step(
     counters = Counters(
         *base, pairs=counters.pairs.at[1].add(_sum_pairs(held)),
         fold=counters.fold + jnp.stack([jnp.float32(sum(fused)), jnp.float32(len(fused)), moved.astype(jnp.float32)]),
+        chunk=counters.chunk,
     )
     cache = HybridCache(tuple(state), tuple(conv), tuple(keys), tuple(values), taken, rows)
     return cache, counters, _head(lm, c, x)
@@ -690,4 +642,7 @@ def report(config: Config, prefix: HybridCache, state, B: int, K: int, T: int) -
         # updates in all (a layer and step), rows whose source was another
         # slot] over the steps
         "gdn_fold": state.shared.fold,
+        # [the prefill's DeltaNet layers whose chunked rule ran in
+        # ``ops/gdn_chunk.py``'s kernel, DeltaNet layers in all]
+        "gdn_chunk": state.shared.chunk,
     }

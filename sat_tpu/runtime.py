@@ -1507,6 +1507,11 @@ def decode_dataset(
                 # 0.0 where the lax form gathered the state first)
                 fused, updates, _ = np.asarray(out.decoder_stats["gdn_fold"], np.float64)  # sync-ok: decode drain boundary
                 tel.gauge("decode/lm_gdn_fold_share", float(fused / max(updates, 1.0)))  # sync-ok: host numpy, already drained
+                # of the prefill's DeltaNet layers, those whose chunked rule
+                # ran in ops/gdn_chunk.py's kernel (1.0 on the chip, 0.0
+                # where the lax form ran)
+                fused, layers = np.asarray(out.decoder_stats["gdn_chunk"], np.float64)  # sync-ok: decode drain boundary
+                tel.gauge("decode/lm_gdn_chunk_share", float(fused / max(layers, 1.0)))  # sync-ok: host numpy, already drained
         occupancy.observe()
         occupancy.publish()
         with tel.span("decode/drain/detok", b):  # host work after it

@@ -814,6 +814,16 @@ def test_qwen3_next_beam_program_fits_the_chip_and_updates_its_state_in_place(mo
     assert not [s for s in shapes if re.search(r"\[250880,(10,)?2048\]", s)], shapes
     assert len(re.findall(r"decoder/lm/moe/experts[^\n]*tpu_custom_call|"
                           r"tpu_custom_call[^\n]*decoder/lm/moe/experts", text)) == 21
+    # the prefill's chunked rule: ``ops/gdn_chunk.py``'s kernel, once a DeltaNet layer inside the pass over 32
+    # images, under the scope the benchmark reads; q, k and v are read where the conv left them (ONE operand,
+    # three times) and no chunk-local matrix of the ``lax`` form is left in the program
+    chunks = [ln for ln in lines if " custom-call(" in ln and "gdn_chunk" in ln]
+    assert len(chunks) == 3, chunks
+    assert all(re.search(r"beam/prefill.*decoder/lm/attn/gdn/scan", ln) for ln in chunks), chunks
+    for ln in chunks:
+        operands = re.search(r"custom-call\(([^)]*)\)", ln).group(1).split(", ")
+        assert operands[0] == operands[1] == operands[2] and "f32[32,196,8192]" in ln, ln
+    assert not [s for s in shapes if re.search(r"\[32,16,(2,)?4,64,(64|128)\]", s)], shapes
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > int(7.3e9)
     held = 3 * B * K * 32 * 128 * 128 * 4
@@ -843,6 +853,32 @@ def test_the_state_s_kernel_compiles_in_place_at_the_published_widths(dtype):
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 1
     assert "output_to_operand_aliasing={{0}: (6, {})}" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("state", [False, True], ids=["from_zero", "from_a_state"])
+def test_the_chunked_rule_s_kernel_compiles_at_the_published_widths(state):
+    """``ops/gdn_chunk.py`` alone at the cell's pass (32 images of 196
+    positions: three chunks and a fourth of 8 whose block reaches past the
+    array's end; 16 key / 32 value heads of 128 x 128): Mosaic takes the
+    three blocks out of the ONE ``[32, 196, 8192]`` array the conv leaves,
+    the float32 products at ``HIGHEST`` (one with its left operand turned),
+    the ``[128, 128]`` transpose and the masked sums of the solve; nothing
+    around the call but the gates' rows (``[32, 196, 32]`` summed and turned:
+    2 MB) and the output, as the kernel writes it (``[.., 4096]``, 105 MB)
+    and turned to heads (as much again)."""
+    from sat_tpu.ops import gdn_chunk
+
+    B, S, nk, nv, dk, dv = 32, 196, 16, 32, 128, 128
+    args = [_sd((B, S, 2 * nk * dk + nv * dv)), _sd((B, S, nv)), _sd((B, S, nv))] + [_sd((B, nv, dk, dv))] * state
+    compiled = jax.jit(
+        lambda *xs: gdn_chunk.gdn_chunk_kernel(*xs, heads=(nk, nv, dk, dv), eps=1e-6)
+    ).lower(*args).compile()
+    text = compiled.as_text()
+    call, = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " custom-call(" in ln]
+    operands = re.search(r"custom-call\(([^)]*)\)", call).group(1).split(", ")
+    # q, k and v out of one array; the gates' rows of the whole chunks and of the short last one; the state
+    assert len(operands) == 5 + state and operands[0] == operands[1] == operands[2], call
+    assert compiled.memory_analysis().temp_size_in_bytes < 216 << 20
 
 
 @pytest.mark.parametrize("V", [65536, 128256], ids=["lfm2", "kanana2"])

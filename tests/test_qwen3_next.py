@@ -36,6 +36,7 @@ from reference.params import nest  # noqa: E402
 from sat_tpu.config import Config  # noqa: E402
 from sat_tpu.models import decoders, lm_common  # noqa: E402
 from sat_tpu.models import qwen3_next as qn  # noqa: E402
+from sat_tpu.ops import gdn_chunk  # noqa: E402
 
 from test_glm_moe_dsa import FORWARD_TOL, LAYER_TOL, PATH_TOL, _close  # noqa: E402
 
@@ -192,7 +193,7 @@ def test_the_blocked_forward_substitution_inverts_a_unit_lower_triangle(C):
     rng = np.random.default_rng(C)
     a = np.tril(rng.normal(size=(3, 2, C, C)), -1).astype(np.float32) * 0.4
     want = np.linalg.inv(np.eye(C) + a.astype(np.float64))
-    _close(qn.unit_lower_inverse(jnp.asarray(a)), want, EXACT_TOL)
+    _close(gdn_chunk.unit_lower_inverse(jnp.asarray(a)), want, EXACT_TOL)
 
 
 def _recurrence(q, k, v, g, beta):
@@ -227,7 +228,7 @@ def test_the_chunked_rule_is_the_recurrence(S, nk, nv):
     which leave S as it was; a key head's products serve its nv / nk value
     heads."""
     inputs = _rule_inputs(S, nk, nv, seed=S)
-    o, state = jax.jit(qn.chunk_gated_delta_rule)(*inputs)
+    o, state = jax.jit(gdn_chunk.gdn_chunk_lax)(*inputs)
     want_o, want_state = _recurrence(*inputs)
     assert o.shape == (2, S, nv, 8) and state.shape == (2, nv, 16, 8)
     _close(o, want_o, EXACT_TOL)
@@ -236,7 +237,7 @@ def test_the_chunked_rule_is_the_recurrence(S, nk, nv):
 
 def test_the_chunked_rule_goes_on_from_a_state():
     inputs = _rule_inputs(100, 2, 6, seed=3)
-    rule = jax.jit(qn.chunk_gated_delta_rule)
+    rule = jax.jit(gdn_chunk.gdn_chunk_lax)
     whole_o, whole_state = rule(*inputs)
     _, first = rule(*(x[:, :37] for x in inputs))
     rest_o, rest = rule(*(x[:, 37:] for x in inputs), state=first)
@@ -378,7 +379,7 @@ def test_the_reorder_swaps_a_beam_s_taps_keys_and_values_and_names_its_state_s_s
                            values=(leaf(5, 32),), routes=leaf(30))
     shared = qn.Counters(t=jnp.int32(7), moe_counts=jnp.arange(8).reshape(2, 4),
                          step_visits=jnp.arange(10).reshape(2, 5), pairs=jnp.arange(12).reshape(2, 6),
-                         fold=jnp.zeros((3,)))
+                         fold=jnp.zeros((3,)), chunk=jnp.zeros((2,)))
     held = {"state": (leaf(NV, DK, DV),) * 3}
     parent = jnp.array([[2, 0, 1], [1, 1, 0]])
     moved = bs._reorder_beams(
@@ -416,6 +417,7 @@ def test_the_search_serves_what_the_reference_scores_and_hands_back_its_state(pa
     live = np.asarray(state.at_source["state"][0]).reshape(4, K, -1)
     assert not np.allclose(live[:, 0], live[:, 1])
     assert state.beam.state is None and np.asarray(stats["gdn_fold"]).tolist()[:2] == [0.0, 3.0 * T]
+    assert np.asarray(stats["gdn_chunk"]).tolist() == [0.0, 3.0]       # off the TPU the prefill's rule is ``lax``
     words, lengths = np.asarray(out.words[:, 0]), np.asarray(out.lengths[:, 0])
     logits, _, states = _reference(weights, ctx, words)
     logp = jax.nn.log_softmax(logits, axis=-1)
@@ -475,6 +477,41 @@ def test_the_search_through_the_kernel_is_the_search_through_the_lax_form(params
     from sat_tpu import runtime
 
     assert '"decode/lm_gdn_fold_share"' in inspect.getsource(runtime)      # the drain's gauge of the first two
+
+
+def test_the_prefill_takes_the_chunk_s_kernel_and_teacher_forcing_keeps_the_lax_form(params, monkeypatch):
+    """Under the tests' hook ``prefill`` runs every DeltaNet layer's chunked
+    rule in ``ops/gdn_chunk.py``'s kernel (interpreted) and says so; its
+    cache is the ``lax`` form's: the first layer's state to float32
+    rounding (its inputs are the same numbers), the layers above it to two
+    paths of the program (a bfloat16 stream carries the rounding on).
+    ``teacher_forced`` is differentiated and keeps ``lax`` whatever the
+    hook says; without the hook the counter reads 0."""
+    ctx, tokens = _inputs(seed=4, B=2, T=5)
+    prefill = lambda: jax.jit(lambda p, c: qn.prefill(p, CONFIG, c))(params, ctx)  # noqa: E731
+    want, (_, _, chunk), _ = prefill()
+    assert np.asarray(chunk).tolist() == [0.0, 3.0]
+    forced = lambda p: qn.teacher_forced(p, CONFIG, ctx, tokens)  # noqa: E731
+    monkeypatch.setattr(gdn_chunk, "FORCE_INTERPRET", True)
+    got, (_, _, chunk), _ = prefill()
+    assert np.asarray(chunk).tolist() == [3.0, 3.0]
+    assert got.state[0].dtype == qn.STATE_DTYPE and got.state[0].shape == (2, NV, DK, DV)
+    _close(got.state[0], want.state[0], EXACT_TOL)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        _close(a, b, PATH_TOL)
+    assert "pallas_call" in str(jax.make_jaxpr(lambda p: qn.prefill(p, CONFIG, ctx))(params))
+    assert "pallas_call" not in str(jax.make_jaxpr(forced)(params))
+    grads = jax.jit(jax.grad(lambda p: jnp.sum(forced(p) ** 2)))(params)
+    assert all(np.isfinite(np.asarray(g, np.float32)).all() for g in jax.tree_util.tree_leaves(grads))
+    assert float(jnp.abs(grads["lm"]["layers"]["00"]["linear_attn"]["in_proj_qkvz"].astype(jnp.float32)).max()) > 0
+    # the search hands the counter on, and the drain turns it into a gauge
+    out, _ = _searched(params, ctx, 3, 2, early_exit=False)
+    assert np.asarray(out.decoder_stats["gdn_chunk"]).tolist() == [3.0, 3.0]
+    import inspect
+
+    from sat_tpu import runtime
+
+    assert '"decode/lm_gdn_chunk_share"' in inspect.getsource(runtime)
 
 
 def test_the_final_state_is_read_at_the_last_step_s_sources(params):
